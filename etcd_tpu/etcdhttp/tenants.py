@@ -633,6 +633,13 @@ class TenantAPI:
             "acked_requests": eng.acked_requests,
             "pending_payloads": len(eng.payloads),
         }
+        # Where the state lives (platform, device_kind, device_count,
+        # per-device rows) and the peer_mask watchdog's repair count, so
+        # a client can check the device without touching JAX itself.
+        info = getattr(eng, "device_info", None)
+        if info is not None:
+            out.update(info())
+            out["mask_repairs"] = eng.mask_repairs
         # Multi-host engines expose their catch-up counters too.
         for k in ("pulls_sent", "payloads_pulled", "pay_frames_dropped",
                   "snaps_sent", "snaps_installed"):

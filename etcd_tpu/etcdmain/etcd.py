@@ -100,17 +100,32 @@ class EngineServer:
     batched kernel at /tenants/{g}/v2/keys (docs/deployment.md §2)."""
 
     def __init__(self, cfg: MainConfig) -> None:
+        import jax
+
         from etcd_tpu.etcdhttp.tenants import EngineHttp
         from etcd_tpu.server.engine import EngineConfig, MultiEngine
+        from etcd_tpu.utils.platform import enable_compile_cache
 
+        # The process entry owns the compile cache: a served member must
+        # not recompile its step variants on every boot, and must not
+        # need a script's help to avoid it.
+        cache_dir = enable_compile_cache()
+        devs = jax.devices()
+        n = len(devs)
+        log.info("engine: %d %s device(s) (%s); compile cache %s",
+                 n, devs[0].platform, devs[0].device_kind, cache_dir)
         mesh = None
         if cfg.engine_mesh_peers_axis > 0:
-            import jax
             from etcd_tpu.parallel.mesh import make_mesh
-            n = len(jax.devices())
             pa = cfg.engine_mesh_peers_axis
             # Fail with a flag-level message, not an opaque sharding error
             # from deep inside device placement.
+            if n < 2:
+                raise ConfigError(
+                    f"-engine-mesh-peers-axis {pa} asks for a device mesh "
+                    f"but only {n} {devs[0].platform} device is visible "
+                    "(a 1x1 mesh shards nothing); drop the flag to run "
+                    "on one device")
             if n % pa != 0:
                 raise ConfigError(
                     f"-engine-mesh-peers-axis {pa} does not divide the "
@@ -124,7 +139,7 @@ class EngineServer:
                     f"-engine-groups {cfg.engine_groups} must be "
                     f"divisible by the groups mesh axis ({n // pa} = "
                     f"{n} devices / peers-axis {pa})")
-            mesh = make_mesh(jax.devices(), peers_axis=pa)
+            mesh = make_mesh(devs, peers_axis=pa)
             log.info("engine: sharding over mesh %s",
                      dict(zip(mesh.axis_names, mesh.devices.shape)))
         self.engine = MultiEngine(EngineConfig(
@@ -275,6 +290,16 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
                  which, which)
 
     stop_ev = threading.Event()
+
+    def wait_for_stop() -> None:
+        # A TIMED wait: the kernel may hand SIGTERM to any thread (JAX
+        # starts dozens), CPython only runs the handler on the main
+        # thread, and an untimed Event.wait() there is woken by nothing
+        # else — the member then ignores SIGTERM for good (seen ~1 in 7
+        # boots under load). Waking twice a second lets it run.
+        while not stop_ev.wait(0.5):
+            pass
+
     for sig in (signal.SIGINT, signal.SIGTERM):
         try:
             signal.signal(sig, lambda *_: stop_ev.set())
@@ -304,7 +329,7 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
             return 1
         runner.start()
         try:
-            stop_ev.wait()
+            wait_for_stop()
         finally:
             runner.stop()
         return 0
@@ -328,7 +353,7 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
         runner.start()
 
     try:
-        stop_ev.wait()
+        wait_for_stop()
     finally:
         runner.stop()
     return 0

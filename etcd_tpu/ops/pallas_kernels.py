@@ -28,8 +28,12 @@ pallas_call boundary blocks XLA from fusing the resolve into the
 surrounding message-assembly ops, so every call site pays HBM
 round-trips for operands the fused program never materializes. The
 jnp path stays production; do not give this a call site without
-beating scripts/pallas_roundbench.py first. On CPU it runs in
-interpret mode (tests pin its windowed-resolve semantics).
+beating scripts/pallas_roundbench.py first. `interpret` is the
+caller's explicit choice: the tests pass True (they pin the
+windowed-resolve semantics on the CPU), a measurement passes False and
+gets the Mosaic kernel or the compiler's refusal — never a quiet switch
+to the interpreter. tests/test_tpu_compile.py compiles it for a
+described v5e at serving widths.
 """
 from __future__ import annotations
 
@@ -59,8 +63,8 @@ def _resolve_block(ring_ref, idx_ref, last_ref, out_ref, *, W: int):
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
 def ring_resolve(ring: jax.Array, idx: jax.Array, last: jax.Array,
-                 block_rows: int = 512,
-                 interpret: bool | None = None) -> jax.Array:
+                 block_rows: int = 512, *,
+                 interpret: bool) -> jax.Array:
     """Pallas version of the windowed ring term resolve.
 
     ring: (G, P, W) int32 entry terms (entry i at slot i % W)
@@ -70,8 +74,6 @@ def ring_resolve(ring: jax.Array, idx: jax.Array, last: jax.Array,
     """
     from jax.experimental import pallas as pl
 
-    if interpret is None:
-        interpret = jax.devices()[0].platform != "tpu"
     G, P, W = ring.shape
     trailing = idx.shape[2:]
     R = G * P

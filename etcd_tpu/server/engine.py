@@ -48,6 +48,7 @@ ring window, host snapshot-install beyond it).
 """
 from __future__ import annotations
 
+import functools
 import json
 import logging
 import queue
@@ -120,6 +121,15 @@ def _unpack_multi(payload: bytes) -> List[bytes]:
         blobs.append(payload[off:off + ln])
         off += ln
     return blobs
+
+
+def _named_partial(fn, *args, **kw):
+    """functools.partial that keeps fn's name: jax.jit names its program
+    (profiler traces, compile-cache entries) after __name__, and a bare
+    partial has none — the mesh steps would all be `jit__unknown`."""
+    p = functools.partial(fn, *args, **kw)
+    p.__name__ = fn.__name__
+    return p
 
 
 class EngineViolation(RuntimeError):
@@ -233,13 +243,13 @@ class EngineConfig:
     # diff is computed ON DEVICE and the host reads back a (G, P) uint8
     # flag map plus values for only the rows that changed, instead of
     # the full O(G*P*W) state every round (32 MB of ring alone at
-    # G=100k — the term that dominates ack latency when the device is
-    # behind a network tunnel). Rounds that change more rows than
-    # compact_cap — or that raise need_host — fall back to the full
-    # readback, so saturated throughput is untouched. None = auto
-    # (enabled when mesh is None); the mesh path keeps full readback
-    # (its readback is sharded-resident and the flag map would need its
-    # own out_sharding).
+    # G=100k; what that costs per round on the chip is not measured).
+    # Rounds that change more rows than compact_cap — or that raise
+    # need_host — fall back to the full readback, so saturated
+    # throughput is untouched. None = auto (enabled when mesh is None);
+    # the mesh path keeps full readback (its readback is
+    # sharded-resident and the flag map would need its own
+    # out_sharding).
     compact_readback: Optional[bool] = None
     # Max changed+staged rows served by the gather path before a round
     # falls back to full readback. 0 = auto: max(2048, G*P//8).
@@ -347,7 +357,6 @@ class MultiEngine:
         # "peers" mesh axis — the ICI transport of SURVEY §2.4).
         self._st_sh = self._mb_sh = None
         if cfg.mesh is not None:
-            import functools
             from etcd_tpu.parallel.mesh import (mailbox_sharding,
                                                 state_sharding)
             self._st_sh = state_sharding(cfg.mesh)
@@ -362,8 +371,8 @@ class MultiEngine:
             # auto+hops program as the single-device engine (drop mask
             # riding into the kernel, cut per hop).
             _mesh_step = jax.jit(
-                functools.partial(kernel.step_routed_auto.__wrapped__,
-                                  self.kcfg, hops=cfg.hops),
+                _named_partial(kernel.step_routed_auto.__wrapped__,
+                               self.kcfg, hops=cfg.hops),
                 donate_argnums=kernel.donate_safe((0, 1)),
                 out_shardings=(self._st_sh, self._mb_sh))
             self._step_fn = (
@@ -414,12 +423,11 @@ class MultiEngine:
         # shardings; the non-mesh path rides step_variant (CPU donation
         # hazard twin, same as the other kernels).
         if cfg.mesh is not None:
-            import functools
             from jax.sharding import NamedSharding, PartitionSpec
             _g_sh = NamedSharding(cfg.mesh, PartitionSpec("groups"))
             _mesh_read = jax.jit(
-                functools.partial(kernel.step_routed_read_auto.__wrapped__,
-                                  self.kcfg, hops=cfg.hops),
+                _named_partial(kernel.step_routed_read_auto.__wrapped__,
+                               self.kcfg, hops=cfg.hops),
                 donate_argnums=kernel.donate_safe((0, 1)),
                 out_shardings=(self._st_sh, self._mb_sh, _g_sh, _g_sh))
             self._step_fn_r = (
@@ -1602,6 +1610,43 @@ class MultiEngine:
             "applied": int(self.applied[g]),
             "active_slots": [int(s) for s in np.nonzero(self.h_mask[g])[0]],
         }
+
+    def device_info(self) -> dict:
+        """Where the kernel state lives, for /engine/status: the backend
+        as JAX reports it, and per device the group rows it holds of the
+        state arrays and the inbox (one int where every array agrees —
+        G on one device, G/n on each device of a groups-sharded mesh — a
+        sorted list where they differ). Read from the live buffers
+        (addressable_shards), not from the requested sharding."""
+        devs = self._jax.devices()
+        for _ in range(100):
+            try:
+                rows: Dict[int, set] = {}
+                for a in (*self.st, self.inbox):
+                    for sh in a.addressable_shards:
+                        rows.setdefault(sh.device.id, set()).add(
+                            sh.data.shape[0])
+                break
+            except RuntimeError:
+                # The round thread donated these buffers to the step
+                # between our read of self.st and the shard walk.
+                time.sleep(0.001)
+        else:
+            raise RuntimeError("engine state buffers stayed deleted")
+        info = {
+            "platform": devs[0].platform,
+            "device_kind": devs[0].device_kind,
+            "device_count": len(devs),
+            "device_rows": {
+                str(d): (r.pop() if len(r) == 1 else sorted(r))
+                for d, r in sorted(rows.items())},
+        }
+        # memory_stats() is None where the backend keeps none (cpu).
+        peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use")
+                 for d in devs]
+        if None not in peaks:
+            info["device_peak_bytes"] = max(peaks)
+        return info
 
     def profile(self, rounds: int = 20, out_dir: Optional[str] = None) -> str:
         """Capture an XLA/device profile of `rounds` engine rounds (the

@@ -86,6 +86,8 @@ class Agent:
                 "--snapshot-count", str(self.snapshot_count)]
 
     def start(self) -> None:
+        # Classic members never use the device (only engine mode steps
+        # the batched kernel); the CPU pin keeps N of them off the chip.
         env = dict(os.environ, PYTHONPATH=os.pathsep.join(
             [p for p in (os.environ.get("PYTHONPATH"),
                          os.path.dirname(os.path.dirname(

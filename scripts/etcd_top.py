@@ -19,6 +19,9 @@ estimate `histogram_quantile()` gives.
 Usage:
     python scripts/etcd_top.py http://127.0.0.1:2379 [--interval 2] [-n N]
 
+The device the engine runs on (platform, device_kind, device_count,
+device_rows) and mask_repairs are not series: read GET /engine/status.
+
 `--once` (or -n) renders N frames then exits (testable / scriptable);
 default runs until Ctrl-C. No dependencies beyond the stdlib.
 """

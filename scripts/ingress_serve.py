@@ -71,9 +71,11 @@ def main() -> int:
     ap.add_argument("--read-lease-ms", type=int, default=0)
     args = ap.parse_args()
 
+    # The engine child inherits the platform: on a TPU host it is the
+    # one process that owns the chip (this launcher and the ingress
+    # processes never import JAX).
     env = dict(os.environ, PYTHONPATH=os.path.dirname(
-        os.path.dirname(os.path.abspath(__file__))),
-        JAX_PLATFORMS="cpu")
+        os.path.dirname(os.path.abspath(__file__))))
     env.pop("XLA_FLAGS", None)
 
     procs = []

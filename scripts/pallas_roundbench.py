@@ -13,7 +13,8 @@ or patched to the Pallas kernel, same seed and schedule:
     python scripts/pallas_roundbench.py pallas [G] [hops]
 
 Run each mode in its own process (the jit caches would otherwise key on
-the same outer callables).
+the same outer callables). The pallas mode compiles the Mosaic kernel
+(interpret=False): it is a TPU measurement and fails on any other backend.
 """
 import functools
 import sys
@@ -42,7 +43,8 @@ def main() -> int:
         from etcd_tpu.ops.pallas_kernels import ring_resolve
 
         def terms_at_many_pallas(st, cfg, idx):
-            return ring_resolve(st.log_term, idx, st.last_index)
+            return ring_resolve(st.log_term, idx, st.last_index,
+                                interpret=False)
 
         kernel._terms_at_many = terms_at_many_pallas
 

@@ -32,6 +32,11 @@ exploits multiple cores without paying the router's process split, and
 a sharded pool multiplies all three (M x K appliers, M x S fsync
 streams — the aggregate scale curve in BENCH_r06.json).
 
+A CPU harness: a chip belongs to one process, so K shard processes
+cannot share one, and each shard is pinned to the CPU backend below. On
+a TPU host the multi-device deployment is ONE member over a device mesh
+(`python -m etcd_tpu --engine-mesh-peers-axis 1`), not this launcher.
+
 Usage:
     python scripts/pool_serve.py --groups 16 --shards 2 --port 0 \
         --data-dir /tmp/pool [--applier-shards 4] [--wal-shards 4]
@@ -206,6 +211,7 @@ def main() -> int:
 
     procs = []
     for k in range(K):
+        # CPU by design (module docstring): K processes, no shared chip.
         env = dict(os.environ, PYTHONPATH=os.path.dirname(
             os.path.dirname(os.path.abspath(__file__))),
             JAX_PLATFORMS="cpu")

@@ -4,7 +4,10 @@ Replicates bench.py's engine scenario load shape (pending queues topped to
 max_ents per group each round) and prints the per-phase share of the round
 plus a micro-breakdown of the apply path.
 
-Usage: JAX_PLATFORMS=cpu python scripts/profile_engine.py [G] [rounds]
+Runs on the platform JAX finds and prints it with the result; a CPU
+profile is asked for by name:
+
+Usage: [JAX_PLATFORMS=cpu] python scripts/profile_engine.py [G] [rounds]
 """
 import os
 import sys
@@ -13,10 +16,8 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from etcd_tpu.utils.platform import enable_compile_cache, force_cpu  # noqa: E402
+from etcd_tpu.utils.platform import enable_compile_cache  # noqa: E402
 
-if os.environ.get("PROFILE_TPU") != "1":
-    force_cpu(1)
 enable_compile_cache()
 
 import numpy as np  # noqa: E402
@@ -67,7 +68,10 @@ def main():
         acked = eng.acked_requests - a0
 
         total_ms = 1000.0 * elapsed / n_rounds
-        print(f"\nG={G} P={P} E={E} fsync=on: {n_rounds} rounds, "
+        import jax
+        d = jax.devices()[0]
+        print(f"\nplatform={d.platform} ({d.device_kind})")
+        print(f"G={G} P={P} E={E} fsync=on: {n_rounds} rounds, "
               f"{total_ms:.2f} ms/round, {acked/elapsed:,.0f} acked "
               f"writes/s")
         ph = dict(eng.phase_s)

@@ -19,6 +19,10 @@ capture; the curve only rises where real cores back the shards. The
 output carries cores_visible so a reader can tell which regime a point
 was measured in.
 
+A CPU harness, like pool_serve.py: M worker processes cannot share one
+chip, so every worker is pinned to the CPU backend and the curve is a
+statement about host cores only — never about the TPU.
+
 Usage:
     python scripts/scale_curve.py --groups 2048 --pool-shards 1,2,4 \
         --applier-shards 2 --wal-shards 2 --seconds 20
@@ -118,6 +122,7 @@ def run_point(M, args):
     per = args.groups // M
     procs = []
     for _ in range(M):
+        # CPU by design (module docstring): M processes, no shared chip.
         env = dict(os.environ, PYTHONPATH=REPO, JAX_PLATFORMS="cpu")
         env.pop("XLA_FLAGS", None)
         procs.append(subprocess.Popen(

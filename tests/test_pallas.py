@@ -40,7 +40,8 @@ def test_ring_resolve_matches_reference(shape):
     last = rng.randint(0, 3 * W, rshape[:2]).astype(np.int32)
     idx = rng.randint(-2, 3 * W + 2, ishape).astype(np.int32)
     got = np.asarray(ring_resolve(jnp.asarray(ring), jnp.asarray(idx),
-                                  jnp.asarray(last), block_rows=4))
+                                  jnp.asarray(last), block_rows=4,
+                                  interpret=True))
     want = _reference(ring, idx, last, W)
     assert (got == want).all()
 
@@ -58,5 +59,6 @@ def test_ring_resolve_matches_kernel_term_at():
     idx = jnp.asarray(rng.randint(0, 44, (4, 3)).astype(np.int32))
     want = np.asarray(state_mod.term_at(st, cfg, idx))
     got = np.asarray(ring_resolve(st.log_term, idx[..., None],
-                                  st.last_index, block_rows=3))[..., 0]
+                                  st.last_index, block_rows=3,
+                                  interpret=True))[..., 0]
     assert (got == want).all()

@@ -1,0 +1,183 @@
+"""Ask the chip's compiler, without the chip: the serving step variants, the
+mesh variant and the Pallas ring kernel compiled ahead of time for a
+DESCRIBED v5e:2x2 topology (the TPU compiler ships with libtpu and needs no
+device attached). Nothing runs, so these say nothing about results or
+speed — they catch what the CPU backend and interpret mode cannot: a
+program the TPU compiler refuses, a step that stopped aliasing its donated
+state, a collective that crept onto the groups axis, a kernel whose tiling
+Mosaic rejects.
+
+Rules this file keeps (on-chip-measurement guide, section 2): the topology
+is described inside a module-scoped fixture, never at import, so every
+xdist worker collects the same tests and only the worker that runs this
+file loads libtpu; compiles happen in this process; the persistent compile
+cache is off around them (a described-device executable cannot be read
+back without a chip); and all of them live in this one file. They compile
+as a served member does — x64 off — not as the CPU suite runs.
+
+Tier-1 keeps the small sizes (the structural floor is ~20 s per serving
+variant even at G=128). The full-size compiles — G=12,500 per variant,
+G=50,000 over four devices — take minutes and are marked slow; CHANGES.md
+(PR 21) records their memory_analysis().
+"""
+import os
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec,
+                          SingleDeviceSharding)
+
+from etcd_tpu.ops import kernel
+from etcd_tpu.ops.pallas_kernels import ring_resolve
+from etcd_tpu.ops.state import KernelConfig, init_state
+from etcd_tpu.server.engine import _named_partial
+
+P, W, HOPS = 5, 32, 3          # BASELINE.json config 4 peers; CLI window/hops
+VARIANTS = ("step_routed_auto", "step_routed_compact",
+            "step_routed_read_auto")
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure means "not here"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def as_served():
+    """Compile the way a served member does, not the way the suite runs:
+    x64 off (conftest turns it on for the CPU tests; a member never does,
+    and Mosaic cannot lower the int64 literals x64 would make), and the
+    persistent cache off around these compiles."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    with jax.enable_x64(False):
+        yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def _shapes(cfg: KernelConfig, state_sh, inbox_sh, small_sh):
+    """(state, inbox, prop_count, prop_slot, tick) as shapes carrying
+    shardings — there is no device to hold an array."""
+    G = cfg.groups
+    st = jax.eval_shape(lambda: init_state(cfg))
+    if not isinstance(state_sh, tuple):      # one sharding for every field
+        state_sh = jax.tree.map(lambda _: state_sh, st)
+    st = jax.tree.map(
+        lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
+        st, state_sh)
+    inbox = jax.ShapeDtypeStruct((G, cfg.peers, cfg.peers, cfg.fields),
+                                 jnp.int32, sharding=inbox_sh)
+    pc = jax.ShapeDtypeStruct((G,), jnp.int32, sharding=small_sh)
+    tick = jax.ShapeDtypeStruct((), jnp.bool_, sharding=small_sh)
+    return st, inbox, pc, pc, tick
+
+
+def _compile_variant(topo, name: str, G: int):
+    """The variant as a TPU engine runs it: donated state and inbox (the
+    suite's JAX_PLATFORMS=cpu makes the module-level jits undonated, so
+    the donating jit is rebuilt here from the same function)."""
+    cfg = KernelConfig(groups=G, peers=P, window=W)
+    one = SingleDeviceSharding(topo.devices[0])
+    fn = jax.jit(getattr(kernel, name).__wrapped__,
+                 static_argnums=kernel._STEP_STATICS[name],
+                 donate_argnums=(1, 2))
+    return fn.lower(cfg, *_shapes(cfg, one, one, one), None, HOPS).compile()
+
+
+def _check_variant(compiled, G: int) -> None:
+    ma = compiled.memory_analysis()
+    state_bytes = sum(
+        int(np.prod(s.shape)) * s.dtype.itemsize
+        for s in jax.eval_shape(
+            lambda: init_state(KernelConfig(groups=G, peers=P, window=W))))
+    # Donation must really alias: the state arrays ARE the HBM budget, and
+    # a step that copies them doubles it. Everything but scalars aliases.
+    assert ma.alias_size_in_bytes >= state_bytes, (
+        ma.alias_size_in_bytes, state_bytes)
+    assert ma.temp_size_in_bytes + ma.argument_size_in_bytes < 16 << 30
+
+
+@pytest.mark.parametrize("name", VARIANTS)
+def test_serving_variant_compiles_for_v5e(topo, as_served, name):
+    _check_variant(_compile_variant(topo, name, 128), 128)
+
+
+def _compile_mesh(topo, G: int):
+    """The engine's mesh step (engine.py: out_shardings pinned, donated)
+    over the four described devices, groups axis 4, peers axis 1."""
+    from etcd_tpu.parallel.mesh import mailbox_sharding, state_sharding
+    cfg = KernelConfig(groups=G, peers=P, window=W)
+    mesh = Mesh(np.array(topo.devices).reshape(4, 1), ("groups", "peers"))
+    st_sh, mb_sh = state_sharding(mesh), mailbox_sharding(mesh)
+    rep = NamedSharding(mesh, PartitionSpec())
+    fn = jax.jit(
+        _named_partial(kernel.step_routed_auto.__wrapped__, cfg, hops=HOPS),
+        donate_argnums=(0, 1), out_shardings=(st_sh, mb_sh))
+    return fn.lower(*_shapes(cfg, st_sh, mb_sh, rep), None).compile()
+
+
+def _check_mesh(compiled) -> None:
+    text = compiled.as_text()
+    # Groups never talk to each other: nothing data-sized may cross the
+    # groups axis. The only collective is the scalar all-reduce of the
+    # global quiet predicate that selects the lax.cond branch, one per hop.
+    for op in ("all-to-all", "all-gather", "collective-permute",
+               "reduce-scatter"):
+        assert f" {op}(" not in text and f" {op}-start(" not in text, op
+    reduces = [ln for ln in text.splitlines() if " all-reduce(" in ln
+               or " all-reduce-start(" in ln]
+    assert len(reduces) <= HOPS, reduces
+    assert all("[]" in ln.split("=", 2)[1] for ln in reduces), reduces
+
+
+def test_mesh_variant_compiles_for_v5e_2x2(topo, as_served):
+    _check_mesh(_compile_mesh(topo, 4))
+
+
+@pytest.mark.parametrize("trailing", [(4,), (P,)])
+def test_pallas_ring_resolve_compiles_for_v5e(topo, as_served,
+                                              trailing):
+    """The Mosaic kernel (interpret=False) at serving widths: G=12,500,
+    P=5, W=32 and the index shapes kernel._terms_at_many is called with
+    ((G,P,E) conflict scan, (G,P,P) prev-term). Its blocks' last dims
+    (32, T*E, 1) are far from the 128-lane tiling; Mosaic accepts them
+    (compiled here, never run; pallas_bench's (G,P,P,E) compiles too —
+    CHANGES.md, PR 21)."""
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def s(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one)
+
+    G = 12_500
+    compiled = ring_resolve.lower(
+        s((G, P, W)), s((G, P) + trailing), s((G, P)),
+        interpret=False).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("name", VARIANTS)
+def test_serving_variant_full_size(topo, as_served, name):
+    """One chip's share of config 4 (G=12,500): ~85 s per variant."""
+    _check_variant(_compile_variant(topo, name, 12_500), 12_500)
+
+
+@pytest.mark.slow
+def test_mesh_variant_full_size(topo, as_served):
+    """G=50,000 over four devices (what chip_smoke.py --chips 4 serves)."""
+    compiled = _compile_mesh(topo, 50_000)
+    _check_mesh(compiled)
+    ma = compiled.memory_analysis()    # per device
+    assert ma.temp_size_in_bytes + ma.argument_size_in_bytes < 16 << 30

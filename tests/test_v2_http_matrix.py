@@ -60,6 +60,17 @@ def member(tmp_path_factory, request):
         time.sleep(0.05)
     else:
         raise AssertionError("engine elections failed")
+    # The first quorum read compiles the read-step variant — tens of
+    # seconds on a cold compile cache, longer than a table row's 10 s
+    # client timeout. Pay it here (the engine answers 500/300 at its own
+    # request timeout while the round loop compiles; ask again).
+    while time.time() < deadline:
+        st, _, _ = req("GET", http.url + "/tenants/2/v2/keys/?quorum=true",
+                       timeout=60.0)
+        if st == 200:
+            break
+    else:
+        raise AssertionError("first quorum read never served")
     yield SimpleNamespace(client_urls=[http.url + "/tenants/2"])
     http.stop()
     eng.stop()
