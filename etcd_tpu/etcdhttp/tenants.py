@@ -653,7 +653,8 @@ class TenantAPI:
         series (reference etcdserver metrics.go + pkg/metrics): the
         proposal reference metrics, per-compartment histograms and
         gauges (round loop, WAL writer shards, applier shards, ack
-        gate), and process stats."""
+        gate, the HTTP front), and process stats (fds, CPU of the process
+        and by thread class)."""
         from etcd_tpu.utils.metrics import REGISTRY, fd_usage
         used, limit = fd_usage()
         extra = [
@@ -664,9 +665,15 @@ class TenantAPI:
             "descriptors.",
             "# TYPE process_max_fds gauge",
             f"process_max_fds {float(limit)}",
-            "",
         ]
-        body = (REGISTRY.expose() + "\n".join(extra)).encode()
+        # CPU of the process and of the engine's thread classes, read
+        # here at scrape time (nothing on any hot path); flat like the
+        # rest under ETCD_TPU_OBS=off, i.e. left out.
+        obs = getattr(self.engine, "obs", None)
+        if obs is not None and obs.enabled:
+            from etcd_tpu.server.obs import cpu_exposition
+            extra += cpu_exposition(obs.thread_cpu)
+        body = (REGISTRY.expose() + "\n".join(extra) + "\n").encode()
         ctx.send(200, body, "text/plain; version=0.0.4")
 
     def handle_debug_flight(self, ctx: Ctx, suffix: str) -> None:

@@ -12,6 +12,7 @@ import json
 import select
 import socket
 import threading
+import time
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Callable, Dict, List, Optional, Tuple
 from urllib.parse import parse_qs, unquote, urlsplit
@@ -186,6 +187,15 @@ class HttpServer:
         # CORS origin whitelist ("*" = any); None disables CORS handling
         # (reference pkg/cors/cors.go CORSInfo + CORSHandler).
         self.cors = set(cors) if cors else None
+        # The front's span and self time (server/obs.py), per kind; off
+        # under ETCD_TPU_OBS=off. Imported here, not at the top: the
+        # server package imports this one.
+        from etcd_tpu.server import obs
+        front = obs.front if obs.obs_enabled() else None
+        h_request = {k: obs.http_request.labels(k)
+                     for k in obs.FRONT_KINDS}
+        h_self = {k: obs.http_front_self.labels(k)
+                  for k in obs.FRONT_KINDS}
 
         outer = self
 
@@ -205,6 +215,30 @@ class HttpServer:
                 super().setup()
 
             def _run(self, method: str) -> None:
+                if front is None:
+                    self._serve(method)
+                    return
+                # From the parsed request line to the response written.
+                # What the engine learned on this thread meanwhile (its
+                # own clock on the wait for the ack, the request's kind,
+                # a sampled rid) comes back through obs.front.
+                front.blocked, front.kind, front.trace = 0.0, "other", None
+                front.t_in = t0 = time.perf_counter()
+                streamed = self._serve(method)
+                kind = front.kind
+                if not streamed and kind in h_request:   # not a watch
+                    dt = time.perf_counter() - t0
+                    h_request[kind].observe(dt)
+                    h_self[kind].observe(dt - front.blocked)
+                    if front.trace is not None:
+                        tracer, rid = front.trace
+                        tracer.mark(rid, "replied")
+
+            def _serve(self, method: str) -> bool:
+                """Handle one request; True if it streamed (a watch, a
+                hijacked connection), which the front's span leaves
+                out."""
+                ctx = None
                 try:
                     parts = urlsplit(self.path)
                     length = int(self.headers.get("Content-Length") or 0)
@@ -230,7 +264,7 @@ class HttpServer:
                             }
                         if method == "OPTIONS":
                             ctx.send(200)
-                            return
+                            return False
                     if not outer.router.dispatch(ctx):
                         ctx.send(404, b"404 page not found\n")
                     if ctx._streaming:
@@ -243,6 +277,7 @@ class HttpServer:
                     except Exception:
                         pass
                     self.close_connection = True
+                return ctx is not None and ctx._streaming
 
             def do_GET(self):
                 self._run("GET")

@@ -876,29 +876,40 @@ def _step_body(cfg: KernelConfig, st: GroupState, inbox: jax.Array,
     heartbeat clock — the ReadIndex step uses it to solicit the quorum
     acks that confirm leadership (reference bcastHeartbeat on a pending
     read, raft.go:313-321 via step MsgReadIndex)."""
+    # The stages carry jax.named_scope names (metadata only: the program
+    # is byte-identical) so a device trace's ops can be told apart after
+    # a refactor.
     active = active_mask(st)
     P = st.term.shape[1]
     st = st._replace(ack_age=jnp.minimum(st.ack_age + 1, 1 << 20))
-    st, hb_fire, vote_fire = _tick(st, cfg, active, tick)
+    with jax.named_scope("etcd.tick"):
+        st, hb_fire, vote_fire = _tick(st, cfg, active, tick)
     if force_hb:
         ldr = active & (st.state == LEADER)
         hb_fire = _where(ldr, st.term, hb_fire)
         # The broadcast resumes paused probes, exactly like a timed one.
         st = st._replace(paused=_where(ldr[..., None], False, st.paused))
     lead_term0 = _where(st.state == LEADER, st.term, 0)
-    if quiet:
-        st, resp = _quiet_msgs(st, cfg, inbox, active)
-    else:
-        resp = jnp.zeros((st.term.shape[0], P, P, cfg.fields), jnp.int32)
-        for q in range(P):
-            st, r = _step_msgs_from(st, cfg, q, inbox[:, :, q, :], active)
-            resp = resp.at[:, :, q, :].set(r)
-    if prop_slot is None:
-        st = _apply_proposals_slots(st, cfg, prop_count, active)
-    else:
-        st = _apply_proposals(st, cfg, prop_count, prop_slot, active)
-    st = _quorum_commit(st, cfg, active, lead_term0)
-    st, outbox = _assemble_sends(st, cfg, resp, hb_fire, vote_fire, active)
+    with jax.named_scope("etcd.step_msgs"):
+        if quiet:
+            st, resp = _quiet_msgs(st, cfg, inbox, active)
+        else:
+            resp = jnp.zeros((st.term.shape[0], P, P, cfg.fields),
+                             jnp.int32)
+            for q in range(P):
+                st, r = _step_msgs_from(st, cfg, q, inbox[:, :, q, :],
+                                        active)
+                resp = resp.at[:, :, q, :].set(r)
+    with jax.named_scope("etcd.apply_proposals"):
+        if prop_slot is None:
+            st = _apply_proposals_slots(st, cfg, prop_count, active)
+        else:
+            st = _apply_proposals(st, cfg, prop_count, prop_slot, active)
+    with jax.named_scope("etcd.quorum_commit"):
+        st = _quorum_commit(st, cfg, active, lead_term0)
+    with jax.named_scope("etcd.assemble_sends"):
+        st, outbox = _assemble_sends(st, cfg, resp, hb_fire, vote_fire,
+                                     active)
     bad = active & (st.commit > st.last_index)
     st = st._replace(need_host=_flag(st.need_host, bad, NH_VIOLATION))
     return st, outbox
@@ -941,8 +952,9 @@ def step_routed_auto(cfg: KernelConfig, st: GroupState, inbox: jax.Array,
             s, out = _step_body(cfg, st, inbox, pc, ps, tick, quiet=False)
             return s, route_local(out)
 
-        st, inbox = jax.lax.cond(quiet, fast, full,
-                                 (st, inbox, pc, prop_slot, tk))
+        with jax.named_scope(f"etcd.hop{h}"):
+            st, inbox = jax.lax.cond(quiet, fast, full,
+                                     (st, inbox, pc, prop_slot, tk))
         if drop_mask is not None:
             inbox = inbox * drop_mask
     return st, inbox
@@ -1052,8 +1064,9 @@ def step_routed_read_auto(cfg: KernelConfig, st: GroupState,
                                 force_hb=(_h == 0))
             return s, route_local(out)
 
-        st, inbox = jax.lax.cond(quiet, fast, full,
-                                 (st, inbox, pc, prop_slot, tk))
+        with jax.named_scope(f"etcd.hop{h}"):
+            st, inbox = jax.lax.cond(quiet, fast, full,
+                                     (st, inbox, pc, prop_slot, tk))
         if drop_mask is not None:
             inbox = inbox * drop_mask
         # Messages routed to the registered leader slot this hop.
@@ -1109,15 +1122,16 @@ def step_routed_compact(cfg: KernelConfig, st: GroupState, inbox: jax.Array,
     st0 = st
     st, inbox = step_routed_auto.__wrapped__(
         cfg, st, inbox, prop_count, prop_slot, tick, drop_mask, hops)
-    hs = ((st.term != st0.term) | (st.vote != st0.vote)
-          | (st.commit != st0.commit))
-    flags = (hs.astype(jnp.uint8) * CHG_HS
-             | (st.last_index != st0.last_index).astype(jnp.uint8)
-             * CHG_LAST
-             | jnp.any(st.log_term != st0.log_term, axis=2)
-             .astype(jnp.uint8) * CHG_RING
-             | (st.state != st0.state).astype(jnp.uint8) * CHG_STATE)
-    any_nh = jnp.any(st.need_host != 0)
+    with jax.named_scope("etcd.compact_flags"):
+        hs = ((st.term != st0.term) | (st.vote != st0.vote)
+              | (st.commit != st0.commit))
+        flags = (hs.astype(jnp.uint8) * CHG_HS
+                 | (st.last_index != st0.last_index).astype(jnp.uint8)
+                 * CHG_LAST
+                 | jnp.any(st.log_term != st0.log_term, axis=2)
+                 .astype(jnp.uint8) * CHG_RING
+                 | (st.state != st0.state).astype(jnp.uint8) * CHG_STATE)
+        any_nh = jnp.any(st.need_host != 0)
     return st, inbox, flags, any_nh
 
 

@@ -474,7 +474,11 @@ class MultiEngine:
         self._stop_ev = threading.Event()
         self._thread: Optional[threading.Thread] = None
         self.round_no = 0
-        self.round_ms_ewma = 0.0   # smoothed wall time per round
+        # Smoothed wall time of run_round, seeded by the first round that
+        # STARTS with every provisioned group led (0.0 until then).
+        self.round_ms_ewma = 0.0
+        self._ewma_live = self._ewma_armed = False
+        self._looping = False      # run_round is being driven by _run
         # Cumulative per-phase wall time (seconds) of the round loop —
         # the profile VERDICT r3 asked for (device/readback/fsync/apply/
         # ack shares). Reset with reset_phase_profile(). The writer
@@ -495,6 +499,11 @@ class MultiEngine:
         # admitted this round (round-thread-private, reset per round).
         self._last_admitted = 0
         self._trace_rids: List[int] = []
+        # This round's blocking device->host reads (count, bytes) and the
+        # record phase's clocked parts; round-thread-private, reset per
+        # round, written only under obs.enabled.
+        self._d2h_n = self._d2h_b = 0
+        self._rec_gather = self._rec_admit = 0.0
         # The WAL compartment: submit() hands records to the writer
         # stage; acks gate on its durability watermark (wait_durable).
         # Constructed after phase_s — the writer threads profile into it.
@@ -917,6 +926,8 @@ class MultiEngine:
         pkey = "apply" if len(self._appliers) == 1 else f"apply[{sh.idx}]"
         o = self.obs if self.obs.enabled else None
         tr = self.obs.tracer
+        if o:
+            o.thread_cpu.register("applier")
         while True:
             with sh.cv:
                 while not sh.q and not sh.stop:
@@ -1067,6 +1078,9 @@ class MultiEngine:
                     return self._quorum_read(g, r, timeout)
                 r = Request(**{**r.__dict__, "method": METHOD_QGET})
             elif r.wait:
+                # A long-poll holds its handler thread for as long as the
+                # client waits: the front's span leaves watches out.
+                obs_mod.front.kind = "watch"
                 return self.store(g).watch(r.path, r.recursive, r.stream,
                                            r.since)
             else:
@@ -1083,21 +1097,24 @@ class MultiEngine:
             tr.mark(r.id, "submit", g=g)
         q = self.wait.register(r.id)
         payload = bytes([P_REQ]) + r.encode()
+        t0 = time.perf_counter()
         with self._lock:
             # The decoded Request rides along so the live apply path never
-            # re-parses JSON it already has (replay still decodes bytes).
-            self._pending[g].append((r.id, payload, r))
+            # re-parses JSON it already has (replay still decodes bytes);
+            # the enqueue time rides too, for the staging-queue wait.
+            self._pending[g].append((r.id, payload, r, t0))
             self._dirty.add(g)
         # Reference proposal metrics (etcdserver/metrics.go), previously
         # observed only by the legacy server.py path.
         if obs_on:
             metrics.propose_pending.inc()
-        t0 = time.perf_counter()
         try:
             result = q.get(timeout=timeout or self.cfg.request_timeout)
         except queue.Empty:
             if obs_on:
                 metrics.propose_failed.inc()
+                self._front_handoff("write", time.perf_counter() - t0,
+                                    r.id)
             self.wait.cancel(r.id)
             raise errors.EtcdError(errors.ECODE_RAFT_INTERNAL,
                                    cause="request timed out",
@@ -1106,8 +1123,9 @@ class MultiEngine:
             if obs_on:
                 metrics.propose_pending.dec()
         if obs_on:
-            metrics.propose_durations.observe(
-                (time.perf_counter() - t0) * 1000.0)
+            dt = time.perf_counter() - t0
+            metrics.propose_durations.observe(dt * 1000.0)
+            self._front_handoff("write", dt, r.id)
         if isinstance(result, errors.EtcdError):
             # Application-level error (e.g. a failed CAS) — served, not
             # a failed proposal; propose_failed counts only proposals
@@ -1119,6 +1137,23 @@ class MultiEngine:
             # off the (serialized) apply stage.
             return result.resolve()
         return result
+
+    def _front_handoff(self, kind: str, blocked: float,
+                       rid: Optional[int] = None) -> None:
+        """The serving thread runs again after its ack: hand the HTTP
+        front (etcdhttp/web.py) the time it spent blocked in here and the
+        request's kind through obs.front, instead of clocking the same
+        wait twice; a sampled rid also gets its front_in (the handler's
+        start, recorded now that the rid exists) and woke marks."""
+        fl = obs_mod.front
+        fl.blocked += blocked
+        fl.kind = kind
+        tr = self.obs.tracer
+        if rid is not None and tr.every and tr.sampled(rid):
+            if fl.t_in:
+                tr.mark(rid, "front_in", t=fl.t_in)
+            tr.mark(rid, "woke")
+            fl.trace = (tr, rid)
 
     def do_many(self, g: int, reqs: List[Request],
                 timeout: Optional[float] = None) -> List[Any]:
@@ -1161,13 +1196,14 @@ class MultiEngine:
         tr = self.obs.tracer
         items = []
         queues = []
+        t0 = time.perf_counter()
         for r in reqs:
             if r.id == 0:
                 r = Request(**{**r.__dict__, "id": self.reqid.next()})
             if tr.every:
                 tr.mark(r.id, "submit", g=g)
             queues.append((r.id, self.wait.register(r.id)))
-            items.append((r.id, bytes([P_REQ]) + r.encode(), r))
+            items.append((r.id, bytes([P_REQ]) + r.encode(), r, t0))
         with self._lock:
             self._pending[g].extend(items)
             if items:
@@ -1212,9 +1248,11 @@ class MultiEngine:
         if obs_on and n:
             # One batch = one client-visible submission window; the
             # per-request proposal latency is the window's mean.
-            dt = (time.perf_counter() - t0) * 1000.0 / n
+            window = time.perf_counter() - t0
+            dt = window * 1000.0 / n
             for _ in range(n):
                 metrics.propose_durations.observe(dt)
+            self._front_handoff("write", window)
         return out
 
     # ------------------------------------------------------------------
@@ -1271,6 +1309,8 @@ class MultiEngine:
         except queue.Empty:
             if obs_on:
                 self.obs.c_reads_failed.inc()
+                self._front_handoff("qread", time.perf_counter() - t0,
+                                    r.id)
             self.wait.cancel(r.id)
             raise errors.EtcdError(errors.ECODE_RAFT_INTERNAL,
                                    cause="quorum read timed out",
@@ -1279,8 +1319,9 @@ class MultiEngine:
             if obs_on:
                 self.obs.g_read_parked.dec()
         if obs_on:
-            self.obs.s_read_dur.observe(
-                (time.perf_counter() - t0) * 1000.0)
+            dt = time.perf_counter() - t0
+            self.obs.s_read_dur.observe(dt * 1000.0)
+            self._front_handoff("qread", dt, r.id)
         if isinstance(result, errors.EtcdError):
             raise result
         return result
@@ -1674,6 +1715,13 @@ class MultiEngine:
     # ------------------------------------------------------------------
 
     def _run(self) -> None:
+        if self.obs.enabled:
+            self.obs.thread_cpu.register("round")
+        # From here run_round ends in the gap phase: one run_round's end
+        # to the next one's start (the round_interval sleep and the wait
+        # to get the interpreter back); a caller that drives run_round
+        # itself sees none.
+        self._looping = True
         try:
             while not self._stop_ev.is_set():
                 self.run_round()
@@ -1683,6 +1731,8 @@ class MultiEngine:
             self.failed = e
             self._stop_ev.set()
             raise
+        finally:
+            self._looping = False
 
     def run_round(self) -> None:
         """One engine round. Callable directly (tests drive the engine
@@ -1692,10 +1742,14 @@ class MultiEngine:
         G, P, W, E = (self.cfg.groups, self.cfg.peers, self.cfg.window,
                       self.cfg.max_ents)
         o = self.obs if self.obs.enabled else None
+        clock = o.clock if o else None
         r_no = self.round_no
         self._last_admitted = 0
         self._trace_rids.clear()
         if o:
+            clock.lap("stage", t_round)
+            self._d2h_n = self._d2h_b = 0
+            self._rec_gather = self._rec_admit = 0.0
             o.flight.mark(r_no, obs_mod.SUBMITTED, t_round)
 
         # -- -1. tenant lifecycle admin ops (rare; round-boundary surgery)
@@ -1773,10 +1827,20 @@ class MultiEngine:
         staged_gs = staged_ss = None
         if self._staged:
             gs_l, ss_l, cnt_l = [], [], []
+            waited = o.h_pending_wait.observe if o else None
+            t_staged = time.perf_counter() if o else 0.0
             for g, (s, ents) in self._staged.items():
                 gs_l.append(g)
                 ss_l.append(s)
                 cnt_l.append(len(ents))
+                if waited:
+                    # Queue wait of each request staged this round (items
+                    # enqueued by do()/submit_many carry their time; a
+                    # requeued item lost it, so each counts once).
+                    for items in ents:
+                        for it in items:
+                            if len(it) > 3:
+                                waited(t_staged - it[3])
             staged_gs = np.asarray(gs_l, np.int64)
             staged_ss = np.asarray(ss_l, np.int64)
             prop_count[staged_gs] = cnt_l
@@ -1802,7 +1866,7 @@ class MultiEngine:
         t_ph = time.perf_counter()
         ph["stage"] = ph.get("stage", 0.0) + (t_ph - t_round)
         if o:
-            o.h_phase["stage"].observe(t_ph - t_round)
+            clock.lap("dispatch", t_ph)
 
         # -- 2. the kernel round (fused step + routing: one ASYNC
         # dispatch; jax queues it and returns immediately) ----------------
@@ -1834,6 +1898,8 @@ class MultiEngine:
         d_dispatch = t_now - t_ph
         ph["dispatch"] = ph.get("dispatch", 0.0) + d_dispatch
         t_ph = t_now
+        if o:
+            clock.lap("readback", t_now)
 
         # -- 3. read back round k (blocks until the device finishes; the
         # GIL is released while waiting, so the applier thread makes
@@ -1843,35 +1909,49 @@ class MultiEngine:
         # more rows than the cap take the full readback below. ----------
         rec = None
         need_host = None
+        gathered = False        # the compact tail built this round's record
         d_readback = d_record = 0.0
         t_stepped = t_ph
         if flags_d is not None:
             # Check the 1-byte attestation BEFORE pulling the flag map:
             # need-host/post-surgery rounds take the full readback anyway
             # and must not pay a discarded (G, P) transfer first.
-            if not bool(anh_d) and not self._force_full:
+            any_nh = bool(anh_d)
+            if o:
+                self._d2h(anh_d)
+            if not any_nh and not self._force_full:
                 flags_np = np.asarray(flags_d)
                 t_now = time.perf_counter()
                 d_readback = t_now - t_ph
                 ph["readback"] = ph.get("readback", 0.0) + d_readback
                 t_ph = t_stepped = t_now
+                if o:
+                    self._d2h(flags_d)
+                    clock.lap("record", t_now)
                 rec = self._compact_record_admit(flags_np, staged_gs,
                                                  staged_ss)
-                if rec is not None:
-                    t_now = time.perf_counter()
-                    d_record = t_now - t_ph
-                    ph["record"] = ph.get("record", 0.0) + d_record
-                    t_ph = t_now
+                # Over the cap (rec is None) the attempt still counts as
+                # record; the full readback below is a second readback lap.
+                t_now = time.perf_counter()
+                d_record = t_now - t_ph
+                ph["record"] = ph.get("record", 0.0) + d_record
+                t_ph = t_now
+                gathered = rec is not None
+                if o:
+                    clock.lap("tail" if gathered else "readback", t_now)
         if rec is None:
+            full = (st.term, st.vote, st.commit, st.state,
+                    st.last_index, st.log_term, st.need_host)
             (term, vote, commit, state, last, ring, need_host) = (
-                np.array(a) for a in
-                self._jax.device_get(
-                    (st.term, st.vote, st.commit, st.state,
-                     st.last_index, st.log_term, st.need_host)))
+                np.array(a) for a in self._jax.device_get(full))
             t_now = time.perf_counter()
-            d_readback = t_now - t_ph
-            ph["readback"] = ph.get("readback", 0.0) + d_readback
+            d_full = t_now - t_ph
+            d_readback += d_full
+            ph["readback"] = ph.get("readback", 0.0) + d_full
             t_ph = t_stepped = t_now
+            if o:
+                self._d2h(*full)
+                clock.lap("record", t_now)
 
             # Violation check FIRST — before this round's WAL append,
             # applies, or acks: a flagged round's commits come from state
@@ -1948,9 +2028,11 @@ class MultiEngine:
             self.h_state, self.h_last, self.h_ring = state, last, ring
             self._force_full = False   # mirrors == device state again
             t_now = time.perf_counter()
-            d_record = t_now - t_ph
-            ph["record"] = ph.get("record", 0.0) + d_record
+            d_record += t_now - t_ph
+            ph["record"] = ph.get("record", 0.0) + (t_now - t_ph)
             t_ph = t_now
+            if o:
+                clock.lap("tail", t_now)
 
         # -- 5b. read plane: pop the snapshotted reads of every group
         # whose ReadIndex confirmation landed into the ripe queue at the
@@ -1960,6 +2042,9 @@ class MultiEngine:
         if conf_d is not None:
             self._confirm_reads(read_take, np.asarray(conf_d),
                                 np.asarray(rc_d))
+            if o:
+                self._d2h(conf_d)
+                self._d2h(rc_d)
 
         # -- 6. persist, then apply+ack. WAL fsync strictly precedes the
         # acks of everything this round committed (doc.go:31-39 ordering)
@@ -1975,10 +2060,15 @@ class MultiEngine:
         # precede the next dispatch, so the record is appended+fsynced
         # before the inline apply below (append_sync).
         if o:
-            o.h_phase["dispatch"].observe(d_dispatch)
-            o.h_phase["readback"].observe(d_readback)
             o.h_step.observe(d_dispatch + d_readback)
-            o.h_phase["record"].observe(d_record)
+            # record's parts: what _compact_record_admit / _admit_staged
+            # clocked, and the rest of the phase as build.
+            part = o.h_rec_part
+            if gathered:
+                part["gather"].observe(self._rec_gather)
+            part["admit"].observe(self._rec_admit)
+            part["build"].observe(
+                d_record - self._rec_gather - self._rec_admit)
             o.flight.mark(r_no, obs_mod.STEPPED, t_stepped)
             if self._staged:
                 o.h_batch.observe(self._last_admitted)
@@ -1987,14 +2077,15 @@ class MultiEngine:
                           or not self.cfg.pipeline_applies)
         if not rec.is_empty():
             t0 = time.perf_counter()
-            if sync_round or not self.cfg.pipeline_wal:
-                self.wal.append_sync(rec)
-            else:
-                self.wal.submit(rec)
+            with self.obs.span("etcd.round.wal_submit"):
+                if sync_round or not self.cfg.pipeline_wal:
+                    self.wal.append_sync(rec)
+                else:
+                    self.wal.submit(rec)
             ph["wal_submit"] = ph.get("wal_submit", 0.0) + \
                 (time.perf_counter() - t0)
             if o:
-                o.h_phase["wal_submit"].observe(time.perf_counter() - t0)
+                o.h_wal_submit.observe(time.perf_counter() - t0)
                 o.flight.mark(r_no, obs_mod.WAL_SUBMITTED)
             tr = self.obs.tracer
             if tr.every and self._trace_rids:
@@ -2030,23 +2121,53 @@ class MultiEngine:
         if need_host is not None and need_host.any():
             self._service_need_host(need_host)
 
-        ph["tail"] = ph.get("tail", 0.0) + (time.perf_counter() - t_ph)
+        t_now = time.perf_counter()
+        ph["tail"] = ph.get("tail", 0.0) + (t_now - t_ph)
         if o:
-            o.h_phase["tail"].observe(time.perf_counter() - t_ph)
+            clock.lap("post", t_now)
             o.c_rounds.inc()
         self.round_no += 1
         if (self.cfg.mask_check_rounds
                 and self.round_no % self.cfg.mask_check_rounds == 0):
             self._check_mask()
         ms = (time.perf_counter() - t_round) * 1000.0
-        if self.round_ms_ewma == 0.0:
-            self.round_ms_ewma = ms      # seed with the first sample
-        else:
+        if self._ewma_live:
             self.round_ms_ewma += 0.05 * (ms - self.round_ms_ewma)
+        elif self._ewma_armed:
+            self._ewma_live = True
+            self.round_ms_ewma = ms
+        else:
+            # The boot rounds are not the serving cadence: the first one
+            # pays the step's compile and, staggered, elects every group
+            # in the same call. Seed with the round after the first that
+            # ended with a leader everywhere.
+            self._ewma_armed = self._all_led()
         if self.round_no % self.cfg.checkpoint_rounds == 0:
+            t0 = time.perf_counter()
             self._drain_applies()    # checkpoint state must be consistent
             self._checkpoint()
             self._gc_payloads()
+            if o:
+                o.h_checkpoint.observe(time.perf_counter() - t0)
+        if o:
+            if self._d2h_n:
+                o.c_d2h_syncs.inc(self._d2h_n)
+                o.c_d2h_bytes.inc(self._d2h_b)
+            # (opened here, not in _run: the thread can lose the
+            # interpreter for tens of ms on its way out of this call)
+            clock.lap("gap" if self._looping else None, time.perf_counter())
+
+    def _d2h(self, *arrays) -> None:
+        """Count one blocking device->host read of `arrays` (called
+        under obs.enabled only; flushed to the counters once a round)."""
+        self._d2h_n += 1
+        for a in arrays:
+            self._d2h_b += a.nbytes
+
+    def _all_led(self) -> bool:
+        """Every provisioned group's mirror shows a leader."""
+        led = (np.where(self.h_mask, self.h_state, 0) == _LEADER).any(axis=1)
+        return bool(led[self.h_mask.any(axis=1)].all())
 
     def _admit_staged(self, rec: RoundRecord, adm_l: list, t_l: list,
                       base_l: list) -> None:
@@ -2058,6 +2179,9 @@ class MultiEngine:
         requeue: List[Tuple[int, List[Tuple[int, bytes]]]] = []
         tr = self.obs.tracer
         n_admitted = 0
+        t_admit = time.perf_counter()
+        ann = self.obs.span("etcd.record.admit")
+        ann.__enter__()
         for (g, (_, ents)), admitted, t, base in zip(
                 self._staged.items(), adm_l, t_l, base_l):
             for j, items in enumerate(ents):
@@ -2078,8 +2202,10 @@ class MultiEngine:
                                 self._trace_rids.append(it[0])
                     rec.entries.append((g, i, t, payload))
                 else:
+                    # (rid, payload, request): the enqueue time stays
+                    # behind, its wait was observed at this staging.
                     requeue.append(
-                        (g, [it for e in ents[j:] for it in e]))
+                        (g, [it[:3] for e in ents[j:] for it in e]))
                     break
         self._last_admitted = n_admitted
         if requeue:
@@ -2087,6 +2213,8 @@ class MultiEngine:
                 for g, rest in requeue:
                     self._pending[g].extendleft(reversed(rest))
                     self._dirty.add(g)
+        ann.__exit__(None, None, None)
+        self._rec_admit += time.perf_counter() - t_admit
 
     def _compact_record_admit(self, flags: np.ndarray,
                               staged_gs, staged_ss
@@ -2122,9 +2250,18 @@ class MultiEngine:
         gi_p = np.zeros(Kp, np.int32)
         pi_p = np.zeros(Kp, np.int32)
         gi_p[:K], pi_p[:K] = gi, pi
-        t_k, v_k, c_k, s_k, l_k, r_k = (
-            np.asarray(a)[:K] for a in kernel.gather_rows(
-                self.st, jnp.asarray(gi_p), jnp.asarray(pi_p)))
+        # The second device round trip of the round: the dispatch, then
+        # one blocking read per gathered array.
+        t_gather = time.perf_counter()
+        with self.obs.span("etcd.record.gather"):
+            rows_d = kernel.gather_rows(
+                self.st, jnp.asarray(gi_p), jnp.asarray(pi_p))
+            t_k, v_k, c_k, s_k, l_k, r_k = (
+                np.asarray(a)[:K] for a in rows_d)
+        if self.obs.enabled:
+            for a in rows_d:
+                self._d2h(a)
+            self._rec_gather += time.perf_counter() - t_gather
 
         def rows(bit):
             g, p = np.nonzero((flags & bit) != 0)
@@ -2483,6 +2620,8 @@ class MultiEngine:
         timeout resumes the leader's paused probes and replication
         catches up."""
         m = np.asarray(self.st.peer_mask)
+        if self.obs.enabled:
+            self._d2h(self.st.peer_mask)
         if np.array_equal(m, self.h_mask):
             return
         self.mask_repairs += 1

@@ -32,12 +32,22 @@ Three planes, all built to stay off the round loop's critical path:
     the durable Request payload, so a SIGKILL'd engine's replay
     re-marks surviving sampled rids as "replayed".
 
+On top of those, the accounting a host-bound member needs (PR 24): the
+round loop's seven disjoint phases tile the loop (RoundClock: wall,
+thread CPU and a jax.profiler.TraceAnnotation per phase, so the device
+trace's idle gaps carry the program's own stage names), `record` is
+split where its work happens, blocking device->host reads are counted,
+a write's wait in the staging queue and the HTTP front's span and self
+time are histograms, and CPU per thread class is read at scrape time
+(ThreadCpu).
+
 ETCD_TPU_OBS=off disables every engine-side observation (the A/B
 switch the instrumentation-overhead gate measures against); the series
 still exist, they just stay flat.
 """
 from __future__ import annotations
 
+import contextlib
 import json
 import logging
 import os
@@ -65,13 +75,73 @@ def obs_enabled() -> bool:
 _COUNT_BUCKETS = (1, 2, 4, 8, 16, 32, 64, 128, 256, 512, 1024, 4096,
                   16384, 65536)
 
+# The seven disjoint phases tile the round loop: their _sum deltas add up
+# to the wall window. wal_submit lies inside tail and is not one of them.
+ROUND_PHASES = ("stage", "dispatch", "readback", "record", "tail", "post",
+                "gap")
+RECORD_PARTS = ("gather", "build", "admit")
+FRONT_KINDS = ("write", "qread", "other")
+
 round_phase = metrics.LabeledHistogram(
     "etcd_engine_round_phase_seconds",
-    "Wall time of one round-loop phase (stage/dispatch/readback/record/"
-    "wal_submit/tail).", ("phase",))
+    "Wall time of one round-loop phase: stage/dispatch/readback/record/"
+    "tail/post (tail's end to the end of run_round)/gap (one run_round's "
+    "end to the next one's start) are disjoint and tile the loop; "
+    "wal_submit lies inside tail.", ("phase",))
+round_phase_cpu = metrics.LabeledCounter(
+    "etcd_engine_round_phase_cpu_seconds_total",
+    "CPU time of the round thread (time.thread_time) per disjoint "
+    "round-loop phase. Wall minus CPU of a phase is what the thread spent "
+    "not running: blocked on the device, on a lock, or waiting for the "
+    "interpreter.", ("phase",))
+record_part = metrics.LabeledHistogram(
+    "etcd_engine_record_part_seconds",
+    "Wall time of the record phase's parts per round: gather (the "
+    "gather_rows dispatch to the last gathered array on the host), admit "
+    "(_admit_staged) and build (the rest); a full-readback round has "
+    "build and admit only.", ("part",))
+d2h_syncs = metrics.Counter(
+    "etcd_engine_d2h_syncs_total",
+    "Blocking device->host reads on the round thread: the need-host "
+    "attestation, the flag map, each gathered array, the full readback "
+    "(one device_get), the read step's conf/read-index arrays, the mask "
+    "check.")
+d2h_bytes = metrics.Counter(
+    "etcd_engine_d2h_bytes_total",
+    "Bytes those device->host reads brought back (the arrays' nbytes).")
+pending_wait = metrics.Histogram(
+    "etcd_engine_pending_wait_seconds",
+    "Time a request sat in the engine's staging queue: do()/submit_many "
+    "enqueue to the round that staged it (observed once per request).")
+checkpoint_seconds = metrics.Histogram(
+    "etcd_engine_checkpoint_seconds",
+    "Full checkpoint on the round thread: applier drain, checkpoint "
+    "write and payload GC.",
+    buckets=(0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 0.5, 1.0,
+             2.5, 5.0, 10.0, 30.0, 60.0))
+jax_compiles = metrics.Counter(
+    "etcd_jax_compiles_total",
+    "Programs XLA built for this process, compiled or loaded from the "
+    "persistent compile cache (jax.monitoring "
+    "backend_compile_duration events).")
+jax_compile_seconds = metrics.Counter(
+    "etcd_jax_compile_seconds_total",
+    "Seconds those builds took.")
+http_request = metrics.LabeledHistogram(
+    "etcd_http_request_seconds",
+    "HTTP front: parsed request line to response written, by kind (write "
+    "= went through the engine's propose path, qread = through the read "
+    "plane, other = the rest); streams and watches left out.", ("kind",))
+http_front_self = metrics.LabeledHistogram(
+    "etcd_http_front_self_seconds",
+    "HTTP front self time: etcd_http_request_seconds minus what the same "
+    "thread spent blocked inside the engine waiting for its ack.",
+    ("kind",))
 kernel_step = metrics.Histogram(
     "etcd_engine_kernel_step_seconds",
-    "Device kernel step wall time per round (dispatch + readback).")
+    "Host wall time of the round's dispatch + readback phases (enqueue "
+    "the step, then block on its first result); the device step itself "
+    "is shorter, see the device trace.")
 round_batch = metrics.Histogram(
     "etcd_engine_round_batch_requests",
     "Client requests admitted into one round's log entries (batch "
@@ -346,8 +416,8 @@ class FlightRecorder:
 
 # -- sampled proposal traces -------------------------------------------------
 
-TRACE_STAGES = ("submit", "admitted", "wal_submit", "durable", "applied",
-                "acked", "replayed")
+TRACE_STAGES = ("front_in", "submit", "admitted", "wal_submit", "durable",
+                "applied", "acked", "woke", "replied", "replayed")
 
 
 class Tracer:
@@ -372,12 +442,15 @@ class Tracer:
     def sampled(self, rid: int) -> bool:
         return bool(self.every) and rid % self.every == 0
 
-    def mark(self, rid: int, stage: str, **extra) -> None:
-        """Record one stage timestamp for a sampled rid. Cold path by
-        construction (1 in N); unsampled rids pay one modulo."""
+    def mark(self, rid: int, stage: str, t: Optional[float] = None,
+             **extra) -> None:
+        """Record one stage timestamp (now, or the perf_counter reading
+        `t` taken earlier) for a sampled rid. Cold path by construction
+        (1 in N); unsampled rids pay one modulo."""
         if not self.sampled(rid):
             return
-        t = time.perf_counter()
+        if t is None:
+            t = time.perf_counter()
         with self._lock:
             span = self._spans.get(rid)
             if span is None:
@@ -413,6 +486,164 @@ class Tracer:
         return {"every": self.every, "spans": out}
 
 
+# -- the HTTP front's hand-off ------------------------------------------------
+
+
+class _FrontLocal(threading.local):
+    """What one handler thread's current request learned inside the
+    engine, read back by etcdhttp/web.py when the response is written:
+    `blocked` seconds the thread waited for its ack (do()'s and
+    _quorum_read's own clocks, handed over instead of clocking twice),
+    the request's `kind`, the request's start `t_in` (perf_counter) and,
+    for a sampled rid, `trace` = (tracer, rid). Class attributes are the
+    defaults a thread that never served HTTP reads."""
+
+    blocked = 0.0
+    kind = "other"
+    t_in = 0.0
+    trace = None
+
+
+front = _FrontLocal()
+
+
+# -- the round loop's clock ---------------------------------------------------
+
+
+class RoundClock:
+    """Wall time, thread CPU time and a profiler annotation for each of
+    the round loop's disjoint phases. The round thread calls lap() at
+    every phase boundary with the perf_counter reading it took there
+    anyway: the phase that ends is observed into
+    etcd_engine_round_phase_seconds / _cpu_seconds_total and its
+    `etcd.round.<phase>` TraceAnnotation closes; the next one opens at
+    the same instant, so the phases tile the loop and no annotation ever
+    encloses a whole round (the benchmark's gap labeller gives an idle
+    gap to the host event that overlaps it most)."""
+
+    def __init__(self) -> None:
+        from jax.profiler import TraceAnnotation
+        self._annotate = TraceAnnotation
+        self._wall = {p: round_phase.labels(p) for p in ROUND_PHASES}
+        self._cpu = {p: round_phase_cpu.labels(p) for p in ROUND_PHASES}
+        self._cur: Optional[str] = None
+        self._ann = None
+        self._t = self._c = 0.0
+
+    def lap(self, nxt: Optional[str], t: float) -> None:
+        """End the running phase at `t` (if one runs) and start `nxt`
+        there (None: stop the clock)."""
+        c = time.thread_time()
+        cur = self._cur
+        if cur is not None:
+            self._wall[cur].observe(t - self._t)
+            self._cpu[cur].inc(c - self._c)
+            self._ann.__exit__(None, None, None)
+        self._cur, self._t, self._c = nxt, t, c
+        if nxt is not None:
+            self._ann = self._annotate("etcd.round." + nxt)
+            self._ann.__enter__()
+
+    def span(self, name: str):
+        """A nested annotation (etcd.round.wal_submit, etcd.record.*)."""
+        return self._annotate(name)
+
+
+# -- CPU by thread class ------------------------------------------------------
+
+
+class ThreadCpu:
+    """CPU clocks of an engine's long-lived threads by class (round,
+    wal, applier). Each thread registers itself once at its start
+    (pthread_getcpuclockid of its own, live id); read() is called at
+    scrape time only. Where the platform has no such clock the class
+    reads nothing and /metrics leaves the series out."""
+
+    def __init__(self) -> None:
+        # class -> [(clock id, thread)]
+        self._clocks: Dict[str, List[tuple]] = {}
+        self._lock = threading.Lock()
+
+    def register(self, cls: str) -> None:
+        try:
+            clk = time.pthread_getcpuclockid(threading.get_ident())
+            time.clock_gettime(clk)
+        except (AttributeError, OSError):
+            return
+        with self._lock:
+            self._clocks.setdefault(cls, []).append(
+                (clk, threading.current_thread()))
+
+    def read(self) -> Dict[str, float]:
+        """{class: CPU seconds of its live threads}. A thread that has
+        exited is skipped (its clock id may name another thread by then),
+        so its CPU falls to the remainder."""
+        with self._lock:
+            clocks = {k: list(v) for k, v in self._clocks.items()}
+        out = {}
+        for cls, entries in clocks.items():
+            tot = 0.0
+            for clk, th in entries:
+                if th.is_alive():
+                    try:
+                        tot += time.clock_gettime(clk)
+                    except OSError:
+                        pass
+            out[cls] = tot
+        return out
+
+
+def cpu_exposition(thread_cpu: ThreadCpu) -> List[str]:
+    """/metrics lines for process_cpu_seconds_total and
+    etcd_thread_cpu_seconds_total{thread}: `front` is the process minus
+    the named classes (handler threads come and go with their
+    connections; the JAX runtime's own threads fall under it too)."""
+    proc = time.process_time()
+    lines = [
+        "# HELP process_cpu_seconds_total Total user and system CPU time "
+        "spent in seconds.",
+        "# TYPE process_cpu_seconds_total counter",
+        f"process_cpu_seconds_total {proc}",
+    ]
+    named = thread_cpu.read()
+    if named:
+        lines += [
+            "# HELP etcd_thread_cpu_seconds_total CPU time by thread "
+            "class: round (the round loop), wal (writer shards), applier "
+            "(applier shards), front (the process minus those: HTTP "
+            "handler threads and the runtime's own).",
+            "# TYPE etcd_thread_cpu_seconds_total counter"]
+        for cls, v in sorted(named.items()):
+            lines.append(
+                f'etcd_thread_cpu_seconds_total{{thread="{cls}"}} {v}')
+        front_s = max(0.0, proc - sum(named.values()))
+        lines.append(
+            f'etcd_thread_cpu_seconds_total{{thread="front"}} {front_s}')
+    return lines
+
+
+# -- compile events -----------------------------------------------------------
+
+_compile_listener_installed = False
+
+
+def install_compile_listener() -> None:
+    """Count XLA program builds (once per process; a callback on compile
+    only, nothing on the round's path)."""
+    global _compile_listener_installed
+    if _compile_listener_installed:
+        return
+    _compile_listener_installed = True
+    from jax import monitoring
+
+    def on_duration(event: str, duration: float, **_kw) -> None:
+        if event == "/jax/core/compile/backend_compile_duration":
+            jax_compiles.inc()
+            jax_compile_seconds.inc(duration)
+
+    monitoring.register_event_duration_secs_listener(on_duration)
+
+
 class EngineObs:
     """One engine's bound observability plane: pre-resolved metric
     children for its shard geometry (hot paths index lists instead of
@@ -420,13 +651,35 @@ class EngineObs:
     `enabled` False (ETCD_TPU_OBS=off) makes the engine skip every
     observation — the series stay registered but flat."""
 
+    _NO_SPAN = contextlib.nullcontext()
+
+    def span(self, name: str):
+        """Context manager: a profiler annotation `name` nested in the
+        running phase's, nothing when the plane is off."""
+        return self.clock.span(name) if self.clock else self._NO_SPAN
+
     def __init__(self, wal_shards: int, applier_shards: int) -> None:
         self.enabled = obs_enabled()
         self.flight = FlightRecorder()
         self.tracer = Tracer()
-        self.h_phase = {p: round_phase.labels(p)
-                        for p in ("stage", "dispatch", "readback",
-                                  "record", "wal_submit", "tail")}
+        self.h_wal_submit = round_phase.labels("wal_submit")
+        for p in ROUND_PHASES:      # the series exist, flat, from the start
+            round_phase.labels(p)
+            round_phase_cpu.labels(p).inc(0.0)
+        # The disjoint phases are observed through the clock (wall, CPU,
+        # annotation); it needs jax.profiler, so only a live plane has one.
+        self.clock = RoundClock() if self.enabled else None
+        self.thread_cpu = ThreadCpu()
+        self.h_rec_part = {p: record_part.labels(p) for p in RECORD_PARTS}
+        self.c_d2h_syncs = d2h_syncs
+        self.c_d2h_bytes = d2h_bytes
+        self.h_pending_wait = pending_wait
+        self.h_checkpoint = checkpoint_seconds
+        for k in FRONT_KINDS:
+            http_request.labels(k)
+            http_front_self.labels(k)
+        if self.enabled:
+            install_compile_listener()
         self.h_step = kernel_step
         self.h_batch = round_batch
         self.h_wal_fsync = [wal_fsync.labels(k)

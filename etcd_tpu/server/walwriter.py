@@ -210,6 +210,8 @@ class WALWriter:
         pkey = ("wal_fsync" if len(self.shards) == 1
                 else f"wal_fsync[{sh.idx}]")
         sharded = len(self.shards) > 1
+        if self._obs is not None:
+            self._obs.thread_cpu.register("wal")
         while True:
             with sh.cv:
                 while not sh.q and not sh.stop:

@@ -4,8 +4,12 @@
 Polls one Prometheus text endpoint (the engine front's /metrics, or the
 pool router's) and renders a compact per-compartment view every interval:
 
-    round loop   rounds/s, batch p50/p99, phase p99s (stage/dispatch/
-                 readback/record/wal_submit/tail), kernel step p99
+    round loop   rounds/s, batch p50/p99, per phase p50/p99 and the mean
+                 wall and CPU ms a round (stage/dispatch/readback/record/
+                 tail/post/gap tile the loop; wal_submit lies in tail),
+                 record's parts, device->host syncs and kB a round
+    front        per kind requests/s, mean span and self time; CPU cores
+                 used by the process and by thread class
     wal writer   per-shard fsync p50/p99, group-commit size, queue
                  depth, watermark lag
     appliers     per-shard queue depth, apply-batch p99, ack-gate p99
@@ -130,7 +134,7 @@ def quantile(buckets, total, q):
     return buckets[-1][0] if buckets else None
 
 
-def counter_rate(prev, cur, name, dt, match=()):
+def counter_delta(prev, cur, name, match=()):
     d = 0.0
     for (n, labels), v in cur.items():
         if n != name:
@@ -139,7 +143,11 @@ def counter_rate(prev, cur, name, dt, match=()):
         if any(lab.get(k) != w for k, w in match):
             continue
         d += v - prev.get((n, labels), 0.0)
-    return d / dt if dt > 0 else 0.0
+    return d
+
+
+def counter_rate(prev, cur, name, dt, match=()):
+    return counter_delta(prev, cur, name, match) / dt if dt > 0 else 0.0
 
 
 def gauge(cur, name, match=()):
@@ -189,17 +197,53 @@ def render(prev, cur, dt):
              f"proposals/s {pps:8.1f}   pending {pend or 0:4.0f}   "
              f"failed {failed or 0:6.0f}")
 
-    L.append("round loop        p50        p99")
+    rounds = rps * dt
+    L.append("round loop        p50        p99   wall/round  cpu/round")
     for ph in ("stage", "dispatch", "readback", "record", "wal_submit",
-               "tail"):
+               "tail", "post", "gap"):
         m = (("phase", ph),)
+        wall = hist_delta(prev, cur, "etcd_engine_round_phase_seconds",
+                          m)[2]
+        cpu = counter_delta(prev, cur,
+                            "etcd_engine_round_phase_cpu_seconds_total", m)
         L.append(f"  {ph:<12}{_ms(_q(prev, cur, 'etcd_engine_round_phase_seconds', 0.5, m))}"
-                 f" {_ms(_q(prev, cur, 'etcd_engine_round_phase_seconds', 0.99, m))}")
+                 f" {_ms(_q(prev, cur, 'etcd_engine_round_phase_seconds', 0.99, m))}"
+                 f" {_ms(wall / rounds if rounds else None)}"
+                 f" {_ms(cpu / rounds if rounds and ph != 'wal_submit' else None)}")
+    parts = [hist_delta(prev, cur, "etcd_engine_record_part_seconds",
+                        (("part", p),))[2] for p in ("gather", "build",
+                                                     "admit")]
+    if rounds:
+        syncs = counter_delta(prev, cur, "etcd_engine_d2h_syncs_total")
+        nbytes = counter_delta(prev, cur, "etcd_engine_d2h_bytes_total")
+        L.append("  record = gather/build/admit "
+                 + "/".join(f"{p / rounds * 1e3:.2f}" for p in parts)
+                 + f" ms   d2h {syncs / rounds:.1f} syncs "
+                   f"{nbytes / rounds / 1e3:.1f} kB a round")
     L.append(f"  {'kernel step':<12}"
              f"{_ms(_q(prev, cur, 'etcd_engine_kernel_step_seconds', 0.5))}"
              f" {_ms(_q(prev, cur, 'etcd_engine_kernel_step_seconds', 0.99))}")
     bq = _q(prev, cur, "etcd_engine_round_batch_requests", 0.99)
     L.append(f"  batch p99   {bq if bq is not None else '-':>10}")
+
+    kinds = label_values(cur, "etcd_http_request_seconds", "kind")
+    if kinds:
+        L.append("front            req/s   mean span   mean self")
+        for kind in kinds:
+            m = (("kind", kind),)
+            _, n, span = hist_delta(prev, cur, "etcd_http_request_seconds",
+                                    m)
+            self_s = hist_delta(prev, cur, "etcd_http_front_self_seconds",
+                                m)[2]
+            L.append(f"  {kind:<12}{n / dt if dt > 0 else 0:8.1f}  "
+                     f"{_ms(span / n if n else None)}  "
+                     f"{_ms(self_s / n if n else None)}")
+        cores = counter_rate(prev, cur, "process_cpu_seconds_total", dt)
+        by = " ".join(
+            f"{t} {counter_rate(prev, cur, 'etcd_thread_cpu_seconds_total', dt, (('thread', t),)):.2f}"
+            for t in label_values(cur, "etcd_thread_cpu_seconds_total",
+                                  "thread"))
+        L.append(f"  cpu cores {cores:.2f}   {by}")
 
     lag = gauge(cur, "etcd_wal_writer_watermark_lag_tickets")
     L.append(f"wal writer (watermark lag {lag if lag is not None else '-'})"

@@ -13,6 +13,12 @@
 - Sampled trace ids ride the durable WAL payloads: a SIGKILL'd engine's
   acked writes come back as `replayed` trace spans in the restarted
   process.
+- The accounting of PR 24: the seven disjoint round phases tile the
+  loop, record's parts add up to record, device->host reads repeat
+  exactly, the HTTP front's span and self time, the staging-queue wait,
+  CPU by thread class, the three front marks of a sampled rid, the
+  profiler annotations on a CPU trace — and all of it flat under
+  ETCD_TPU_OBS=off.
 """
 import importlib.util
 import json
@@ -157,6 +163,269 @@ def test_obs_disabled_master_switch(monkeypatch):
     fl = obs_mod.FlightRecorder(capacity=16)
     fl.mark(1, obs_mod.SUBMITTED, 1.0)
     assert all(r[0] == -1 for r in fl.snapshot())
+
+
+
+# -- PR 24 units: run before the module's live engine exists, so the
+# process-global series move only by what each test does ---------------------
+
+
+def _reg():
+    """The live registry as {(series, sorted labels): value} (etcd_top's
+    parser, as the HTTP-level tests use)."""
+    return _load_script("etcd_top").parse_metrics(metrics.REGISTRY.expose())
+
+
+def _val(scrape, series, **labels):
+    want = set(labels.items())
+    vals = [v for (name, lab), v in scrape.items()
+            if name == series and want <= set(lab)]
+    return sum(vals) if vals else None
+
+
+def _delta(a, b, series, **labels):
+    return _val(b, series, **labels) - _val(a, series, **labels)
+
+
+def _small_engine(tmp_path, **kw):
+    from etcd_tpu.server.engine import EngineConfig, MultiEngine
+    cfg = dict(groups=4, peers=3, data_dir=str(tmp_path), window=16,
+               max_ents=4, heartbeat_tick=3, fsync=False,
+               checkpoint_rounds=1 << 30, request_timeout=60.0)
+    cfg.update(kw)
+    return MultiEngine(EngineConfig(**cfg))
+
+
+def _elect(eng, rounds=400):
+    for _ in range(rounds):
+        eng.run_round()
+        if all(eng.leader_slot(g) >= 0 for g in range(eng.cfg.groups)):
+            return
+    raise AssertionError("no leaders")
+
+
+def test_thread_cpu_reads_another_threads_clock():
+    tc = obs_mod.ThreadCpu()
+    go, done = threading.Event(), threading.Event()
+
+    def busy():
+        tc.register("round")
+        go.wait(10)
+        end = time.thread_time() + 0.2
+        while time.thread_time() < end:
+            pass
+        done.wait(10)
+
+    th = threading.Thread(target=busy)
+    th.start()
+    try:
+        if not tc.read():
+            pytest.skip("no per-thread CPU clock on this platform")
+        before = tc.read()["round"]
+        go.set()
+        deadline = time.time() + 20
+        while tc.read()["round"] - before < 0.19 and time.time() < deadline:
+            time.sleep(0.01)
+        assert tc.read()["round"] - before >= 0.19
+        lines = obs_mod.cpu_exposition(tc)
+        assert any(ln.startswith("process_cpu_seconds_total ")
+                   for ln in lines)
+        assert any('thread="front"' in ln for ln in lines)
+    finally:
+        go.set()
+        done.set()
+        th.join(10)
+    assert not th.is_alive()
+    # An exited thread is skipped, not read through a stale clock id.
+    assert tc.read() == {"round": 0.0}
+
+
+def test_tracer_mark_takes_an_earlier_reading():
+    tr = obs_mod.Tracer(every=1)
+    tr.mark(7, "submit")
+    tr.mark(7, "front_in", t=time.perf_counter() - 1.0)
+    stages = tr.dump()["spans"][0]["stages"]
+    assert list(stages) == ["front_in", "submit"]
+    assert 0.9 < stages["submit"] < 1.5
+    assert {"front_in", "woke", "replied"} <= set(obs_mod.TRACE_STAGES)
+
+
+def test_d2h_syncs_per_round_repeat_exactly(tmp_path):
+    """An idle compact round reads the need-host attestation and the
+    flag map, nothing else: syncs/round is the same integer in two
+    windows of one idle engine, and bytes/round the same number."""
+    eng = _small_engine(tmp_path, mask_check_rounds=0)
+    try:
+        _elect(eng)
+        for _ in range(40):          # let the election's traffic settle
+            eng.run_round()
+        per_round = []
+        for _ in range(2):
+            a = _reg()
+            for _ in range(20):
+                eng.run_round()
+            b = _reg()
+            assert _delta(a, b, "etcd_engine_rounds_total") == 20
+            per_round.append(
+                (_delta(a, b, "etcd_engine_d2h_syncs_total") / 20,
+                 _delta(a, b, "etcd_engine_d2h_bytes_total") / 20))
+        assert per_round[0] == per_round[1]
+        assert per_round[0][0] == 2
+        assert per_round[0][1] == 4 * 3 + 1      # (G, P) uint8 flags + bool
+        # run_round driven by hand: no gap, the loop's own phase.
+        assert _delta(a, b, "etcd_engine_round_phase_seconds_count",
+                      phase="gap") == 0
+        assert _delta(a, b, "etcd_engine_round_phase_seconds_count",
+                      phase="post") == 20
+    finally:
+        eng.stop()
+
+
+def test_round_ms_ewma_is_seeded_after_the_elections(tmp_path):
+    """/engine/status round_ms_ewma: 0 through the boot rounds (the
+    first pays the step's compile and, staggered, elects every group in
+    the same call), seeded by the first round that starts with a leader
+    everywhere."""
+    eng = _small_engine(tmp_path)
+    try:
+        eng.run_round()              # the compile round
+        assert eng._all_led() and eng.round_ms_ewma == 0.0
+        eng.run_round()
+        seed = eng.round_ms_ewma
+        assert 0.0 < seed < 5000.0
+        eng.run_round()
+        assert eng.round_ms_ewma != seed     # live: smoothed from here on
+    finally:
+        eng.stop()
+
+
+def test_profiler_trace_carries_the_round_phases(tmp_path):
+    """A few rounds under jax.profiler.trace: the host plane holds the
+    program's own stages (read with the benchmark's reader), and no
+    annotation encloses a whole round — the gap labeller would give
+    every idle gap to it."""
+    import jax
+    spec = importlib.util.spec_from_file_location(
+        "trace_reduce_for_obs_test",
+        os.path.join(REPO, "benchmark", "lib", "trace_reduce.py"))
+    trace_reduce = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(trace_reduce)
+    from etcd_tpu.server.request import METHOD_PUT, Request
+
+    eng = _small_engine(tmp_path / "data")
+    try:
+        _elect(eng)
+        out = str(tmp_path / "trace")
+        acks = []
+        with jax.profiler.trace(out):
+            for i in range(3):
+                th = threading.Thread(target=lambda i=i: acks.append(
+                    eng.do(i % 4, Request(method=METHOD_PUT,
+                                          path=f"/tr/k{i}", val="v"))))
+                th.start()
+                deadline = time.time() + 60
+                while th.is_alive() and time.time() < deadline:
+                    eng.run_round()
+                th.join(1)
+                assert not th.is_alive()
+        assert len(acks) == 3
+    finally:
+        eng.stop()
+    _, host, _, _ = trace_reduce.read_xplane(trace_reduce.find_xplane(out))
+    ours = [h for h in host if h[0].startswith("etcd.")]
+    names = {h[0] for h in ours}
+    assert {"etcd.round." + p for p in
+            ("stage", "dispatch", "readback", "record", "wal_submit",
+             "tail", "post")} <= names
+    assert {"etcd.record.gather", "etcd.record.admit"} <= names
+    assert "etcd.round.gap" not in names       # run_round driven by hand
+    stages = sorted(h for h in ours if h[0] == "etcd.round.stage")
+    posts = sorted(h for h in ours if h[0] == "etcd.round.post")
+    assert len(stages) >= 3 and len(posts) >= 3
+    for name, s, e in ours:
+        for st in stages:
+            nxt = next((p for p in posts if p[1] >= st[1]), None)
+            if nxt is not None and name not in ("etcd.round.stage",
+                                                "etcd.round.post"):
+                assert not (s <= st[1] and e >= nxt[2]), (
+                    f"{name} encloses a whole round")
+
+
+_OBS_OFF_CHILD = r"""
+import json, os, sys, threading, urllib.request
+os.environ["ETCD_TPU_OBS"] = "off"
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+from etcd_tpu.etcdhttp.tenants import EngineHttp
+from etcd_tpu.server.engine import EngineConfig, MultiEngine
+
+eng = MultiEngine(EngineConfig(
+    groups=4, peers=3, data_dir=sys.argv[1], window=16, max_ents=4,
+    heartbeat_tick=3, fsync=False, checkpoint_rounds=16,
+    request_timeout=60.0))
+eng.start()
+assert eng.wait_leaders(180), eng.failed
+front = EngineHttp(eng, port=0)
+front.start()
+base = front.url.rstrip("/")
+
+def http(method, url, body=None):
+    req = urllib.request.Request(url, method=method,
+                                 data=body.encode() if body else None)
+    with urllib.request.urlopen(req, timeout=60) as r:
+        return r.read().decode()
+
+before = http("GET", base + "/metrics")
+for i in range(8):
+    http("PUT", f"{base}/tenants/{i % 4}/v2/keys/off/k{i}", f"value=v{i}")
+    http("GET", f"{base}/tenants/{i % 4}/v2/keys/off/k{i}?quorum=true")
+after = http("GET", base + "/metrics")
+print(json.dumps({"before": before, "after": after,
+                  "rounds": eng.round_no}))
+front.stop()
+eng.stop()
+"""
+
+NEW_SERIES = (
+    "etcd_engine_round_phase_cpu_seconds_total",
+    "etcd_engine_record_part_seconds_sum",
+    "etcd_engine_record_part_seconds_count",
+    "etcd_engine_d2h_syncs_total",
+    "etcd_engine_d2h_bytes_total",
+    "etcd_engine_pending_wait_seconds_sum",
+    "etcd_engine_pending_wait_seconds_count",
+    "etcd_engine_checkpoint_seconds_sum",
+    "etcd_engine_checkpoint_seconds_count",
+    "etcd_jax_compiles_total",
+    "etcd_jax_compile_seconds_total",
+    "etcd_http_request_seconds_sum",
+    "etcd_http_request_seconds_count",
+    "etcd_http_front_self_seconds_sum",
+    "etcd_http_front_self_seconds_count",
+)
+
+
+def test_new_series_stay_flat_with_obs_off(tmp_path):
+    """ETCD_TPU_OBS=off: writes, quorum reads and checkpoints go by, and
+    every series this plane added is still there and has not moved; the
+    scrape-time CPU series are left out."""
+    r = subprocess.run(
+        [sys.executable, "-c", _OBS_OFF_CHILD, str(tmp_path / "off")],
+        cwd=REPO, capture_output=True, text=True, timeout=300)
+    assert r.returncode == 0, r.stderr[-2000:]
+    doc = json.loads(r.stdout.strip().splitlines()[-1])
+    assert doc["rounds"] > 32          # checkpoints at 16 rounds happened
+    parse = _load_script("etcd_top").parse_metrics
+    before, after = parse(doc["before"]), parse(doc["after"])
+    for series in NEW_SERIES:
+        assert _val(after, series) is not None, series
+        assert _delta(before, after, series) == 0, series
+    for phase in ("post", "gap"):
+        assert _delta(before, after, "etcd_engine_round_phase_seconds_count",
+                      phase=phase) == 0
+    names = {k[0] for k in after}
+    assert "process_cpu_seconds_total" not in names
+    assert "etcd_thread_cpu_seconds_total" not in names
+    assert "process_open_fds" in names
 
 
 # -- engine-level: /metrics over HTTP under concurrent load ------------------
@@ -333,6 +602,257 @@ def test_sigusr2_dumps_flight_ring(eng_http):
         doc = json.load(f)
     assert {e["name"] for e in doc["traceEvents"]
             if e["ph"] == "i"} == set(obs_mod.STAGE_NAMES)
+
+
+
+# -- PR 24 on the live engine: a whole round, a whole request, the CPU -------
+
+
+def _load(base, n=24, reads=True):
+    """n writes (and as many quorum reads) over HTTP; returns when the
+    handler threads have observed all of them (the front observes after
+    the response is written, so the client can be ahead of it)."""
+    a = _reg()
+    for i in range(n):
+        _http("PUT", f"{base}/tenants/{i % G}/v2/keys/acct/k{i}",
+              f"value=v{i}")
+        if reads:
+            _http("GET", f"{base}/tenants/{i % G}/v2/keys/acct/k{i}"
+                         "?quorum=true")
+    deadline = time.time() + 20
+    while time.time() < deadline:
+        b = _reg()
+        if (_delta(a, b, "etcd_http_request_seconds_count",
+                   kind="write") >= n
+                and (not reads or _delta(
+                    a, b, "etcd_http_request_seconds_count",
+                    kind="qread") >= n)):
+            return a, b
+        time.sleep(0.02)
+    raise AssertionError("the front did not observe every request")
+
+
+@pytest.mark.parametrize("series,labels", [
+    ("etcd_engine_round_phase_seconds_count", {"phase": "post"}),
+    ("etcd_engine_round_phase_seconds_count", {"phase": "gap"}),
+    *[("etcd_engine_round_phase_cpu_seconds_total", {"phase": p})
+      for p in obs_mod.ROUND_PHASES],
+    *[("etcd_engine_record_part_seconds_count", {"part": p})
+      for p in obs_mod.RECORD_PARTS],
+    ("etcd_engine_d2h_syncs_total", {}),
+    ("etcd_engine_d2h_bytes_total", {}),
+    ("etcd_engine_pending_wait_seconds_count", {}),
+    *[("etcd_http_request_seconds_count", {"kind": k})
+      for k in obs_mod.FRONT_KINDS],
+    *[("etcd_http_front_self_seconds_sum", {"kind": k})
+      for k in obs_mod.FRONT_KINDS],
+    ("process_cpu_seconds_total", {}),
+])
+def test_new_series_move_under_load(eng_http, series, labels):
+    """Every series this plane added is on /metrics (the HTTP
+    exposition, not only the registry) and moves under load."""
+    eng, base = eng_http
+    parse = _load_script("etcd_top").parse_metrics
+    a = parse(_http("GET", base + "/metrics"))
+    _load(base, n=6)
+    b = parse(_http("GET", base + "/metrics"))
+    assert _val(a, series, **labels) is not None, (series, labels)
+    assert _delta(a, b, series, **labels) > 0, (series, labels)
+
+
+@pytest.mark.parametrize("series", [
+    "etcd_engine_checkpoint_seconds_count",
+    "etcd_jax_compiles_total", "etcd_jax_compile_seconds_total"])
+def test_rare_event_series_are_exposed(eng_http, series):
+    """Checkpoints and compiles need not happen in a window; their
+    series are there all the same (the compile counters have counted
+    this process's step variants)."""
+    eng, base = eng_http
+    scrape = _load_script("etcd_top").parse_metrics(
+        _http("GET", base + "/metrics"))
+    assert _val(scrape, series) is not None
+    if "jax" in series:
+        assert _val(scrape, series) > 0
+
+
+def test_round_phases_tile_the_loop(eng_http):
+    """With the engine thread running, the seven disjoint phases' sums
+    add up to the wall window (2 %), under load and idle alike; CPU per
+    phase never exceeds its wall."""
+    eng, base = eng_http
+    stop = threading.Event()
+
+    def writer():
+        i = 0
+        while not stop.is_set():
+            _http("PUT", f"{base}/tenants/{i % G}/v2/keys/tile/k{i % 7}",
+                  f"value=v{i}")
+            i += 1
+
+    th = threading.Thread(target=writer)
+    th.start()
+    try:
+        for _ in range(2):
+            t0 = time.perf_counter()
+            a = _reg()
+            ta = time.perf_counter()
+            time.sleep(1.5)
+            t1 = time.perf_counter()
+            b = _reg()
+            tb = time.perf_counter()
+            # each registry walk takes a moment of its own: the window
+            # the deltas cover lies between these two
+            lo, hi = (t1 - ta) * 0.98, (tb - t0) * 1.02
+            wall = {p: _delta(a, b, "etcd_engine_round_phase_seconds_sum",
+                              phase=p) for p in obs_mod.ROUND_PHASES}
+            cpu = {p: _delta(a, b,
+                             "etcd_engine_round_phase_cpu_seconds_total",
+                             phase=p) for p in obs_mod.ROUND_PHASES}
+            assert lo <= sum(wall.values()) <= hi, (wall, lo, hi)
+            assert all(v > 0 for v in wall.values()), wall
+            for p in obs_mod.ROUND_PHASES:
+                assert cpu[p] <= wall[p] * 1.02 + 1e-3, (p, cpu, wall)
+            # wal_submit lies inside tail
+            assert _delta(a, b, "etcd_engine_round_phase_seconds_sum",
+                          phase="wal_submit") <= wall["tail"]
+            stop.set()               # second window: idle
+            th.join(30)
+    finally:
+        stop.set()
+        th.join(30)
+    assert not th.is_alive()
+
+
+def test_record_parts_add_up_to_record(eng_http):
+    eng, base = eng_http
+    a, b = _load(base, n=24, reads=False)
+    parts = {p: _delta(a, b, "etcd_engine_record_part_seconds_sum", part=p)
+             for p in obs_mod.RECORD_PARTS}
+    record = _delta(a, b, "etcd_engine_round_phase_seconds_sum",
+                    phase="record")
+    # the two scrapes walk the registry while rounds run: one round's
+    # record of slack on top of the 2 %
+    rounds = _delta(a, b, "etcd_engine_rounds_total")
+    assert abs(sum(parts.values()) - record) <= (0.02 * record
+                                                 + 2 * record / rounds)
+    assert parts["gather"] > 0 and parts["admit"] > 0
+    assert parts["build"] > 0
+
+
+def test_front_self_time_is_the_span_minus_the_engine_wait(eng_http):
+    eng, base = eng_http
+    a, b = _load(base, n=24)
+    for kind in obs_mod.FRONT_KINDS:
+        span = _delta(a, b, "etcd_http_request_seconds_sum", kind=kind)
+        self_s = _delta(a, b, "etcd_http_front_self_seconds_sum", kind=kind)
+        assert 0 <= self_s <= span, kind
+        assert (_delta(a, b, "etcd_http_request_seconds_count", kind=kind)
+                == _delta(a, b, "etcd_http_front_self_seconds_count",
+                          kind=kind))
+    blocked = (_delta(a, b, "etcd_http_request_seconds_sum", kind="write")
+               - _delta(a, b, "etcd_http_front_self_seconds_sum",
+                        kind="write"))
+    proposed = _delta(
+        a, b, "etcd_server_proposal_durations_milliseconds_sum") / 1e3
+    assert blocked == pytest.approx(proposed, rel=0.05)
+    waited = (_delta(a, b, "etcd_http_request_seconds_sum", kind="qread")
+              - _delta(a, b, "etcd_http_front_self_seconds_sum",
+                       kind="qread"))
+    read = _delta(a, b, "etcd_read_index_durations_milliseconds_sum") / 1e3
+    assert waited == pytest.approx(read, rel=0.05)
+    # A coalesced batch is one request whose thread waits in
+    # collect_many: a write, its wait handed over the same way.
+    a = _reg()
+    body = json.dumps({"reqs": [{"method": "PUT", "path": f"/b/k{i}",
+                                 "value": "v"} for i in range(3)]})
+    assert len(json.loads(_http("POST", base + "/tenants/0/batch",
+                                body))["results"]) == 3
+    deadline = time.time() + 20
+    while time.time() < deadline:
+        b = _reg()
+        if _delta(a, b, "etcd_http_request_seconds_count", kind="write"):
+            break
+        time.sleep(0.02)
+    assert _delta(a, b, "etcd_http_request_seconds_count", kind="write") == 1
+    blocked = (_delta(a, b, "etcd_http_request_seconds_sum", kind="write")
+               - _delta(a, b, "etcd_http_front_self_seconds_sum",
+                        kind="write"))
+    # (the batch's window is observed as three proposals of a third each)
+    assert blocked == pytest.approx(_delta(
+        a, b, "etcd_server_proposal_durations_milliseconds_sum") / 1e3,
+        rel=0.05)
+    # /metrics, /engine/status, /debug/* never reach the engine: other,
+    # all of it self time.
+    a = _reg()
+    for path in ("/metrics", "/engine/status", "/debug/traces"):
+        _http("GET", base + path)
+    deadline = time.time() + 20
+    while time.time() < deadline:
+        b = _reg()
+        if _delta(a, b, "etcd_http_request_seconds_count",
+                  kind="other") >= 3:
+            break
+        time.sleep(0.02)
+    assert _delta(a, b, "etcd_http_request_seconds_count", kind="other") == 3
+    assert (_delta(a, b, "etcd_http_request_seconds_sum", kind="other")
+            == pytest.approx(_delta(a, b, "etcd_http_front_self_seconds_sum",
+                                    kind="other")))
+    assert _delta(a, b, "etcd_http_request_seconds_count", kind="write") == 0
+
+
+def test_pending_wait_counts_every_acked_write(eng_http):
+    eng, base = eng_http
+    a, b = _load(base, n=24, reads=False)
+    acked = _delta(a, b, "etcd_engine_acked_requests_total")
+    assert acked == 24
+    assert _delta(a, b, "etcd_engine_pending_wait_seconds_count") >= acked
+    waited = _delta(a, b, "etcd_engine_pending_wait_seconds_sum")
+    # a write waits for the round that stages it, inside its whole ack
+    assert 0 < waited < _delta(
+        a, b, "etcd_server_proposal_durations_milliseconds_sum") / 1e3
+
+
+def test_thread_cpu_series_name_the_classes_and_sum_to_the_process(eng_http):
+    eng, base = eng_http
+    parse = _load_script("etcd_top").parse_metrics
+    _load(base, n=12)
+    scrape = parse(_http("GET", base + "/metrics"))
+    threads = {dict(k[1])["thread"]: v for k, v in scrape.items()
+               if k[0] == "etcd_thread_cpu_seconds_total"}
+    if not threads:
+        pytest.skip("no per-thread CPU clock on this platform")
+    assert set(threads) == {"round", "wal", "applier", "front"}
+    proc = scrape[("process_cpu_seconds_total", ())]
+    assert sum(threads.values()) == pytest.approx(proc, rel=0.05)
+    assert threads["round"] > 0 and threads["applier"] > 0
+    assert threads["round"] < proc
+
+
+def test_sampled_rid_carries_the_front_marks_in_order(eng_http):
+    """/debug/traces: front_in (the handler's start) ... acked -> woke
+    (the handler thread runs again) -> replied (response written)."""
+    eng, base = eng_http
+    _load(base, n=2 * G)
+    deadline = time.time() + 20
+    full = []
+    while time.time() < deadline and not full:
+        tr = json.loads(_http("GET", base + "/debug/traces"))
+        full = [s["stages"] for s in tr["spans"]
+                if {"front_in", "admitted", "woke", "replied"}
+                <= set(s["stages"])]
+        time.sleep(0.05)
+    assert full, "no sampled write carries the front's marks"
+    for stages in full:
+        order = list(stages)         # sorted by time in the dump
+        assert order[0] == "front_in" and order[-1] == "replied"
+        for x, y in (("front_in", "submit"), ("submit", "admitted"),
+                     ("admitted", "acked"), ("acked", "woke"),
+                     ("woke", "replied")):
+            assert order.index(x) < order.index(y), order
+    # a sampled quorum read has them too (no admission: nothing proposed)
+    reads = [s["stages"] for s in tr["spans"]
+             if "replied" in s["stages"] and "admitted" not in s["stages"]]
+    assert reads and all(list(s)[0] == "front_in" for s in reads)
 
 
 # -- trace ids survive SIGKILL + WAL replay ----------------------------------
