@@ -19,7 +19,7 @@ from etcd_tpu.utils import metrics
 from etcd_tpu.server.cluster import Member, STORE_KEYS_PREFIX
 from etcd_tpu.server.request import (METHOD_DELETE, METHOD_GET, METHOD_POST,
                                      METHOD_PUT, Request)
-from etcd_tpu.etcdhttp.web import Ctx, Router
+from etcd_tpu.etcdhttp.web import REPLIED, Ctx, LoopOp, Router
 from etcd_tpu.store.event import Event
 
 KEYS_PREFIX = "/v2/keys"
@@ -29,6 +29,8 @@ STATS_PREFIX = "/v2/stats"
 
 _BOOL_FIELDS = ("recursive", "sorted", "quorum", "wait", "stream", "dir",
                 "refresh", "noValueOnSuccess")
+
+_KEYS_METHODS = ("GET", "PUT", "POST", "DELETE", "HEAD")
 
 # Actions whose successful response is 201 Created (reference
 # store/event.go IsCreated: create, or set with prevExist=false).
@@ -112,7 +114,7 @@ class ClientAPI:
     # -- /v2/keys -------------------------------------------------------------
 
     def handle_keys(self, ctx: Ctx, suffix: str) -> None:
-        if ctx.method not in ("GET", "PUT", "POST", "DELETE", "HEAD"):
+        if ctx.method not in _KEYS_METHODS:
             ctx.send(405, b"Method Not Allowed",
                      headers={"Allow": "GET, PUT, POST, DELETE, HEAD"})
             return
@@ -130,6 +132,47 @@ class ClientAPI:
         else:  # a Watcher from store.watch
             self._handle_watch(ctx, r, result)
 
+    def begin_keys(self, ctx: Ctx, suffix: str):
+        """handle_keys cut in two at `self.server.do(r)`, for the front's
+        event loop (web.Router.add's `begin`; must not block). Where the
+        server offers a non-blocking submit (the engine's _TenantServer;
+        the single-cluster EtcdServer has only a blocking do), a write or
+        a `?quorum=true` read is parsed here and comes back as a LoopOp:
+        the loop submits it and calls the op's `finish` with the result
+        the ack path released, which writes the reply handle_keys would
+        have written. A local read (no quorum, no wait) waits for nothing:
+        it is served from the store here and now, REPLIED. None sends the
+        request down the thread path to handle_keys as written: watches
+        (a watch holds its connection), a tenant with auth on (checking
+        a password hashes it: milliseconds), any other method."""
+        submitter = getattr(self.server, "submitter", None)
+        if submitter is None or ctx.method not in _KEYS_METHODS:
+            return None
+        if ctx.method in ("GET", "HEAD") and ctx.has("wait"):
+            return None
+        try:
+            if self.security is not None and self.security.enabled():
+                return None
+            r = self._parse_key_request(ctx, suffix)
+            no_value = _parse_bool(ctx, "noValueOnSuccess")
+            if r.method == METHOD_GET and not r.quorum:
+                self._write_key_event(ctx, self.server.do(r),
+                                      no_value=no_value)
+                return REPLIED
+        except errors.EtcdError as e:
+            self._error(ctx, e)
+            return REPLIED
+
+        def finish(result) -> None:
+            if isinstance(result, errors.EtcdError):
+                self._error(ctx, result)
+            else:
+                self._write_key_event(ctx, result, no_value=no_value)
+
+        return LoopOp(submitter, self.server.submit_item(r), finish,
+                      kind="qread" if r.method == METHOD_GET else "write",
+                      timeout=self.server.request_timeout)
+
     def _parse_key_request(self, ctx: Ctx, suffix: str) -> Request:
         """reference parseKeyRequest client.go:390-534."""
         method = "GET" if ctx.method == "HEAD" else ctx.method
@@ -143,7 +186,8 @@ class ClientAPI:
             # internal /0 cluster-metadata tree.
             raise errors.EtcdError(errors.ECODE_INVALID_FORM,
                                    cause=f"invalid key path {suffix!r}")
-        flags = {f: _parse_bool(ctx, f) for f in _BOOL_FIELDS}
+        flags = {f: ctx.has(f) and _parse_bool(ctx, f)
+                 for f in _BOOL_FIELDS}
 
         if ctx.has("prevValue") and ctx.value("prevValue") == "":
             raise errors.EtcdError(errors.ECODE_PREV_VALUE_REQUIRED,

@@ -69,11 +69,19 @@ class _TenantServer:
     parsing, CAS/CAD, long-poll + stream watch — is shared verbatim with
     the single-cluster server (etcdhttp/client.py)."""
 
-    def __init__(self, engine, g: int) -> None:
+    def __init__(self, engine, g: int, submitter=None) -> None:
         self._engine = engine
         self._g = g
         self.cluster = _TenantCluster(g)
         self.clock = time.time
+        # What ClientAPI.begin_keys looks for: the engine's non-blocking
+        # submit, if it has one (TenantAPI).
+        self.submitter = submitter
+        self.request_timeout = engine.cfg.request_timeout
+
+    def submit_item(self, r):
+        """The (g, request) pair the submitter stages for this tenant."""
+        return self._g, r
 
     def cluster_version(self) -> str:
         # All tenants of one engine run the binary's version — there is no
@@ -122,10 +130,18 @@ class TenantAPI:
         # previous generation's SecurityHandler/store adapters.
         self._apis: Dict[int, tuple] = {}   # g -> (gen, ClientAPI)
         self._secs: Dict[int, tuple] = {}   # g -> (gen, SecurityHandler)
+        # An engine that can take a request without a thread parked on it
+        # (MultiEngine.submit_pairs; the multi-host engine has only the
+        # blocking do) is the front's submitter (web.LoopOp) for ALL its
+        # tenants, so a select pass of the loop is one submit across
+        # tenants.
+        self._submitter = (engine if hasattr(engine, "submit_pairs")
+                           else None)
 
     def install(self, router: Router) -> None:
         router.add("/tenants", self.handle_tenants_root, exact=True)
-        router.add("/tenants/", self.handle_tenants)
+        router.add("/tenants/", self.handle_tenants,
+                   begin=self.begin_tenants)
         router.add("/engine/status", self.handle_engine_status)
         router.add("/metrics", self.handle_metrics)
         router.add("/debug/flight", self.handle_debug_flight)
@@ -183,7 +199,7 @@ class TenantAPI:
         # doer seam bound to this group's consensus) — tenants enable
         # and administer auth independently of each other.
         from etcd_tpu.etcdhttp.client_security import SecurityHandler
-        srv = _TenantServer(self.engine, g)
+        srv = _TenantServer(self.engine, g, self._submitter)
         sec = SecurityHandler(srv)
         api = ClientAPI(srv, security=sec)
         self._secs[g] = (gen, sec)
@@ -215,6 +231,25 @@ class TenantAPI:
         if g is not None and self.engine.tenant_active(g):
             return self._sec(g).check_members_access(ctx)
         return True
+
+    def begin_tenants(self, ctx: Ctx, suffix: str):
+        """The event loop's way into /tenants/{g}/v2/keys (web.Router.add's
+        `begin`; must not block): a keys request of a provisioned tenant
+        goes to its ClientAPI.begin_keys. Everything else under /tenants/
+        (lifecycle verbs, status, conf, security, stats, batch,
+        batchframe, and every request that is answered with an error
+        here) is handle_tenants' on a thread, as written."""
+        g, _, rest = suffix.partition("/")
+        if not (rest == "v2/keys" or rest.startswith("v2/keys/")):
+            return None
+        try:
+            g = int(g)
+        except ValueError:
+            return None
+        if not (0 <= g < self.engine.cfg.groups
+                and self.engine.tenant_active(g)):
+            return None
+        return self._api(g).begin_keys(ctx, rest[len("v2/keys"):])
 
     def handle_tenants(self, ctx: Ctx, suffix: str) -> None:
         parts = suffix.split("/", 1)

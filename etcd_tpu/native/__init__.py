@@ -6,6 +6,7 @@ tests assert byte-identical behavior — tests/test_native.py).
 """
 from __future__ import annotations
 
+import os
 import struct
 import zlib
 from typing import List, Tuple
@@ -33,6 +34,36 @@ try:
 except ImportError:
     _c_scan_requests = _c_format_responses = None
     HAVE_NATIVE_INGRESS = False
+
+
+def _py_recv_many(fds, bufsize: int) -> list:
+    out = []
+    for fd in fds:
+        try:
+            out.append(os.read(fd, bufsize))
+        except OSError as e:
+            out.append(-e.errno)
+    return out
+
+
+def _py_send_many(items) -> list:
+    out = []
+    for fd, data in items:
+        try:
+            out.append(os.write(fd, data))
+        except OSError as e:
+            out.append(-e.errno)
+    return out
+
+
+try:
+    from etcd_tpu.native.frontcore import recv_many, send_many
+    HAVE_NATIVE_FRONT = True
+except ImportError:
+    # The HTTP front's batched socket calls (frontcore.c has the why),
+    # one at a time: the same lists, an interpreter-lock hand-off a call.
+    recv_many, send_many = _py_recv_many, _py_send_many
+    HAVE_NATIVE_FRONT = False
 
 
 def pack_multi(items, tag: int) -> bytes:

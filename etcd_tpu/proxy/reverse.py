@@ -69,7 +69,7 @@ class ReverseProxy:
                                       else client_ip)
 
         # Original request target including the query string.
-        target = ctx._h.path
+        target = ctx.target
 
         for ep in endpoints:
             conn = self._dial_and_send(ep.url, ctx.method, target, ctx.body,

@@ -73,7 +73,7 @@ from etcd_tpu.server.request import (METHOD_DELETE, METHOD_GET, METHOD_POST,
 from etcd_tpu.store import new_store
 from etcd_tpu.store.event import LazyWriteEvent
 from etcd_tpu.utils import idutil
-from etcd_tpu.utils.wait import Wait
+from etcd_tpu.utils.wait import Sink, Wait
 
 log = logging.getLogger("etcd_tpu.engine")
 
@@ -306,6 +306,20 @@ class _AckBatch:
     def __init__(self) -> None:
         self.items: List[Tuple[int, Any]] = []
         self.acked = 0
+
+
+class _Submitted:
+    """One request staged through submit_pairs: what settle() / expire()
+    need to close its accounts. The front keys its in-flight table on
+    `rid`."""
+
+    __slots__ = ("rid", "g", "read", "t0")
+
+    def __init__(self, rid: int, g: int, read: bool, t0: float) -> None:
+        self.rid = rid
+        self.g = g
+        self.read = read
+        self.t0 = t0
 
 
 class _ApplierShard:
@@ -956,10 +970,10 @@ class MultiEngine:
                     if tr.every:
                         for rid, _res in batch.items:
                             tr.mark(rid, "durable", ticket=view[4])
-                    for rid, res in batch.items:
-                        self.wait.trigger(rid, res)
-                        if tr.every:
                             tr.mark(rid, "acked")
+                    # The whole ack batch at once: the waiters the event
+                    # loop front registered are released under one signal.
+                    self.wait.trigger_many(batch.items)
                     sh.acct.acked += batch.acked
                     if o:
                         o.c_acked.inc(batch.acked)
@@ -1155,6 +1169,126 @@ class MultiEngine:
             tr.mark(rid, "woke")
             fl.trace = (tr, rid)
 
+    # ------------------------------------------------------------------
+    # the event-loop front's submit (etcdhttp/web.py): nobody parks a
+    # thread; results reach the front through a utils.wait.Sink
+    # ------------------------------------------------------------------
+
+    @property
+    def tracer(self):
+        """The sampled-request tracer, for the front's own marks."""
+        return self.obs.tracer
+
+    def submit_pairs(self, pairs: List[Tuple[int, Request]],
+                     sink: Sink) -> List[Any]:
+        """Stage one select pass of the front's requests, writes and
+        `?quorum=true` reads of any tenants, under ONE lock acquisition,
+        and return at once with one token per pair (settle() or expire()
+        takes it back; a pair that cannot be staged has an EtcdError in
+        its token's place and is not staged). A write is enqueued exactly
+        as do() enqueues it; a quorum read is parked exactly as
+        _quorum_read parks it, under the same lock the round takes its
+        read_take snapshot under, so a read parked after a round's
+        snapshot waits for its own round's confirmation. Results come
+        through `sink` from the same trigger calls that release a blocked
+        do(): a write's only after its round's fsync (the applier's
+        release behind wait_durable), a read's from _serve_ripe_reads."""
+        obs_on = self.obs.enabled
+        tr = self.obs.tracer
+        register = self.wait.register
+        writes: List[tuple] = []
+        reads: List[tuple] = []
+        tokens: List[Any] = []
+        t0 = time.perf_counter()
+        for g, r in pairs:
+            # One request's fault refuses that request: its place in
+            # `tokens` holds the error, nothing of it is registered or
+            # staged, and the rest of the pass (other tenants') goes on.
+            try:
+                if r.id == 0:
+                    r = Request(**{**r.__dict__, "id": self.reqid.next()})
+                read = r.method == METHOD_GET
+                if read:
+                    if not r.quorum or r.wait:
+                        raise errors.EtcdError(
+                            errors.ECODE_INVALID_FORM,
+                            cause="submit_pairs takes writes and quorum "
+                                  "reads only")
+                    item = (g, r)
+                elif r.method in (METHOD_PUT, METHOD_POST, METHOD_DELETE):
+                    item = (g, (r.id, bytes([P_REQ]) + r.encode(), r, t0))
+                else:
+                    raise errors.EtcdError(errors.ECODE_INVALID_FORM,
+                                           cause=f"bad method {r.method}")
+                register(r.id, sink)        # ValueError: a duplicate id
+            except Exception as e:  # noqa: BLE001 — answered, per request
+                tokens.append(e if isinstance(e, errors.EtcdError) else
+                              errors.EtcdError(errors.ECODE_RAFT_INTERNAL,
+                                               cause=str(e)))
+                continue
+            (reads if read else writes).append(item)
+            tokens.append(_Submitted(r.id, g, read, t0))
+            if tr.every:
+                tr.mark(r.id, "submit", g=g)
+        lease = 0
+        try:
+            with self._lock:
+                pending, dirty = self._pending, self._dirty
+                for g, item in writes:
+                    pending[g].append(item)
+                    dirty.add(g)
+                for g, r in reads:
+                    lease += self._park_read(g, r)
+        except BaseException:
+            # Nobody will hold these tokens: leave no waiter behind.
+            for tok in tokens:
+                if type(tok) is _Submitted:
+                    self.wait.cancel(tok.rid)
+            raise
+        if obs_on:
+            if writes:
+                metrics.propose_pending.inc(len(writes))
+            if reads:
+                self.obs.g_read_parked.inc(len(reads))
+                if lease:
+                    self.obs.c_reads_lease.inc(lease)
+        return tokens
+
+    def settle(self, tok: "_Submitted", result: Any) -> Any:
+        """The front drained `tok`'s result from its sink: observe what
+        do() / _quorum_read observe when their thread wakes, and return
+        the result with a LazyWriteEvent resolved (here, off the apply
+        stage). An EtcdError result is returned, not raised."""
+        if self.obs.enabled:
+            dt = (time.perf_counter() - tok.t0) * 1000.0
+            if tok.read:
+                self.obs.g_read_parked.dec()
+                self.obs.s_read_dur.observe(dt)
+            else:
+                metrics.propose_pending.dec()
+                metrics.propose_durations.observe(dt)
+        if type(result) is LazyWriteEvent:
+            return result.resolve()
+        return result
+
+    def expire(self, tok: "_Submitted") -> errors.EtcdError:
+        """The front's sweep found `tok` past cfg.request_timeout (or is
+        shutting down): cancel the waiter and answer what a timed-out
+        do() / _quorum_read raises, with their counters."""
+        self.wait.cancel(tok.rid)
+        if self.obs.enabled:
+            if tok.read:
+                self.obs.c_reads_failed.inc()
+                self.obs.g_read_parked.dec()
+            else:
+                metrics.propose_failed.inc()
+                metrics.propose_pending.dec()
+        return errors.EtcdError(
+            errors.ECODE_RAFT_INTERNAL,
+            cause=("quorum read timed out" if tok.read
+                   else "request timed out"),
+            index=int(self.applied[tok.g]))
+
     def do_many(self, g: int, reqs: List[Request],
                 timeout: Optional[float] = None) -> List[Any]:
         """Serve a BATCH of write requests against group g from one
@@ -1284,26 +1418,11 @@ class MultiEngine:
         q = self.wait.register(r.id)
         t0 = time.perf_counter()
         with self._lock:
-            lease_ms = self.cfg.read_lease_ms
-            if (lease_ms > 0
-                    and time.monotonic() < float(self._lease_until[g])
-                    and int(self._lease_term[g]) == self._mirror_term(g)):
-                # Lease fast path: a confirmation round within the lease
-                # window proved leadership, and the lease term still
-                # matches — skip the confirmation and park directly at
-                # the CURRENT commit mirror (>= every acked write's
-                # index, so acked writes stay visible).
-                self._ripe[g].append((r.id, r, self._mirror_commit(g)))
-                self._ripe_dirty.add(g)
-                self._ripe_waiting += 1
-                if obs_on:
-                    self.obs.c_reads_lease.inc()
-            else:
-                self._reads[g].append((r.id, r))
-                self._read_dirty.add(g)
-                self._reads_waiting += 1
-            if obs_on:
-                self.obs.g_read_parked.inc()
+            lease = self._park_read(g, r)
+        if obs_on:
+            if lease:
+                self.obs.c_reads_lease.inc()
+            self.obs.g_read_parked.inc()
         try:
             result = q.get(timeout=timeout or self.cfg.request_timeout)
         except queue.Empty:
@@ -1325,6 +1444,28 @@ class MultiEngine:
         if isinstance(result, errors.EtcdError):
             raise result
         return result
+
+    def _park_read(self, g: int, r: Request) -> int:
+        """Park one quorum read (self._lock held): on the group's parked
+        queue for the next round's ReadIndex confirmation or, inside a
+        live read lease, straight on the ripe queue. Returns 1 for the
+        lease path, else 0."""
+        if (self.cfg.read_lease_ms > 0
+                and time.monotonic() < float(self._lease_until[g])
+                and int(self._lease_term[g]) == self._mirror_term(g)):
+            # Lease fast path: a confirmation round within the lease
+            # window proved leadership, and the lease term still
+            # matches — skip the confirmation and park directly at
+            # the CURRENT commit mirror (>= every acked write's
+            # index, so acked writes stay visible).
+            self._ripe[g].append((r.id, r, self._mirror_commit(g)))
+            self._ripe_dirty.add(g)
+            self._ripe_waiting += 1
+            return 1
+        self._reads[g].append((r.id, r))
+        self._read_dirty.add(g)
+        self._reads_waiting += 1
+        return 0
 
     def _confirm_reads(self, read_take: Dict[int, int], conf: np.ndarray,
                        rc: np.ndarray) -> None:
@@ -1397,6 +1538,7 @@ class MultiEngine:
         # read_only.go advance; hot-key read storms collapse to one
         # tree walk per key per round.)
         memo: Dict[Tuple[int, str, bool, bool], Any] = {}
+        results: List[Tuple[int, Any]] = []
         for rid, r, g in served:
             k = (g, r.path, r.recursive, r.sorted)
             result = memo.get(k)
@@ -1407,9 +1549,10 @@ class MultiEngine:
                 except errors.EtcdError as err:
                     result = err
                 memo[k] = result
-            self.wait.trigger(rid, result)
+            results.append((rid, result))
             if tr.every:
                 tr.mark(rid, "acked", g=g)
+        self.wait.trigger_many(results)
         if o:
             o.c_reads_served.inc(len(served))
 

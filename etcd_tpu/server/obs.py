@@ -129,14 +129,31 @@ jax_compile_seconds = metrics.Counter(
     "Seconds those builds took.")
 http_request = metrics.LabeledHistogram(
     "etcd_http_request_seconds",
-    "HTTP front: parsed request line to response written, by kind (write "
-    "= went through the engine's propose path, qread = through the read "
-    "plane, other = the rest); streams and watches left out.", ("kind",))
+    "HTTP front: parsed request line to reply handed to the socket, by "
+    "kind (write = went through the engine's propose path, qread = "
+    "through the read plane, other = the rest); streams and watches left "
+    "out.", ("kind",))
 http_front_self = metrics.LabeledHistogram(
     "etcd_http_front_self_seconds",
-    "HTTP front self time: etcd_http_request_seconds minus what the same "
-    "thread spent blocked inside the engine waiting for its ack.",
+    "HTTP front self time: etcd_http_request_seconds minus the request's "
+    "wait for its ack (submit to the completion drained by the event "
+    "loop; on a handler thread, the time blocked inside the engine).",
     ("kind",))
+FRONT_PATHS = ("loop", "thread")
+http_front_served = metrics.LabeledCounter(
+    "etcd_http_front_served_total",
+    "Requests by where the front ran their handler: loop (the event "
+    "loop itself: split-phase keys requests, replies that need no "
+    "handler) or thread (what may block: watches, streams, upgrades, "
+    "admin routes, TLS connections, servers with only a blocking do).",
+    ("path",))
+http_front_wakes = metrics.Counter(
+    "etcd_http_front_wakes_total",
+    "Passes of the front's event loop that drained at least one "
+    "completion from its sink (one signal per ack batch).")
+http_front_completions = metrics.Counter(
+    "etcd_http_front_completions_total",
+    "Completions the front's event loop drained from its sink.")
 kernel_step = metrics.Histogram(
     "etcd_engine_kernel_step_seconds",
     "Host wall time of the round's dispatch + readback phases (enqueue "
@@ -490,8 +507,10 @@ class Tracer:
 
 
 class _FrontLocal(threading.local):
-    """What one handler thread's current request learned inside the
-    engine, read back by etcdhttp/web.py when the response is written:
+    """What one worker thread's current request (the front's thread
+    path; a request served by the event loop carries these on its LoopOp
+    instead) learned inside the engine, read back by etcdhttp/web.py
+    when the response is written:
     `blocked` seconds the thread waited for its ack (do()'s and
     _quorum_read's own clocks, handed over instead of clocking twice),
     the request's `kind`, the request's start `t_in` (perf_counter) and,
@@ -596,8 +615,9 @@ class ThreadCpu:
 def cpu_exposition(thread_cpu: ThreadCpu) -> List[str]:
     """/metrics lines for process_cpu_seconds_total and
     etcd_thread_cpu_seconds_total{thread}: `front` is the process minus
-    the named classes (handler threads come and go with their
-    connections; the JAX runtime's own threads fall under it too)."""
+    the named classes (the HTTP front's event loop, its worker threads,
+    which come and go; the JAX runtime's own threads fall under it
+    too)."""
     proc = time.process_time()
     lines = [
         "# HELP process_cpu_seconds_total Total user and system CPU time "
@@ -610,8 +630,8 @@ def cpu_exposition(thread_cpu: ThreadCpu) -> List[str]:
         lines += [
             "# HELP etcd_thread_cpu_seconds_total CPU time by thread "
             "class: round (the round loop), wal (writer shards), applier "
-            "(applier shards), front (the process minus those: HTTP "
-            "handler threads and the runtime's own).",
+            "(applier shards), front (the process minus those: the HTTP "
+            "front's loop and workers, and the runtime's own).",
             "# TYPE etcd_thread_cpu_seconds_total counter"]
         for cls, v in sorted(named.items()):
             lines.append(
@@ -678,6 +698,8 @@ class EngineObs:
         for k in FRONT_KINDS:
             http_request.labels(k)
             http_front_self.labels(k)
+        for p in FRONT_PATHS:
+            http_front_served.labels(p).inc(0.0)
         if self.enabled:
             install_compile_listener()
         self.h_step = kernel_step

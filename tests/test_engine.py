@@ -980,3 +980,171 @@ def test_engine_batched_fast_path_mixed_entry(tmp_path):
                                  False).node.modified_index == idxs[i]
     assert eng2.store(0).get("/seed", False, False).node.value == "s2"
     eng2.wal.close()
+
+
+# -- the event-loop front's submit (submit_pairs / settle / expire) ----------
+
+
+def test_sink_submitted_writes_are_released_only_behind_wait_durable(
+        tmp_path):
+    """submit_pairs stages writes of several tenants under one lock and
+    parks no thread; their results reach the sink from the applier's
+    release behind wal.wait_durable, never earlier: with the durability
+    gate held the writes are applied and nothing is delivered."""
+    from etcd_tpu.utils import metrics
+    from etcd_tpu.utils.wait import Sink
+
+    eng = MultiEngine(make_cfg(tmp_path / "sink"))
+    run_until(eng, lambda: all(eng.leader_slot(g) >= 0 for g in range(4)),
+              msg="leaders")
+    woke = []
+    sink = Sink(lambda: woke.append(1))
+    gate, entered = threading.Event(), threading.Event()
+    real = eng.wal.wait_durable
+
+    def held(ticket):
+        entered.set()
+        gate.wait(30)
+        return real(ticket)
+
+    eng.wal.wait_durable = held
+    pending0 = metrics.propose_pending.value
+    toks = eng.submit_pairs(
+        [(0, Request(method="PUT", path="/a", val="1")),
+         (1, Request(method="PUT", path="/b", val="2")),
+         (0, Request(method="PUT", path="/c", val="3"))], sink)
+    assert [t.g for t in toks] == [0, 1, 0]
+    assert metrics.propose_pending.value == pending0 + 3
+    stop = threading.Event()
+
+    def drive():            # blocks on the applier's queue cap meanwhile
+        while not stop.is_set():
+            eng.run_round()
+
+    driver = threading.Thread(target=drive, daemon=True)
+    driver.start()
+    try:
+        assert entered.wait(30), "no ack batch reached the gate"
+
+        def applied():
+            try:
+                return [eng.store(g).get(k, False, False).node.value
+                        for g, k in ((0, "/a"), (1, "/b"), (0, "/c"))]
+            except errors.EtcdError:
+                return None
+
+        deadline = time.time() + 30
+        while applied() is None and time.time() < deadline:
+            time.sleep(0.01)
+        # applied (the stores run ahead of the WAL pipeline) ...
+        assert applied() == ["1", "2", "3"]
+        time.sleep(0.3)
+        # ... and not released: no signal, nothing in the sink
+        assert not woke and sink.drain() == []
+        gate.set()
+        deadline = time.time() + 30
+        while len(sink._items) < 3 and time.time() < deadline:
+            time.sleep(0.01)
+    finally:
+        gate.set()
+        stop.set()
+        driver.join(30)
+    # however many ack batches the three came in, the owner that has not
+    # drained yet was signalled once
+    assert woke == [1]
+    got = dict(sink.drain())
+    assert set(got) == {t.rid for t in toks}
+    vals = [eng.settle(t, got[t.rid]).node.value for t in toks]
+    assert vals == ["1", "2", "3"]
+    assert metrics.propose_pending.value == pending0
+    eng.stop()
+
+
+def test_front_sweep_times_a_request_out_like_do(tmp_path):
+    """With nobody running rounds a request can only time out: the
+    front's sweep answers what a timed-out do() / _quorum_read raises,
+    cancels the waiter and closes the request's accounts."""
+    import http.client
+    import json
+
+    from etcd_tpu.etcdhttp.tenants import EngineHttp
+    from etcd_tpu.utils import metrics
+
+    eng = MultiEngine(make_cfg(tmp_path / "sweep", request_timeout=0.4))
+    run_until(eng, lambda: all(eng.leader_slot(g) >= 0 for g in range(4)),
+              msg="leaders")
+    http_front = EngineHttp(eng)
+    http_front.start()
+    try:
+        pending0 = metrics.propose_pending.value
+        failed0 = metrics.propose_failed.value
+        parked0 = eng.obs.g_read_parked.value
+        rfailed0 = eng.obs.c_reads_failed.value
+        c = http.client.HTTPConnection("127.0.0.1", http_front.http.port,
+                                       timeout=10)
+        t0 = time.time()
+        c.request("PUT", "/tenants/2/v2/keys/k", body="value=v", headers={
+            "Content-Type": "application/x-www-form-urlencoded"})
+        resp = c.getresponse()
+        err = json.loads(resp.read())
+        assert 0.4 <= time.time() - t0 < 5.0
+        assert resp.status == 500
+        assert err["errorCode"] == errors.ECODE_RAFT_INTERNAL
+        assert err["cause"] == "request timed out"
+        assert metrics.propose_pending.value == pending0
+        assert metrics.propose_failed.value == failed0 + 1
+        c.request("GET", "/tenants/2/v2/keys/k?quorum=true")
+        resp = c.getresponse()
+        err = json.loads(resp.read())
+        assert resp.status == 500
+        assert err["cause"] == "quorum read timed out"
+        assert eng.obs.g_read_parked.value == parked0
+        assert eng.obs.c_reads_failed.value == rfailed0 + 1
+        assert not eng.wait._waiters    # both rids were cancelled
+    finally:
+        http_front.stop()
+        eng.stop()
+
+
+def test_submit_pairs_refuses_a_bad_pair_alone(tmp_path):
+    """A pair submit_pairs cannot stage (a local read or a watch, a bad
+    method, an id already waited for) has an EtcdError in its token's
+    place and leaves no waiter; the other tenants' pairs of the same call
+    are staged and acknowledged as if it had not been there."""
+    from etcd_tpu.utils import metrics
+    from etcd_tpu.utils.wait import Sink
+
+    eng = MultiEngine(make_cfg(tmp_path / "refuse"))
+    run_until(eng, lambda: all(eng.leader_slot(g) >= 0 for g in range(4)),
+              msg="leaders")
+    sink = Sink(lambda: None)
+    pending0 = metrics.propose_pending.value
+    taken = eng.reqid.next()
+    eng.wait.register(taken)
+    toks = eng.submit_pairs(
+        [(0, Request(method="PUT", path="/a", val="1")),
+         (1, Request(method="GET", path="/a")),                 # local read
+         (1, Request(method="GET", path="/a", quorum=True, wait=True)),
+         (2, Request(method="PATCH", path="/a")),
+         (2, Request(method="PUT", path="/a", val="2", id=taken)),
+         (3, Request(method="PUT", path="/a", val="3"))], sink)
+    bad = toks[1:5]
+    assert all(isinstance(t, errors.EtcdError) for t in bad)
+    assert [t.code for t in bad] == [
+        errors.ECODE_INVALID_FORM, errors.ECODE_INVALID_FORM,
+        errors.ECODE_INVALID_FORM, errors.ECODE_RAFT_INTERNAL]
+    assert "duplicate" in bad[3].cause
+    good = [toks[0], toks[5]]
+    assert [t.g for t in good] == [0, 3]
+    # two waiters of this call and the one the test took: nothing is left
+    # behind for a refused pair, and only the staged ones are pending
+    assert set(eng.wait._waiters) == {taken, good[0].rid, good[1].rid}
+    assert metrics.propose_pending.value == pending0 + 2
+    got = {}
+    run_until(eng, lambda: got.update(sink.drain()) or len(got) == 2,
+              msg="acks of the staged pairs")
+    assert [eng.settle(t, got[t.rid]).node.value for t in good] == ["1", "3"]
+    assert metrics.propose_pending.value == pending0
+    with pytest.raises(errors.EtcdError):
+        eng.store(2).get("/a", False, False)    # tenant 2 got neither
+    eng.stop()

@@ -197,3 +197,115 @@ def test_native_format_responses_matches_python():
         _c_format_responses([(200, "not-bytes")])
     with pytest.raises(TypeError):
         _c_format_responses([200])
+
+
+# -- frontcore: the HTTP front's batched socket calls -------------------------
+
+
+def _socketpairs(n):
+    import socket
+    pairs = [socket.socketpair() for _ in range(n)]
+    for a, b in pairs:
+        a.setblocking(False)
+        b.setblocking(False)
+    return pairs
+
+
+@pytest.mark.parametrize("impl", ["native", "python"])
+def test_front_recv_many_and_send_many(impl):
+    """One call, one result per descriptor: bytes (b"" at end of file) or
+    -errno from recv_many, bytes taken or -errno from send_many; the C
+    module and the os.read / os.write fallback give the same lists."""
+    import errno
+    if impl == "native":
+        if not native.HAVE_NATIVE_FRONT:
+            pytest.skip("frontcore not built")
+        recv_many, send_many = native.recv_many, native.send_many
+    else:
+        recv_many, send_many = native._py_recv_many, native._py_send_many
+    assert recv_many([], 4096) == [] and send_many([]) == []
+    pairs = _socketpairs(4)
+    try:
+        (a0, b0), (a1, b1), (a2, b2), (a3, b3) = pairs
+        # send: bytes and a bytearray (the front's wbuf), whole or in part
+        big = bytearray(b"z" * (8 << 20))
+        sent = send_many([(a0.fileno(), b"hello"),
+                          (a1.fileno(), bytearray(b"wbuf")),
+                          (a2.fileno(), big)])
+        assert sent[:2] == [5, 4] and 0 < sent[2] < len(big)
+        assert len(big) == 8 << 20      # the buffer is released, unchanged
+        del big[:sent[2]]               # and can be resized again
+        # a full socket takes nothing more: -EAGAIN, not an exception
+        assert send_many([(a2.fileno(), big)])[0] in (
+            -errno.EAGAIN, -errno.EWOULDBLOCK)
+        a3.close()                      # b3 sees end of file
+        got = recv_many([b0.fileno(), b1.fileno(), b3.fileno()], 16384)
+        assert got == [b"hello", b"wbuf", b""]
+        # nothing left to read: -EAGAIN; the read is capped by bufsize
+        assert recv_many([b0.fileno()], 16384) == [-errno.EAGAIN]
+        assert recv_many([b2.fileno()], 1000) == [b"z" * 1000]
+        # writing to a peer that is gone is an errno, never a signal
+        assert send_many([(b3.fileno(), b"late")]) == [-errno.EPIPE]
+        with pytest.raises((TypeError, ValueError)):
+            send_many([(a0.fileno(), "text")])
+    finally:
+        for a, b in pairs:
+            a.close()
+            b.close()
+
+
+@pytest.mark.skipif(not native.HAVE_NATIVE_FRONT,
+                    reason="frontcore not built")
+def test_front_recv_many_shares_its_scratch_between_many_descriptors():
+    """More descriptors than 4 MiB / bufsize: each read is capped lower
+    and no byte is lost, the rest is there for the next call."""
+    pairs = _socketpairs(100)
+    try:
+        for i, (a, _b) in enumerate(pairs):
+            a.send(bytes([i]) * 60000)
+        fds = [b.fileno() for _a, b in pairs]
+        got = native.recv_many(fds, 65536)      # 100 x 64 KiB > 4 MiB
+        assert all(0 < len(g) <= (4 << 20) // 100 for g in got)
+        total = [len(g) for g in got]
+        for _ in range(8):
+            more = native.recv_many(fds, 65536)
+            total = [t + (len(m) if isinstance(m, bytes) else 0)
+                     for t, m in zip(total, more)]
+        assert total == [60000] * 100
+        assert all(set(g) == {i} for i, g in enumerate(got))
+    finally:
+        for a, b in pairs:
+            a.close()
+            b.close()
+
+
+@pytest.mark.parametrize("impl", ["native", "python"])
+def test_front_recv_many_takes_any_number_of_descriptors(impl):
+    """More descriptors than share the scratch at 4 KiB each (1024): the
+    call works them off in groups, one result per descriptor in order,
+    and never refuses."""
+    import errno
+    if impl == "native":
+        if not native.HAVE_NATIVE_FRONT:
+            pytest.skip("frontcore not built")
+        recv_many = native.recv_many
+    else:
+        recv_many = native._py_recv_many
+    n = 2500
+    a, b = _socketpairs(1)[0]
+    c, d = _socketpairs(1)[0]
+    try:
+        a.send(b"x" * 100)              # b has 100 bytes, d has none
+        fds = [b.fileno() if i % 1000 == 7 else d.fileno()
+               for i in range(n)]
+        got = recv_many(fds, 10)
+        assert len(got) == n
+        # b was read 10 bytes at a time, at 7, 1007 and 2007, in order
+        assert [i for i, g in enumerate(got) if g == b"x" * 10] == [
+            7, 1007, 2007]
+        assert all(g == -errno.EAGAIN for i, g in enumerate(got)
+                   if i % 1000 != 7)
+        assert recv_many([b.fileno()], 4096) == [b"x" * 70]
+    finally:
+        for s in (a, b, c, d):
+            s.close()

@@ -401,6 +401,10 @@ NEW_SERIES = (
     "etcd_http_request_seconds_count",
     "etcd_http_front_self_seconds_sum",
     "etcd_http_front_self_seconds_count",
+    # PR 25: the event-loop front's own counters
+    "etcd_http_front_served_total",
+    "etcd_http_front_wakes_total",
+    "etcd_http_front_completions_total",
 )
 
 
@@ -798,6 +802,56 @@ def test_front_self_time_is_the_span_minus_the_engine_wait(eng_http):
             == pytest.approx(_delta(a, b, "etcd_http_front_self_seconds_sum",
                                     kind="other")))
     assert _delta(a, b, "etcd_http_request_seconds_count", kind="write") == 0
+
+
+def test_loop_served_requests_count_once_and_the_layer_metrics_read_them(
+        eng_http):
+    """PR 25: keys writes and quorum reads are served by the front's event
+    loop. Each is one observation of the span and of the self time (self
+    <= span), one `served{path=loop}`, one completion; a batch of acks is
+    at most one wake per completion; and the benchmark's two layer-metric
+    files read exactly these series off two /metrics scrapes."""
+    import importlib.util
+    eng, base = eng_http
+    spec = importlib.util.spec_from_file_location(
+        "bench_prom", os.path.join(REPO, "benchmark", "lib", "prom.py"))
+    prom = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(prom)
+    text0 = _http("GET", base + "/metrics")        # itself on a thread
+    for series in ("etcd_http_front_served_total{path=\"loop\"}",
+                   "etcd_http_front_served_total{path=\"thread\"}",
+                   "etcd_http_front_wakes_total",
+                   "etcd_http_front_completions_total"):
+        assert series in text0, series
+    deadline = time.time() + 20        # until that scrape is counted
+    while (_val(_reg(), "etcd_http_front_served_total", path="thread")
+           <= _val(prom.parse(text0), "etcd_http_front_served_total",
+                   path="thread") and time.time() < deadline):
+        time.sleep(0.02)
+    a, b = _load(base, n=24)
+    assert _delta(a, b, "etcd_http_front_served_total", path="loop") == 48
+    assert _delta(a, b, "etcd_http_front_served_total", path="thread") == 0
+    assert _delta(a, b, "etcd_http_front_completions_total") == 48
+    assert 1 <= _delta(a, b, "etcd_http_front_wakes_total") <= 48
+    for kind in ("write", "qread"):
+        assert _delta(a, b, "etcd_http_request_seconds_count",
+                      kind=kind) == 24
+        assert _delta(a, b, "etcd_http_front_self_seconds_count",
+                      kind=kind) == 24
+        assert (0 < _delta(a, b, "etcd_http_front_self_seconds_sum",
+                           kind=kind)
+                <= _delta(a, b, "etcd_http_request_seconds_sum", kind=kind))
+    before, after = prom.parse(text0), prom.parse(
+        _http("GET", base + "/metrics"))
+    got = {}
+    for name in ("front_loop_share", "front_acks_per_wake"):
+        with open(os.path.join(REPO, "benchmark", "layer_metrics",
+                               name + ".json")) as f:
+            got[name] = prom.prom_delta(before, after,
+                                        json.load(f)["source"], 1.0)
+    # 48 on the loop and the first scrape on a thread
+    assert got["front_loop_share"] == pytest.approx(48 / 49)
+    assert 1 <= got["front_acks_per_wake"] <= 48
 
 
 def test_pending_wait_counts_every_acked_write(eng_http):

@@ -275,3 +275,48 @@ def test_engine_stop_fails_parked_reads(tmp_path):
     t.join(timeout=5.0)
     assert not t.is_alive()
     assert "err" in out and isinstance(out["err"], errors.EtcdError)
+
+
+def test_read_parked_by_submit_pairs_after_the_snapshot_waits_its_own_round(
+        tmp_path):
+    """The event-loop front parks quorum reads through submit_pairs, under
+    the lock the round takes its read_take snapshot under: a read parked
+    after a round's snapshot (its step already dispatched) is not moved
+    by that round's confirmation; it is confirmed by a later round."""
+    from etcd_tpu.utils.wait import Sink
+
+    eng = MultiEngine(make_cfg(tmp_path / "late"))
+    run_until(eng, lambda: all(eng.leader_slot(g) >= 0 for g in range(4)),
+              msg="leaders")
+    put(eng, 0, "/k", "v")
+    parked0 = eng.obs.g_read_parked.value
+    sink = Sink(lambda: None)
+    read = Request(method="GET", path="/k", quorum=True)
+    early = eng.submit_pairs([(0, read)], sink)[0]
+    late, takes = [], []
+    real = eng._confirm_reads
+
+    def confirm(read_take, conf, rc):
+        # this round's step was dispatched with `read_take` pinned
+        if not late:
+            late.append(eng.submit_pairs([(0, read)], sink)[0])
+            takes.append(dict(read_take))
+        return real(read_take, conf, rc)
+
+    eng._confirm_reads = confirm
+    run_until(eng, lambda: late, msg="a read round")
+    assert takes == [{0: 1}]            # the snapshot holds the early read
+    assert late[0].rid in [rid for rid, _r in eng._reads[0]]
+    order = []
+    for _ in range(400):
+        order += [rid for rid, _v in sink.drain()]
+        if len(order) == 2:
+            break
+        eng.run_round()
+    assert order == [early.rid, late[0].rid]
+    assert eng._reads_waiting == 0 and eng._ripe_waiting == 0
+    # settle closes the read's accounts as a woken _quorum_read does
+    for tok in (early, late[0]):
+        eng.settle(tok, None)
+    assert eng.obs.g_read_parked.value == parked0
+    eng.stop()
