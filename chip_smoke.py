@@ -474,12 +474,6 @@ def run_member(m: Member, ops, chips: int, rehearsal: bool,
          window=WINDOW, hops=HOPS, mesh=m.mesh, device=device,
          device_rows=st["device_rows"], seconds_to_all_leaders=cold_s,
          step_cache_entries_before=len(entries0))
-    if m.mesh:
-        # Compact readback is off on the mesh path (EngineConfig.
-        # compact_readback): each round reads back term, vote, commit,
-        # state, last_index, need_host (G*P int32 each) and the ring.
-        emit(phase=m.name + ":readback", compact=False,
-             bytes_per_round=4 * m.groups * PEERS * (6 + WINDOW))
 
     emit(**write_all(m, ops, m.name + ":writes"))
     emit(**cas_pair(m, model))

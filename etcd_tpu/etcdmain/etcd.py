@@ -9,6 +9,7 @@ discovery full-cluster errors fall back to proxy mode when configured
 """
 from __future__ import annotations
 
+import gc
 import json
 import logging
 import os
@@ -30,6 +31,18 @@ log = logging.getLogger("etcdmain")
 
 DIR_MEMBER, DIR_PROXY, DIR_ENGINE, DIR_EMPTY = ("member", PROXY_DIR_NAME,
                                                 "engine", "empty")
+
+# The engine member's collector thresholds (CPython's default: 700, 10, 10).
+# A request lives a few rounds (tens to hundreds of ms) and a round stages a
+# hundred of them, so with a young collection every 700 net container
+# allocations every request survives a gen-0 and a gen-1 collection and is
+# counted as long-lived; once those counts reach a quarter of the heap
+# CPython traverses ALL of it with every thread stopped: O(G) tenant stores
+# and API objects (1.1 M objects at G=50,000) for garbage that reference
+# counts had freed long before. Young collections rarer than a request's
+# life leave the old generation to what really is long-lived; a gen-1
+# collection every second gen-0 keeps any one of them under ~100,000 objects.
+ENGINE_GC_THRESHOLD = (50_000, 2, 10)
 
 
 def identify_data_dir(dir_: str) -> str:
@@ -106,6 +119,7 @@ class EngineServer:
         from etcd_tpu.server.engine import EngineConfig, MultiEngine
         from etcd_tpu.utils.platform import enable_compile_cache
 
+        gc.set_threshold(*ENGINE_GC_THRESHOLD)
         # The process entry owns the compile cache: a served member must
         # not recompile its step variants on every boot, and must not
         # need a script's help to avoid it.

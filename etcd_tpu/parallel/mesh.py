@@ -67,6 +67,25 @@ def mailbox_sharding(mesh: Mesh) -> NamedSharding:
     return NamedSharding(mesh, P("groups", "peers", None, None))
 
 
+def flag_sharding(mesh: Mesh) -> NamedSharding:
+    """Sharding for the compact step's (G, P) change-flag map: as the
+    (G, P) state fields it is computed from, so the diff needs no
+    collective."""
+    return NamedSharding(mesh, P("groups", "peers"))
+
+
+def group_sharding(mesh: Mesh) -> NamedSharding:
+    """Sharding for a per-group (G,) array: the read step's confirmed flag
+    and captured commit index."""
+    return NamedSharding(mesh, P("groups"))
+
+
+def replicated_sharding(mesh: Mesh) -> NamedSharding:
+    """Every device holds the whole value: the compact step's need-host
+    attestation and the rows gather_rows collects from the shards."""
+    return NamedSharding(mesh, P())
+
+
 def shard_state(st: GroupState, mesh: Mesh) -> GroupState:
     """Place a host-built GroupState onto the mesh."""
     sh = state_sharding(mesh)

@@ -243,13 +243,15 @@ class EngineConfig:
     # diff is computed ON DEVICE and the host reads back a (G, P) uint8
     # flag map plus values for only the rows that changed, instead of
     # the full O(G*P*W) state every round (32 MB of ring alone at
-    # G=100k; what that costs per round on the chip is not measured).
-    # Rounds that change more rows than compact_cap — or that raise
-    # need_host — fall back to the full readback, so saturated
-    # throughput is untouched. None = auto (enabled when mesh is None);
-    # the mesh path keeps full readback (its readback is
-    # sharded-resident and the flag map would need its own
-    # out_sharding).
+    # G=100k). Rounds that change more rows than compact_cap, that raise
+    # need_host, that follow a snapshot-install surgery or that carry a
+    # quorum read take the full readback, so saturated throughput is
+    # untouched. None = on, mesh or not: on a mesh the flag map is
+    # sharded like the state, the need-host attestation and the
+    # gathered rows come back replicated, and the row gather is the
+    # round's one data-carrying collective across the groups axis (one
+    # all-reduce of the K gathered rows). Which path built a round's
+    # record is counted in etcd_engine_readback_rounds_total{kind}.
     compact_readback: Optional[bool] = None
     # Max changed+staged rows served by the gather path before a round
     # falls back to full readback. 0 = auto: max(2048, G*P//8).
@@ -365,53 +367,77 @@ class MultiEngine:
             heartbeat_tick=cfg.heartbeat_tick)
         G, P, W = cfg.groups, cfg.peers, cfg.window
 
-        # Mesh placement: pinned out_shardings keep the state AND the routed
-        # inbox on their canonical shardings round over round (one compile;
-        # the outbox->inbox peer-axis swap lowers to an all_to_all over the
-        # "peers" mesh axis — the ICI transport of SURVEY §2.4).
+        # The round's three step programs, all built the same way:
+        # step_routed_auto (quiescent rounds, the serving steady state,
+        # take the one-pass fast path, election/term-change rounds the
+        # full sequential path, selected on device with bit-identical
+        # trajectories, tests/test_quiet_path.py; cfg.hops chains
+        # propose->replicate->commit inside the one program; the drop
+        # mask rides into the kernel so fault injection cuts EVERY hop),
+        # step_routed_compact (the same plus the on-device diff: a (G, P)
+        # flag map and the need-host attestation) and
+        # step_routed_read_auto (the zero-append read plane: the same
+        # round plus a forced leader heartbeat and a per-group
+        # read-quorum tally; a (G,) confirmed flag and a (G,) captured
+        # commit index come back with the state).
         self._st_sh = self._mb_sh = None
         if cfg.mesh is not None:
-            from etcd_tpu.parallel.mesh import (mailbox_sharding,
+            # Mesh placement: pinned out_shardings keep the state AND the
+            # routed inbox on their canonical shardings round over round
+            # (one compile; the outbox->inbox peer-axis swap lowers to an
+            # all_to_all over the "peers" mesh axis, the ICI transport of
+            # SURVEY §2.4; lax.cond keeps the sharded layouts:
+            # tests/test_tpu_compile.py holds the collectives to one
+            # scalar all-reduce per hop). What a step returns beside them
+            # is pinned too: the flag map sharded like the state, the
+            # attestation replicated, the read plane's two (G,) arrays
+            # sharded on groups.
+            from etcd_tpu.parallel.mesh import (flag_sharding,
+                                                group_sharding,
+                                                mailbox_sharding,
+                                                replicated_sharding,
                                                 state_sharding)
             self._st_sh = state_sharding(cfg.mesh)
             self._mb_sh = mailbox_sharding(cfg.mesh)
-            # Measured on the 8-device CPU mesh at G=4096 (r4): the auto
-            # (quiescent-fast-path) kernel runs the sharded round 2x
-            # faster than the always-full kernel (62 vs 127 ms), and
-            # hops=3 beats three 1-hop rounds (145 vs 187 ms) while
-            # cutting propose->commit to one round — the earlier
-            # "lax.cond constrains sharded layouts" concern did not
-            # survive measurement, so the mesh path now runs the same
-            # auto+hops program as the single-device engine (drop mask
-            # riding into the kernel, cut per hop).
-            _mesh_step = jax.jit(
-                _named_partial(kernel.step_routed_auto.__wrapped__,
-                               self.kcfg, hops=cfg.hops),
-                donate_argnums=kernel.donate_safe((0, 1)),
-                out_shardings=(self._st_sh, self._mb_sh))
-            self._step_fn = (
-                lambda st, inbox, pc, ps, t: _mesh_step(
-                    st, inbox, pc, ps, t, self.drop_mask))
+            rep = replicated_sharding(cfg.mesh)
+            g_sh = group_sharding(cfg.mesh)
+            extra_out = {"step_routed_auto": (),
+                         "step_routed_compact": (flag_sharding(cfg.mesh),
+                                                 rep),
+                         "step_routed_read_auto": (g_sh, g_sh)}
+
+            def step_fn(name):
+                fn = jax.jit(
+                    _named_partial(getattr(kernel, name).__wrapped__,
+                                   self.kcfg, hops=cfg.hops),
+                    donate_argnums=kernel.donate_safe((0, 1)),
+                    out_shardings=(self._st_sh, self._mb_sh,
+                                   *extra_out[name]))
+                return lambda st, inbox, pc, ps, t: fn(
+                    st, inbox, pc, ps, t, self.drop_mask)
+
+            # The compact round's row gather, from gather_rows' body: every
+            # chip gathers the rows it holds and one all-reduce of the K
+            # rows brings them together, replicated.
+            self._gather_rows = jax.jit(kernel.gather_rows.__wrapped__,
+                                        out_shardings=rep)
         else:
-            # step_routed_auto: quiescent rounds (the serving steady
-            # state) take the one-pass fast path; election/term-change
-            # rounds take the full sequential path — selected on device,
-            # bit-identical trajectories (tests/test_quiet_path.py).
-            # cfg.hops chains propose->replicate->commit inside the one
-            # program (see kernel.step_routed_auto); the drop mask rides
-            # into the kernel so fault injection cuts EVERY hop.
-            # step_variant: undonated twin on the cpu backend — XLA:CPU
-            # has a donated-buffer race (see kernel.py "CPU donation
-            # hazard"); donation stays on TPU.
-            _auto = kernel.step_variant("step_routed_auto")
-            self._step_fn = (
-                lambda st, inbox, pc, ps, t: _auto(
+            def step_fn(name):
+                # step_variant: undonated twin on the cpu backend (XLA:CPU
+                # has a donated-buffer race, see kernel.py "CPU donation
+                # hazard"); donation stays on TPU.
+                fn = kernel.step_variant(name)
+                return lambda st, inbox, pc, ps, t: fn(
                     self.kcfg, st, inbox, pc, ps, t, self.drop_mask,
-                    self.cfg.hops))
-        self._compact = (cfg.compact_readback if cfg.compact_readback
-                         is not None else cfg.mesh is None)
-        if cfg.mesh is not None:
-            self._compact = False    # see EngineConfig.compact_readback
+                    self.cfg.hops)
+
+            self._gather_rows = kernel.gather_rows
+        self._step_fn = step_fn("step_routed_auto")
+        self._step_fn_c = step_fn("step_routed_compact")
+        self._step_fn_r = step_fn("step_routed_read_auto")
+        # None = on, mesh or not (EngineConfig.compact_readback).
+        self._compact = (cfg.compact_readback is None
+                         or bool(cfg.compact_readback))
         self._compact_cap = cfg.compact_cap or max(2048, G * P // 8)
         # Set whenever device state was mutated WITHOUT updating the
         # h_* mirrors (the snapshot-install surgery leaves mirrors stale
@@ -424,35 +450,6 @@ class MultiEngine:
         # mask_check_rounds); >0 means the device mask diverged from the
         # host's and was restored.
         self.mask_repairs = 0
-        _compact_step = kernel.step_variant("step_routed_compact")
-        self._step_fn_c = (
-            lambda st, inbox, pc, ps, t: _compact_step(
-                self.kcfg, st, inbox, pc, ps, t, self.drop_mask,
-                self.cfg.hops))
-        # The ReadIndex step (the zero-append read plane): the same
-        # routed round plus a forced leader heartbeat and a per-group
-        # read-quorum tally — one extra (G,) confirmed flag and one (G,)
-        # captured commit index come back with the state. The mesh path
-        # pins both to a groups-sharded layout next to the state/mailbox
-        # shardings; the non-mesh path rides step_variant (CPU donation
-        # hazard twin, same as the other kernels).
-        if cfg.mesh is not None:
-            from jax.sharding import NamedSharding, PartitionSpec
-            _g_sh = NamedSharding(cfg.mesh, PartitionSpec("groups"))
-            _mesh_read = jax.jit(
-                _named_partial(kernel.step_routed_read_auto.__wrapped__,
-                               self.kcfg, hops=cfg.hops),
-                donate_argnums=kernel.donate_safe((0, 1)),
-                out_shardings=(self._st_sh, self._mb_sh, _g_sh, _g_sh))
-            self._step_fn_r = (
-                lambda st, inbox, pc, ps, t: _mesh_read(
-                    st, inbox, pc, ps, t, self.drop_mask))
-        else:
-            _read_step = kernel.step_variant("step_routed_read_auto")
-            self._step_fn_r = (
-                lambda st, inbox, pc, ps, t: _read_step(
-                    self.kcfg, st, inbox, pc, ps, t, self.drop_mask,
-                    self.cfg.hops))
 
         # Geometry guard BEFORE anything touches the data dir: a mismatch
         # must refuse the dir before the WAL opens/creates any file in it.
@@ -1768,8 +1765,13 @@ class MultiEngine:
         leaderless group must not accumulate one queued SYNC per interval);
         the inflight marker self-heals by deadline in case the SYNC entry
         is orphaned by a leader change and never applies."""
-        due = [g for g, s in list(self._stores.items())
-               if (x := s.next_expiration()) is not None and x <= now
+        # A snapshot of the keys, not of the items: appliers add stores
+        # while this runs, and G (g, store) tuples twice a second are G
+        # containers for the collector to count and promote.
+        stores = self._stores
+        due = [g for g in list(stores)
+               if (s := stores.get(g)) is not None
+               and (x := s.next_expiration()) is not None and x <= now
                and self._sync_pending.get(g, 0.0) <= now]
         if not due:
             return
@@ -2052,7 +2054,10 @@ class MultiEngine:
         # more rows than the cap take the full readback below. ----------
         rec = None
         need_host = None
-        gathered = False        # the compact tail built this round's record
+        # Which readback builds this round's record: "compact" (the flag
+        # map + gathered rows), "over_cap" (the attempt, then the full
+        # readback) or "full".
+        readback_kind = "full"
         d_readback = d_record = 0.0
         t_stepped = t_ph
         if flags_d is not None:
@@ -2079,9 +2084,10 @@ class MultiEngine:
                 d_record = t_now - t_ph
                 ph["record"] = ph.get("record", 0.0) + d_record
                 t_ph = t_now
-                gathered = rec is not None
+                readback_kind = "compact" if rec is not None else "over_cap"
                 if o:
-                    clock.lap("tail" if gathered else "readback", t_now)
+                    clock.lap("tail" if rec is not None else "readback",
+                              t_now)
         if rec is None:
             full = (st.term, st.vote, st.commit, st.state,
                     st.last_index, st.log_term, st.need_host)
@@ -2207,11 +2213,12 @@ class MultiEngine:
             # record's parts: what _compact_record_admit / _admit_staged
             # clocked, and the rest of the phase as build.
             part = o.h_rec_part
-            if gathered:
+            if readback_kind == "compact":
                 part["gather"].observe(self._rec_gather)
             part["admit"].observe(self._rec_admit)
             part["build"].observe(
                 d_record - self._rec_gather - self._rec_admit)
+            o.c_readback[readback_kind].inc()
             o.flight.mark(r_no, obs_mod.STEPPED, t_stepped)
             if self._staged:
                 o.h_batch.observe(self._last_admitted)
@@ -2397,7 +2404,7 @@ class MultiEngine:
         # one blocking read per gathered array.
         t_gather = time.perf_counter()
         with self.obs.span("etcd.record.gather"):
-            rows_d = kernel.gather_rows(
+            rows_d = self._gather_rows(
                 self.st, jnp.asarray(gi_p), jnp.asarray(pi_p))
             t_k, v_k, c_k, s_k, l_k, r_k = (
                 np.asarray(a)[:K] for a in rows_d)

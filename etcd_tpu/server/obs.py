@@ -109,6 +109,14 @@ d2h_syncs = metrics.Counter(
 d2h_bytes = metrics.Counter(
     "etcd_engine_d2h_bytes_total",
     "Bytes those device->host reads brought back (the arrays' nbytes).")
+READBACK_KINDS = ("compact", "full", "over_cap")
+readback_rounds = metrics.LabeledCounter(
+    "etcd_engine_readback_rounds_total",
+    "Rounds by the readback that built their record: compact (flag map "
+    "+ gathered rows), over_cap (the flag map named more rows than "
+    "compact_cap, so the full readback followed it) or full (need-host, "
+    "post-surgery and quorum-read rounds, and every round with compact "
+    "readback off).", ("kind",))
 pending_wait = metrics.Histogram(
     "etcd_engine_pending_wait_seconds",
     "Time a request sat in the engine's staging queue: do()/submit_many "
@@ -691,6 +699,10 @@ class EngineObs:
         self.clock = RoundClock() if self.enabled else None
         self.thread_cpu = ThreadCpu()
         self.h_rec_part = {p: record_part.labels(p) for p in RECORD_PARTS}
+        self.c_readback = {k: readback_rounds.labels(k)
+                           for k in READBACK_KINDS}
+        for c in self.c_readback.values():
+            c.inc(0.0)              # flat from the start, like the phases
         self.c_d2h_syncs = d2h_syncs
         self.c_d2h_bytes = d2h_bytes
         self.h_pending_wait = pending_wait

@@ -729,6 +729,58 @@ def test_engine_ttl_expiry_watch_and_restart(tmp_path):
     eng2.wal.close()
 
 
+def test_stage_syncs_due_tenants_once_and_no_tuple_per_tenant(tmp_path):
+    """The twice-a-second TTL scan stages one SYNC for each tenant whose
+    store holds a DUE expiration, none for the others, none again while
+    that SYNC is in flight; it tolerates a store that goes away under the
+    scan, and it allocates no container per tenant (G tuples a scan were
+    G objects for the collector to count and promote at G=50,000)."""
+    import gc
+
+    from etcd_tpu.server.engine import METHOD_SYNC
+
+    class FakeStore:
+        def __init__(self, exp):
+            self.exp = exp
+
+        def next_expiration(self):
+            return self.exp
+
+    eng = MultiEngine(make_cfg(tmp_path, groups=8, sync_interval=0.0))
+    try:
+        now = 1000.0
+
+        class Stores(dict):
+            def get(self, g, default=None):      # store 5 is removed
+                return None if g == 5 else dict.get(self, g, default)
+
+        eng._stores = Stores({0: FakeStore(None), 1: FakeStore(now - 1),
+                              2: FakeStore(now + 60), 3: FakeStore(now),
+                              5: FakeStore(now - 1)})
+        eng._stage_syncs(now)
+        staged = {g: [t[2].method for t in eng._pending[g]]
+                  for g in range(8) if eng._pending[g]}
+        assert staged == {1: [METHOD_SYNC], 3: [METHOD_SYNC]}
+        assert eng._dirty >= {1, 3}
+        eng._stage_syncs(now + 0.5)              # still in flight
+        assert [len(eng._pending[g]) for g in (1, 3)] == [1, 1]
+
+        eng._stores = {g: FakeStore(None) for g in range(8)}
+        eng._stores.update({g: FakeStore(None) for g in range(8, 20000)})
+        gc.collect()
+        was = gc.get_threshold()
+        gc.set_threshold(1_000_000, 10, 10)      # count, do not collect
+        try:
+            before = gc.get_count()[0]
+            eng._stage_syncs(now)
+            grown = gc.get_count()[0] - before
+        finally:
+            gc.set_threshold(*was)
+        assert grown < 100, grown
+    finally:
+        eng.wal.close()
+
+
 def admin_async(eng, fn, *args):
     """Run a blocking tenant admin op from a side thread while the test
     thread drives rounds."""

@@ -9,17 +9,23 @@ all_to_all on the peers axis (conftest forces 8 virtual CPU devices).
 Reference seam: raft.MultiNode's one-process-many-groups loop
 (raft/multinode.go:166-322) scaled over chips instead of goroutines.
 """
+import json
+import random
 import threading
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from etcd_tpu.server.engine import EngineConfig, MultiEngine
+from etcd_tpu.server import obs
+from etcd_tpu.server.engine import P_CONF, EngineConfig, MultiEngine
 from etcd_tpu.server.request import Request
-from etcd_tpu.parallel.mesh import make_mesh
+from etcd_tpu.parallel.mesh import (flag_sharding, make_mesh,
+                                    replicated_sharding)
 
 from tests.test_engine import put_async, run_until, settle
+from tests.test_engine_compact import _assert_same_records, _wal_records
 
 pytestmark = pytest.mark.skipif(
     len(jax.devices()) < 8, reason="needs the 8-device CPU mesh")
@@ -41,8 +47,17 @@ def mesh(request):
     return make_mesh(jax.devices()[:8], peers_axis=request.param)
 
 
-def test_sharded_engine_serves_and_keeps_shardings(tmp_path, mesh):
-    eng = MultiEngine(make_cfg(tmp_path / "s1", mesh))
+@pytest.fixture(params=[True, False], ids=["compact", "full"])
+def compact(request):
+    """The mesh engine's two readbacks: flag map + gathered rows (the
+    default), and the full state every round."""
+    return request.param
+
+
+def test_sharded_engine_serves_and_keeps_shardings(tmp_path, mesh, compact):
+    eng = MultiEngine(make_cfg(tmp_path / "s1", mesh,
+                               compact_readback=compact))
+    assert eng._compact is compact
     G = eng.cfg.groups
     run_until(eng, lambda: all(eng.leader_slot(g) >= 0 for g in range(G)),
               msg="leaders")
@@ -66,9 +81,9 @@ def test_sharded_engine_serves_and_keeps_shardings(tmp_path, mesh):
     eng.stop()
 
 
-def test_sharded_engine_restart_from_wal(tmp_path, mesh):
+def test_sharded_engine_restart_from_wal(tmp_path, mesh, compact):
     d = tmp_path / "s2"
-    eng = MultiEngine(make_cfg(d, mesh))
+    eng = MultiEngine(make_cfg(d, mesh, compact_readback=compact))
     G = eng.cfg.groups
     run_until(eng, lambda: all(eng.leader_slot(g) >= 0 for g in range(G)),
               msg="leaders")
@@ -77,7 +92,7 @@ def test_sharded_engine_restart_from_wal(tmp_path, mesh):
         settle(eng, t, out)
     eng.stop()
 
-    eng2 = MultiEngine(make_cfg(d, mesh))
+    eng2 = MultiEngine(make_cfg(d, mesh, compact_readback=compact))
     for g in range(G):
         assert eng2.do(g, Request(method="GET", path="/persist")).node.value \
             == f"g{g}"
@@ -88,12 +103,13 @@ def test_sharded_engine_restart_from_wal(tmp_path, mesh):
     eng2.stop()
 
 
-def test_sharded_engine_conf_change_and_host_surgery_keep_sharding(tmp_path,
-                                                                   mesh):
+def test_sharded_engine_conf_change_and_host_surgery_keep_sharding(
+        tmp_path, mesh, compact):
     """Membership surgery (host writebacks) must put fields back on their
     canonical shardings — the regression this guards: a jnp.asarray
     writeback would strand a field on one device and force resharding."""
-    eng = MultiEngine(make_cfg(tmp_path / "s3", mesh, initial_peers=3))
+    eng = MultiEngine(make_cfg(tmp_path / "s3", mesh, initial_peers=3,
+                               compact_readback=compact))
     run_until(eng, lambda: eng.leader_slot(0) >= 0, msg="leader")
 
     res = {}
@@ -127,3 +143,211 @@ def test_sharded_engine_conf_change_and_host_surgery_keep_sharding(tmp_path,
     assert eng.do(0, Request(method="GET", path="/post-conf")).node.value \
         == "ok"
     eng.stop()
+
+
+# ---------------------------------------------------------------------------
+# Compact readback across devices == full readback == one device
+# ---------------------------------------------------------------------------
+# The same seeded script of proposals, two conf changes and one
+# snapshot-install surgery through three engines. The single-device engine
+# with full readback is the independent implementation of the semantics;
+# the mesh engine must journal the same RoundRecord stream, keep the same
+# mirrors and give the same answers, with full and with compact readback.
+
+ROUNDS = 130
+ADD_AT, CUT_AT, HEAL_AT, REMOVE_AT = 20, 30, 58, 100
+CAP = 6            # rows: busy rounds go over it, quiet ones stay under
+
+
+class _Seq:        # idutil embeds wall time; payload bytes must be equal
+    def __init__(self):
+        self.i = 0
+
+    def next(self):
+        self.i += 1
+        return self.i
+
+
+def _kinds() -> dict:
+    return {lab["kind"]: v for _, lab, v in obs.readback_rounds.samples()}
+
+
+def _enqueue(eng, g, rid, payload, rq, conf=False):
+    q = eng.wait.register(rid)
+    with eng._lock:
+        eng._pending[g].append((rid, payload, rq))
+        eng._dirty.add(g)
+        if conf:
+            eng._confs_outstanding += 1
+    return q
+
+
+def _drive(data_dir, mesh, compact, cap=0):
+    """Runs the script; returns (engine, kind of every round, rounds that
+    ended in a snapshot-install surgery, {rid: answer}, what the compact
+    step and the gather returned on which shardings)."""
+    eng = MultiEngine(make_cfg(
+        data_dir, mesh, initial_peers=3, stagger=True, sync_interval=0.0,
+        compact_readback=compact, compact_cap=cap,
+        checkpoint_rounds=1 << 30, pipeline_applies=False))
+    eng.reqid = _Seq()
+    G, P = eng.cfg.groups, eng.cfg.peers
+    shardings = {"flags": set(), "attest": set(), "rows": set()}
+    if mesh is not None and compact:
+        step_c, gather = eng._step_fn_c, eng._gather_rows
+
+        def spy_step(*a):
+            out = step_c(*a)
+            shardings["flags"].add(out[2].sharding)
+            shardings["attest"].add(out[3].sharding)
+            return out
+
+        def spy_gather(*a):
+            out = gather(*a)
+            shardings["rows"].update(x.sharding for x in out)
+            return out
+
+        eng._step_fn_c, eng._gather_rows = spy_step, spy_gather
+    surgeries = []
+    service = eng._service_need_host
+
+    def spy_service(nh):
+        service(nh)
+        if eng._force_full:
+            surgeries.append(eng.round_no)
+
+    eng._service_need_host = spy_service
+
+    rng = random.Random(11)
+    waits, kinds, victim = {}, [], None
+    for r in range(ROUNDS):
+        for _ in range(rng.randrange(0, 7)):
+            g = rng.randrange(G)
+            rid = eng.reqid.next()
+            rq = Request(method="PUT", path=f"/k{rng.randrange(4)}",
+                         val=f"v{r}", id=rid)
+            waits[rid] = _enqueue(eng, g, rid, bytes([0]) + rq.encode(), rq)
+        if CUT_AT <= r < HEAL_AT:
+            # Group 0 outgrows the ring window while one follower is cut.
+            rid = eng.reqid.next()
+            rq = Request(method="PUT", path="/grow", val=f"r{r}", id=rid)
+            waits[rid] = _enqueue(eng, 0, rid, bytes([0]) + rq.encode(), rq)
+        for at, g, op, slot in ((ADD_AT, 1, "add", 3),
+                                (REMOVE_AT, 2, "remove", 1)):
+            if r == at:
+                rid = eng.reqid.next()
+                payload = bytes([P_CONF]) + json.dumps(
+                    {"id": rid, "op": op, "slot": slot}).encode()
+                waits[rid] = _enqueue(eng, g, rid, payload, None, conf=True)
+        if r == CUT_AT:
+            victim = (eng.leader_slot(0) + 1) % 3
+            m_to = np.ones((G, P, 1, 1), np.int32)
+            m_from = np.ones((G, 1, P, 1), np.int32)
+            m_to[0, victim] = 0
+            m_from[0, 0, victim] = 0
+            eng.drop_mask = jnp.asarray(m_to * m_from)
+        elif r == HEAL_AT:
+            eng.drop_mask = None
+        before = _kinds()
+        eng.run_round()
+        after = _kinds()
+        (kind,) = [k for k in after if after[k] != before[k]]
+        kinds.append(kind)
+    answers = {}
+    for rid, q in waits.items():
+        res = q.get_nowait()
+        if hasattr(res, "resolve"):        # store/event.py LazyWriteEvent
+            res = res.resolve()
+        node = getattr(res, "node", None)
+        answers[rid] = ((res.action, node.key, node.value,
+                         node.modified_index) if node is not None
+                        else res)          # a conf change: the slot list
+    return eng, kinds, surgeries, answers, shardings
+
+
+def _mirrors(eng):
+    return {n: getattr(eng, n).copy() for n in (
+        "h_term", "h_vote", "h_commit", "h_state", "h_last", "h_ring",
+        "h_mask", "applied")}
+
+
+def _stores(eng):
+    return {g: st.save() for g, st in eng._stores.items()}
+
+
+@pytest.fixture(scope="module")
+def reference(tmp_path_factory):
+    """(i) one device, full readback."""
+    d = tmp_path_factory.mktemp("ref")
+    eng, kinds, surgeries, answers, _ = _drive(d, None, compact=False)
+    out = {"records": _wal_records(str(d)), "mirrors": _mirrors(eng),
+           "stores": _stores(eng), "answers": answers,
+           "acked": eng.acked_requests, "surgeries": surgeries,
+           "mask": eng.h_mask.copy()}
+    eng.stop()
+    assert set(kinds) == {"full"}
+    assert surgeries, "the script must reach a snapshot install"
+    assert out["mask"][1, 3] and not out["mask"][2, 1]
+    assert len(answers) > 200 and all(
+        isinstance(a, (tuple, list)) for a in answers.values()), answers
+    return out
+
+
+def _assert_same_run(ref, eng, answers, data_dir):
+    for name, want in ref["mirrors"].items():
+        assert np.array_equal(want, getattr(eng, name)), name
+    assert eng.acked_requests == ref["acked"]
+    assert answers == ref["answers"]
+    assert _stores(eng) == ref["stores"]
+    eng.stop()
+    _assert_same_records(ref["records"], _wal_records(str(data_dir)))
+
+
+def test_mesh_full_readback_equals_one_device(tmp_path, mesh, reference):
+    """(ii): sharding alone changes nothing."""
+    eng, kinds, surgeries, answers, _ = _drive(tmp_path / "mf", mesh,
+                                               compact=False)
+    assert set(kinds) == {"full"}
+    assert surgeries == reference["surgeries"]
+    _assert_same_run(reference, eng, answers, tmp_path / "mf")
+
+
+@pytest.mark.parametrize("cap", [0, CAP], ids=["autocap", f"cap{CAP}"])
+def test_mesh_compact_readback_equals_one_device(tmp_path, mesh, reference,
+                                                 cap):
+    """(iii): flag map + gathered rows across devices journal the records
+    the full path would have, round by round; need-host, post-surgery and
+    over-cap rounds take the full path and say so; every field stays on
+    its sharding; a restart from this WAL serves the same keys."""
+    d = tmp_path / "mc"
+    eng, kinds, surgeries, answers, seen = _drive(d, mesh, compact=True,
+                                                  cap=cap)
+    assert surgeries == reference["surgeries"]
+    for r in surgeries:
+        # the need-host round itself, and the round after the surgery
+        # (_force_full: the install is journaled by a full diff)
+        assert kinds[r] == "full" and kinds[r + 1] == "full", (r, kinds)
+    want = {"compact", "full", "over_cap"} if cap else {"compact", "full"}
+    assert set(kinds) == want, kinds
+    assert kinds.count("compact") > (ROUNDS // 8 if cap else ROUNDS // 2)
+    assert kinds.count("over_cap") > (ROUNDS // 8 if cap else -1)
+
+    assert seen["flags"] and all(
+        sh.is_equivalent_to(flag_sharding(mesh), 2) for sh in seen["flags"])
+    rep = replicated_sharding(mesh)
+    assert all(sh.is_equivalent_to(rep, 0) for sh in seen["attest"])
+    assert seen["rows"] and all(sh.is_fully_replicated
+                                for sh in seen["rows"])
+    for name, want_sh in zip(eng.st._fields, eng._st_sh):
+        arr = getattr(eng.st, name)
+        assert arr.sharding.is_equivalent_to(want_sh, arr.ndim), name
+    assert eng.inbox.sharding.is_equivalent_to(eng._mb_sh, eng.inbox.ndim)
+
+    _assert_same_run(reference, eng, answers, d)
+
+    eng2 = MultiEngine(make_cfg(
+        d, mesh, initial_peers=3, sync_interval=0.0, compact_readback=True,
+        compact_cap=cap, checkpoint_rounds=1 << 30, pipeline_applies=False))
+    assert _stores(eng2) == reference["stores"]
+    assert np.array_equal(eng2.h_mask, reference["mask"])
+    eng2.stop()

@@ -339,6 +339,33 @@ def test_engine_geometry_mismatch_refused(tmp_path):
     eng3.stop()
 
 
+def test_engine_member_sizes_the_young_generations(tmp_path):
+    """The engine member's entry sets the collector's thresholds so that a
+    request does not outlive a young collection (etcd.py
+    ENGINE_GC_THRESHOLD): with CPython's 700 every request is promoted and
+    the O(G) heap is traversed, all threads stopped, every few seconds."""
+    import gc
+
+    from etcd_tpu.etcdmain.etcd import ENGINE_GC_THRESHOLD, EngineServer
+
+    cfg = MainConfig()
+    cfg.data_dir = str(tmp_path / "gceng")
+    cfg.engine_groups, cfg.engine_peers = 4, 3
+    cfg.engine_interval_ms = 1
+    cfg.listen_client_urls = ("http://127.0.0.1:0",)
+    was = gc.get_threshold()
+    try:
+        s = EngineServer(cfg)
+        s.start()
+        try:
+            assert gc.get_threshold() == ENGINE_GC_THRESHOLD
+            assert ENGINE_GC_THRESHOLD[0] >= 10_000
+        finally:
+            s.stop()
+    finally:
+        gc.set_threshold(*was)
+
+
 def test_engine_mesh_flag_serves(tmp_path):
     """--engine-mesh-peers-axis shards the CLI engine over all visible
     devices (the 8-device CPU mesh under conftest) and still serves."""

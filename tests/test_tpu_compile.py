@@ -27,8 +27,7 @@ import pytest
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import (Mesh, NamedSharding, PartitionSpec,
-                          SingleDeviceSharding)
+from jax.sharding import Mesh, SingleDeviceSharding
 
 from etcd_tpu.ops import kernel
 from etcd_tpu.ops.pallas_kernels import ring_resolve
@@ -114,36 +113,88 @@ def test_serving_variant_compiles_for_v5e(topo, as_served, name):
     _check_variant(_compile_variant(topo, name, 128), 128)
 
 
-def _compile_mesh(topo, G: int):
-    """The engine's mesh step (engine.py: out_shardings pinned, donated)
-    over the four described devices, groups axis 4, peers axis 1."""
-    from etcd_tpu.parallel.mesh import mailbox_sharding, state_sharding
+def _mesh4(topo) -> Mesh:
+    """The four described devices, groups axis 4, peers axis 1."""
+    return Mesh(np.array(topo.devices).reshape(4, 1), ("groups", "peers"))
+
+
+def _compile_mesh(topo, G: int, name: str = "step_routed_auto"):
+    """The engine's mesh step (engine.py: out_shardings pinned, donated);
+    step_routed_compact adds the flag map, sharded like the state, and
+    the replicated need-host attestation."""
+    from etcd_tpu.parallel.mesh import (flag_sharding, mailbox_sharding,
+                                        replicated_sharding, state_sharding)
     cfg = KernelConfig(groups=G, peers=P, window=W)
-    mesh = Mesh(np.array(topo.devices).reshape(4, 1), ("groups", "peers"))
+    mesh = _mesh4(topo)
     st_sh, mb_sh = state_sharding(mesh), mailbox_sharding(mesh)
-    rep = NamedSharding(mesh, PartitionSpec())
+    rep = replicated_sharding(mesh)
+    out_sh = (st_sh, mb_sh)
+    if name == "step_routed_compact":
+        out_sh += (flag_sharding(mesh), rep)
     fn = jax.jit(
-        _named_partial(kernel.step_routed_auto.__wrapped__, cfg, hops=HOPS),
-        donate_argnums=(0, 1), out_shardings=(st_sh, mb_sh))
+        _named_partial(getattr(kernel, name).__wrapped__, cfg, hops=HOPS),
+        donate_argnums=(0, 1), out_shardings=out_sh)
     return fn.lower(*_shapes(cfg, st_sh, mb_sh, rep), None).compile()
 
 
-def _check_mesh(compiled) -> None:
+def _collectives(compiled) -> list:
+    """[all-reduce lines]; asserts there is no other kind of collective."""
     text = compiled.as_text()
-    # Groups never talk to each other: nothing data-sized may cross the
-    # groups axis. The only collective is the scalar all-reduce of the
-    # global quiet predicate that selects the lax.cond branch, one per hop.
     for op in ("all-to-all", "all-gather", "collective-permute",
                "reduce-scatter"):
         assert f" {op}(" not in text and f" {op}-start(" not in text, op
-    reduces = [ln for ln in text.splitlines() if " all-reduce(" in ln
-               or " all-reduce-start(" in ln]
-    assert len(reduces) <= HOPS, reduces
+    return [ln for ln in text.splitlines() if " all-reduce(" in ln
+            or " all-reduce-start(" in ln]
+
+
+def _check_mesh(compiled, scalars: int = HOPS) -> None:
+    # Groups never talk to each other: nothing data-sized may cross the
+    # groups axis. The only collective is the scalar all-reduce of the
+    # global quiet predicate that selects the lax.cond branch, one per hop
+    # (the compact step folds its need-host attestation in one more).
+    reduces = _collectives(compiled)
+    assert len(reduces) <= scalars, reduces
     assert all("[]" in ln.split("=", 2)[1] for ln in reduces), reduces
 
 
 def test_mesh_variant_compiles_for_v5e_2x2(topo, as_served):
     _check_mesh(_compile_mesh(topo, 4))
+
+
+def test_mesh_compact_variant_compiles_for_v5e_2x2(topo, as_served):
+    """The flag map is computed shard by shard: the compact step adds one
+    scalar all-reduce (any need-host) to the mesh step's, nothing else."""
+    _check_mesh(_compile_mesh(topo, 4, "step_routed_compact"), HOPS + 1)
+
+
+def _compile_mesh_gather(topo, G: int, K: int):
+    """The engine's mesh row gather (engine.py: gather_rows' body, rows
+    replicated) over a state sharded on four devices."""
+    from etcd_tpu.parallel.mesh import replicated_sharding, state_sharding
+    cfg = KernelConfig(groups=G, peers=P, window=W)
+    mesh = _mesh4(topo)
+    st_sh, rep = state_sharding(mesh), replicated_sharding(mesh)
+    st = _shapes(cfg, st_sh, rep, rep)[0]
+    idx = jax.ShapeDtypeStruct((K,), jnp.int32, sharding=rep)
+    fn = jax.jit(kernel.gather_rows.__wrapped__, out_shardings=rep)
+    return fn.lower(st, idx, idx).compile()
+
+
+def _check_mesh_gather(compiled, G: int, K: int) -> None:
+    """Every chip gathers from the rows it holds and ONE all-reduce of the
+    K gathered rows brings them together: no all-gather of the sharded
+    state (the ring alone is G*P*W*4 bytes), which is what would make the
+    compact path cost more than the full readback it replaces."""
+    reduces = _collectives(compiled)
+    assert len(reduces) == 1, reduces
+    assert f"s32[{K},{W}]" in reduces[0] and f"[{G}" not in reduces[0]
+    ma = compiled.memory_analysis()
+    assert ma.output_size_in_bytes < 8 * K * (W + 5) and \
+        ma.temp_size_in_bytes < 8 * K * (W + 5)
+
+
+def test_mesh_gather_rows_compiles_for_v5e_2x2(topo, as_served):
+    _check_mesh_gather(_compile_mesh_gather(topo, 128, 256), 128, 256)
 
 
 @pytest.mark.parametrize("trailing", [(4,), (P,)])
@@ -175,9 +226,20 @@ def test_serving_variant_full_size(topo, as_served, name):
 
 
 @pytest.mark.slow
-def test_mesh_variant_full_size(topo, as_served):
-    """G=50,000 over four devices (what chip_smoke.py --chips 4 serves)."""
-    compiled = _compile_mesh(topo, 50_000)
-    _check_mesh(compiled)
+@pytest.mark.parametrize("name,scalars", [("step_routed_auto", HOPS),
+                                          ("step_routed_compact", HOPS + 1)])
+def test_mesh_variant_full_size(topo, as_served, name, scalars):
+    """G=50,000 over four devices (what chip_smoke.py --chips 4 and the
+    cell mesh50k.put256-c256 serve)."""
+    compiled = _compile_mesh(topo, 50_000, name)
+    _check_mesh(compiled, scalars)
     ma = compiled.memory_analysis()    # per device
     assert ma.temp_size_in_bytes + ma.argument_size_in_bytes < 16 << 30
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("K", [256, 32_768])
+def test_mesh_gather_rows_full_size(topo, as_served, K):
+    """G=50,000: the smallest bucket and the one that holds the auto cap
+    (max(2048, G*P//8) = 31,250 rows)."""
+    _check_mesh_gather(_compile_mesh_gather(topo, 50_000, K), 50_000, K)
