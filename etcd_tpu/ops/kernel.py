@@ -1011,9 +1011,12 @@ def step_routed_read_auto(cfg: KernelConfig, st: GroupState,
                           prop_slot: jax.Array, tick: jax.Array,
                           drop_mask=None, hops: int = 1
                           ) -> Tuple[GroupState, jax.Array, jax.Array,
-                                     jax.Array]:
+                                     jax.Array, jax.Array, jax.Array]:
     """step_routed_auto plus a batched ReadIndex pass: returns
-    (st, inbox, confirmed (G,) bool, read_commit (G,) int32).
+    (st, inbox, confirmed (G,) bool, read_commit (G,) int32, flags,
+    any_need_host), the last two being step_routed_compact's on-device
+    diff against the pre-step state (_compact_flags), so a read round's
+    record is built from what changed, like a write round's.
 
     Protocol (reference raft.go step MsgReadIndex + ReadOnlySafe recvAck,
     data-parallel over (groups, peers)): each group's leader registers
@@ -1042,6 +1045,7 @@ def step_routed_read_auto(cfg: KernelConfig, st: GroupState,
     groups. Proposals/tick fire on hop 0 exactly like step_routed_auto:
     a read round is also a full write round."""
     G, P = st.term.shape
+    st0 = st
     read_slot, read_term, read_commit, has_ldr = _read_register(st, cfg)
     oh_lead = (jnp.arange(P, dtype=jnp.int32)[None, :]
                == read_slot[:, None])                        # (G, P)
@@ -1081,14 +1085,32 @@ def step_routed_read_auto(cfg: KernelConfig, st: GroupState,
     still = ((_at_slot(st.state, read_slot) == LEADER)
              & (_at_slot(st.term, read_slot) == read_term))
     confirmed = has_ldr & still & (n_acks + 1 >= quorum(st))
-    return st, inbox, confirmed, read_commit
+    return (st, inbox, confirmed, read_commit) + _compact_flags(st0, st)
 
 
-# Per-(g, p) change flags emitted by step_routed_compact.
+# Per-(g, p) change flags emitted by step_routed_compact and
+# step_routed_read_auto.
 CHG_HS = 1       # term | vote | commit changed (the WAL HardState diff)
 CHG_LAST = 2     # last_index changed
 CHG_RING = 4     # any ring (log-term window) slot changed
 CHG_STATE = 8    # role changed (host mirror only; never journaled)
+
+
+def _compact_flags(st0: GroupState, st: GroupState
+                   ) -> Tuple[jax.Array, jax.Array]:
+    """The on-device state diff of one round: ((G, P) uint8 CHG_* bitmask
+    of st against the pre-step st0, any_need_host scalar)."""
+    with jax.named_scope("etcd.compact_flags"):
+        hs = ((st.term != st0.term) | (st.vote != st0.vote)
+              | (st.commit != st0.commit))
+        flags = (hs.astype(jnp.uint8) * CHG_HS
+                 | (st.last_index != st0.last_index).astype(jnp.uint8)
+                 * CHG_LAST
+                 | jnp.any(st.log_term != st0.log_term, axis=2)
+                 .astype(jnp.uint8) * CHG_RING
+                 | (st.state != st0.state).astype(jnp.uint8) * CHG_STATE)
+        any_nh = jnp.any(st.need_host != 0)
+    return flags, any_nh
 
 
 @functools.partial(jax.jit, static_argnums=(0, 7), donate_argnums=_donate_at_import((1, 2)))
@@ -1122,17 +1144,7 @@ def step_routed_compact(cfg: KernelConfig, st: GroupState, inbox: jax.Array,
     st0 = st
     st, inbox = step_routed_auto.__wrapped__(
         cfg, st, inbox, prop_count, prop_slot, tick, drop_mask, hops)
-    with jax.named_scope("etcd.compact_flags"):
-        hs = ((st.term != st0.term) | (st.vote != st0.vote)
-              | (st.commit != st0.commit))
-        flags = (hs.astype(jnp.uint8) * CHG_HS
-                 | (st.last_index != st0.last_index).astype(jnp.uint8)
-                 * CHG_LAST
-                 | jnp.any(st.log_term != st0.log_term, axis=2)
-                 .astype(jnp.uint8) * CHG_RING
-                 | (st.state != st0.state).astype(jnp.uint8) * CHG_STATE)
-        any_nh = jnp.any(st.need_host != 0)
-    return st, inbox, flags, any_nh
+    return (st, inbox) + _compact_flags(st0, st)
 
 
 @jax.jit

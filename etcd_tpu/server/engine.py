@@ -244,9 +244,10 @@ class EngineConfig:
     # flag map plus values for only the rows that changed, instead of
     # the full O(G*P*W) state every round (32 MB of ring alone at
     # G=100k). Rounds that change more rows than compact_cap, that raise
-    # need_host, that follow a snapshot-install surgery or that carry a
-    # quorum read take the full readback, so saturated throughput is
-    # untouched. None = on, mesh or not: on a mesh the flag map is
+    # need_host or that follow a snapshot-install surgery take the full
+    # readback, so saturated throughput is untouched; a round that
+    # carries quorum reads is built like any other (its step returns the
+    # same diff). None = on, mesh or not: on a mesh the flag map is
     # sharded like the state, the need-host attestation and the
     # gathered rows come back replicated, and the row gather is the
     # round's one data-carrying collective across the groups axis (one
@@ -379,7 +380,8 @@ class MultiEngine:
         # step_routed_read_auto (the zero-append read plane: the same
         # round plus a forced leader heartbeat and a per-group
         # read-quorum tally; a (G,) confirmed flag and a (G,) captured
-        # commit index come back with the state).
+        # commit index come back with the state, and the compact step's
+        # flag map and attestation).
         self._st_sh = self._mb_sh = None
         if cfg.mesh is not None:
             # Mesh placement: pinned out_shardings keep the state AND the
@@ -391,7 +393,7 @@ class MultiEngine:
             # scalar all-reduce per hop). What a step returns beside them
             # is pinned too: the flag map sharded like the state, the
             # attestation replicated, the read plane's two (G,) arrays
-            # sharded on groups.
+            # sharded on groups (the read step returns all four).
             from etcd_tpu.parallel.mesh import (flag_sharding,
                                                 group_sharding,
                                                 mailbox_sharding,
@@ -401,10 +403,10 @@ class MultiEngine:
             self._mb_sh = mailbox_sharding(cfg.mesh)
             rep = replicated_sharding(cfg.mesh)
             g_sh = group_sharding(cfg.mesh)
+            diff_out = (flag_sharding(cfg.mesh), rep)
             extra_out = {"step_routed_auto": (),
-                         "step_routed_compact": (flag_sharding(cfg.mesh),
-                                                 rep),
-                         "step_routed_read_auto": (g_sh, g_sh)}
+                         "step_routed_compact": diff_out,
+                         "step_routed_read_auto": (g_sh, g_sh) + diff_out}
 
             def step_fn(name):
                 fn = jax.jit(
@@ -2020,13 +2022,15 @@ class MultiEngine:
         conf_d = rc_d = None
         if read_take:
             # A ReadIndex round is a full round (proposals, ticks and
-            # the forced leader heartbeat all ride the same program) but
-            # skips the compact path: the read step returns no flag map,
-            # and the confirmation wants the full mirror refresh anyway.
-            st, inbox, conf_d, rc_d = self._step_fn_r(
+            # the forced leader heartbeat all ride the same program),
+            # and its step returns the same on-device diff as the
+            # compact step's: one record builder serves both.
+            st, inbox, conf_d, rc_d, f_d, a_d = self._step_fn_r(
                 self.st, self.inbox,
                 jnp.asarray(prop_count), jnp.asarray(prop_slot),
                 jnp.asarray(bool(tick)))
+            if self._compact:
+                flags_d, anh_d = f_d, a_d
         elif self._compact:
             st, inbox, flags_d, anh_d = self._step_fn_c(
                 self.st, self.inbox,
@@ -2185,9 +2189,9 @@ class MultiEngine:
 
         # -- 5b. read plane: pop the snapshotted reads of every group
         # whose ReadIndex confirmation landed into the ripe queue at the
-        # captured commit index (read rounds always take the full
-        # readback above, so the mirrors the confirmation consults are
-        # this round's).
+        # captured commit index (either record path above leaves the
+        # mirrors the confirmation consults equal to this round's device
+        # state).
         if conf_d is not None:
             self._confirm_reads(read_take, np.asarray(conf_d),
                                 np.asarray(rc_d))

@@ -114,9 +114,9 @@ readback_rounds = metrics.LabeledCounter(
     "etcd_engine_readback_rounds_total",
     "Rounds by the readback that built their record: compact (flag map "
     "+ gathered rows), over_cap (the flag map named more rows than "
-    "compact_cap, so the full readback followed it) or full (need-host, "
-    "post-surgery and quorum-read rounds, and every round with compact "
-    "readback off).", ("kind",))
+    "compact_cap, so the full readback followed it) or full (need-host "
+    "and post-surgery rounds, and every round with compact readback "
+    "off).", ("kind",))
 pending_wait = metrics.Histogram(
     "etcd_engine_pending_wait_seconds",
     "Time a request sat in the engine's staging queue: do()/submit_many "

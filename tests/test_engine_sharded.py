@@ -18,14 +18,14 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from etcd_tpu.server import obs
 from etcd_tpu.server.engine import P_CONF, EngineConfig, MultiEngine
 from etcd_tpu.server.request import Request
-from etcd_tpu.parallel.mesh import (flag_sharding, make_mesh,
-                                    replicated_sharding)
+from etcd_tpu.parallel.mesh import (flag_sharding, group_sharding,
+                                    make_mesh, replicated_sharding)
 
 from tests.test_engine import put_async, run_until, settle
-from tests.test_engine_compact import _assert_same_records, _wal_records
+from tests.test_engine_compact import (_answer, _assert_same_records,
+                                       _kinds, _wal_records)
 
 pytestmark = pytest.mark.skipif(
     len(jax.devices()) < 8, reason="needs the 8-device CPU mesh")
@@ -148,8 +148,8 @@ def test_sharded_engine_conf_change_and_host_surgery_keep_sharding(
 # ---------------------------------------------------------------------------
 # Compact readback across devices == full readback == one device
 # ---------------------------------------------------------------------------
-# The same seeded script of proposals, two conf changes and one
-# snapshot-install surgery through three engines. The single-device engine
+# The same seeded script of proposals, quorum reads parked in most rounds,
+# two conf changes and one snapshot-install surgery through three engines. The single-device engine
 # with full readback is the independent implementation of the semantics;
 # the mesh engine must journal the same RoundRecord stream, keep the same
 # mirrors and give the same answers, with full and with compact readback.
@@ -168,10 +168,6 @@ class _Seq:        # idutil embeds wall time; payload bytes must be equal
         return self.i
 
 
-def _kinds() -> dict:
-    return {lab["kind"]: v for _, lab, v in obs.readback_rounds.samples()}
-
-
 def _enqueue(eng, g, rid, payload, rq, conf=False):
     q = eng.wait.register(rid)
     with eng._lock:
@@ -185,16 +181,18 @@ def _enqueue(eng, g, rid, payload, rq, conf=False):
 def _drive(data_dir, mesh, compact, cap=0):
     """Runs the script; returns (engine, kind of every round, rounds that
     ended in a snapshot-install surgery, {rid: answer}, what the compact
-    step and the gather returned on which shardings)."""
+    step, the read step and the gather returned on which shardings)."""
     eng = MultiEngine(make_cfg(
         data_dir, mesh, initial_peers=3, stagger=True, sync_interval=0.0,
         compact_readback=compact, compact_cap=cap,
         checkpoint_rounds=1 << 30, pipeline_applies=False))
     eng.reqid = _Seq()
     G, P = eng.cfg.groups, eng.cfg.peers
-    shardings = {"flags": set(), "attest": set(), "rows": set()}
+    shardings = {"flags": set(), "attest": set(), "rows": set(),
+                 "read": set(), "read_flags": set(), "read_attest": set()}
     if mesh is not None and compact:
-        step_c, gather = eng._step_fn_c, eng._gather_rows
+        step_c, step_r, gather = (eng._step_fn_c, eng._step_fn_r,
+                                  eng._gather_rows)
 
         def spy_step(*a):
             out = step_c(*a)
@@ -202,12 +200,20 @@ def _drive(data_dir, mesh, compact, cap=0):
             shardings["attest"].add(out[3].sharding)
             return out
 
+        def spy_read(*a):
+            out = step_r(*a)
+            shardings["read"].update(x.sharding for x in out[2:4])
+            shardings["read_flags"].add(out[4].sharding)
+            shardings["read_attest"].add(out[5].sharding)
+            return out
+
         def spy_gather(*a):
             out = gather(*a)
             shardings["rows"].update(x.sharding for x in out)
             return out
 
-        eng._step_fn_c, eng._gather_rows = spy_step, spy_gather
+        eng._step_fn_c, eng._step_fn_r, eng._gather_rows = (
+            spy_step, spy_read, spy_gather)
     surgeries = []
     service = eng._service_need_host
 
@@ -219,8 +225,20 @@ def _drive(data_dir, mesh, compact, cap=0):
     eng._service_need_host = spy_service
 
     rng = random.Random(11)
+    read_rng = random.Random(17)
     waits, kinds, victim = {}, [], None
     for r in range(ROUNDS):
+        # A read of any group in most rounds (parked as the front parks
+        # it), one in every round from the heal past the install.
+        for _ in range(max(read_rng.choice((0, 1, 1, 2)),
+                           HEAL_AT <= r < HEAL_AT + 20)
+                       if r < ROUNDS - 10 else 0):
+            rid = eng.reqid.next()
+            waits[rid] = eng.wait.register(rid)
+            with eng._lock:
+                eng._park_read(read_rng.randrange(G), Request(
+                    method="GET", path=f"/k{read_rng.randrange(4)}",
+                    quorum=True, id=rid))
         for _ in range(rng.randrange(0, 7)):
             g = rng.randrange(G)
             rid = eng.reqid.next()
@@ -253,15 +271,7 @@ def _drive(data_dir, mesh, compact, cap=0):
         after = _kinds()
         (kind,) = [k for k in after if after[k] != before[k]]
         kinds.append(kind)
-    answers = {}
-    for rid, q in waits.items():
-        res = q.get_nowait()
-        if hasattr(res, "resolve"):        # store/event.py LazyWriteEvent
-            res = res.resolve()
-        node = getattr(res, "node", None)
-        answers[rid] = ((res.action, node.key, node.value,
-                         node.modified_index) if node is not None
-                        else res)          # a conf change: the slot list
+    answers = {rid: _answer(q) for rid, q in waits.items()}
     return eng, kinds, surgeries, answers, shardings
 
 
@@ -271,8 +281,16 @@ def _mirrors(eng):
         "h_mask", "applied")}
 
 
-def _stores(eng):
-    return {g: st.save() for g, st in eng._stores.items()}
+def _stores(eng, replayed=False):
+    """Every tenant's saved store; `replayed` leaves out the counts of
+    gets, which a replay of the WAL does not make again."""
+    out = {g: st.save() for g, st in eng._stores.items()}
+    if replayed:
+        for g, blob in out.items():
+            doc = json.loads(blob)
+            doc["stats"].update(getsSuccess=0, getsFail=0)
+            out[g] = doc
+    return out
 
 
 @pytest.fixture(scope="module")
@@ -281,7 +299,8 @@ def reference(tmp_path_factory):
     d = tmp_path_factory.mktemp("ref")
     eng, kinds, surgeries, answers, _ = _drive(d, None, compact=False)
     out = {"records": _wal_records(str(d)), "mirrors": _mirrors(eng),
-           "stores": _stores(eng), "answers": answers,
+           "stores": _stores(eng), "replayed": _stores(eng, True),
+           "answers": answers,
            "acked": eng.acked_requests, "surgeries": surgeries,
            "mask": eng.h_mask.copy()}
     eng.stop()
@@ -338,6 +357,14 @@ def test_mesh_compact_readback_equals_one_device(tmp_path, mesh, reference,
     assert all(sh.is_equivalent_to(rep, 0) for sh in seen["attest"])
     assert seen["rows"] and all(sh.is_fully_replicated
                                 for sh in seen["rows"])
+    # the read step's four: (G,) confirmed and read index on groups, its
+    # flag map and attestation where the compact step's are
+    assert seen["read"] and all(
+        sh.is_equivalent_to(group_sharding(mesh), 1) for sh in seen["read"])
+    assert seen["read_flags"] and all(
+        sh.is_equivalent_to(flag_sharding(mesh), 2)
+        for sh in seen["read_flags"])
+    assert all(sh.is_equivalent_to(rep, 0) for sh in seen["read_attest"])
     for name, want_sh in zip(eng.st._fields, eng._st_sh):
         arr = getattr(eng.st, name)
         assert arr.sharding.is_equivalent_to(want_sh, arr.ndim), name
@@ -348,6 +375,6 @@ def test_mesh_compact_readback_equals_one_device(tmp_path, mesh, reference,
     eng2 = MultiEngine(make_cfg(
         d, mesh, initial_peers=3, sync_interval=0.0, compact_readback=True,
         compact_cap=cap, checkpoint_rounds=1 << 30, pipeline_applies=False))
-    assert _stores(eng2) == reference["stores"]
+    assert _stores(eng2, True) == reference["replayed"]
     assert np.array_equal(eng2.h_mask, reference["mask"])
     eng2.stop()

@@ -7,7 +7,9 @@ raft read_only.go — ReadIndex piggybacks on the heartbeat quorum), must
 serve exactly what the propose-path QGET would have served at the same
 index, must FAIL (or re-confirm) — never serve stale — when leadership is
 lost while the read is parked, and must keep the leader-lease fast path
-off unless explicitly configured.
+off unless explicitly configured. Every case runs with the read round's
+record built from the on-device diff (compact readback, the default) and
+from the full state.
 """
 import os
 import threading
@@ -21,7 +23,19 @@ from etcd_tpu.server.engine import EngineConfig, MultiEngine
 from etcd_tpu.server.request import Request
 
 
+@pytest.fixture(autouse=True, params=[True, False], ids=["compact", "full"])
+def compact_readback(request, monkeypatch):
+    """The readback that make_cfg's engines build their records from."""
+    monkeypatch.setitem(DEFAULTS, "compact_readback", request.param)
+    return request.param
+
+
+DEFAULTS = {}
+
+
 def make_cfg(tmp, **kw):
+    for k, v in DEFAULTS.items():
+        kw.setdefault(k, v)
     kw.setdefault("groups", 4)
     kw.setdefault("peers", 5)
     kw.setdefault("window", 16)
@@ -189,12 +203,16 @@ def test_parked_read_fails_on_leadership_loss(tmp_path):
     s = eng.leader_slot(0)
 
     # Fully partition group 0's leader: its forced read heartbeats can
-    # reach no one, so no quorum confirmation can form.
+    # reach no one, so no quorum confirmation can form. Two followers go
+    # with it, each alone, so the two peers left cannot elect a leader
+    # that could re-confirm the read inside its timeout either (they
+    # could once the step variants are compiled: ~12 rounds).
     G, P = eng.cfg.groups, eng.cfg.peers
     m_to = np.ones((G, P, 1, 1), np.int32)
     m_from = np.ones((G, 1, P, 1), np.int32)
-    m_to[0, s] = 0
-    m_from[0, 0, s] = 0
+    for cut in (s, (s + 1) % P, (s + 2) % P):
+        m_to[0, cut] = 0
+        m_from[0, 0, cut] = 0
     eng.drop_mask = jnp.asarray(m_to * m_from)
 
     t, out = do_async(eng, 0,
@@ -207,8 +225,9 @@ def test_parked_read_fails_on_leadership_loss(tmp_path):
     t.join(timeout=1.0)
     assert not t.is_alive(), "parked read neither served nor failed"
     # Either outcome must be an error — never a stale Event. (With the
-    # partition still up, re-confirmation is impossible, so the only
-    # legal result here is the timeout/raft error.)
+    # partition still up no side holds a quorum, re-confirmation is
+    # impossible, so the only legal result here is the timeout/raft
+    # error.)
     assert "err" in out, f"read served under a partitioned leader: {out}"
     assert isinstance(out["err"], errors.EtcdError)
     assert out["err"].code == errors.ECODE_RAFT_INTERNAL

@@ -121,15 +121,19 @@ def _mesh4(topo) -> Mesh:
 def _compile_mesh(topo, G: int, name: str = "step_routed_auto"):
     """The engine's mesh step (engine.py: out_shardings pinned, donated);
     step_routed_compact adds the flag map, sharded like the state, and
-    the replicated need-host attestation."""
-    from etcd_tpu.parallel.mesh import (flag_sharding, mailbox_sharding,
+    the replicated need-host attestation; step_routed_read_auto the read
+    plane's two (G,) arrays, sharded on groups, and then those two."""
+    from etcd_tpu.parallel.mesh import (flag_sharding, group_sharding,
+                                        mailbox_sharding,
                                         replicated_sharding, state_sharding)
     cfg = KernelConfig(groups=G, peers=P, window=W)
     mesh = _mesh4(topo)
     st_sh, mb_sh = state_sharding(mesh), mailbox_sharding(mesh)
     rep = replicated_sharding(mesh)
     out_sh = (st_sh, mb_sh)
-    if name == "step_routed_compact":
+    if name == "step_routed_read_auto":
+        out_sh += (group_sharding(mesh),) * 2
+    if name != "step_routed_auto":
         out_sh += (flag_sharding(mesh), rep)
     fn = jax.jit(
         _named_partial(getattr(kernel, name).__wrapped__, cfg, hops=HOPS),
@@ -165,6 +169,14 @@ def test_mesh_compact_variant_compiles_for_v5e_2x2(topo, as_served):
     """The flag map is computed shard by shard: the compact step adds one
     scalar all-reduce (any need-host) to the mesh step's, nothing else."""
     _check_mesh(_compile_mesh(topo, 4, "step_routed_compact"), HOPS + 1)
+
+
+def test_mesh_read_variant_compiles_for_v5e_2x2(topo, as_served):
+    """The read step's collectives are what they were before it returned
+    the flag map (the quiet predicate's scalar, one per hop: the
+    ReadIndex tally is per group) plus the attestation's scalar: nothing
+    gathers the flag map or the (G,) confirmations."""
+    _check_mesh(_compile_mesh(topo, 4, "step_routed_read_auto"), HOPS + 1)
 
 
 def _compile_mesh_gather(topo, G: int, K: int):
@@ -226,8 +238,9 @@ def test_serving_variant_full_size(topo, as_served, name):
 
 
 @pytest.mark.slow
-@pytest.mark.parametrize("name,scalars", [("step_routed_auto", HOPS),
-                                          ("step_routed_compact", HOPS + 1)])
+@pytest.mark.parametrize("name,scalars", [
+    ("step_routed_auto", HOPS), ("step_routed_compact", HOPS + 1),
+    ("step_routed_read_auto", HOPS + 1)])
 def test_mesh_variant_full_size(topo, as_served, name, scalars):
     """G=50,000 over four devices (what chip_smoke.py --chips 4 and the
     cell mesh50k.put256-c256 serve)."""
