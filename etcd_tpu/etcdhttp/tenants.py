@@ -500,8 +500,8 @@ class TenantAPI:
                 # submit_many registered waiters and counted pending
                 # proposals, and only collect_many releases both. Skip
                 # it and the engine reports phantom pending proposals
-                # forever (the bench's inter-leg drain barrier hangs on
-                # exactly that gauge after the SIGKILL leg).
+                # forever (a drain barrier on that gauge then hangs
+                # after an ingress SIGKILL).
                 if queues:
                     self.engine.collect_many(g, queues)
                 continue

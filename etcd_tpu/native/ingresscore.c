@@ -4,8 +4,8 @@
  * shallow client connections on one epoll loop; at that fan-in the
  * pure-Python per-request work — find("\r\n\r\n"), split/partition
  * header parsing, f-string response assembly — IS the serving cost
- * (docs/perf.md round 10 measured the engine idling behind it). This
- * module replaces both directions of that loop with one C pass each:
+ * (the engine idles behind it). This module replaces both directions
+ * of that loop with one C pass each:
  *
  *   scan_requests(data) -> (reqs, consumed, err)
  *       Scan a connection's read buffer and emit every COMPLETE

@@ -442,10 +442,10 @@ def _terms_at_many(st: GroupState, cfg: KernelConfig,
     terms (G, P, E); 0 outside the window / beyond last. The one-hot
     select-sum below IS the measured-fastest TPU formulation (it replaced
     the take_along_axis gathers that originally dominated the round). A
-    Pallas variant (ops/pallas_kernels.ring_resolve) was measured on real
-    TPU in r4: 2.3x faster in isolation but 9.3x SLOWER wired in here
-    (scripts/pallas_roundbench.py — the pallas_call boundary defeats the
-    fusion this formulation exists for), so the jnp path stays."""
+    Pallas kernel for this resolve was measured on a TPU: 2.3x faster in
+    isolation but 9.3x SLOWER wired in here (the pallas_call boundary
+    defeats the fusion this formulation exists for), so the jnp path
+    stays; PERF.md section 6 keeps the finding."""
     slot = jnp.mod(idx, cfg.window)
     t = ring_lookup(st.log_term, slot)
     last = st.last_index[..., None]
@@ -1201,7 +1201,7 @@ def step_routed(cfg: KernelConfig, st: GroupState, inbox: jax.Array,
                 tick: jax.Array) -> Tuple[GroupState, jax.Array]:
     """step + route_local fused into ONE device program: returns
     (new_state, next_inbox). Saves a dispatch + transpose copy per round
-    for single-host callers that always route locally (bench, engine)."""
+    for single-host callers that always route locally (the engine)."""
     st, outbox = step.__wrapped__(cfg, st, inbox, prop_count, prop_slot,
                                   tick)
     return st, route_local(outbox)

@@ -142,7 +142,7 @@ def init_state(cfg: KernelConfig, n_peers=None,
     past its election timeout so it campaigns on the FIRST tick and wins
     uncontested ~3 rounds later — the deterministic fast-boot the reference
     gets probabilistically from randomized timeouts (raft.go:765-771).
-    Benchmarks and the multichip dryrun use this to reach steady state in
+    The engine and the multichip dryrun use this to reach steady state in
     O(1) rounds instead of O(election_tick) with tie retries."""
     G, P = cfg.groups, cfg.peers
     if n_peers is None:

@@ -214,11 +214,10 @@ class EngineConfig:
     # fsync covers every round queued when it starts) and publishes a
     # durability watermark that applier workers gate acks on — fsync
     # leaves the round loop's critical path without weakening the
-    # ack-after-fsync contract. False = the pre-compartment behavior:
-    # append+fsync inline in the round loop before applies (rounds that
-    # carry conf flips do this regardless — device surgery must follow
-    # a durable record).
-    pipeline_wal: bool = True
+    # ack-after-fsync contract. Rounds that carry conf flips (and every
+    # round under pipeline_applies=False) append+fsync inline instead:
+    # device surgery must follow a durable record.
+    #
     # Per-tenant-range WAL segment streams (aligned with applier_shards
     # ranges): each RoundRecord splits into per-range sub-records
     # appended to its range's own stream by its own writer thread, so S
@@ -492,13 +491,6 @@ class MultiEngine:
         self.round_ms_ewma = 0.0
         self._ewma_live = self._ewma_armed = False
         self._looping = False      # run_round is being driven by _run
-        # Cumulative per-phase wall time (seconds) of the round loop —
-        # the profile VERDICT r3 asked for (device/readback/fsync/apply/
-        # ack shares). Reset with reset_phase_profile(). The writer
-        # compartment's threads record "wal_fsync"/"wal_fsync[k]" here
-        # (one writer thread per key); the round loop records only the
-        # cheap "wal_submit" hand-off.
-        self.phase_s: Dict[str, float] = {}
         # Observability plane (obs.py): per-compartment Prometheus
         # series with children pre-bound to this engine's shard
         # geometry, the round flight recorder, and the sampled proposal
@@ -519,11 +511,10 @@ class MultiEngine:
         self._rec_gather = self._rec_admit = 0.0
         # The WAL compartment: submit() hands records to the writer
         # stage; acks gate on its durability watermark (wait_durable).
-        # Constructed after phase_s — the writer threads profile into it.
         self.wal = WALWriter(cfg.data_dir, groups=G,
                              shards=cfg.wal_shards, fsync=cfg.fsync,
                              queue_rounds=cfg.wal_queue_rounds,
-                             phase_s=self.phase_s, obs=self.obs)
+                             obs=self.obs)
         # Last few durable round records, kept for the violation dump.
         self._recent_recs: deque = deque(maxlen=8)
         self.failed: Optional[Exception] = None
@@ -933,10 +924,6 @@ class MultiEngine:
                 sh.thread.start()
 
     def _applier_loop(self, sh: _ApplierShard) -> None:
-        # Phase key: "apply" for the single-shard pool (keeps profiles
-        # comparable with pre-pool captures), "apply[k]" per worker
-        # otherwise — each key has exactly one writer thread.
-        pkey = "apply" if len(self._appliers) == 1 else f"apply[{sh.idx}]"
         o = self.obs if self.obs.enabled else None
         tr = self.obs.tracer
         if o:
@@ -948,7 +935,6 @@ class MultiEngine:
                 if not sh.q:
                     return           # stop requested and queue drained
                 view = sh.q[0]       # stays queued while in progress
-            t0 = time.perf_counter()
             try:
                 # Applies run ahead of the WAL pipeline; the acks they
                 # produce are collected and released only once the
@@ -989,8 +975,6 @@ class MultiEngine:
                 # would re-apply and re-ack around the hole. The engine
                 # fail-stops at the next enqueue/drain, which re-raises.
                 return
-            self.phase_s[pkey] = self.phase_s.get(pkey, 0.0) + \
-                (time.perf_counter() - t0)
             with sh.cv:
                 sh.q.popleft()
                 sh.cv.notify_all()
@@ -2009,10 +1993,8 @@ class MultiEngine:
                                  for g in self._read_dirty
                                  if self._reads[g]}
 
-        ph = self.phase_s
-        t_ph = time.perf_counter()
-        ph["stage"] = ph.get("stage", 0.0) + (t_ph - t_round)
         if o:
+            t_ph = time.perf_counter()
             clock.lap("dispatch", t_ph)
 
         # -- 2. the kernel round (fused step + routing: one ASYNC
@@ -2043,11 +2025,10 @@ class MultiEngine:
                 jnp.asarray(bool(tick)))
         self.st = st
         self.inbox = inbox
-        t_now = time.perf_counter()
-        d_dispatch = t_now - t_ph
-        ph["dispatch"] = ph.get("dispatch", 0.0) + d_dispatch
-        t_ph = t_now
         if o:
+            t_now = time.perf_counter()
+            d_dispatch = t_now - t_ph
+            t_ph = t_now
             clock.lap("readback", t_now)
 
         # -- 3. read back round k (blocks until the device finishes; the
@@ -2063,7 +2044,6 @@ class MultiEngine:
         # readback) or "full".
         readback_kind = "full"
         d_readback = d_record = 0.0
-        t_stepped = t_ph
         if flags_d is not None:
             # Check the 1-byte attestation BEFORE pulling the flag map:
             # need-host/post-surgery rounds take the full readback anyway
@@ -2073,23 +2053,21 @@ class MultiEngine:
                 self._d2h(anh_d)
             if not any_nh and not self._force_full:
                 flags_np = np.asarray(flags_d)
-                t_now = time.perf_counter()
-                d_readback = t_now - t_ph
-                ph["readback"] = ph.get("readback", 0.0) + d_readback
-                t_ph = t_stepped = t_now
                 if o:
+                    t_now = time.perf_counter()
+                    d_readback = t_now - t_ph
+                    t_ph = t_stepped = t_now
                     self._d2h(flags_d)
                     clock.lap("record", t_now)
                 rec = self._compact_record_admit(flags_np, staged_gs,
                                                  staged_ss)
                 # Over the cap (rec is None) the attempt still counts as
                 # record; the full readback below is a second readback lap.
-                t_now = time.perf_counter()
-                d_record = t_now - t_ph
-                ph["record"] = ph.get("record", 0.0) + d_record
-                t_ph = t_now
                 readback_kind = "compact" if rec is not None else "over_cap"
                 if o:
+                    t_now = time.perf_counter()
+                    d_record = t_now - t_ph
+                    t_ph = t_now
                     clock.lap("tail" if rec is not None else "readback",
                               t_now)
         if rec is None:
@@ -2097,12 +2075,10 @@ class MultiEngine:
                     st.last_index, st.log_term, st.need_host)
             (term, vote, commit, state, last, ring, need_host) = (
                 np.array(a) for a in self._jax.device_get(full))
-            t_now = time.perf_counter()
-            d_full = t_now - t_ph
-            d_readback += d_full
-            ph["readback"] = ph.get("readback", 0.0) + d_full
-            t_ph = t_stepped = t_now
             if o:
+                t_now = time.perf_counter()
+                d_readback += t_now - t_ph
+                t_ph = t_stepped = t_now
                 self._d2h(*full)
                 clock.lap("record", t_now)
 
@@ -2180,11 +2156,9 @@ class MultiEngine:
             self.h_term, self.h_vote, self.h_commit = term, vote, commit
             self.h_state, self.h_last, self.h_ring = state, last, ring
             self._force_full = False   # mirrors == device state again
-            t_now = time.perf_counter()
-            d_record += t_now - t_ph
-            ph["record"] = ph.get("record", 0.0) + (t_now - t_ph)
-            t_ph = t_now
             if o:
+                t_now = time.perf_counter()
+                d_record += t_now - t_ph
                 clock.lap("tail", t_now)
 
         # -- 5b. read plane: pop the snapshotted reads of every group
@@ -2230,14 +2204,12 @@ class MultiEngine:
         sync_round = bool(rec.confs or self._confs_outstanding
                           or not self.cfg.pipeline_applies)
         if not rec.is_empty():
-            t0 = time.perf_counter()
+            t0 = time.perf_counter() if o else 0.0
             with self.obs.span("etcd.round.wal_submit"):
-                if sync_round or not self.cfg.pipeline_wal:
+                if sync_round:
                     self.wal.append_sync(rec)
                 else:
                     self.wal.submit(rec)
-            ph["wal_submit"] = ph.get("wal_submit", 0.0) + \
-                (time.perf_counter() - t0)
             if o:
                 o.h_wal_submit.observe(time.perf_counter() - t0)
                 o.flight.mark(r_no, obs_mod.WAL_SUBMITTED)
@@ -2248,10 +2220,8 @@ class MultiEngine:
             self._recent_recs.append(rec)
         if sync_round:
             self._drain_applies()
-            t0 = time.perf_counter()
             a0 = self._acks.acked
             self._apply_committed(trigger=True)
-            ph["apply"] = ph.get("apply", 0.0) + (time.perf_counter() - t0)
             if o:
                 o.flight.mark(r_no, obs_mod.APPLIED)
                 o.flight.mark(r_no, obs_mod.ACKED)
@@ -2275,10 +2245,8 @@ class MultiEngine:
         if need_host is not None and need_host.any():
             self._service_need_host(need_host)
 
-        t_now = time.perf_counter()
-        ph["tail"] = ph.get("tail", 0.0) + (t_now - t_ph)
         if o:
-            clock.lap("post", t_now)
+            clock.lap("post", time.perf_counter())
             o.c_rounds.inc()
         self.round_no += 1
         if (self.cfg.mask_check_rounds
@@ -2297,7 +2265,7 @@ class MultiEngine:
             # ended with a leader everywhere.
             self._ewma_armed = self._all_led()
         if self.round_no % self.cfg.checkpoint_rounds == 0:
-            t0 = time.perf_counter()
+            t0 = time.perf_counter() if o else 0.0
             self._drain_applies()    # checkpoint state must be consistent
             self._checkpoint()
             self._gc_payloads()

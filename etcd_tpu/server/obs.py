@@ -1,9 +1,8 @@
 """Pipeline observability plane: per-compartment metrics, a round
 flight recorder, and sampled end-to-end proposal traces.
 
-The compartment pipeline (round loop -> WAL writer shards -> applier
-shards -> ack gate) was observable only as cumulative phase_s sums that
-bench.py scrapes post-hoc. This module gives each stage the live
+This module gives each stage of the compartment pipeline (round loop
+-> WAL writer shards -> applier shards -> ack gate) the live
 queue+latency view "Scaling Replicated State Machines with
 Compartmentalization" (PAPERS.md) assumes — the reference ships the
 same shape as etcdserver/wal/snap/rafthttp metrics.go behind /metrics.
@@ -175,8 +174,7 @@ rounds_total = metrics.Counter(
     "etcd_engine_rounds_total", "Engine rounds completed.")
 acked_total = metrics.Counter(
     "etcd_engine_acked_requests_total",
-    "Client requests acked by live rounds (the BENCH acked-writes "
-    "counter's Prometheus twin).")
+    "Client requests acked by live rounds.")
 
 wal_fsync = metrics.LabeledHistogram(
     "etcd_wal_writer_fsync_seconds",

@@ -45,14 +45,12 @@ import os
 import threading
 import time
 from collections import deque
-from typing import Dict, Iterator, List, Optional, Tuple
-
-import numpy as np
+from typing import Iterator, List, Optional, Tuple
 
 from etcd_tpu.server.enginewal import EngineWAL, RoundRecord
 from etcd_tpu.server.obs import DURABLE as _FLIGHT_DURABLE
 
-_STATS_WINDOW = 4096   # per-shard rolling sample window for stats()
+_STATS_WINDOW = 4096   # per-shard rolling window of group-commit sizes
 
 
 def shard_dir(root: str, idx: int) -> str:
@@ -95,11 +93,11 @@ class _WriterShard:
     """One compartment of the writer pool: a thread owning one segment
     stream and the contiguous tenant range [g_lo, g_hi), with its own
     hand-off queue, condition variable, durable-tail publication and
-    rolling stats. Streams share no files, so S shards drive S parallel
+    group-commit counts. Streams share no files, so S shards drive S parallel
     fsyncs (each an I/O wait with the GIL released)."""
 
     __slots__ = ("idx", "g_lo", "g_hi", "wal", "cv", "q", "stop", "exc",
-                 "thread", "durable", "fsyncs", "fsync_ms", "batch_sizes")
+                 "thread", "durable", "fsyncs", "batch_sizes")
 
     def __init__(self, idx: int, g_lo: int, g_hi: int,
                  wal: EngineWAL) -> None:
@@ -114,7 +112,6 @@ class _WriterShard:
         self.thread: Optional[threading.Thread] = None
         self.durable = 0           # published ticket (guarded by owner._wm)
         self.fsyncs = 0
-        self.fsync_ms: deque = deque(maxlen=_STATS_WINDOW)
         self.batch_sizes: deque = deque(maxlen=_STATS_WINDOW)
 
 
@@ -130,13 +127,11 @@ class WALWriter:
     def __init__(self, dirname: str, groups: int, shards: int = 1,
                  segment_size: int = 64 * 1024 * 1024,
                  fsync: bool = True, queue_rounds: int = 64,
-                 phase_s: Optional[Dict[str, float]] = None,
                  obs=None) -> None:
         self.dir = dirname
         self.groups = groups
         self.fsync = fsync
         self.queue_rounds = max(1, queue_rounds)
-        self.phase_s = phase_s if phase_s is not None else {}
         # Observability plane (obs.EngineObs): per-shard fsync/group-
         # commit histograms, queue-depth + watermark-lag gauges, flight
         # recorder durable marks. None (or disabled) = zero overhead.
@@ -172,7 +167,6 @@ class WALWriter:
         self._wm = threading.Condition()
         self._durable = 0
         self._last_ticket = 0
-        self._depths: deque = deque(maxlen=_STATS_WINDOW)
         self._submitted = 0
         self._closed = False
 
@@ -201,17 +195,13 @@ class WALWriter:
         self._closed = False
 
     def _writer_loop(self, sh: _WriterShard) -> None:
-        # Phase key: "wal_fsync" for the single-stream writer (keeps
-        # profiles comparable with pre-compartment captures),
-        # "wal_fsync[k]" per stream otherwise — one writer thread per
-        # key. This is also where the fsync phase time is RECORDED now:
-        # it happens here, not in the round loop, so the per-phase
-        # profile stays truthful with fsync off the critical path.
-        pkey = ("wal_fsync" if len(self.shards) == 1
-                else f"wal_fsync[{sh.idx}]")
+        # The fsync is clocked HERE, on the writer thread, into this
+        # shard's own histogram: the round loop only ever pays for the
+        # hand-off.
         sharded = len(self.shards) > 1
-        if self._obs is not None:
-            self._obs.thread_cpu.register("wal")
+        ob = self._obs
+        if ob is not None:
+            ob.thread_cpu.register("wal")
         while True:
             with sh.cv:
                 while not sh.q and not sh.stop:
@@ -222,7 +212,7 @@ class WALWriter:
                 sh.q.clear()
                 sh.cv.notify_all()  # unblock submit() backpressure NOW:
                 # the round loop refills while this batch fsyncs
-            t0 = time.perf_counter()
+            t0 = time.perf_counter() if ob is not None else 0.0
             try:
                 for _, _, sub in batch:
                     if sub is not None:
@@ -237,28 +227,23 @@ class WALWriter:
                     sh.wal.append_nosync(RoundRecord(round_no=top_round))
                 sh.wal.sync()       # ONE fsync covers the whole batch
             except Exception as e:  # noqa: BLE001 — re-raised at the seam
-                if self._obs is not None:
+                if ob is not None:
                     # A writer-shard fail-stop kills the whole
                     # durability pipeline: dump the round timeline.
-                    self._obs.flight.dump(self.dir,
-                                          f"wal-shard-{sh.idx}")
+                    ob.flight.dump(self.dir, f"wal-shard-{sh.idx}")
                 with sh.cv:
                     sh.exc = e
                     sh.cv.notify_all()
                 with self._wm:
                     self._wm.notify_all()   # wake waiters to observe exc
                 return
-            dt = time.perf_counter() - t0
-            self.phase_s[pkey] = self.phase_s.get(pkey, 0.0) + dt
-            sh.fsyncs += 1
-            sh.fsync_ms.append(dt * 1000.0)
-            sh.batch_sizes.append(len(batch))
-            ob = self._obs
             if ob is not None:
-                ob.h_wal_fsync[sh.idx].observe(dt)
+                ob.h_wal_fsync[sh.idx].observe(time.perf_counter() - t0)
                 ob.h_wal_commit[sh.idx].observe(len(batch))
                 for _t, rnd, _sub in batch:
                     ob.flight.mark(rnd, _FLIGHT_DURABLE)
+            sh.fsyncs += 1
+            sh.batch_sizes.append(len(batch))
             with self._wm:
                 sh.durable = top_ticket
                 d = min(s.durable for s in self.shards)
@@ -286,7 +271,6 @@ class WALWriter:
                     sh.cv.wait(0.5)
                 if sh.exc is None:
                     sh.q.append((ticket, rec.round_no, sub))
-                    self._depths.append(len(sh.q))
                     if self._obs is not None:
                         self._obs.g_wal_queue[sh.idx].set(len(sh.q))
                     sh.cv.notify_all()
@@ -383,30 +367,3 @@ class WALWriter:
         if self.shards[0].wal is not self.root:
             for sh in self.shards:
                 sh.wal.purge_segments(fallback)
-
-    # -- introspection ------------------------------------------------------
-
-    def stats(self) -> Dict[str, object]:
-        """Rolling writer-compartment profile for bench.py: fsync
-        latency percentiles (per group commit, measured IN the writer
-        thread), group-commit batch sizes, and the submit-side queue
-        depth the round loop observed."""
-        fs = [v for sh in self.shards for v in sh.fsync_ms]
-        bs = [v for sh in self.shards for v in sh.batch_sizes]
-        dep = list(self._depths)
-
-        def pct(a, q):
-            return round(float(np.percentile(a, q)), 3) if a else None
-
-        return {
-            "wal_shards": len(self.shards),
-            "wal_rounds_submitted": self._submitted,
-            "wal_group_commits": sum(sh.fsyncs for sh in self.shards),
-            "wal_fsync_p50_ms": pct(fs, 50),
-            "wal_fsync_p99_ms": pct(fs, 99),
-            "wal_group_commit_mean": (round(sum(bs) / len(bs), 2)
-                                      if bs else None),
-            "wal_group_commit_max": (max(bs) if bs else None),
-            "wal_queue_depth_p50": pct(dep, 50),
-            "wal_queue_depth_max": (max(dep) if dep else None),
-        }

@@ -368,22 +368,6 @@ class Registry:
                     lines.append(f"{series} {v}")
         return "\n".join(lines) + "\n"
 
-    def snapshot(self) -> Dict[str, float]:
-        """Flat {series-with-labels: value} map of every finite sample.
-
-        The bench uses before/after snapshots of this to cross-check its
-        own BENCH columns against what /metrics would have reported.
-        """
-        out: Dict[str, float] = {}
-        with self._lock:
-            metrics = list(self._metrics.values())
-        for m in metrics:
-            for name, labels, v in m.samples():
-                if isinstance(v, float) and math.isnan(v):
-                    continue
-                out[self._series_name(name, labels)] = float(v)
-        return out
-
 
 REGISTRY = Registry()
 

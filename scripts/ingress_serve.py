@@ -6,7 +6,7 @@ The ingress tier (etcd_tpu/server/ingress.py) is stateless — it holds
 no WAL, no store, nothing durable — so scaling it is purely horizontal:
 run one process per core, point them all at the same upstream, and
 spread shallow clients across them (round-robin DNS, an L4 balancer, or
-the bench harness's explicit striping). Each process coalesces its own
+explicit striping by the client). Each process coalesces its own
 clients' writes into /tenants/{t}/batch flushes; the upstream engine
 sees N deep submitters instead of tens of thousands of shallow ones.
 
@@ -22,7 +22,7 @@ Usage:
 
 Prints one JSON line {"ingress": [ports], "upstream": url,
 "pids": [...]} then serves until SIGTERM, tearing down every child.
-Tests and the shallow_clients bench scenario drive it as a subprocess.
+Tests drive it as a subprocess.
 """
 import argparse
 import json

@@ -30,7 +30,7 @@ per-tenant-range segment streams with parallel group-commit fsyncs), so
 a single-shard pool (--shards 1 --applier-shards 4 --wal-shards 4)
 exploits multiple cores without paying the router's process split, and
 a sharded pool multiplies all three (M x K appliers, M x S fsync
-streams — the aggregate scale curve in BENCH_r06.json).
+streams).
 
 A CPU harness: a chip belongs to one process, so K shard processes
 cannot share one, and each shard is pinned to the CPU backend below. On

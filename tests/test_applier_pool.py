@@ -37,7 +37,7 @@ def make_engine(tmp, shards, **kw):
 
 def inject(eng, g, r):
     """Queue a request WITHOUT registering a waiter (the waiterless
-    batched fast path; bench.py offers load the same way)."""
+    batched fast path)."""
     if r.id == 0:
         r = Request(**{**r.__dict__, "id": eng.reqid.next()})
     with eng._lock:

@@ -517,8 +517,8 @@ def test_batchframe_sever_midflight_collects_staged_flushes(tmp_path):
     SIGKILL) must not leak them: the engine-side collector keeps
     draining its queue and COLLECTS every staged flush even though the
     responses have nowhere to go — otherwise each abandoned slot pins
-    etcd_server_pending_proposal_total forever (the bench's inter-leg
-    drain barrier hangs on exactly that gauge after the kill leg)."""
+    etcd_server_pending_proposal_total forever (a drain barrier on
+    that gauge then hangs after the kill)."""
     import socket
     import struct
     import time

@@ -5,8 +5,8 @@
   un-torn and monotone under concurrent deep-queue writes — verified
   at the HTTP level through the same parser etcd_top uses.
 - The registry's acked-requests counter moves by EXACTLY the number of
-  writes the engine reports acked (the differential cross-check the
-  bench's metrics_delta column relies on).
+  writes the engine reports acked (a scrape-to-scrape delta is what
+  the benchmark's per-layer metrics are made of).
 - The flight recorder ring wraps without mixing rounds, drops late
   marks for evicted rounds, and its SIGUSR2 dump is valid Chrome
   trace-event JSON carrying all six pipeline stages.
@@ -554,22 +554,16 @@ def test_metrics_http_all_compartments_under_load(eng_http):
 
 
 def test_acked_counter_differential(eng_http):
-    """Registry movement == engine-reported acks: the cross-check
-    bench.py's metrics_delta column institutionalizes."""
+    """Registry movement == engine-reported acks: what a scrape-to-scrape
+    delta of /metrics says is what the engine acknowledged."""
     eng, base = eng_http
-    spec = importlib.util.spec_from_file_location(
-        "bench_for_obs_test", os.path.join(REPO, "bench.py"))
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-
-    snap0 = bench._metrics_snapshot()
+    snap0 = _reg()
     a0 = eng.acked_requests
     N = 12
     for i in range(N):
         _http("PUT", f"{base}/tenants/{i % G}/v2/keys/diff/k{i}",
               f"value=v{i}")
-    delta = bench._metrics_delta(snap0, bench._metrics_snapshot())
-    moved = delta.get("etcd_engine_acked_requests_total", 0)
+    moved = _delta(snap0, _reg(), "etcd_engine_acked_requests_total")
     assert moved == N == eng.acked_requests - a0
 
 

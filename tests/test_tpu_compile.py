@@ -1,11 +1,9 @@
-"""Ask the chip's compiler, without the chip: the serving step variants, the
-mesh variant and the Pallas ring kernel compiled ahead of time for a
-DESCRIBED v5e:2x2 topology (the TPU compiler ships with libtpu and needs no
-device attached). Nothing runs, so these say nothing about results or
-speed — they catch what the CPU backend and interpret mode cannot: a
-program the TPU compiler refuses, a step that stopped aliasing its donated
-state, a collective that crept onto the groups axis, a kernel whose tiling
-Mosaic rejects.
+"""Ask the chip's compiler, without the chip: the serving step variants and
+the mesh variant compiled ahead of time for a DESCRIBED v5e:2x2 topology
+(the TPU compiler ships with libtpu and needs no device attached). Nothing
+runs, so these say nothing about results or speed — they catch what the CPU
+backend cannot: a program the TPU compiler refuses, a step that stopped
+aliasing its donated state, a collective that crept onto the groups axis.
 
 Rules this file keeps (on-chip-measurement guide, section 2): the topology
 is described inside a module-scoped fixture, never at import, so every
@@ -30,7 +28,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, SingleDeviceSharding
 
 from etcd_tpu.ops import kernel
-from etcd_tpu.ops.pallas_kernels import ring_resolve
 from etcd_tpu.ops.state import KernelConfig, init_state
 from etcd_tpu.server.engine import _named_partial
 
@@ -53,9 +50,8 @@ def topo():
 @pytest.fixture(scope="module")
 def as_served():
     """Compile the way a served member does, not the way the suite runs:
-    x64 off (conftest turns it on for the CPU tests; a member never does,
-    and Mosaic cannot lower the int64 literals x64 would make), and the
-    persistent cache off around these compiles."""
+    x64 off (conftest turns it on for the CPU tests; a member never
+    does), and the persistent cache off around these compiles."""
     from jax.experimental.compilation_cache import compilation_cache as cc
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
@@ -207,27 +203,6 @@ def _check_mesh_gather(compiled, G: int, K: int) -> None:
 
 def test_mesh_gather_rows_compiles_for_v5e_2x2(topo, as_served):
     _check_mesh_gather(_compile_mesh_gather(topo, 128, 256), 128, 256)
-
-
-@pytest.mark.parametrize("trailing", [(4,), (P,)])
-def test_pallas_ring_resolve_compiles_for_v5e(topo, as_served,
-                                              trailing):
-    """The Mosaic kernel (interpret=False) at serving widths: G=12,500,
-    P=5, W=32 and the index shapes kernel._terms_at_many is called with
-    ((G,P,E) conflict scan, (G,P,P) prev-term). Its blocks' last dims
-    (32, T*E, 1) are far from the 128-lane tiling; Mosaic accepts them
-    (compiled here, never run; pallas_bench's (G,P,P,E) compiles too —
-    CHANGES.md, PR 21)."""
-    one = SingleDeviceSharding(topo.devices[0])
-
-    def s(shape):
-        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one)
-
-    G = 12_500
-    compiled = ring_resolve.lower(
-        s((G, P, W)), s((G, P) + trailing), s((G, P)),
-        interpret=False).compile()
-    assert "tpu_custom_call" in compiled.as_text()
 
 
 @pytest.mark.slow

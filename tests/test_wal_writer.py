@@ -22,6 +22,7 @@ import pytest
 
 from etcd_tpu import errors
 from etcd_tpu.server.engine import EngineConfig, MultiEngine
+from etcd_tpu.server import obs as obs_mod
 from etcd_tpu.server.enginewal import EngineWAL, RoundRecord
 from etcd_tpu.server.request import Request
 from etcd_tpu.server.walwriter import WALWriter, shard_dir, split_record
@@ -93,10 +94,10 @@ def test_group_commit_one_fsync_covers_queued_rounds(tmp_path):
         w.submit(mkrec(r))           # queue up behind the parked fsync
     gate.set()
     w.flush()
-    st = w.stats()
-    assert st["wal_rounds_submitted"] == 10
-    assert st["wal_group_commit_max"] >= 5, st
-    assert st["wal_group_commits"] < 10, st
+    sh = w.shards[0]
+    assert w._submitted == 10
+    assert max(sh.batch_sizes) >= 5, list(sh.batch_sizes)
+    assert sh.fsyncs < 10, sh.fsyncs
     assert t0 == 1 and w.ticket == 10   # tickets: monotonic submission seq
     w.shards[0].wal.sync = orig_sync
     w.close()
@@ -107,23 +108,21 @@ def test_group_commit_one_fsync_covers_queued_rounds(tmp_path):
 
 def test_append_sync_is_durable_on_return_and_phase_in_writer(tmp_path):
     """append_sync keeps the old inline EngineWAL.append contract, and
-    the wal_fsync phase time is recorded by the WRITER thread (the
-    round loop only ever pays for the hand-off)."""
-    phase = {}
-    w = WALWriter(str(tmp_path), groups=G, shards=1, fsync=False,
-                  phase_s=phase)
-    w.append_sync(mkrec(0))
-    assert w._durable == w.ticket == 1
-    assert phase.get("wal_fsync", 0.0) > 0.0
-    w.close()
-
-    phase4 = {}
-    d4 = tmp_path / "s4"
-    w4 = WALWriter(str(d4), groups=G, shards=4, fsync=False,
-                   phase_s=phase4)
-    w4.append_sync(mkrec(0))
-    assert sorted(phase4) == [f"wal_fsync[{k}]" for k in range(4)]
-    w4.close()
+    the fsync is clocked by the WRITER thread into its shard's own
+    histogram, etcd_wal_writer_fsync_seconds (the round loop only ever
+    pays for the hand-off): one observation a shard for one record."""
+    for S in (1, 4):
+        ob = obs_mod.EngineObs(wal_shards=S, applier_shards=1)
+        before = [(h.count, h.sum) for h in ob.h_wal_fsync]
+        w = WALWriter(str(tmp_path / f"s{S}"), groups=G, shards=S,
+                      fsync=False, obs=ob)
+        w.append_sync(mkrec(0))
+        assert w._durable == w.ticket == 1
+        after = [(h.count, h.sum) for h in ob.h_wal_fsync]
+        assert len(after) == S
+        for (c0, s0), (c1, s1) in zip(before, after):
+            assert c1 == c0 + 1 and s1 > s0
+        w.close()
 
 
 def test_writer_failure_is_fail_stop(tmp_path):
