@@ -12,7 +12,7 @@ from __future__ import annotations
 
 import json
 import posixpath
-from typing import Dict, Optional
+from typing import Optional
 
 from etcd_tpu import errors, version as ver
 from etcd_tpu.utils import metrics
@@ -20,7 +20,7 @@ from etcd_tpu.server.cluster import Member, STORE_KEYS_PREFIX
 from etcd_tpu.server.request import (METHOD_DELETE, METHOD_GET, METHOD_POST,
                                      METHOD_PUT, Request)
 from etcd_tpu.etcdhttp.web import REPLIED, Ctx, LoopOp, Router
-from etcd_tpu.store.event import Event
+from etcd_tpu.store.event import Event, format_expiration
 
 KEYS_PREFIX = "/v2/keys"
 MEMBERS_PREFIX = "/v2/members"
@@ -29,6 +29,8 @@ STATS_PREFIX = "/v2/stats"
 
 _BOOL_FIELDS = ("recursive", "sorted", "quorum", "wait", "stream", "dir",
                 "refresh", "noValueOnSuccess")
+_BOOL_SET = frozenset(_BOOL_FIELDS)
+_NO_FLAGS = (False,) * len(_BOOL_FIELDS)
 
 _KEYS_METHODS = ("GET", "PUT", "POST", "DELETE", "HEAD")
 
@@ -36,34 +38,83 @@ _KEYS_METHODS = ("GET", "PUT", "POST", "DELETE", "HEAD")
 # store/event.go IsCreated: create, or set with prevExist=false).
 _CREATED_ACTIONS = {"create"}
 
+# A str as json.dumps writes it.
+_json_str = json.encoder.encode_basestring_ascii
 
-def _parse_bool(ctx: Ctx, field: str) -> bool:
-    raw = ctx.value(field, "")
-    if raw in ("", "false"):
+
+def _bool_of(get, field: str) -> bool:
+    """A flag among a request's parameters (`get` is Ctx.params().get)."""
+    v = get(field)
+    if v is None or v[0] in ("", "false"):
         return False
-    if raw == "true":
+    if v[0] == "true":
         return True
     raise errors.EtcdError(errors.ECODE_INVALID_FIELD,
                            cause=f'invalid value for "{field}"')
 
 
+def _count_of(get, field: str, code: int) -> int:
+    """A non-negative integer parameter, 0 where absent or blank."""
+    v = get(field)
+    if not v or not v[0]:
+        return 0
+    try:
+        n = int(v[0])
+        if n < 0:
+            raise ValueError
+    except ValueError:
+        raise errors.EtcdError(code, cause=f'invalid value for "{field}"')
+    return n
+
+
 def trim_prefix(d: dict, prefix: str = STORE_KEYS_PREFIX) -> dict:
     """Strip the internal keys prefix from every node key in a response body
-    (reference trimEventPrefix / trimNodeExternPrefix client.go:600-625)."""
-    def trim_node(n: dict) -> dict:
-        n = dict(n)
+    (reference trimEventPrefix / trimNodeExternPrefix client.go:600-625),
+    in place: `d` is an Event.to_dict() of the caller's own."""
+    def trim_node(n: dict) -> None:
         k = n.get("key", "")
         if k.startswith(prefix):
             n["key"] = k[len(prefix):] or "/"
-        if n.get("nodes") is not None:
-            n["nodes"] = [trim_node(c) for c in n["nodes"]]
-        return n
+        for c in n.get("nodes") or ():
+            trim_node(c)
 
-    d = dict(d)
     for field in ("node", "prevNode"):
         if d.get(field) is not None:
-            d[field] = trim_node(d[field])
+            trim_node(d[field])
     return d
+
+
+def _leaf_json(n, prefix: str = STORE_KEYS_PREFIX) -> str:
+    """json.dumps(trim_prefix(...)) of a NodeExtern that lists no
+    children, straight from its fields in NodeExtern.to_dict's order."""
+    k = n.key
+    if k.startswith(prefix):
+        k = k[len(prefix):] or "/"
+    out = '{"key": ' + _json_str(k)
+    if n.dir:
+        out += ', "dir": true'
+    if n.value is not None:
+        out += ', "value": ' + _json_str(n.value)
+    if n.expiration is not None:
+        out += ', "expiration": "%s", "ttl": %d' % (
+            format_expiration(n.expiration), n.ttl)
+    return out + ', "modifiedIndex": %d, "createdIndex": %d}' % (
+        n.modified_index, n.created_index)
+
+
+def _event_json(e: Event, bare: bool = False) -> bytes:
+    """json.dumps(trim_prefix(e.to_dict())) and a newline (`bare`: no
+    node, no prevNode); only a listing goes through the dicts."""
+    node, prev = (None, None) if bare else (e.node, e.prev_node)
+    if (node is None or node.nodes is None) and \
+            (prev is None or prev.nodes is None):
+        body = '{"action": ' + _json_str(e.action)
+        if node is not None:
+            body += ', "node": ' + _leaf_json(node)
+        if prev is not None:
+            body += ', "prevNode": ' + _leaf_json(prev)
+        return body.encode() + b"}\n"
+    return json.dumps(trim_prefix(e.to_dict())).encode() + b"\n"
 
 
 class ClientAPI:
@@ -92,14 +143,14 @@ class ClientAPI:
 
     # -- shared helpers -------------------------------------------------------
 
-    def _headers(self, etcd_index: Optional[int] = None) -> Dict[str, str]:
+    def _headers(self, etcd_index: Optional[int] = None) -> bytes:
+        """A reply's X-Etcd-* / X-Raft-* header lines, made for the wire."""
         s = self.server
-        h = {"X-Etcd-Cluster-ID": f"{s.cluster.cluster_id:x}"}
-        if etcd_index is not None:
-            h["X-Etcd-Index"] = str(etcd_index)
-            h["X-Raft-Index"] = str(s.commit_index)
-            h["X-Raft-Term"] = str(s.term)
-        return h
+        if etcd_index is None:
+            return b"X-Etcd-Cluster-ID: %x\r\n" % s.cluster.cluster_id
+        return (b"X-Etcd-Cluster-ID: %x\r\nX-Etcd-Index: %d\r\n"
+                b"X-Raft-Index: %d\r\nX-Raft-Term: %d\r\n"
+                % (s.cluster.cluster_id, etcd_index, s.commit_index, s.term))
 
     def _error(self, ctx: Ctx, err: errors.EtcdError) -> None:
         if not err.index:
@@ -119,8 +170,7 @@ class ClientAPI:
                      headers={"Allow": "GET, PUT, POST, DELETE, HEAD"})
             return
         try:
-            r = self._parse_key_request(ctx, suffix)
-            no_value = _parse_bool(ctx, "noValueOnSuccess")
+            r, no_value = self._parse_key_request(ctx, suffix)
             if self.security is not None:
                 self.security.check_key_access(ctx, r)
             result = self.server.do(r)
@@ -153,8 +203,9 @@ class ClientAPI:
         try:
             if self.security is not None and self.security.enabled():
                 return None
-            r = self._parse_key_request(ctx, suffix)
-            no_value = _parse_bool(ctx, "noValueOnSuccess")
+            # (the id is given here: the submitter stages this object)
+            r, no_value = self._parse_key_request(ctx, suffix,
+                                                  self.server.new_id)
             if r.method == METHOD_GET and not r.quorum:
                 self._write_key_event(ctx, self.server.do(r),
                                       no_value=no_value)
@@ -173,8 +224,10 @@ class ClientAPI:
                       kind="qread" if r.method == METHOD_GET else "write",
                       timeout=self.server.request_timeout)
 
-    def _parse_key_request(self, ctx: Ctx, suffix: str) -> Request:
-        """reference parseKeyRequest client.go:390-534."""
+    def _parse_key_request(self, ctx: Ctx, suffix: str, new_id=None):
+        """reference parseKeyRequest client.go:390-534: (the Request,
+        noValueOnSuccess). `new_id` names a write or a quorum read that
+        will be submitted; without it the id stays 0 for do() to give."""
         method = "GET" if ctx.method == "HEAD" else ctx.method
         if method not in (METHOD_GET, METHOD_PUT, METHOD_POST, METHOD_DELETE):
             raise errors.EtcdError(errors.ECODE_INVALID_FORM,
@@ -186,64 +239,42 @@ class ClientAPI:
             # internal /0 cluster-metadata tree.
             raise errors.EtcdError(errors.ECODE_INVALID_FORM,
                                    cause=f"invalid key path {suffix!r}")
-        flags = {f: ctx.has(f) and _parse_bool(ctx, f)
-                 for f in _BOOL_FIELDS}
+        params = ctx.params()
+        get = params.get
+        (recursive, sorted_, quorum, wait, stream, dir_, refresh,
+         no_value) = ([_bool_of(get, f) for f in _BOOL_FIELDS]
+                      if not _BOOL_SET.isdisjoint(params) else _NO_FLAGS)
 
-        if ctx.has("prevValue") and ctx.value("prevValue") == "":
-            raise errors.EtcdError(errors.ECODE_PREV_VALUE_REQUIRED,
-                                   cause='"prevValue" cannot be empty')
-        prev_value = ctx.value("prevValue", "")
+        prev_value = get("prevValue")
+        if prev_value is not None:
+            prev_value = prev_value[0]
+            if prev_value == "":
+                raise errors.EtcdError(errors.ECODE_PREV_VALUE_REQUIRED,
+                                       cause='"prevValue" cannot be empty')
+        prev_index = _count_of(get, "prevIndex", errors.ECODE_INDEX_NAN)
 
-        prev_index = 0
-        if ctx.value("prevIndex"):
-            try:
-                prev_index = int(ctx.value("prevIndex"))
-                if prev_index < 0:
-                    raise ValueError
-            except ValueError:
-                raise errors.EtcdError(errors.ECODE_INDEX_NAN,
-                                       cause='invalid value for "prevIndex"')
-
-        prev_exist: Optional[bool] = None
-        if ctx.has("prevExist"):
-            raw = ctx.value("prevExist")
-            if raw not in ("true", "false"):
+        prev_exist = get("prevExist")
+        if prev_exist is not None:
+            if prev_exist[0] not in ("true", "false"):
                 raise errors.EtcdError(errors.ECODE_INVALID_FIELD,
                                        cause='invalid value for "prevExist"')
-            prev_exist = raw == "true"
+            prev_exist = prev_exist[0] == "true"
 
-        since = 0
-        if ctx.value("waitIndex"):
-            try:
-                since = int(ctx.value("waitIndex"))
-                if since < 0:
-                    raise ValueError
-            except ValueError:
-                raise errors.EtcdError(errors.ECODE_INDEX_NAN,
-                                       cause='invalid value for "waitIndex"')
+        since = _count_of(get, "waitIndex", errors.ECODE_INDEX_NAN)
+        ttl = _count_of(get, "ttl", errors.ECODE_TTL_NAN)
+        expiration = self.server.clock() + ttl if ttl > 0 else None
+        value = get("value")
 
-        expiration: Optional[float] = None
-        if ctx.value("ttl"):
-            try:
-                ttl = int(ctx.value("ttl"))
-                if ttl < 0:
-                    raise ValueError
-            except ValueError:
-                raise errors.EtcdError(errors.ECODE_TTL_NAN,
-                                       cause='invalid value for "ttl"')
-            if ttl > 0:
-                expiration = self.server.clock() + ttl
-
-        if flags["wait"] and flags["quorum"]:
+        if wait and quorum:
             raise errors.EtcdError(
                 errors.ECODE_INVALID_FIELD,
                 cause='"quorum" is incompatible with "wait"')
-        if flags["stream"] and not flags["wait"]:
+        if stream and not wait:
             raise errors.EtcdError(
                 errors.ECODE_INVALID_FIELD,
                 cause='"stream" requires "wait"')
-        if flags["refresh"]:
-            if ctx.has("value"):
+        if refresh:
+            if value is not None:
                 raise errors.EtcdError(
                     errors.ECODE_REFRESH_VALUE,
                     cause="A value was provided on a refresh")
@@ -252,13 +283,16 @@ class ClientAPI:
                     errors.ECODE_REFRESH_TTL_REQUIRED,
                     cause="No TTL value set")
 
+        rid = 0
+        if new_id is not None and (method != METHOD_GET or quorum):
+            rid = new_id()
         return Request(
-            method=method, path=p, val=ctx.value("value", ""),
-            dir=flags["dir"], prev_value=prev_value, prev_index=prev_index,
+            id=rid, method=method, path=p, val=value[0] if value else "",
+            dir=dir_, prev_value=prev_value or "", prev_index=prev_index,
             prev_exist=prev_exist, expiration=expiration,
-            wait=flags["wait"], since=since, recursive=flags["recursive"],
-            sorted=flags["sorted"], quorum=flags["quorum"],
-            stream=flags["stream"], refresh=flags["refresh"])
+            wait=wait, since=since, recursive=recursive,
+            sorted=sorted_, quorum=quorum, stream=stream,
+            refresh=refresh), no_value
 
     def _write_key_event(self, ctx: Ctx, e: Event,
                          no_value: bool = False) -> None:
@@ -268,15 +302,11 @@ class ClientAPI:
         created = (e.action in _CREATED_ACTIONS or
                    (e.action == "set" and e.prev_node is None))
         status = 201 if created else 200
-        d = e.to_dict()
-        if no_value and e.action in ("set", "update", "create",
-                                     "compareAndSwap", "compareAndDelete"):
-            # noValueOnSuccess strips the payload echo (reference
-            # writeKeyEvent noValueOnSuccess handling).
-            d.pop("node", None)
-            d.pop("prevNode", None)
-        body = json.dumps(trim_prefix(d)).encode() + b"\n"
-        ctx.send(status, body, "application/json",
+        # noValueOnSuccess strips the payload echo (reference
+        # writeKeyEvent noValueOnSuccess handling).
+        bare = no_value and e.action in ("set", "update", "create",
+                                         "compareAndSwap", "compareAndDelete")
+        ctx.send(status, _event_json(e, bare), "application/json",
                  self._headers(e.etcd_index))
 
     def _handle_watch(self, ctx: Ctx, r: Request, watcher) -> None:
@@ -289,9 +319,8 @@ class ClientAPI:
                 while True:
                     e = watcher.next_event(timeout=0.5)
                     if e is not None:
-                        body = (json.dumps(trim_prefix(e.to_dict())).encode()
-                                + b"\n")
-                        ctx.send(200, body, "application/json", headers)
+                        ctx.send(200, _event_json(e), "application/json",
+                                 headers)
                         return
                     if watcher.removed or ctx.client_gone() or \
                             self.server.stopped:
@@ -302,9 +331,7 @@ class ClientAPI:
                 while True:
                     e = watcher.next_event(timeout=0.5)
                     if e is not None:
-                        data = (json.dumps(trim_prefix(e.to_dict())).encode()
-                                + b"\n")
-                        if not ctx.write_chunk(data):
+                        if not ctx.write_chunk(_event_json(e)):
                             return
                     elif watcher.removed or ctx.client_gone() or \
                             self.server.stopped:

@@ -78,6 +78,8 @@ class _TenantServer:
         # submit, if it has one (TenantAPI).
         self.submitter = submitter
         self.request_timeout = engine.cfg.request_timeout
+        # ... and the ids of the requests it parses for that submit.
+        self.new_id = engine.reqid.next
 
     def submit_item(self, r):
         """The (g, request) pair the submitter stages for this tenant."""
@@ -102,11 +104,11 @@ class _TenantServer:
 
     @property
     def commit_index(self) -> int:
-        return int(self._engine.h_commit[self._g].max())
+        return max(self._engine.h_commit[self._g].tolist())
 
     @property
     def term(self) -> int:
-        return int(self._engine.h_term[self._g].max())
+        return max(self._engine.h_term[self._g].tolist())
 
 
 class TenantAPI:
@@ -753,8 +755,10 @@ class EngineHttp:
         router = Router()
         self.api = TenantAPI(engine, admin_credentials=admin_credentials)
         self.api.install(router)
+        obs = getattr(engine, "obs", None)
         self.http = HttpServer(host, port, router, cors=cors,
-                               tls_context=tls_context)
+                               tls_context=tls_context,
+                               thread_cpu=obs and obs.thread_cpu)
 
     @property
     def url(self) -> str:

@@ -57,8 +57,9 @@ import threading
 import time
 from email.utils import formatdate
 from http import HTTPStatus
+from types import MappingProxyType
 from typing import Any, Callable, Dict, List, Optional, Tuple
-from urllib.parse import parse_qs, unquote, urlsplit
+from urllib.parse import unquote, urlsplit
 
 from etcd_tpu.native import _py_recv_many as _recv_each
 from etcd_tpu.native import _py_send_many as _send_each
@@ -93,18 +94,26 @@ _AGAIN = (-errno.EAGAIN, -errno.EWOULDBLOCK, -errno.EINTR)
 class Headers:
     """A request's headers: get() is case-insensitive and returns the
     first value (email.message.Message.get's contract, which the handlers
-    were written against); items() keeps wire order and spelling."""
+    were written against); items() keeps wire order and spelling. The
+    look-up table is made at the first get(): for the request whose
+    handler reads a header."""
 
     __slots__ = ("_items", "_first")
 
     def __init__(self, items: List[Tuple[str, str]]) -> None:
         self._items = items
-        self._first: Dict[str, str] = {}
-        for k, v in items:
-            self._first.setdefault(k.lower(), v)
+        self._first: Optional[Dict[str, str]] = None
+
+    def _table(self) -> Dict[str, str]:
+        first = self._first
+        if first is None:
+            first = self._first = {}
+            for k, v in self._items:
+                first.setdefault(k.lower(), v)
+        return first
 
     def get(self, name: str, default=None):
-        return self._first.get(name.lower(), default)
+        return self._table().get(name.lower(), default)
 
     def items(self):
         return list(self._items)
@@ -136,7 +145,8 @@ def _parse_head(raw: bytes, t_in: float) -> Tuple[_Head, bool]:
     """Request line + headers (without the blank line) -> (_Head, whether
     the client waits for `100 Continue`). Raises _BadRequest."""
     # iso-8859-1 maps bytes to characters one to one: lengths are bytes.
-    lines = raw.decode("iso-8859-1").split("\n")
+    text = raw.decode("iso-8859-1")
+    lines = text.split("\n")
     first = lines[0]
     if len(first) > _MAX_LINE:
         raise _BadRequest(414, "Request-URI Too Long")
@@ -161,18 +171,24 @@ def _parse_head(raw: bytes, t_in: float) -> Tuple[_Head, bool]:
         raise _BadRequest(431, "Too many headers")
     items: List[Tuple[str, str]] = []
     for i in range(1, len(lines)):
-        text = lines[i]
-        if len(text) > _MAX_LINE:
+        line = lines[i]
+        if len(line) > _MAX_LINE:
             raise _BadRequest(431, "Line too long")
-        if text[:1] in (" ", "\t") and items:      # obsolete line folding
-            items[-1] = (items[-1][0], items[-1][1] + " " + text.strip())
+        if line[:1] in (" ", "\t") and items:      # obsolete line folding
+            items[-1] = (items[-1][0], items[-1][1] + " " + line.strip())
             continue
-        name, sep, value = text.partition(":")
+        name, sep, value = line.partition(":")
         if not sep or not name:
             raise _BadRequest(400, "Bad header line")
         items.append((name, value.strip()))
     headers = Headers(items)
-    get = headers._first.get
+    low = text.lower()
+    if not ("content-length" in low or "connection" in low
+            or "expect" in low or "transfer-encoding" in low):
+        # The head spells none of the four headers the front itself
+        # reads (a GET of two header lines): nothing to look up.
+        return _Head(method, target, headers, 0, vers >= (1, 1), t_in), False
+    get = headers._table().get
     if get("transfer-encoding") is not None:
         raise _BadRequest(501, "Transfer-Encoding is not supported")
     try:
@@ -273,9 +289,30 @@ class _SockWriter:
         pass
 
 
+def _add_params(qs: str, into: Dict[str, List[str]]) -> None:
+    """parse_qs(qs, keep_blank_values=True), added to `into`: `&` alone
+    separates, `+` is a space, %XX a byte of UTF-8, repeats keep order."""
+    for pair in qs.split("&"):
+        if not pair:
+            continue
+        name, _, value = pair.partition("=")
+        if "+" in pair or "%" in pair:
+            name = unquote(name.replace("+", " "))
+            value = unquote(value.replace("+", " "))
+        if name in into:
+            into[name].append(value)
+        else:
+            into[name] = [value]
+
+
 class Ctx:
-    """One request: parsed query+form values, response helpers, and a
-    client-disconnect probe for long-polls."""
+    """One request: query+form values (parsed when first asked for),
+    response helpers, and a client-disconnect probe for long-polls."""
+
+    # Extra headers of every response (CORS): set whole by the server.
+    extra_headers: Dict[str, str] = MappingProxyType({})
+    _values: Optional[Dict[str, List[str]]] = None
+    _streaming = False
 
     def __init__(self, conn: _Conn, head: _Head, body: bytes) -> None:
         self._conn = conn
@@ -288,30 +325,38 @@ class Ctx:
         target = head.target
         if target.startswith("//"):
             target = "/" + target.lstrip("/")
-        parts = urlsplit(target)
-        self.path = unquote(parts.path) if "%" in parts.path else parts.path
-        self._values: Dict[str, List[str]] = (
-            parse_qs(parts.query, keep_blank_values=True)
-            if parts.query else {})
-        if body and (self.headers.get("Content-Type") or "").startswith(
-                "application/x-www-form-urlencoded"):
-            # Body parameters take precedence over the URL query string
-            # (Go net/http Request.Form semantics the reference relies on).
-            for k, v in parse_qs(body.decode("utf-8", "replace"),
-                                 keep_blank_values=True).items():
-                self._values[k] = v + self._values.get(k, [])
-        self._streaming = False
-        # Extra headers injected into every response (CORS); set by the
-        # server before dispatch.
-        self.extra_headers: Dict[str, str] = {}
+        if target[:1] == "/":
+            # Origin form: no scheme or authority for urlsplit to find,
+            # so it would drop the fragment and cut at the first `?`.
+            if "#" in target:
+                target = target[:target.index("#")]
+            path, _, self._query = target.partition("?")
+        else:
+            parts = urlsplit(target)
+            path, self._query = parts.path, parts.query
+        self.path = unquote(path) if "%" in path else path
 
     # -- inputs -------------------------------------------------------------
 
+    def params(self) -> Dict[str, List[str]]:
+        """Every parameter's values (read only), the form's before the
+        query's: body parameters take precedence over the URL query string
+        (Go net/http Request.Form semantics the reference relies on)."""
+        values = self._values
+        if values is None:
+            values = self._values = {}
+            if self.body and (self.headers.get("Content-Type") or ""
+                              ).startswith("application/x-www-form-urlencoded"):
+                _add_params(self.body.decode("utf-8", "replace"), values)
+            if self._query:
+                _add_params(self._query, values)
+        return values
+
     def has(self, key: str) -> bool:
-        return key in self._values
+        return key in self.params()
 
     def value(self, key: str, default: str = "") -> str:
-        v = self._values.get(key)
+        v = self.params().get(key)
         return v[0] if v else default
 
     def remote_addr(self) -> str:
@@ -320,41 +365,38 @@ class Ctx:
 
     # -- buffered responses ---------------------------------------------------
 
-    def _head(self, status: int, fields: List[str],
-              headers: Optional[Dict[str, str]]) -> bytes:
-        out = [f"HTTP/1.1 {status} {_REASONS.get(status, '')}\r\n"
-               f"Server: {self._conn.server.server_version}\r\n"
-               f"Date: {_http_date()}\r\n"]
-        out += fields
-        for k, v in self.extra_headers.items():
-            out.append(f"{k}: {v}\r\n")
+    def _head(self, status: int, content_type: str, rest: bytes,
+              headers) -> bytes:
+        """_head_start's lines, `rest` (length or transfer encoding), the
+        CORS headers, the handler's: a dict, or lines made for the wire."""
+        out = _head_start(status, self._conn.server.server_version,
+                          content_type) + rest
+        if self.extra_headers:
+            out += _header_lines(self.extra_headers)
         if headers:
-            for k, v in headers.items():
-                out.append(f"{k}: {v}\r\n")
-        out.append("\r\n")
-        return "".join(out).encode("iso-8859-1")
+            out += (headers if type(headers) is bytes
+                    else _header_lines(headers))
+        return out + b"\r\n"
 
     def send(self, status: int, body: bytes = b"",
-             content_type: str = "text/plain",
-             headers: Optional[Dict[str, str]] = None) -> None:
+             content_type: str = "text/plain", headers=None) -> None:
         """The whole reply as one buffer and one send."""
-        fields = [f"Content-Type: {content_type}\r\n"
-                  f"Content-Length: {len(body)}\r\n"]
-        if not self.keep_alive:
-            fields.append("Connection: close\r\n")
-        head = self._head(status, fields, headers)
+        head = self._head(
+            status, content_type,
+            (b"Content-Length: %d\r\n" if self.keep_alive else
+             b"Content-Length: %d\r\nConnection: close\r\n") % len(body),
+            headers)
         self._conn.write(head + body if body and self.method != "HEAD"
                          else head)
 
-    def send_json(self, status: int, obj,
-                  headers: Optional[Dict[str, str]] = None) -> None:
+    def send_json(self, status: int, obj, headers=None) -> None:
         self.send(status, json.dumps(obj).encode(), "application/json",
                   headers)
 
     # -- chunked streaming (watch streams) ------------------------------------
 
     def begin_stream(self, status: int, content_type: str,
-                     headers: Optional[Dict[str, str]] = None) -> None:
+                     headers=None) -> None:
         # A stream writer must never block forever on a stalled client:
         # with no socket timeout, a peer that stops reading (TCP buffers
         # full) would pin this handler thread inside sendall and its
@@ -366,8 +408,8 @@ class Ctx:
             pass
         self._streaming = True
         self._conn.write(self._head(
-            status, [f"Content-Type: {content_type}\r\n"
-                     "Transfer-Encoding: chunked\r\n"], headers))
+            status, content_type, b"Transfer-Encoding: chunked\r\n",
+            headers))
 
     def write_chunk(self, data: bytes) -> bool:
         try:
@@ -416,16 +458,27 @@ class Ctx:
             return True
 
 
-_date_cache: Tuple[int, str] = (0, "")
+# (status, server version, content type) -> (second, the first lines)
+_head_starts: Dict[tuple, Tuple[int, bytes]] = {}
 
 
-def _http_date() -> str:
-    """The Date header, formatted once a second."""
-    global _date_cache
+def _head_start(status: int, server_version: str, content_type: str) -> bytes:
+    """Status line, Server, Date, Content-Type: made once a second."""
     now = int(time.time())
-    if _date_cache[0] != now:
-        _date_cache = (now, formatdate(now, usegmt=True))
-    return _date_cache[1]
+    key = (status, server_version, content_type)
+    hit = _head_starts.get(key)
+    if hit is None or hit[0] != now:
+        hit = _head_starts[key] = (now, (
+            f"HTTP/1.1 {status} {_REASONS.get(status, '')}\r\n"
+            f"Server: {server_version}\r\n"
+            f"Date: {formatdate(now, usegmt=True)}\r\n"
+            f"Content-Type: {content_type}\r\n").encode("iso-8859-1"))
+    return hit[1]
+
+
+def _header_lines(headers: Dict[str, str]) -> bytes:
+    return "".join(f"{k}: {v}\r\n"
+                   for k, v in headers.items()).encode("iso-8859-1")
 
 
 # What a route's `begin` returns when it has answered the request itself.
@@ -441,7 +494,8 @@ class LoopOp:
             with a `.rid` the sink will name, or an exception in the
             place of an item it refuses (answered at once, through
             `finish`, and that request only); returns at once
-        submitter.settle(token, value) -> the value to answer with
+        submitter.settle(tokens, values) -> the values to answer with,
+            for the completions of one wake
         submitter.expire(token) -> the value to answer a request with
             that outlived `timeout` (and must not be delivered later)
         submitter.tracer (optional) -> a server.obs.Tracer for the
@@ -466,7 +520,7 @@ class LoopOp:
 
 
 Handler = Callable[[Ctx, str], None]
-Route = Tuple[str, bool, Handler, Optional[Callable[[Ctx, str], Any]]]
+Route = Tuple[str, bool, Handler, Optional[Callable[[Ctx, str], Any]], str]
 
 
 class Router:
@@ -482,17 +536,16 @@ class Router:
         tried first, on the event loop, and must not block: it returns
         REPLIED (answered), a LoopOp (submitted; answered later) or None
         (run `fn` on a thread)."""
-        self._routes.append((prefix, exact, fn, begin))
+        self._routes.append((prefix, exact, fn, begin,
+                             prefix if prefix.endswith("/") else prefix + "/"))
         self._routes.sort(key=lambda r: len(r[0]), reverse=True)
 
     def match(self, path: str):
         """(fn, begin, suffix) of the route that serves `path`, or None."""
-        for prefix, exact, fn, begin in self._routes:
-            if exact:
-                if path == prefix:
-                    return fn, begin, ""
-            elif path == prefix or path.startswith(
-                    prefix if prefix.endswith("/") else prefix + "/"):
+        for prefix, exact, fn, begin, below in self._routes:
+            if path == prefix:
+                return fn, begin, ""
+            if not exact and path.startswith(below):
                 return fn, begin, path[len(prefix):]
         return None
 
@@ -574,8 +627,12 @@ class HttpServer:
 
     def __init__(self, host: str, port: int, router: Router,
                  server_version: str = "etcd-tpu",
-                 cors: Optional[set] = None, tls_context=None) -> None:
+                 cors: Optional[set] = None, tls_context=None,
+                 thread_cpu=None) -> None:
         self.router = router
+        # An obs.ThreadCpu for the loop thread's CPU clock (class "loop",
+        # read at scrape time only), where the server behind keeps one.
+        self._thread_cpu = thread_cpu
         self.server_version = server_version
         # CORS origin whitelist ("*" = any); None disables CORS handling
         # (reference pkg/cors/cors.go CORSInfo + CORSHandler).
@@ -681,6 +738,8 @@ class HttpServer:
 
     def _serve(self) -> None:
         sel = self._sel
+        if self._thread_cpu is not None:
+            self._thread_cpu.register("loop")
         sel.register(self._lsock, selectors.EVENT_READ, _ACCEPT)
         sel.register(self._wake_r, selectors.EVENT_READ, _WAKE)
         next_sweep = time.monotonic() + _SWEEP_S
@@ -855,6 +914,7 @@ class HttpServer:
             log.exception("http: send to %d connections failed", len(items))
             sent = _send_each(items)
         now = time.perf_counter()
+        handed = []
         for conn, n in zip(live, sent):
             if n < 0:
                 if n not in _AGAIN:
@@ -863,16 +923,19 @@ class HttpServer:
                 n = 0
             del conn.wbuf[:n]
             if conn.reply is not None:
-                self._handed(conn, now)
+                handed.append(conn.reply)
+                conn.reply = None
             if conn.wbuf:
                 self._watch(conn, conn.events | selectors.EVENT_WRITE)
                 continue
-            self._watch(conn, conn.events & ~selectors.EVENT_WRITE)
+            if conn.events & selectors.EVENT_WRITE:
+                self._watch(conn, conn.events & ~selectors.EVENT_WRITE)
             try:
                 self._advance(conn)
             except Exception:  # noqa: BLE001 — one connection's
                 log.exception("http: connection %s failed", conn.addr)
                 self._close(conn)
+        self._handed(handed, now)
 
     def _advance(self, conn: _Conn) -> None:
         """Serve what the read buffer holds, one request at a time, until
@@ -881,6 +944,8 @@ class HttpServer:
             if conn.closing or (conn.eof and not conn.rbuf):
                 self._close(conn)
                 return
+            if not conn.rbuf:
+                break                   # nothing read: no request to find
             ctx = self._next_request(conn)
             if ctx is not None:
                 self._dispatch(conn, ctx)
@@ -888,7 +953,8 @@ class HttpServer:
                 conn.closing = True     # half a request and no more to come
             elif not conn.closing:
                 break                   # more bytes needed
-        if not (conn.closed or conn.eof or conn.blocking):
+        if not (conn.events & selectors.EVENT_READ or conn.closed
+                or conn.eof or conn.blocking):
             self._watch(conn, conn.events | selectors.EVENT_READ)
 
     def _next_request(self, conn: _Conn) -> Optional[Ctx]:
@@ -938,16 +1004,14 @@ class HttpServer:
     def _refuse(self, conn: _Conn, status: int, message: str) -> None:
         """Answer a request the front cannot serve (the stdlib handler's
         send_error) and close the connection."""
-        reason = _REASONS.get(status, "")
         body = (f"<html><head><title>Error response</title></head><body>"
                 f"<h1>Error response</h1><p>Error code: {status}</p>"
                 f"<p>Message: {message}.</p></body></html>\n").encode(
                     "utf-8", "replace")
-        head = (f"HTTP/1.1 {status} {reason}\r\n"
-                f"Server: {self.server_version}\r\nDate: {_http_date()}\r\n"
-                f"Content-Type: text/html;charset=utf-8\r\n"
-                f"Connection: close\r\n"
-                f"Content-Length: {len(body)}\r\n\r\n").encode("iso-8859-1")
+        head = (_head_start(status, self.server_version,
+                            "text/html;charset=utf-8")
+                + b"Connection: close\r\nContent-Length: %d\r\n\r\n"
+                % len(body))
         conn.closing = True
         conn.rbuf.clear()
         try:
@@ -1031,24 +1095,31 @@ class HttpServer:
                  op: Optional[LoopOp] = None) -> None:
         """A reply was built on the loop and waits in conn.wbuf for this
         pass's sends; _handed observes it when the socket has it."""
-        conn.reply = (ctx.t_in, kind, waited, op)
+        reply = (ctx.t_in, kind, waited, op)
         conn.busy = False
         if not ctx.keep_alive:
             conn.closing = True
-        if not conn.wbuf:           # the handler wrote nothing to wait for
-            self._handed(conn, time.perf_counter())
+        if conn.wbuf:
+            conn.reply = reply
+        else:                       # the handler wrote nothing to wait for
+            self._handed([reply], time.perf_counter())
 
-    def _handed(self, conn: _Conn, now: float) -> None:
-        """The reply was handed to the socket: the front's span ends."""
-        t_in, kind, waited, op = conn.reply
-        conn.reply = None
-        if op is not None and op.traced:
-            op.submitter.tracer.mark(op.token.rid, "replied", t=now)
-        if self._obs_on:
-            dt = now - t_in
-            self._h_request[kind].observe(dt)
-            self._h_self[kind].observe(dt - waited)
-            self._c_loop.inc()
+    def _handed(self, replies: list, now: float) -> None:
+        """Handed to the socket at `now`, the front's span of each ends: a
+        pass's spans in one call per kind, a sampled mark on its own."""
+        spans: Dict[str, Tuple[list, list]] = {}
+        for t_in, kind, waited, op in replies:
+            if op is not None and op.traced:
+                op.submitter.tracer.mark(op.token.rid, "replied", t=now)
+            if self._obs_on:
+                whole, own = spans.setdefault(kind, ([], []))
+                whole.append(now - t_in)
+                own.append(now - t_in - waited)
+        for kind, (whole, own) in spans.items():
+            self._h_request[kind].observe_many(whole)
+            self._h_self[kind].observe_many(own)
+        if spans:
+            self._c_loop.inc(len(replies))
 
     def _submit(self) -> None:
         """Every LoopOp of this select pass, one call per submitter."""
@@ -1096,11 +1167,17 @@ class HttpServer:
                 self._c_completions.inc(len(done))
             t_woke = time.perf_counter()
             pop = self._inflight.pop
+            groups: Dict[int, list] = {}
             for rid, value in done:
                 op = pop(rid, None)
                 if op is not None:        # else: expired a moment ago
-                    self._finish(op, op.submitter.settle(op.token, value),
-                                 t_woke)
+                    groups.setdefault(id(op.submitter), []).append(
+                        (op, value))
+            for pairs in groups.values():   # one settle per submitter
+                settled = pairs[0][0].submitter.settle(
+                    [op.token for op, _ in pairs], [v for _, v in pairs])
+                for (op, _), value in zip(pairs, settled):
+                    self._finish(op, value, t_woke)
         while True:
             try:
                 conn, back = self._returned.get_nowait()

@@ -567,7 +567,7 @@ node_desc(const CNode *n)
  * requested path as cause (reference internalGet; walking INTO a file is
  * also KEY_NOT_FOUND, store.py _walk). */
 static CNode *
-core_walk(CoreObject *c, const char *path, Py_ssize_t len)
+core_find(CoreObject *c, const char *path, Py_ssize_t len)
 {
     CNode *cur = c->root;
     Py_ssize_t i = 0;
@@ -580,17 +580,23 @@ core_walk(CoreObject *c, const char *path, Py_ssize_t len)
         while (j < len && path[j] != '/')
             j++;
         if (cur->children == NULL)
-            goto notfound;
+            return NULL;
         CNode *nxt = cmap_get(cur->children, path + i, (uint32_t)(j - i));
         if (nxt == NULL)
-            goto notfound;
+            return NULL;
         cur = nxt;
         i = j;
     }
     return cur;
-notfound:
-    raise_etcd(ECODE_KEY_NOT_FOUND, path, len, c->current_index);
-    return NULL;
+}
+
+static CNode *
+core_walk(CoreObject *c, const char *path, Py_ssize_t len)
+{
+    CNode *n = core_find(c, path, len);
+    if (n == NULL)
+        raise_etcd(ECODE_KEY_NOT_FOUND, path, len, c->current_index);
+    return n;
 }
 
 /* Walk to dirname creating missing dirs at `index`. GIL-FREE variant
@@ -1795,6 +1801,22 @@ Core_get(CoreObject *c, PyObject *args)
                          (unsigned long long)c->current_index);
 }
 
+/* get(path)'s node value, and None where get raises 100 (or the node is
+ * a directory): the answer without the exception. Counted like the get. */
+static PyObject *
+Core_value(CoreObject *c, PyObject *args)
+{
+    const char *path;
+    Py_ssize_t plen;
+    if (!PyArg_ParseTuple(args, "s#", &path, &plen))
+        return NULL;
+    CNode *n = core_find(c, path, plen);
+    c->stats[n == NULL ? ST_GETS_FAIL : ST_GETS_OK]++;
+    if (n == NULL || n->children != NULL)
+        Py_RETURN_NONE;
+    return PyUnicode_FromStringAndSize(n->value, n->value_len);
+}
+
 /* ------------------------------------------------------- dump/load/clone */
 
 /* Full tree incl. hidden nodes, children always materialized, insertion
@@ -2055,6 +2077,7 @@ LOCKED(Core_next_expiration)
 LOCKED(Core_scan)
 LOCKED(Core_ring_bounds)
 LOCKED(Core_get)
+LOCKED(Core_value)
 LOCKED(Core_dump)
 LOCKED(Core_load)
 LOCKED(Core_clone)
@@ -2211,6 +2234,8 @@ static PyMethodDef Core_methods[] = {
      "(start_index, last_index, len) of the history ring"},
     {"get", (PyCFunction)Core_get_L, METH_VARARGS,
      "get(path, recursive, sorted) -> 7-tuple tree"},
+    {"value", (PyCFunction)Core_value_L, METH_VARARGS,
+     "value(path) -> the file's value, None where there is none"},
     {"dump", (PyCFunction)Core_dump_L, METH_NOARGS,
      "full tree as 7-tuples (snapshot shape)"},
     {"load", (PyCFunction)Core_load_L, METH_VARARGS,

@@ -1237,22 +1237,25 @@ class MultiEngine:
                     self.obs.c_reads_lease.inc(lease)
         return tokens
 
-    def settle(self, tok: "_Submitted", result: Any) -> Any:
-        """The front drained `tok`'s result from its sink: observe what
-        do() / _quorum_read observe when their thread wakes, and return
-        the result with a LazyWriteEvent resolved (here, off the apply
-        stage). An EtcdError result is returned, not raised."""
+    def settle(self, toks: List["_Submitted"],
+               results: List[Any]) -> List[Any]:
+        """The front drained these tokens' results from its sink in one
+        wake: observe what do() / _quorum_read observe when their thread
+        wakes (a wake's writes and its reads in one call each), and
+        return the results with every LazyWriteEvent resolved (here, off
+        the apply stage). An EtcdError result is returned, not raised."""
         if self.obs.enabled:
-            dt = (time.perf_counter() - tok.t0) * 1000.0
-            if tok.read:
-                self.obs.g_read_parked.dec()
-                self.obs.s_read_dur.observe(dt)
-            else:
-                metrics.propose_pending.dec()
-                metrics.propose_durations.observe(dt)
-        if type(result) is LazyWriteEvent:
-            return result.resolve()
-        return result
+            now = time.perf_counter()
+            writes = [(now - t.t0) * 1000.0 for t in toks if not t.read]
+            reads = [(now - t.t0) * 1000.0 for t in toks if t.read]
+            if writes:
+                metrics.propose_pending.dec(len(writes))
+                metrics.propose_durations.observe_many(writes)
+            if reads:
+                self.obs.g_read_parked.dec(len(reads))
+                self.obs.s_read_dur.observe_many(reads)
+        return [r.resolve() if type(r) is LazyWriteEvent else r
+                for r in results]
 
     def expire(self, tok: "_Submitted") -> errors.EtcdError:
         """The front's sweep found `tok` past cfg.request_timeout (or is
@@ -1600,7 +1603,7 @@ class MultiEngine:
 
     def tenant_active(self, g: int) -> bool:
         """Provisioned = at least one active peer slot."""
-        return bool(self.h_mask[g].any())
+        return True in self.h_mask[g].tolist()
 
     def tenants(self) -> List[int]:
         return [int(g) for g in np.nonzero(self.h_mask.any(axis=1))[0]]

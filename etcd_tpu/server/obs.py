@@ -579,10 +579,10 @@ class RoundClock:
 
 class ThreadCpu:
     """CPU clocks of an engine's long-lived threads by class (round,
-    wal, applier). Each thread registers itself once at its start
-    (pthread_getcpuclockid of its own, live id); read() is called at
-    scrape time only. Where the platform has no such clock the class
-    reads nothing and /metrics leaves the series out."""
+    wal, applier, and the HTTP front's event loop). Each thread registers
+    itself once at its start (pthread_getcpuclockid of its own, live id);
+    read() is called at scrape time only. Where the platform has no such
+    clock the class reads nothing and /metrics leaves the series out."""
 
     def __init__(self) -> None:
         # class -> [(clock id, thread)]
@@ -621,9 +621,10 @@ class ThreadCpu:
 def cpu_exposition(thread_cpu: ThreadCpu) -> List[str]:
     """/metrics lines for process_cpu_seconds_total and
     etcd_thread_cpu_seconds_total{thread}: `front` is the process minus
-    the named classes (the HTTP front's event loop, its worker threads,
-    which come and go; the JAX runtime's own threads fall under it
-    too)."""
+    round, wal and applier (the HTTP front's event loop, its worker
+    threads, which come and go; the JAX runtime's own threads fall under
+    it too); `loop` is the event loop's own clock, a part of `front`, so
+    `front` minus `loop` is the workers and the runtime."""
     proc = time.process_time()
     lines = [
         "# HELP process_cpu_seconds_total Total user and system CPU time "
@@ -637,12 +638,14 @@ def cpu_exposition(thread_cpu: ThreadCpu) -> List[str]:
             "# HELP etcd_thread_cpu_seconds_total CPU time by thread "
             "class: round (the round loop), wal (writer shards), applier "
             "(applier shards), front (the process minus those: the HTTP "
-            "front's loop and workers, and the runtime's own).",
+            "front's loop and workers, and the runtime's own), loop (the "
+            "front's event loop alone, counted in front too).",
             "# TYPE etcd_thread_cpu_seconds_total counter"]
         for cls, v in sorted(named.items()):
             lines.append(
                 f'etcd_thread_cpu_seconds_total{{thread="{cls}"}} {v}')
-        front_s = max(0.0, proc - sum(named.values()))
+        front_s = max(0.0, proc - sum(v for cls, v in named.items()
+                                      if cls != "loop"))
         lines.append(
             f'etcd_thread_cpu_seconds_total{{thread="front"}} {front_s}')
     return lines

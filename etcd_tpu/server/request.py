@@ -42,6 +42,21 @@ class Request:
     refresh: bool = False               # TTL refresh without value change
     v3: Optional[dict] = None           # METHOD_V3 payload (server/v3.py)
 
+    def __init__(self, id=0, method=METHOD_GET, path="", val="", dir=False,
+                 prev_value="", prev_index=0, prev_exist=None,
+                 expiration=None, wait=False, since=0, recursive=False,
+                 sorted=False, quorum=False, stream=False, time=0.0,
+                 refresh=False, v3=None) -> None:
+        # The fields above, in one dict update: the __init__ a frozen
+        # dataclass writes itself goes through object.__setattr__ once a
+        # field (2.4 against 0.9 us), and one is built for every request.
+        self.__dict__.update(
+            id=id, method=method, path=path, val=val, dir=dir,
+            prev_value=prev_value, prev_index=prev_index,
+            prev_exist=prev_exist, expiration=expiration, wait=wait,
+            since=since, recursive=recursive, sorted=sorted, quorum=quorum,
+            stream=stream, time=time, refresh=refresh, v3=v3)
+
     def encode(self) -> bytes:
         # self.__dict__ instead of dataclasses.asdict: asdict deep-copies
         # recursively (19 internal calls per request) and was the single

@@ -448,13 +448,12 @@ class SecurityStore:
     # -- enable/disable ------------------------------------------------------
 
     def enabled(self) -> bool:
-        try:
-            ev = self._get("/enabled")
-        except errors.EtcdError as e:
-            if e.code == errors.ECODE_KEY_NOT_FOUND:
-                return False  # never configured
-            raise  # anything else must DENY upstream, not fail open
-        return ev.node.value == "true"
+        """Asked at every keys request, of the local replica like _get,
+        but without a Request built and an EtcdError raised to say
+        "never configured". Any error must DENY upstream, not fail open:
+        none is caught here."""
+        return self.server.store.value(
+            STORE_PERMS_PREFIX + "/enabled") == "true"
 
     def enable(self) -> None:
         """reference EnableSecurity security.go:358-381: needs a root user;

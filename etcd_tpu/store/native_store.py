@@ -184,6 +184,10 @@ class NativeStore:
         return Event(ev.GET, node=_extern_tree(t, self.clock()),
                      etcd_index=idx)
 
+    def value(self, node_path: str) -> Optional[str]:
+        """get(node_path).node.value, with None where get raises 100."""
+        return self._core.value(_norm(node_path))
+
     def watch(self, key: str, recursive: bool = False, stream: bool = False,
               since_index: int = 0) -> Watcher:
         key = _norm(key)

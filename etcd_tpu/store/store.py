@@ -127,6 +127,16 @@ class Store:
             self.stats.inc("getsSuccess")
             return e
 
+    def value(self, node_path: str) -> Optional[str]:
+        """get(node_path).node.value, None where get raises 100 (the auth
+        gate's question; the native store answers without the exception)."""
+        try:
+            return self.get(node_path).node.value
+        except errors.EtcdError as e:
+            if e.code != errors.ECODE_KEY_NOT_FOUND:
+                raise
+            return None
+
     def watch(self, key: str, recursive: bool = False, stream: bool = False,
               since_index: int = 0) -> Watcher:
         key = normalize(key)

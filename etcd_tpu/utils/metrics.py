@@ -123,6 +123,14 @@ class Histogram(_Metric):
         self._counts[i] += 1
         self._sum += v
 
+    def observe_many(self, vs: Sequence[float]) -> None:
+        """observe() of each, in order: same cells, same sum to the bit."""
+        buckets, counts, total = self.buckets, self._counts, self._sum
+        for v in vs:
+            counts[bisect.bisect_left(buckets, v)] += 1
+            total += v
+        self._sum = total
+
     @property
     def count(self) -> int:
         return sum(self._counts)
@@ -235,6 +243,14 @@ class Summary(_Metric):
             self._count += 1
             self._sum += v
             self._window.append(v)
+
+    def observe_many(self, vs: Sequence[float]) -> None:
+        """observe() of each, in order, under one hold of the lock."""
+        with self._lock:
+            self._count += len(vs)
+            for v in vs:
+                self._sum += v
+            self._window.extend(vs)
 
     def samples(self):
         with self._lock:

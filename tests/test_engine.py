@@ -1106,7 +1106,8 @@ def test_sink_submitted_writes_are_released_only_behind_wait_durable(
     assert woke == [1]
     got = dict(sink.drain())
     assert set(got) == {t.rid for t in toks}
-    vals = [eng.settle(t, got[t.rid]).node.value for t in toks]
+    vals = [e.node.value
+            for e in eng.settle(toks, [got[t.rid] for t in toks])]
     assert vals == ["1", "2", "3"]
     assert metrics.propose_pending.value == pending0
     eng.stop()
@@ -1195,7 +1196,8 @@ def test_submit_pairs_refuses_a_bad_pair_alone(tmp_path):
     got = {}
     run_until(eng, lambda: got.update(sink.drain()) or len(got) == 2,
               msg="acks of the staged pairs")
-    assert [eng.settle(t, got[t.rid]).node.value for t in good] == ["1", "3"]
+    assert [e.node.value for e in eng.settle(
+        good, [got[t.rid] for t in good])] == ["1", "3"]
     assert metrics.propose_pending.value == pending0
     with pytest.raises(errors.EtcdError):
         eng.store(2).get("/a", False, False)    # tenant 2 got neither

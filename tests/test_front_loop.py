@@ -12,6 +12,7 @@ tests/test_engine.py and tests/test_read_plane.py.
 """
 import itertools
 import json
+import os
 import socket
 import threading
 import time
@@ -47,6 +48,7 @@ class StubServer:
         self.submits = []           # items per submit call
         self.expired = []
         self._ids = itertools.count(1)
+        self.new_id = itertools.count(1).__next__
 
     def submit_item(self, r):
         return r
@@ -94,8 +96,8 @@ class StubServer:
         self.wait.trigger_many([(rid, self._apply(r))
                                 for rid, r in pending])
 
-    def settle(self, tok, value):
-        return value
+    def settle(self, toks, values):
+        return values
 
     def expire(self, tok):
         self.wait.cancel(tok.rid)
@@ -771,3 +773,634 @@ def test_a_busy_connection_of_the_thread_path_keeps_its_worker(front):
     assert _read_reply(s)[0] == 200
     s.close()
     assert _wait_for(lambda: not http._lent and not http._conns)
+
+
+# -- the same answers as the parent's front, on both paths ---------------------
+#
+# One table of request shapes, each sent as raw bytes to a fresh, seeded,
+# deterministic server twice: on the loop path (the route has `begin`; the
+# request is parsed by begin_keys and staged through submit_pairs) and on
+# the thread path (handle_keys on a worker). What came back (status line,
+# every header but Date in wire order, body bytes) and what the server was
+# handed (the Request staged or done, and its encode() bytes: the WAL
+# payload) must equal tests/front_loop_table.json, recorded from the parent
+# commit of PR 30 with this same code (`python tests/test_front_loop.py
+# --record` writes the file from whatever tree it runs in).
+
+_TABLE_PATH = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                           "front_loop_table.json")
+_NOW = 4102444800.0             # 2100-01-01T00:00:00Z: no TTL runs out
+_FORM = "Content-Type: application/x-www-form-urlencoded\r\n"
+
+
+class TableServer:
+    """What ClientAPI drives, the same at every run: a Python Store on a
+    fixed clock with a few seeded keys, request ids from 1, and a record
+    of every Request it is handed (staged by the loop, or done)."""
+
+    def __init__(self, loop: bool):
+        from etcd_tpu.store import Store
+        self.store = Store(clock=lambda: _NOW, namespaces=("/0", "/1"))
+        self.store.set("/1/seed/a", value="1")
+        self.store.set("/1/seed/b", value="two words")
+        self.store.set("/1/seed/dir/c", value="3")
+        self.store.set("/1/seed/ttl", value="t", expire_time=_NOW + 100)
+        self.cluster = SimpleNamespace(cluster_id=0x2a)
+        self.clock = lambda: _NOW
+        self.stopped = False
+        self.commit_index = 12
+        self.term = 3
+        self.request_timeout = 5.0
+        self.submitter = self if loop else None
+        self.wait = Wait()
+        self.seen = []
+        self._ids = itertools.count(1)
+
+    def new_id(self):
+        return next(self._ids)
+
+    def submit_item(self, r):
+        return r
+
+    def _apply(self, r: Request):
+        """server/engine.py _apply_request's mapping, on this store."""
+        st, exp = self.store, r.expiration
+        if r.method == "GET":
+            return st.get(r.path, r.recursive, r.sorted)
+        if r.method == "POST":
+            return st.create(r.path, is_dir=r.dir, value=r.val, unique=True,
+                             expire_time=exp)
+        if r.method == "DELETE":
+            if r.prev_index or r.prev_value:
+                return st.compare_and_delete(r.path, r.prev_value,
+                                             r.prev_index)
+            return st.delete(r.path, is_dir=r.dir, recursive=r.recursive)
+        if r.refresh:
+            return st.update(r.path, None, exp, refresh=True)
+        if r.prev_exist:
+            if r.prev_index or r.prev_value:
+                return st.compare_and_swap(r.path, r.prev_value,
+                                           r.prev_index, r.val, exp)
+            return st.update(r.path, r.val, exp)
+        if r.prev_exist is not None:
+            return st.create(r.path, is_dir=r.dir, value=r.val,
+                             expire_time=exp)
+        if r.prev_index or r.prev_value:
+            return st.compare_and_swap(r.path, r.prev_value, r.prev_index,
+                                       r.val, exp)
+        return st.set(r.path, is_dir=r.dir, value=r.val, expire_time=exp)
+
+    def do(self, r: Request):
+        self.seen.append(r)
+        if r.method == "GET" and r.wait:
+            return self.store.watch(r.path, r.recursive, r.stream, r.since)
+        return self._apply(r)
+
+    def submit_pairs(self, items, sink):
+        done, tokens = [], []
+        for r in items:
+            if r.id == 0:           # as MultiEngine.submit_pairs does
+                r = Request(**{**r.__dict__, "id": self.new_id()})
+            self.seen.append(r)
+            self.wait.register(r.id, sink)
+            tokens.append(SimpleNamespace(rid=r.id))
+            try:
+                done.append((r.id, self._apply(r)))
+            except errors.EtcdError as e:
+                done.append((r.id, e))
+        self.wait.trigger_many(done)
+        return tokens
+
+    def settle(self, toks, values):
+        return values
+
+    def expire(self, tok):
+        self.wait.cancel(tok.rid)
+        return errors.EtcdError(errors.ECODE_RAFT_INTERNAL,
+                                cause="request timed out")
+
+
+def _req(line, headers="", body=""):
+    """Raw request bytes; a body gets its Content-Length."""
+    if isinstance(body, str):
+        body = body.encode("iso-8859-1")
+    if body:
+        headers += f"Content-Length: {len(body)}\r\n"
+    return (f"{line}\r\n{headers}\r\n").encode("iso-8859-1") + body
+
+
+def _form(line, body, headers=""):
+    return _req(line, headers + _FORM, body)
+
+
+_K = "/v2/keys"
+# name -> request bytes, or (head, body) sent in two parts around the
+# server's `100 Continue`
+_SHAPES = {
+    "put-form": _form(f"PUT {_K}/a/b HTTP/1.1", "value=v1"),
+    "put-query-value": _req(f"PUT {_K}/q?value=fromquery HTTP/1.1"),
+    "put-body-over-query": _form(
+        f"PUT {_K}/bq?value=fromquery&ttl=7 HTTP/1.1", "value=frombody"),
+    "put-percent-key-and-value": _form(
+        f"PUT {_K}/p%20q%2Fr/%C3%A9 HTTP/1.1", "value=a%26b%3Dc%25+%C3%A9"),
+    "put-plus": _form(f"PUT {_K}/plus+key HTTP/1.1", "value=a+b++c"),
+    "put-repeated-parameter": _form(
+        f"PUT {_K}/rep?value=q1&value=q2 HTTP/1.1", "value=b1&value=b2"),
+    "put-blank-value": _form(f"PUT {_K}/blank HTTP/1.1", "value="),
+    "put-blank-flags": _form(
+        f"PUT {_K}/bf?dir=&recursive&sorted= HTTP/1.1", "value=x&&=y&"),
+    "put-semicolon-is-no-separator": _form(
+        f"PUT {_K}/semi HTTP/1.1", "value=a;ttl=5"),
+    "put-non-utf8": _form(f"PUT {_K}/nu HTTP/1.1",
+                          b"value=%ff%fe\xff\xfe&x=\xe9"),
+    "put-double-slash-target": _form(f"PUT //v2/keys//ds///k HTTP/1.1",
+                                     "value=ds"),
+    "put-fragment": _form(f"PUT {_K}/frag?value=q#f?value=no HTTP/1.1", ""),
+    "get-absolute-form": _req(
+        f"GET http://example.com:2379{_K}/seed/a?quorum=true HTTP/1.1"),
+    "put-dotdot-escapes": _form(f"PUT {_K}/a/../../0/x HTTP/1.1",
+                                "value=no"),
+    "put-dotdot-inside": _form(f"PUT {_K}/a/./b/../c HTTP/1.1", "value=in"),
+    "put-root": _form(f"PUT {_K} HTTP/1.1", "value=root"),
+    "head-quorum": _req(f"HEAD {_K}/seed/a?quorum=true HTTP/1.1"),
+    "head-local": _req(f"HEAD {_K}/seed/a HTTP/1.1"),
+    "get-local": _req(f"GET {_K}/seed/a HTTP/1.1"),
+    "get-quorum": _req(f"GET {_K}/seed/a?quorum=true HTTP/1.1"),
+    "get-quorum-false": _req(f"GET {_K}/seed/b?quorum=false HTTP/1.1"),
+    "get-ttl-node": _req(f"GET {_K}/seed/ttl?quorum=true HTTP/1.1"),
+    "get-recursive-sorted": _req(
+        f"GET {_K}/seed?recursive=true&sorted=true HTTP/1.1"),
+    "get-quorum-recursive": _req(
+        f"GET {_K}/seed?recursive=true&quorum=true&sorted=true HTTP/1.1"),
+    "get-keys-root": _req(f"GET {_K}/?quorum=true HTTP/1.1"),
+    "get-missing": _req(f"GET {_K}/nope HTTP/1.1"),
+    "get-quorum-missing": _req(f"GET {_K}/nope/deeper?quorum=true HTTP/1.1"),
+    "post-in-order": _form(f"POST {_K}/queue HTTP/1.1", "value=job1"),
+    "post-in-order-ttl": _form(f"POST {_K}/queue HTTP/1.1",
+                               "value=job2&ttl=9"),
+    "put-prevExist-false-new": _form(
+        f"PUT {_K}/new?prevExist=false HTTP/1.1", "value=n"),
+    "put-prevExist-false-there": _form(
+        f"PUT {_K}/seed/a?prevExist=false HTTP/1.1", "value=n"),
+    "put-prevExist-true": _form(f"PUT {_K}/seed/a HTTP/1.1",
+                                "value=u&prevExist=true"),
+    "put-prevExist-true-missing": _form(
+        f"PUT {_K}/nope?prevExist=true HTTP/1.1", "value=u"),
+    "put-prevExist-bad": _form(f"PUT {_K}/seed/a?prevExist=maybe HTTP/1.1",
+                               "value=u"),
+    "put-prevIndex": _form(f"PUT {_K}/seed/a?prevIndex=1 HTTP/1.1",
+                           "value=cas"),
+    "put-prevIndex-mismatch": _form(
+        f"PUT {_K}/seed/a?prevIndex=99 HTTP/1.1", "value=cas"),
+    "put-prevIndex-nan": _form(f"PUT {_K}/seed/a?prevIndex=abc HTTP/1.1",
+                               "value=cas"),
+    "put-prevIndex-negative": _form(
+        f"PUT {_K}/seed/a?prevIndex=-1 HTTP/1.1", "value=cas"),
+    "put-prevValue": _form(f"PUT {_K}/seed/b HTTP/1.1",
+                           "value=cas&prevValue=two+words"),
+    "put-prevValue-mismatch": _form(
+        f"PUT {_K}/seed/b?prevValue=three HTTP/1.1", "value=cas"),
+    "put-prevValue-empty": _form(f"PUT {_K}/seed/b?prevValue= HTTP/1.1",
+                                 "value=cas"),
+    "put-prevValue-and-index": _form(
+        f"PUT {_K}/seed/a?prevValue=1&prevIndex=1&prevExist=true HTTP/1.1",
+        "value=both"),
+    "put-ttl": _form(f"PUT {_K}/t HTTP/1.1", "value=v&ttl=30"),
+    "put-ttl-zero": _form(f"PUT {_K}/t0 HTTP/1.1", "value=v&ttl=0"),
+    "put-ttl-nan": _form(f"PUT {_K}/t HTTP/1.1", "value=v&ttl=soon"),
+    "put-ttl-negative": _form(f"PUT {_K}/t HTTP/1.1", "value=v&ttl=-5"),
+    "put-ttl-blank": _form(f"PUT {_K}/tb HTTP/1.1", "value=v&ttl="),
+    "put-refresh": _form(f"PUT {_K}/seed/ttl HTTP/1.1",
+                         "refresh=true&ttl=60&prevExist=true"),
+    "put-refresh-with-value": _form(f"PUT {_K}/seed/ttl HTTP/1.1",
+                                    "refresh=true&ttl=60&value=x"),
+    "put-refresh-with-blank-value": _form(f"PUT {_K}/seed/ttl HTTP/1.1",
+                                          "refresh=true&ttl=60&value="),
+    "put-refresh-no-ttl": _form(f"PUT {_K}/seed/ttl HTTP/1.1",
+                                "refresh=true"),
+    "put-dir": _form(f"PUT {_K}/d1?dir=true HTTP/1.1", ""),
+    "put-dir-ttl": _form(f"PUT {_K}/d2 HTTP/1.1", "dir=true&ttl=20"),
+    "put-over-dir": _form(f"PUT {_K}/seed/dir HTTP/1.1", "value=file"),
+    "put-noValueOnSuccess": _form(
+        f"PUT {_K}/seed/a?noValueOnSuccess=true HTTP/1.1", "value=quiet"),
+    "put-noValueOnSuccess-false": _form(
+        f"PUT {_K}/seed/a?noValueOnSuccess=false HTTP/1.1", "value=loud"),
+    "put-noValueOnSuccess-bad": _form(
+        f"PUT {_K}/seed/a?noValueOnSuccess=yes HTTP/1.1", "value=x"),
+    "get-noValueOnSuccess": _req(
+        f"GET {_K}/seed/a?noValueOnSuccess=true&quorum=true HTTP/1.1"),
+    "put-bad-bool": _form(f"PUT {_K}/x?recursive=1 HTTP/1.1", "value=x"),
+    "put-bad-bool-order": _form(
+        f"PUT {_K}/x?dir=T&quorum=nope&prevExist=maybe HTTP/1.1", "value=x"),
+    "put-every-flag-false": _form(
+        f"PUT {_K}/ff?recursive=false&sorted=false&quorum=false&wait=false"
+        "&stream=false&dir=false&refresh=false HTTP/1.1", "value=ff"),
+    "delete": _req(f"DELETE {_K}/seed/a HTTP/1.1"),
+    "delete-missing": _req(f"DELETE {_K}/nope HTTP/1.1"),
+    "delete-dir-as-file": _req(f"DELETE {_K}/seed HTTP/1.1"),
+    "delete-dir": _req(f"DELETE {_K}/seed/dir?dir=true HTTP/1.1"),
+    "delete-recursive": _req(f"DELETE {_K}/seed?recursive=true HTTP/1.1"),
+    "delete-prevValue": _req(f"DELETE {_K}/seed/a?prevValue=1 HTTP/1.1"),
+    "delete-prevValue-mismatch": _req(
+        f"DELETE {_K}/seed/a?prevValue=2 HTTP/1.1"),
+    "delete-prevIndex": _req(f"DELETE {_K}/seed/b?prevIndex=2 HTTP/1.1"),
+    "delete-with-body": _form(f"DELETE {_K}/seed/b HTTP/1.1",
+                              "prevValue=two+words"),
+    "get-wait-and-quorum": _req(
+        f"GET {_K}/seed/a?wait=true&quorum=true HTTP/1.1"),
+    "get-stream-without-wait": _req(f"GET {_K}/seed/a?stream=true HTTP/1.1"),
+    "get-waitIndex-nan": _req(
+        f"GET {_K}/seed/a?waitIndex=x&quorum=true HTTP/1.1"),
+    "get-waitIndex": _req(f"GET {_K}/seed/a?waitIndex=4&quorum=true HTTP/1.1"),
+    "patch-is-405": _form(f"PATCH {_K}/seed/a HTTP/1.1", "value=x"),
+    "not-a-route": _req("GET /v2/nothing HTTP/1.1"),
+    "connection-close": _form(f"PUT {_K}/cc HTTP/1.1", "value=bye",
+                              "Connection: close\r\n"),
+    "connection-close-error": _req(f"GET {_K}/nope?quorum=true HTTP/1.1",
+                                   "Connection: Close\r\n"),
+    "http-1.0": _req(f"GET {_K}/seed/a?quorum=true HTTP/1.0"),
+    "http-1.0-keep-alive": _form(f"PUT {_K}/ka HTTP/1.0", "value=ka",
+                                 "Connection: Keep-Alive\r\n"),
+    "folded-header": _form(f"PUT {_K}/fold HTTP/1.1", "value=folded",
+                           "X-Fold: a\r\n\tb\r\n  c\r\n"),
+    "folded-content-type": _req(
+        f"PUT {_K}/foldct HTTP/1.1",
+        "Content-Type:\r\n application/x-www-form-urlencoded\r\n",
+        "value=unseen"),
+    "duplicate-headers": _req(
+        f"PUT {_K}/dup HTTP/1.1",
+        "Content-Type: text/plain\r\n" + _FORM
+        + "Content-Length: 7\r\nContent-Length: 99\r\n"
+        + "Connection: close\r\nConnection: keep-alive\r\n") + b"value=d",
+    "header-case-and-parameters": _req(
+        f"PUT {_K}/case HTTP/1.1",
+        "content-type: application/x-www-form-urlencoded; charset=utf-8\r\n"
+        "CONTENT-LENGTH: 10\r\nHOST: t\r\n") + b"value=case",
+    "body-without-content-type": _req(f"PUT {_K}/noct HTTP/1.1", "",
+                                      "value=unseen"),
+    "bare-lf": f"PUT {_K}/lf?value=lf HTTP/1.1\nHost: t\n\n".encode(),
+    "expect-100-continue": (
+        (f"PUT {_K}/e HTTP/1.1\r\n{_FORM}Expect: 100-Continue\r\n"
+         "Content-Length: 9\r\n\r\n").encode(), b"value=big"),
+    "expect-on-http-1.0": _form(f"PUT {_K}/e10 HTTP/1.0", "value=no100",
+                                "Expect: 100-continue\r\n"),
+    "400-syntax": b"GARBAGE\r\n\r\n",
+    "400-four-words": f"GET {_K}/a b HTTP/1.1\r\n\r\n".encode(),
+    "400-version": f"GET {_K}/a HTTP/x.y\r\n\r\n".encode(),
+    "400-version-no-http": f"GET {_K}/a FTP/1.1\r\n\r\n".encode(),
+    "400-version-one-part": f"GET {_K}/a HTTP/1\r\n\r\n".encode(),
+    "505": f"GET {_K}/a HTTP/2.0\r\n\r\n".encode(),
+    "http-1.2-is-1.1": _req(f"GET {_K}/seed/a?quorum=true HTTP/1.2"),
+    "431-count": (f"GET {_K}/a HTTP/1.1\r\n".encode()
+                  + b"".join(b"X-H%d: v\r\n" % i for i in range(101))
+                  + b"\r\n"),
+    "100-headers-pass": (f"GET {_K}/seed/a?quorum=true HTTP/1.1\r\n".encode()
+                         + b"".join(b"X-H%d: v\r\n" % i for i in range(100))
+                         + b"\r\n"),
+    "431-line": (f"GET {_K}/a HTTP/1.1\r\nX-Long: ".encode()
+                 + b"v" * 70000 + b"\r\n\r\n"),
+    "414": b"GET /" + b"a" * 70000 + b" HTTP/1.1\r\n\r\n",
+    "400-header-line": f"GET {_K}/a HTTP/1.1\r\nNoColonHere\r\n\r\n".encode(),
+    "400-header-no-name": f"GET {_K}/a HTTP/1.1\r\n: v\r\n\r\n".encode(),
+    "400-fold-before-any-header": (
+        f"GET {_K}/a HTTP/1.1\r\n folded\r\n\r\n".encode()),
+    "501-transfer-encoding": (
+        f"PUT {_K}/te HTTP/1.1\r\nTransfer-Encoding: chunked\r\n\r\n"
+        "0\r\n\r\n").encode(),
+    "400-length-nan": (
+        f"GET {_K}/a HTTP/1.1\r\nContent-Length: nope\r\n\r\n").encode(),
+    "400-length-negative": (
+        f"GET {_K}/a HTTP/1.1\r\nContent-Length: -1\r\n\r\n").encode(),
+    "blank-content-length": _req(f"GET {_K}/seed/a?quorum=true HTTP/1.1",
+                                 "Content-Length:\r\n"),
+}
+
+
+def _observe(name: str, loop: bool) -> dict:
+    """Send one shape to a fresh TableServer on one path; what came back
+    and what the server saw, as JSON holds it."""
+    srv = TableServer(loop)
+    router = Router()
+    api = ClientAPI(srv)
+    router.add(_K, api.handle_keys, begin=api.begin_keys if loop else None)
+    http = HttpServer("127.0.0.1", 0, router)
+    http.start()
+    try:
+        s = _connect(http)
+        shape = _SHAPES[name]
+        raw = b""
+        if isinstance(shape, tuple):
+            s.sendall(shape[0])
+            while not raw.endswith(b"\r\n\r\n"):
+                raw += s.recv(65536)
+            shape = shape[1]
+        s.sendall(shape)
+        s.settimeout(10.0)
+        # whole replies: a head, and the body its Content-Length names
+        # (none after a HEAD)
+        no_body = shape.startswith(b"HEAD ")
+        while True:
+            head, sep, rest = raw.partition(b"\r\n\r\n")
+            if sep and head.startswith(b"HTTP/1.1 100"):
+                head, sep, rest = rest.partition(b"\r\n\r\n")
+            if sep:
+                m = [ln for ln in head.split(b"\r\n")
+                     if ln.lower().startswith(b"content-length:")]
+                if len(rest) >= (0 if no_body or not m
+                                 else int(m[0].split(b":")[1])):
+                    break
+            data = s.recv(65536)
+            if not data:
+                break
+            raw += data
+        closed = b"Connection: close" in raw and _closed(s)
+        s.close()
+    finally:
+        http.stop()
+    lines = raw.decode("iso-8859-1").split("\r\n")
+    return {
+        "reply": [ln for ln in lines if not ln.startswith("Date: ")],
+        "closed": bool(closed),
+        "requests": [dict(r.__dict__) for r in srv.seen],
+        "encoded": [r.encode().decode() for r in srv.seen],
+    }
+
+
+def _table():
+    with open(_TABLE_PATH) as f:
+        return json.load(f)
+
+
+@pytest.mark.parametrize("name", sorted(_SHAPES))
+def test_same_reply_and_same_staged_request_as_the_parent(name):
+    want = _table()[name]
+    got = {"loop": _observe(name, True), "thread": _observe(name, False)}
+    for path in ("loop", "thread"):
+        assert got[path]["reply"] == want[path]["reply"], path
+        assert got[path]["closed"] == want[path]["closed"], path
+        assert got[path]["encoded"] == want[path]["encoded"], path
+        assert ([Request(**d) for d in got[path]["requests"]]
+                == [Request(**d) for d in want[path]["requests"]]), path
+    # and the two paths answer alike, as they did at the parent
+    assert got["loop"]["reply"] == got["thread"]["reply"]
+    assert ([dict(d, id=0) for d in got["loop"]["requests"]]
+            == got["thread"]["requests"])
+
+
+# -- the parsers against their references, on generated input -----------------
+
+def _reference_parse_head(raw: bytes):
+    """etcdhttp/web.py _parse_head as the parent commit of PR 30 had it
+    (every header split into a list and a dict for every request), kept
+    as the reference: (method, target, items, length, keep_alive,
+    expect), or the (status, message) it refused with."""
+    from etcd_tpu.etcdhttp.web import _MAX_HEADERS, _MAX_LINE
+    lines = raw.decode("iso-8859-1").split("\n")
+    first = lines[0]
+    if len(first) > _MAX_LINE:
+        return 414, "Request-URI Too Long"
+    words = first.split()
+    if len(words) != 3:
+        return 400, f"Bad request syntax ({first[:64]!r})"
+    method, target, version = words
+    if version == "HTTP/1.1":
+        vers = (1, 1)
+    else:
+        try:
+            if not version.startswith("HTTP/"):
+                raise ValueError
+            major, minor = version[5:].split(".")
+            vers = (int(major), int(minor))
+        except ValueError:
+            return 400, f"Bad request version ({version[:32]!r})"
+        if vers >= (2, 0):
+            return 505, f"Invalid HTTP version ({version[5:]})"
+    if len(lines) - 1 > _MAX_HEADERS:
+        return 431, "Too many headers"
+    items = []
+    for text in lines[1:]:
+        if len(text) > _MAX_LINE:
+            return 431, "Line too long"
+        if text[:1] in (" ", "\t") and items:
+            items[-1] = (items[-1][0], items[-1][1] + " " + text.strip())
+            continue
+        name, sep, value = text.partition(":")
+        if not sep or not name:
+            return 400, "Bad header line"
+        items.append((name, value.strip()))
+    first_of = {}
+    for k, v in items:
+        first_of.setdefault(k.lower(), v)
+    get = first_of.get
+    if get("transfer-encoding") is not None:
+        return 501, "Transfer-Encoding is not supported"
+    try:
+        length = int(get("content-length") or 0)
+        if length < 0:
+            raise ValueError
+    except ValueError:
+        return 400, "Bad Content-Length"
+    conn_hdr = get("connection")
+    if vers >= (1, 1):
+        keep_alive = conn_hdr is None or "close" not in conn_hdr.lower()
+    else:
+        keep_alive = conn_hdr is not None and \
+            "keep-alive" in conn_hdr.lower()
+    expect = (vers >= (1, 1) and
+              (get("expect") or "").lower() == "100-continue")
+    return method, target, items, length, keep_alive, expect
+
+
+_HEAD_PARTS = [
+    "Host: t", "host:t", "Content-Length: 5", "content-length:  7 ",
+    "Content-Length: -1", "Content-Length: x", "Content-Length:",
+    "CONTENT-LENGTH: 3", "Connection: close", "Connection: Keep-Alive",
+    "connection: x, CLOSE", "Connection:", "Expect: 100-continue",
+    "Expect: 100-Continue", "expect: nope", "Transfer-Encoding: chunked",
+    "transfer-encoding:", "X-A: b", "X-A: c", " folded", "\tfolded too",
+    " close", " 100-continue", "NoColon", ": noname", "", " ", ":", "a:",
+    "X-expect-connection: content-length transfer-encoding",
+    "Content-Length : 9", "Content-Type: application/x-www-form-urlencoded",
+]
+_LINES = ["GET /v2/keys/a HTTP/1.1", "PUT /v2/keys/content-length HTTP/1.0",
+          "GET /expect?connection=close HTTP/1.1", "HEAD / HTTP/1.2",
+          "GET / HTTP/0.9", "GET / HTTP/2.0", "GET / HTTP/1", "GET / http/1.1",
+          "GET  /two-spaces  HTTP/1.1", "GET / HTTP/1.1 extra", "GET /",
+          "POST /a\xa0b HTTP/1.1", ""]
+
+
+@pytest.mark.parametrize("seed", range(12))
+def test_generated_heads_parse_as_the_reference_parser(seed):
+    import random
+
+    from etcd_tpu.etcdhttp.web import _BadRequest, _parse_head
+    rng = random.Random(seed)
+    for _ in range(400):
+        head = [rng.choice(_LINES)] + [
+            rng.choice(_HEAD_PARTS) for _ in range(rng.randrange(0, 7))]
+        raw = rng.choice(["\r\n", "\n"]).join(head).encode("iso-8859-1")
+        want = _reference_parse_head(raw)
+        try:
+            h, expect = _parse_head(raw, 1.5)
+        except _BadRequest as e:
+            assert (e.status, e.message) == want, raw
+            continue
+        assert (h.method, h.target, h.headers.items(), h.length,
+                h.keep_alive, expect) == want, raw
+        assert h.t_in == 1.5
+        for name, value in want[2]:
+            first = [v for k, v in want[2] if k.lower() == name.lower()][0]
+            assert h.headers.get(name.upper()) == first
+        assert h.headers.get("X-Absent") is None
+        assert h.headers.get("X-Absent", "d") == "d"
+
+
+@pytest.mark.parametrize("seed", range(12))
+def test_generated_parameters_parse_as_parse_qs(seed):
+    import random
+    from urllib.parse import parse_qs
+
+    from etcd_tpu.etcdhttp.web import Ctx, _add_params, _Head, Headers
+    rng = random.Random(seed)
+    alphabet = ["&", "=", "+", "%", ";", "#", "?", "/", " ", "a", "b", "value",
+                "ttl", "%20", "%2", "%zz", "%C3%A9", "%ff", "\xe9", "\u20ac",
+                "true", "1", "&&", "=="]
+
+    def some():
+        return "".join(rng.choice(alphabet)
+                       for _ in range(rng.randrange(0, 9)))
+    for _ in range(400):
+        qs, form = some(), some()
+        got = {}
+        _add_params(qs, got)
+        assert got == parse_qs(qs, keep_blank_values=True), qs
+        # a request's values: the form's first, then the query's, as the
+        # parent's Ctx merged two parse_qs results
+        want = parse_qs(qs.partition("#")[0], keep_blank_values=True)
+        for k, v in parse_qs(form.encode().decode("utf-8", "replace"),
+                             keep_blank_values=True).items():
+            want[k] = v + want.get(k, [])
+        headers = Headers(
+            [("Content-Type", "application/x-www-form-urlencoded")])
+        ctx = Ctx(None, _Head("PUT", "/p?" + qs, headers, 0, True, 0.0),
+                  form.encode())
+        assert ctx.path == "/p"
+        assert ctx.params() == want, (qs, form)
+        for k in list(want) + ["absent"]:
+            assert ctx.has(k) == (k in want)
+            assert ctx.value(k, "d") == (want[k][0] if k in want else "d")
+
+
+@pytest.mark.parametrize("seed", range(6))
+def test_a_leaf_node_is_written_as_json_dumps_writes_it(seed):
+    """client._leaf_json against json.dumps(trim_prefix(to_dict())), which
+    stays the code of every node that lists children."""
+    import random
+
+    from etcd_tpu.etcdhttp.client import _leaf_json, trim_prefix
+    from etcd_tpu.store.event import Event, NodeExtern
+    rng = random.Random(seed)
+    texts = ["", "/", "x", "/1", "/1/", "/1/a b", "/10/a", "/2/security",
+             'q"uo\\te', "tab\tnl\ncr\r\x00\x1f\x7f", "\u00e9\u20ac\U0001f600",
+             "\ud800", "</script>", "ab" * 256]
+    for _ in range(300):
+        n = NodeExtern(
+            key=rng.choice(["/1", "/1/", ""]) + rng.choice(texts),
+            value=rng.choice([None] + texts), dir=rng.random() < 0.3,
+            created_index=rng.randrange(0, 1 << 62),
+            modified_index=rng.randrange(0, 1 << 62),
+            expiration=rng.choice([None, 0.0, 1.5, _NOW + rng.random()]),
+            ttl=rng.randrange(0, 99999))
+        want = json.dumps(trim_prefix(Event("get", node=n).to_dict()))
+        assert '{"action": "get", "node": ' + _leaf_json(n) + "}" == want
+
+
+def test_head_starts_are_whole_under_many_threads():
+    """web._head_start's table is shared by the loop and every worker
+    thread: whoever formats a second's lines, each caller gets whole lines
+    for its own status and a Date no more than a second off."""
+    import sys
+    from email.utils import parsedate_to_datetime
+
+    from etcd_tpu.etcdhttp.web import _head_start
+    bad, stop = [], time.time() + 1.5
+
+    def hammer(status):
+        while time.time() < stop and not bad:
+            before = int(time.time())
+            lines = _head_start(status, "t", "text/x").split(b"\r\n")
+            when = parsedate_to_datetime(lines[2][6:].decode()).timestamp()
+            if (len(lines) != 5 or not lines[0].startswith(
+                    b"HTTP/1.1 %d " % status) or lines[3:] != [
+                    b"Content-Type: text/x", b""]
+                    or not before - 1 <= when <= time.time() + 1):
+                bad.append(lines)
+
+    was = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(target=hammer, args=(200 + i % 5,))
+                   for i in range(16)]
+        for th in threads:
+            th.start()
+        for th in threads:
+            th.join(timeout=20)
+    finally:
+        sys.setswitchinterval(was)
+    assert not any(th.is_alive() for th in threads) and not bad, bad[:1]
+
+
+@pytest.mark.parametrize("kind", ["histogram", "summary"])
+def test_a_pass_observed_in_one_call_scrapes_as_one_call_each(kind):
+    """observe_many (the loop's pass, settle's wake) leaves what observe()
+    of each value in turn leaves: same cells, same count, same sum to the
+    bit, same window."""
+    import random
+
+    from etcd_tpu.utils import metrics
+    rng = random.Random(7)
+    vs = [rng.choice([0.0, 1e-4, 0.005, 12.0, rng.random(),
+                      rng.expovariate(50.0)]) for _ in range(3000)]
+    reg = metrics.Registry()
+    make = (metrics.Histogram if kind == "histogram" else
+            lambda n, h, registry: metrics.Summary(n, h, window=64,
+                                                   registry=registry))
+    one, many = make("t_one", "h", registry=reg), make("t_many", "h",
+                                                       registry=reg)
+    for v in vs:
+        one.observe(v)
+    for i in range(0, len(vs), 127):
+        many.observe_many(vs[i:i + 127])
+    many.observe_many([])
+    assert ([(lbl, v) for _, lbl, v in one.samples()]
+            == [(lbl, v) for _, lbl, v in many.samples()])
+
+
+def test_request_init_names_every_field_with_its_default():
+    """server/request.py writes Request.__init__ by hand (one dict update
+    instead of a setattr a field): it has to take every field of the
+    dataclass, in order, with the field's default, or encode() (which
+    reads __dict__) would lose the one that was forgotten."""
+    import dataclasses
+    import inspect
+    params = list(inspect.signature(Request.__init__).parameters.values())[1:]
+    fields = dataclasses.fields(Request)
+    assert [p.name for p in params] == [f.name for f in fields]
+    assert [p.default for p in params] == [f.default for f in fields]
+    r = Request(*range(len(fields)))
+    assert list(r.__dict__.values()) == list(range(len(fields)))
+    assert list(r.__dict__) == [f.name for f in fields]
+    with pytest.raises(dataclasses.FrozenInstanceError):
+        r.id = 1
+
+
+if __name__ == "__main__":
+    import sys
+    if sys.argv[1:] == ["--record"]:
+        table = {name: {"loop": _observe(name, True),
+                        "thread": _observe(name, False)}
+                 for name in sorted(_SHAPES)}
+        with open(_TABLE_PATH, "w") as f:
+            json.dump(table, f, indent=1, sort_keys=True)
+            f.write("\n")
+        print(f"{len(table)} shapes recorded in {_TABLE_PATH}")

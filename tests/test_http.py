@@ -69,14 +69,39 @@ def cluster(tmp_path_factory):
     for m in members:
         m.start()
     assert all(m.wait_leader(10) for m in members)
+    assert settled(members)
     yield members
     for m in members:
         m.stop()
 
 
+def settled(members, timeout=20.0):
+    """Every member names the same leader: after boot, and again after the
+    cluster lost one (at tick_ms=10 an election is 100 ms of silence away,
+    and six test workers on one machine give a thread that much)."""
+    deadline = time.time() + timeout
+    while time.time() < deadline:
+        leads = {m.server.leader_id for m in members}
+        if len(leads) == 1 and 0 not in leads:
+            return True
+        time.sleep(0.02)
+    return False
+
+
 def curl(cluster, method, path, body=None, headers=None, member=0):
+    """One request to one member, sent again across a leader change: a
+    proposal answered 301 ("no leader") was dropped, never logged, so the
+    client sends it again once there is a leader, as real etcd clients
+    do. Every other answer is returned as it came."""
     base = cluster[member].client_urls[0]
-    return req(method, base + path, body, headers)
+    deadline = time.time() + 30
+    while True:
+        st, hdrs, parsed = req(method, base + path, body, headers)
+        dropped = (st == 500 and isinstance(parsed, dict)
+                   and parsed.get("errorCode") == 301)
+        if not dropped or time.time() > deadline:
+            return st, hdrs, parsed
+        settled(cluster)
 
 
 class TestKeys:
@@ -238,8 +263,15 @@ class TestKeys:
                                form({"value": str(i)}), FORM_HDR, member=i)
             assert st in (200, 201)
         for i in range(3):
-            st, _, body = curl(cluster, "GET", f"/v2/keys/via{i}",
-                               member=(i + 1) % 3)
+            # a plain GET is served from the member's own store, which a
+            # follower applies to a moment after the leader acknowledged
+            deadline = time.time() + 10
+            while True:
+                st, _, body = curl(cluster, "GET", f"/v2/keys/via{i}",
+                                   member=(i + 1) % 3)
+                if st == 200 or time.time() > deadline:
+                    break
+                time.sleep(0.02)
             assert body["node"]["value"] == str(i)
 
 

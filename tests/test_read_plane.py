@@ -335,7 +335,6 @@ def test_read_parked_by_submit_pairs_after_the_snapshot_waits_its_own_round(
     assert order == [early.rid, late[0].rid]
     assert eng._reads_waiting == 0 and eng._ripe_waiting == 0
     # settle closes the read's accounts as a woken _quorum_read does
-    for tok in (early, late[0]):
-        eng.settle(tok, None)
+    eng.settle([early, late[0]], [None, None])
     assert eng.obs.g_read_parked.value == parked0
     eng.stop()

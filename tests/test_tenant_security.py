@@ -141,6 +141,89 @@ def test_tenant_stats(cluster):
     assert st == 200 and "followers" in body
 
 
+def test_the_auth_gate_is_asked_at_every_request(cluster, monkeypatch):
+    """The front's loop serves a tenant's keys requests only while the
+    tenant's own replicated keyspace says auth is off, and asks it at
+    every request (etcdhttp/client.py begin_keys): on ONE keep-alive
+    connection the request after `enable` is already on the thread path
+    and refused without credentials, the one after `disable` is back on
+    the loop, and a store that cannot answer denies."""
+    import socket
+    from urllib.parse import urlsplit
+
+    from etcd_tpu import errors
+    from etcd_tpu.server import obs
+    eng, base, _ = cluster
+    t2 = f"{base}/tenants/2"
+    u = urlsplit(base)
+    s = socket.create_connection((u.hostname, u.port), timeout=30)
+
+    def served():
+        return {lbl["path"]: v
+                for _, lbl, v in obs.http_front_served.samples()}
+
+    def put(value, path):
+        """One write on the connection: (status, body, the path it took)."""
+        before = served()
+        body = f"value={value}".encode()
+        s.sendall(b"PUT /tenants/2/v2/keys/gate HTTP/1.1\r\nHost: t\r\n"
+                  b"Content-Type: application/x-www-form-urlencoded\r\n"
+                  b"Content-Length: %d\r\n\r\n%b" % (len(body), body))
+        raw = b""
+        while b"\r\n\r\n" not in raw:
+            raw += s.recv(65536)
+        head, _, rest = raw.partition(b"\r\n\r\n")
+        n = int([ln for ln in head.split(b"\r\n")
+                 if ln.startswith(b"Content-Length:")][0].split(b":")[1])
+        while len(rest) < n:
+            rest += s.recv(65536)
+        deadline = time.time() + 5      # counted once the reply is out
+        while served().get(path, 0) == before.get(path, 0) \
+                and time.time() < deadline:
+            time.sleep(0.01)
+        took = [k for k, v in served().items() if v != before.get(k, 0)]
+        return int(head.split()[1]), json.loads(rest), took
+
+    def admin(method, path, body=None, headers=None):
+        """A security request on a connection of its own (thread path),
+        returned once it is counted: the front counts a request after
+        the reply is out, and put() must not see that count as its own."""
+        before = served().get("thread", 0)
+        st, _ = _req(method, t2 + path, body, headers)
+        deadline = time.time() + 5
+        while served().get("thread", 0) == before and time.time() < deadline:
+            time.sleep(0.01)
+        return st
+
+    st, body, took = put("open", "loop")
+    assert st == 201 and took == ["loop"]
+    assert admin("PUT", "/v2/security/users/root", json.dumps(
+        {"user": "root", "password": "rpw"}).encode(), JH) == 201
+    assert admin("PUT", "/v2/security/roles/guest", json.dumps(
+        {"role": "guest", "permissions": {
+            "kv": {"read": ["/*"], "write": []}}}).encode(), JH) == 201
+    assert admin("PUT", "/v2/security/enable") == 200
+    st, body, took = put("shut", "thread")
+    assert st == 401 and body["errorCode"] == 110 and took == ["thread"]
+    assert admin("DELETE", "/v2/security/enable",
+                 headers=_auth("root", "rpw")) == 200
+    st, body, took = put("open again", "loop")
+    assert st == 200 and took == ["loop"]
+    assert body["prevNode"]["value"] == "open"
+    # a store error that is not "absent" denies: it is not read as "off"
+    sec = eng.store(2)
+
+    def broken(path):
+        raise errors.EtcdError(errors.ECODE_RAFT_INTERNAL, cause="broken")
+    monkeypatch.setattr(sec, "value", broken)
+    st, body, took = put("never", "loop")
+    assert st == 500 and body["errorCode"] == 300 and took == ["loop"]
+    monkeypatch.undo()
+    st, body, _ = put("mended", "loop")
+    assert st == 200 and body["prevNode"]["value"] == "open again"
+    s.close()
+
+
 def test_tenant_auth_survives_restart(cluster, tmp_path):
     eng, base, data_dir = cluster
     # (uses the module cluster's data dir written by the matrix test)
