@@ -25,7 +25,7 @@ import urllib.request
 LIB = os.path.dirname(os.path.abspath(__file__))
 BENCH = os.path.dirname(LIB)
 ROOT = os.path.dirname(BENCH)
-NATIVE = ("walcodec", "storecore", "ingresscore")
+NATIVE = ("walcodec", "storecore", "ingresscore", "frontcore")
 TRACE_CTL_ENV = "ETCD_BENCH_TRACE_CTL"
 
 

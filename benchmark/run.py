@@ -19,8 +19,10 @@ for the CPU rehearsal only: it overrides the configuration's G, forces
 `correct: false` and a non-zero exit.
 
 The last line of standard output is the result: {"correct", "attempted",
-"failed", "metrics", "device"[, "breakdown"]}. Earlier lines are one JSON
-object each: phases, sample counts, and every number compared with its limit.
+"failed", "metrics", "device"[, "breakdown"], "compared"}; `compared` holds
+every number compared with its limit, and the last lines of standard error
+say the same. Earlier lines of standard output are one JSON object each:
+phases, sample counts, the numbers compared.
 """
 from __future__ import annotations
 
@@ -217,6 +219,18 @@ def read_layer_metrics(workload, mix, ctx) -> dict:
     return out
 
 
+def client_stats(ops: list, think: list, late: list, t1: float) -> dict:
+    """What the `client` reader reads: statistics of the generator's own
+    records (think and late sorted, seconds). A closed loop has no due time
+    and an open one no think time: those read nothing."""
+    return {
+        "think_p50_us": percentile(think, 0.5) * 1e6 if think else None,
+        "late_p99_ms": percentile(late, 0.99) * 1e3 if late else None,
+        # sent (or due) and unanswered when the window ended: in flight,
+        # waiting for a connection, or never answered
+        "backlog_end": sum(1 for o in ops if o[2] is None or o[2] > t1)}
+
+
 def module_patterns(workload: str, mix: dict) -> list:
     """The programs this cell's layer metrics time, each named by a pattern
     in the metric's own file."""
@@ -296,7 +310,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
         preloaded = gen.preload() if mix.get("preload") else 0
         preload_s = time.monotonic() - t
         t0 = time.monotonic() + 0.05
-        gen.run(t0, t0 + mix["warmup_seconds"], record=False)
+        gen.run(t0, t0 + mix["warmup_seconds"], record=False, run_id=1)
         round0 = m.status(30.0)["round"]
         emit(phase="setup", boot_s=boot_s, first_quorum_read=qr,
              preloaded=preloaded, preload_s=preload_s,
@@ -393,9 +407,9 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
         if r_ms:
             e2e["qread_p99_ms"] = {"value": percentile(r_ms, 0.99),
                                    "unit": "ms"}
-        think = sorted(rec["think"])
-        client = {"think_p50_us": percentile(think, 0.5) * 1e6
-                  if think else None}
+        think, late = sorted(rec["think"]), sorted(rec["late"])
+        is_open = mix["loop"] == "open"
+        client = client_stats(ops, think, late, t1)
         slices = [0] * max(1, math.ceil(seconds / 5.0))
         for o in ops:
             if o[3] and o[2] <= t1:
@@ -406,7 +420,13 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
              read_back=n_read, think_samples=len(think),
              think_p99_us=percentile(think, 0.99) * 1e6 if think else None,
              gen_max_loop_gap_ms=rec["max_loop_gap_s"] * 1e3,
-             ack_max_ms=all_ms[-1])
+             ack_max_ms=all_ms[-1],
+             **({"rate": mix["rate"],
+                 "backlog_end": client["backlog_end"],
+                 "pool_dry": rec["pool_dry"], "given_up": rec["given_up"],
+                 "late_p50_ms": percentile(late, 0.5) * 1e3 if late else None,
+                 "late_max_ms": late[-1] * 1e3 if late else None}
+                if is_open else {}))
         layers = read_layer_metrics(workload, mix, {
             "prom0": prom0, "prom1": prom1, "window_s": seconds,
             "client": client, "trace": {}})
@@ -437,6 +457,9 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
             result["breakdown"] = {"device_ops": red["device_ops"],
                                    "idle_gaps": red["idle_gaps"]}
             emit(phase="end_to_end_of_traced_run", metrics=e2e)
+        result["compared"] = {ln["check"]: {"value": ln["value"],
+                                            "limit": ln["limit"]}
+                              for ln in lines}
         return result
     except BaseException:
         tail = m.log_tail()
@@ -486,6 +509,10 @@ def main(argv=None) -> int:
         return 1
     if rehearsal or result["device"]["platform"] != "tpu":
         result["correct"] = False
+    for name, c in result["compared"].items():
+        print(f"compared {name}: {c['value']} (limit {c['limit']})",
+              file=sys.stderr)
+    sys.stderr.flush()
     print(json.dumps(result), flush=True)
     return 0 if result["correct"] and not rehearsal else 1
 

@@ -129,10 +129,25 @@ BASE = {"loop": "closed", "clients": 4, "gen_procs": 1, "write_share": 1.0,
 
 
 @pytest.mark.parametrize("change,word", [
-    ({"loop": "open"}, "closed"),
+    ({"loop": "open"}, "rate"),
+    ({"loop": "open", "arrival": "poisson"}, "rate"),
+    ({"loop": "open", "rate": 0, "arrival": "poisson"}, "rate"),
+    ({"loop": "open", "rate": 1000}, "arrival"),
+    ({"loop": "open", "rate": 1000, "arrival": "uniform"}, "arrival"),
+    ({"loop": "open", "rate": 1000, "arrival": "poisson",
+      "start_spread_ms": 160}, "start_spread_ms"),
     ({"rate": 1000}, "rate"),
+    ({"arrival": "poisson"}, "arrival"),
+    ({"loop": "paced"}, "loop"),
     ({"via": "ingress"}, "via"),
-    ({"tenant_dist": {"kind": "zipf", "s": 0.99}}, "uniform"),
+    ({"tenant_dist": {"kind": "zipf"}}, "theta"),
+    ({"tenant_dist": {"kind": "zipf", "theta": 0}}, "theta"),
+    ({"tenant_dist": {"kind": "zipf", "s": 0.99}}, "theta"),
+    ({"tenant_dist": {"kind": "zipf", "theta": 0.99, "shift_every_s": 10}},
+     "theta"),
+    ({"tenant_dist": {"kind": "uniform", "theta": 0.99}}, "uniform"),
+    ({"tenant_dist": {"kind": "hotspot"}}, "uniform"),
+    ({"window_phase": {"modulo": 2048, "offset": 64}}, "window_phase"),
     ({"write_share": 0.5}, "quorum"),
     ({"write_share": 0, "read": "quorum"}, "preload"),
     ({"write_share": 0, "read": "plain", "preload": {"keys_per_tenant": 1}},
@@ -145,3 +160,16 @@ def test_unimplemented_mix_keys_are_refused(change, word):
     loadgen.validate_mix(BASE)
     with pytest.raises(loadgen.MixError, match=word):
         loadgen.validate_mix({**BASE, **change})
+
+
+@pytest.mark.parametrize("change", [
+    {"loop": "open", "rate": 3900, "arrival": "poisson"},
+    {"loop": "open", "rate": 0.5, "arrival": "poisson", "clients": 1024},
+    {"tenant_dist": {"kind": "zipf", "theta": 0.99}},
+    {"tenant_dist": {"kind": "zipf", "theta": 1.1}},
+    {"loop": "open", "rate": 3900, "arrival": "poisson",
+     "tenant_dist": {"kind": "zipf", "theta": 0.99}},
+    {"start_spread_ms": 160},
+])
+def test_open_loop_and_zipf_mixes_are_accepted(change):
+    loadgen.validate_mix({**BASE, **change})
