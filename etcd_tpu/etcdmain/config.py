@@ -102,6 +102,11 @@ class MainConfig:
     # so a MainConfig built directly (embed, tests) boots the engine.
     engine_applier_shards: int = 1
     engine_wal_shards: int = 1
+    # Lagging-follower injection (server/lag.py): fault injection for
+    # measurement, off at share 0.
+    engine_lag_share: float = 0.0
+    engine_lag_hold_rounds: int = 256
+    engine_lag_seed: int = 0
 
     @property
     def is_proxy(self) -> bool:
@@ -197,6 +202,15 @@ _FLAGS = [
      "WAL-writer pool size: shard the engine log into N per-tenant-range "
      "segment streams with parallel group-commit fsyncs (1 = single "
      "stream; an existing data dir may upgrade 1 -> N once)"),
+    ("engine-lag-share", float, 0.0,
+     "Fault injection for measurement: hold this share of all follower "
+     "slots at every round, at most one a group (a held follower gets no "
+     "append and no snapshot; quorum is never at risk). 0 = off"),
+    ("engine-lag-hold-rounds", int, 256,
+     "Rounds one follower stays held under -engine-lag-share"),
+    ("engine-lag-seed", int, 0,
+     "Seed of the -engine-lag-share schedule: which follower of which "
+     "group is held in which round"),
 ]
 
 
@@ -220,8 +234,8 @@ def parse_args(argv: Sequence[str],
         elif isinstance(kind, tuple):
             ap.add_argument(f"--{flag}", dest=dest, default=None,
                             choices=kind, help=help_)
-        elif kind is int:
-            ap.add_argument(f"--{flag}", dest=dest, default=None, type=int,
+        elif kind in (int, float):
+            ap.add_argument(f"--{flag}", dest=dest, default=None, type=kind,
                             help=help_)
         else:
             ap.add_argument(f"--{flag}", dest=dest, default=None, help=help_)
@@ -238,13 +252,13 @@ def parse_args(argv: Sequence[str],
             raw = env[_env_name(flag)]
             if kind is bool:
                 val = raw.lower() in ("1", "true", "yes", "on")
-            elif kind is int:
+            elif kind in (int, float):
                 try:
-                    val = int(raw)
+                    val = kind(raw)
                 except ValueError:
                     raise ConfigError(
                         f"invalid value {raw!r} for {_env_name(flag)}: "
-                        f"expected an integer")
+                        f"expected {'an integer' if kind is int else 'a number'}")
             else:
                 val = raw
         if val is None:
@@ -297,6 +311,18 @@ def parse_args(argv: Sequence[str],
             raise ConfigError("-engine-applier-shards must be >= 1")
         if cfg.engine_wal_shards < 1:
             raise ConfigError("-engine-wal-shards must be >= 1")
+        if cfg.engine_lag_share:
+            if cfg.engine_peers < 3:
+                raise ConfigError(
+                    "-engine-lag-share needs -engine-peers >= 3: holding "
+                    "a follower of a smaller group puts its quorum at risk")
+            if not 0 < cfg.engine_lag_share * (cfg.engine_peers - 1) <= 1:
+                raise ConfigError(
+                    "-engine-lag-share must be between 0 and "
+                    f"1/{cfg.engine_peers - 1} (at most one follower a "
+                    "group is held)")
+            if cfg.engine_lag_hold_rounds < 1:
+                raise ConfigError("-engine-lag-hold-rounds must be >= 1")
     if 5 * cfg.heartbeat_interval > cfg.election_timeout:
         raise ConfigError(
             f"-election-timeout[{cfg.election_timeout}ms] should be at least "

@@ -163,6 +163,9 @@ class EngineServer:
             round_interval=cfg.engine_interval_ms / 1000.0,
             applier_shards=cfg.engine_applier_shards,
             wal_shards=cfg.engine_wal_shards,
+            lag_share=cfg.engine_lag_share,
+            lag_hold_rounds=cfg.engine_lag_hold_rounds,
+            lag_seed=cfg.engine_lag_seed,
             mesh=mesh))
         client_tls = TLSInfo(cert_file=cfg.cert_file, key_file=cfg.key_file,
                              ca_file=cfg.ca_file,

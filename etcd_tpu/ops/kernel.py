@@ -571,9 +571,15 @@ def _quorum_commit(st: GroupState, cfg: KernelConfig, active: jax.Array,
 
 def _assemble_sends(st: GroupState, cfg: KernelConfig, resp: jax.Array,
                     hb_fire_term: jax.Array, vote_fire_term: jax.Array,
-                    active: jax.Array) -> Tuple[GroupState, jax.Array]:
+                    active: jax.Array, hold=None
+                    ) -> Tuple[GroupState, jax.Array]:
     """Build the outbox (G, P_from, P_to, F) and apply optimistic progress
-    updates for sent appends."""
+    updates for sent appends. `hold` (G, P) bool names HELD target slots
+    (lagging-follower injection, the reference's Progress.Paused): a leader
+    sends a held slot no append and raises no snapshot for it, however far
+    behind it falls; heartbeats, votes and responses are not gated, so the
+    held follower keeps its leader and its term. None (the default) leaves
+    the program as it is without the hold."""
     G, P = st.term.shape
     F = cfg.fields
     E = cfg.max_ents
@@ -586,6 +592,9 @@ def _assemble_sends(st: GroupState, cfg: KernelConfig, resp: jax.Array,
     unacked = st.next - 1 - st.match
     paused_eff = _where(st.pr_state == PR_PROBE, st.paused,
                         unacked >= cfg.effective_flow_window)
+    if hold is not None:
+        held = hold[:, None, :]                  # by target column
+        paused_eff = paused_eff | held
     has_gap = st.next <= last
     prev = st.next - 1
     prev_in_win = in_window(st, cfg, prev)
@@ -598,6 +607,10 @@ def _assemble_sends(st: GroupState, cfg: KernelConfig, resp: jax.Array,
     sendable = prev_in_win & ents_ok
     # Target lags below the device window -> host must ship a snapshot.
     need_snap = is_ldr & tgt_ok & has_gap & ~sendable
+    if hold is not None:
+        # Without this gate a held follower of a busy group would be
+        # installed every W entries DURING its hold and never lag.
+        need_snap = need_snap & ~held
     st = st._replace(need_host=_flag(st.need_host,
                                      jnp.any(need_snap, axis=2), NH_SNAP))
 
@@ -867,7 +880,8 @@ def _quiet_msgs(st: GroupState, cfg: KernelConfig, inbox: jax.Array,
 def _step_body(cfg: KernelConfig, st: GroupState, inbox: jax.Array,
                prop_count: jax.Array, prop_slot: Optional[jax.Array],
                tick: jax.Array, quiet: bool,
-               force_hb: bool = False) -> Tuple[GroupState, jax.Array]:
+               force_hb: bool = False, hold=None
+               ) -> Tuple[GroupState, jax.Array]:
     """Shared round skeleton; `quiet` (Python bool, traced twice under the
     cond) selects the message-phase implementation. prop_slot=None selects
     per-SLOT proposal admission (prop_count is then (G, P) — the
@@ -909,7 +923,7 @@ def _step_body(cfg: KernelConfig, st: GroupState, inbox: jax.Array,
         st = _quorum_commit(st, cfg, active, lead_term0)
     with jax.named_scope("etcd.assemble_sends"):
         st, outbox = _assemble_sends(st, cfg, resp, hb_fire, vote_fire,
-                                     active)
+                                     active, hold)
     bad = active & (st.commit > st.last_index)
     st = st._replace(need_host=_flag(st.need_host, bad, NH_VIOLATION))
     return st, outbox
@@ -919,7 +933,8 @@ def _step_body(cfg: KernelConfig, st: GroupState, inbox: jax.Array,
 def step_routed_auto(cfg: KernelConfig, st: GroupState, inbox: jax.Array,
                      prop_count: jax.Array, prop_slot: jax.Array,
                      tick: jax.Array, drop_mask=None,
-                     hops: int = 1) -> Tuple[GroupState, jax.Array]:
+                     hops: int = 1, hold=None
+                     ) -> Tuple[GroupState, jax.Array]:
     """step + route_local with on-device fast-path selection: quiescent
     rounds (the steady-state common case) skip the P sequential message
     passes. ONE compiled program; lax.cond executes exactly one branch at
@@ -935,7 +950,10 @@ def step_routed_auto(cfg: KernelConfig, st: GroupState, inbox: jax.Array,
     host to one device program, which is what makes sub-round ack
     latencies possible on the serving path. `drop_mask` (G, P_to, P_from,
     1) int32, applied to the routed inbox after EVERY hop, keeps
-    fault-injection (partitions, message drops) hop-accurate."""
+    fault-injection (partitions, message drops) hop-accurate. `hold`
+    (G, P) bool, the held follower slots of _assemble_sends, applies on
+    every hop too; like drop_mask it is no argument of the program when
+    None."""
     for h in range(hops):
         pc = prop_count if h == 0 else jnp.zeros_like(prop_count)
         tk = tick if h == 0 else jnp.asarray(False)
@@ -944,12 +962,14 @@ def step_routed_auto(cfg: KernelConfig, st: GroupState, inbox: jax.Array,
 
         def fast(ops):
             st, inbox, pc, ps, tick = ops
-            s, out = _step_body(cfg, st, inbox, pc, ps, tick, quiet=True)
+            s, out = _step_body(cfg, st, inbox, pc, ps, tick, quiet=True,
+                                hold=hold)
             return s, route_local(out)
 
         def full(ops):
             st, inbox, pc, ps, tick = ops
-            s, out = _step_body(cfg, st, inbox, pc, ps, tick, quiet=False)
+            s, out = _step_body(cfg, st, inbox, pc, ps, tick, quiet=False,
+                                hold=hold)
             return s, route_local(out)
 
         with jax.named_scope(f"etcd.hop{h}"):
@@ -1009,7 +1029,7 @@ def _read_register(st: GroupState, cfg: KernelConfig
 def step_routed_read_auto(cfg: KernelConfig, st: GroupState,
                           inbox: jax.Array, prop_count: jax.Array,
                           prop_slot: jax.Array, tick: jax.Array,
-                          drop_mask=None, hops: int = 1
+                          drop_mask=None, hops: int = 1, hold=None
                           ) -> Tuple[GroupState, jax.Array, jax.Array,
                                      jax.Array, jax.Array, jax.Array]:
     """step_routed_auto plus a batched ReadIndex pass: returns
@@ -1059,13 +1079,13 @@ def step_routed_read_auto(cfg: KernelConfig, st: GroupState,
         def fast(ops, _h=h):
             st, inbox, pc, ps, tick = ops
             s, out = _step_body(cfg, st, inbox, pc, ps, tick, quiet=True,
-                                force_hb=(_h == 0))
+                                force_hb=(_h == 0), hold=hold)
             return s, route_local(out)
 
         def full(ops, _h=h):
             st, inbox, pc, ps, tick = ops
             s, out = _step_body(cfg, st, inbox, pc, ps, tick, quiet=False,
-                                force_hb=(_h == 0))
+                                force_hb=(_h == 0), hold=hold)
             return s, route_local(out)
 
         with jax.named_scope(f"etcd.hop{h}"):
@@ -1116,7 +1136,8 @@ def _compact_flags(st0: GroupState, st: GroupState
 @functools.partial(jax.jit, static_argnums=(0, 7), donate_argnums=_donate_at_import((1, 2)))
 def step_routed_compact(cfg: KernelConfig, st: GroupState, inbox: jax.Array,
                         prop_count: jax.Array, prop_slot: jax.Array,
-                        tick: jax.Array, drop_mask=None, hops: int = 1
+                        tick: jax.Array, drop_mask=None, hops: int = 1,
+                        hold=None
                         ) -> Tuple[GroupState, jax.Array, jax.Array,
                                    jax.Array]:
     """step_routed_auto plus an ON-DEVICE state diff: returns (st, inbox,
@@ -1143,7 +1164,7 @@ def step_routed_compact(cfg: KernelConfig, st: GroupState, inbox: jax.Array,
     anyway)."""
     st0 = st
     st, inbox = step_routed_auto.__wrapped__(
-        cfg, st, inbox, prop_count, prop_slot, tick, drop_mask, hops)
+        cfg, st, inbox, prop_count, prop_slot, tick, drop_mask, hops, hold)
     return (st, inbox) + _compact_flags(st0, st)
 
 

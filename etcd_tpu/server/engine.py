@@ -65,6 +65,7 @@ from etcd_tpu import errors
 from etcd_tpu.server import obs as obs_mod
 from etcd_tpu.server.enginewal import (CONF_ADD, CONF_REMOVE, EngineWAL,
                                        RoundRecord, b64_np, np_b64)
+from etcd_tpu.server.lag import LagSchedule
 from etcd_tpu.server.walwriter import WALWriter
 from etcd_tpu.utils import metrics
 from etcd_tpu.server.request import (METHOD_DELETE, METHOD_GET, METHOD_POST,
@@ -280,6 +281,16 @@ class EngineConfig:
     # within the lease window); 0 keeps every quorum read on the full
     # confirmation path.
     read_lease_ms: int = 0
+    # Lagging-follower injection (server/lag.py; fault injection for
+    # measurement, OFF by default: BASELINE.json configs[3]'s "5% lagging
+    # followers (Progress.Paused)"). lag_share > 0 holds that share of
+    # all follower slots at every round, at most one a group, each for
+    # lag_hold_rounds rounds, on a schedule that is a pure function of
+    # (lag_seed, group, round number). The held slots ride into the step
+    # as drop_mask does: 0 = no such argument, today's programs.
+    lag_share: float = 0.0
+    lag_hold_rounds: int = 256
+    lag_seed: int = 0
 
 
 class _AckCounter:
@@ -414,8 +425,8 @@ class MultiEngine:
                     donate_argnums=kernel.donate_safe((0, 1)),
                     out_shardings=(self._st_sh, self._mb_sh,
                                    *extra_out[name]))
-                return lambda st, inbox, pc, ps, t: fn(
-                    st, inbox, pc, ps, t, self.drop_mask)
+                return lambda st, inbox, pc, ps, t, hold: fn(
+                    st, inbox, pc, ps, t, self.drop_mask, hold=hold)
 
             # The compact round's row gather, from gather_rows' body: every
             # chip gathers the rows it holds and one all-reduce of the K
@@ -428,9 +439,9 @@ class MultiEngine:
                 # has a donated-buffer race, see kernel.py "CPU donation
                 # hazard"); donation stays on TPU.
                 fn = kernel.step_variant(name)
-                return lambda st, inbox, pc, ps, t: fn(
+                return lambda st, inbox, pc, ps, t, hold: fn(
                     self.kcfg, st, inbox, pc, ps, t, self.drop_mask,
-                    self.cfg.hops)
+                    self.cfg.hops, hold)
 
             self._gather_rows = kernel.gather_rows
         self._step_fn = step_fn("step_routed_auto")
@@ -451,6 +462,14 @@ class MultiEngine:
         # mask_check_rounds); >0 means the device mask diverged from the
         # host's and was restored.
         self.mask_repairs = 0
+        # Lagging-follower injection (EngineConfig.lag_share): the
+        # schedule, the slots held in the round last dispatched (a
+        # release is a slot held then and not in the next) and the
+        # snapshot installs done.
+        self._lag = (LagSchedule(G, P, cfg.lag_share, cfg.lag_hold_rounds,
+                                 cfg.lag_seed) if cfg.lag_share else None)
+        self._lag_held = np.zeros((G, P), bool)
+        self.snap_installs = 0
 
         # Geometry guard BEFORE anything touches the data dir: a mismatch
         # must refuse the dir before the WAL opens/creates any file in it.
@@ -1996,6 +2015,9 @@ class MultiEngine:
                                  for g in self._read_dirty
                                  if self._reads[g]}
 
+        # -- 1c. lagging-follower injection: this round's held slots
+        hold = self._lag_hold() if self._lag is not None else None
+
         if o:
             t_ph = time.perf_counter()
             clock.lap("dispatch", t_ph)
@@ -2013,19 +2035,19 @@ class MultiEngine:
             st, inbox, conf_d, rc_d, f_d, a_d = self._step_fn_r(
                 self.st, self.inbox,
                 jnp.asarray(prop_count), jnp.asarray(prop_slot),
-                jnp.asarray(bool(tick)))
+                jnp.asarray(bool(tick)), hold)
             if self._compact:
                 flags_d, anh_d = f_d, a_d
         elif self._compact:
             st, inbox, flags_d, anh_d = self._step_fn_c(
                 self.st, self.inbox,
                 jnp.asarray(prop_count), jnp.asarray(prop_slot),
-                jnp.asarray(bool(tick)))
+                jnp.asarray(bool(tick)), hold)
         else:
             st, inbox = self._step_fn(
                 self.st, self.inbox,
                 jnp.asarray(prop_count), jnp.asarray(prop_slot),
-                jnp.asarray(bool(tick)))
+                jnp.asarray(bool(tick)), hold)
         self.st = st
         self.inbox = inbox
         if o:
@@ -2246,7 +2268,11 @@ class MultiEngine:
         # acked). need_host is None on a compact round — the device
         # already attested any_need_host == False for it.
         if need_host is not None and need_host.any():
-            self._service_need_host(need_host)
+            t0 = time.perf_counter() if o else 0.0
+            with self.obs.span("etcd.round.need_host"):
+                self._service_need_host(need_host)
+            if o:
+                o.h_need_host.observe(time.perf_counter() - t0)
 
         if o:
             clock.lap("post", time.perf_counter())
@@ -2281,6 +2307,17 @@ class MultiEngine:
             # (opened here, not in _run: the thread can lose the
             # interpreter for tens of ms on its way out of this call)
             clock.lap("gap" if self._looping else None, time.perf_counter())
+
+    def _lag_hold(self):
+        """This round's held follower slots (server/lag.py), on the
+        device, sharded like the state's (G, P) fields on a mesh."""
+        held = self._lag.held(self.round_no, self.h_mask, self.h_state)
+        if self.obs.enabled:
+            self.obs.c_lag_releases.inc(
+                np.count_nonzero(self._lag_held & ~held))
+            self.obs.g_lag_held.set(np.count_nonzero(held))
+        self._lag_held = held
+        return self._dev("state", held)
 
     def _d2h(self, *arrays) -> None:
         """Count one blocking device->host read of `arrays` (called
@@ -2879,13 +2916,17 @@ class MultiEngine:
         """Consume need_host flags: for each flagged group with a live
         leader, snapshot-install every active follower whose needed entries
         fell below the leader's ring window (the host side of MsgSnap,
-        reference raft.go:246-260 + etcdserver snapshot catch-up §3.5)."""
-        jax, jnp = self._jax, self._jnp
+        reference raft.go:246-260 + etcdserver snapshot catch-up §3.5).
+        A follower the lag injection holds in this round is left alone."""
         st = self.st
         W = self.cfg.window
         flagged = np.nonzero(need_host.any(axis=1))[0]
         if not len(flagged):
             return
+        if self.obs.enabled:
+            for a in (st.next, st.match, st.pr_state, st.paused, st.lead,
+                      st.elapsed):
+                self._d2h(a)
         nxt = np.asarray(st.next).copy()
         match = np.asarray(st.match).copy()
         prs = np.asarray(st.pr_state).copy()
@@ -2898,7 +2939,7 @@ class MultiEngine:
         lead = np.asarray(st.lead).copy()
         stat = self.h_state.copy()
         elapsed = np.asarray(st.elapsed).copy()
-        touched = False
+        installs = 0
         for g in flagged:
             g = int(g)
             s = self.leader_slot(g)
@@ -2907,7 +2948,7 @@ class MultiEngine:
             c = int(commit[g, s])
             for f in np.nonzero(self.h_mask[g])[0]:
                 f = int(f)
-                if f == s:
+                if f == s or self._lag_held[g, f]:
                     continue
                 # Lagging = the kernel's need_snap condition: entries from
                 # next are no longer resolvable from the leader's ring
@@ -2916,8 +2957,8 @@ class MultiEngine:
                     continue  # still reachable by appends
                 if term[g, f] > term[g, s]:
                     continue  # follower is ahead in term; let raft sort it
-                log.info("engine: snapshot-install g=%d slot=%d from "
-                         "leader=%d commit=%d", g, f, s, c)
+                log.debug("engine: snapshot-install g=%d slot=%d from "
+                          "leader=%d commit=%d", g, f, s, c)
                 if term[g, f] < term[g, s]:
                     vote[g, f] = 0
                 term[g, f] = term[g, s]
@@ -2941,9 +2982,9 @@ class MultiEngine:
                 nxt[g, s, f] = c + 1
                 prs[g, s, f] = 1       # PR_REPLICATE
                 paused[g, s, f] = False
-                touched = True
+                installs += 1
         nh = np.zeros_like(need_host)
-        if touched:
+        if installs:
             # Mirrors stay pre-surgery (see NOTE below); the next round
             # must therefore run the FULL readback so its diff journals
             # the install — a compact (device-vs-device) diff cannot see
@@ -2966,6 +3007,9 @@ class MultiEngine:
             # term/commit/ring/last changes, making it durable.
         else:
             self.st = st._replace(need_host=self._dev("need_host", nh))
+        self.snap_installs += installs
+        if self.obs.enabled:
+            self.obs.c_snap_installs.inc(installs)
 
     # ------------------------------------------------------------------
     # checkpoint

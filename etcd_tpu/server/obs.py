@@ -116,6 +116,29 @@ readback_rounds = metrics.LabeledCounter(
     "compact_cap, so the full readback followed it) or full (need-host "
     "and post-surgery rounds, and every round with compact readback "
     "off).", ("kind",))
+need_host_seconds = metrics.Histogram(
+    "etcd_engine_need_host_seconds",
+    "Wall time of the need-host surgery on the round thread, one "
+    "observation a serviced round (it lies at the end of tail): the progress "
+    "arrays read back whole, the leader's ring row copied into each "
+    "lagging follower's on the host, thirteen state arrays uploaded "
+    "again.",
+    buckets=(0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 0.5, 1.0,
+             2.5, 5.0))
+snapshot_installs = metrics.Counter(
+    "etcd_engine_snapshot_installs_total",
+    "Followers the host snapshot-installed: their entries had fallen out "
+    "of the leader's ring, so the need-host surgery set them to the "
+    "leader's commit; the next round's full readback journals it.")
+lag_releases = metrics.Counter(
+    "etcd_engine_lag_releases_total",
+    "Lagging-follower injection (--engine-lag-share): follower slots held "
+    "in one round and no longer in the next (a hold ran out, or the "
+    "group's leader changed).")
+lag_held_slots = metrics.Gauge(
+    "etcd_engine_lag_held_slots",
+    "Lagging-follower injection: follower slots held in the current "
+    "round (at most one a group; 0 with the injection off).")
 pending_wait = metrics.Histogram(
     "etcd_engine_pending_wait_seconds",
     "Time a request sat in the engine's staging queue: do()/submit_many "
@@ -708,6 +731,10 @@ class EngineObs:
         self.c_d2h_bytes = d2h_bytes
         self.h_pending_wait = pending_wait
         self.h_checkpoint = checkpoint_seconds
+        self.h_need_host = need_host_seconds
+        self.c_snap_installs = snapshot_installs
+        self.c_lag_releases = lag_releases
+        self.g_lag_held = lag_held_slots
         for k in FRONT_KINDS:
             http_request.labels(k)
             http_front_self.labels(k)

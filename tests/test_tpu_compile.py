@@ -79,16 +79,21 @@ def _shapes(cfg: KernelConfig, state_sh, inbox_sh, small_sh):
     return st, inbox, pc, pc, tick
 
 
-def _compile_variant(topo, name: str, G: int):
+def _compile_variant(topo, name: str, G: int, hold: bool = False):
     """The variant as a TPU engine runs it: donated state and inbox (the
     suite's JAX_PLATFORMS=cpu makes the module-level jits undonated, so
-    the donating jit is rebuilt here from the same function)."""
+    the donating jit is rebuilt here from the same function). `hold`: with
+    the (G, P) bool of held follower slots as its one more argument
+    (--engine-lag-share)."""
     cfg = KernelConfig(groups=G, peers=P, window=W)
     one = SingleDeviceSharding(topo.devices[0])
     fn = jax.jit(getattr(kernel, name).__wrapped__,
                  static_argnums=kernel._STEP_STATICS[name],
                  donate_argnums=(1, 2))
-    return fn.lower(cfg, *_shapes(cfg, one, one, one), None, HOPS).compile()
+    held = ((jax.ShapeDtypeStruct((G, P), jnp.bool_, sharding=one),)
+            if hold else ())
+    return fn.lower(cfg, *_shapes(cfg, one, one, one), None, HOPS,
+                    *held).compile()
 
 
 def _check_variant(compiled, G: int) -> None:
@@ -107,6 +112,13 @@ def _check_variant(compiled, G: int) -> None:
 @pytest.mark.parametrize("name", VARIANTS)
 def test_serving_variant_compiles_for_v5e(topo, as_served, name):
     _check_variant(_compile_variant(topo, name, 128), 128)
+
+
+@pytest.mark.parametrize("name", VARIANTS[1:])
+def test_serving_variant_with_the_hold_compiles_for_v5e(topo, as_served,
+                                                        name):
+    """The two programs a member with --engine-lag-share serves."""
+    _check_variant(_compile_variant(topo, name, 128, hold=True), 128)
 
 
 def _mesh4(topo) -> Mesh:
@@ -210,6 +222,13 @@ def test_mesh_gather_rows_compiles_for_v5e_2x2(topo, as_served):
 def test_serving_variant_full_size(topo, as_served, name):
     """One chip's share of config 4 (G=12,500): ~85 s per variant."""
     _check_variant(_compile_variant(topo, name, 12_500), 12_500)
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("name", VARIANTS[1:])
+def test_serving_variant_with_the_hold_full_size(topo, as_served, name):
+    """mt100k-p5-lag5's programs (G=12,500 with the hold)."""
+    _check_variant(_compile_variant(topo, name, 12_500, hold=True), 12_500)
 
 
 @pytest.mark.slow
