@@ -92,7 +92,6 @@ class MainConfig:
     engine_groups: int = 0
     engine_peers: int = 5
     engine_window: int = 32
-    engine_interval_ms: int = 1
     # 0 = single-device arrays; >0 = shard the kernel over a
     # ("groups", "peers") mesh of all visible devices, with this many on
     # the peers axis (1 = all devices on the groups axis).
@@ -190,8 +189,6 @@ _FLAGS = [
      "one batched kernel at /tenants/{g}/v2/keys (0 = off)"),
     ("engine-peers", int, 5, "Peer slots per engine group"),
     ("engine-window", int, 32, "On-device log ring length per engine slot"),
-    ("engine-interval-ms", int, 1,
-     "Milliseconds between engine rounds (0 = flat out)"),
     ("engine-mesh-peers-axis", int, 0,
      "Shard the engine over all visible devices: mesh peers-axis size "
      "(0 = no mesh, 1 = all devices on the groups axis)"),
@@ -303,8 +300,6 @@ def parse_args(argv: Sequence[str],
             raise ConfigError("-engine-peers must be >= 1")
         if cfg.engine_window < 4:
             raise ConfigError("-engine-window must be >= 4")
-        if cfg.engine_interval_ms < 0:
-            raise ConfigError("-engine-interval-ms must be >= 0")
         if cfg.engine_mesh_peers_axis < 0:
             raise ConfigError("-engine-mesh-peers-axis must be >= 0")
         if cfg.engine_applier_shards < 1:

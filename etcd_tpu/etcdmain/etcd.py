@@ -160,7 +160,6 @@ class EngineServer:
             groups=cfg.engine_groups, peers=cfg.engine_peers,
             window=cfg.engine_window,
             data_dir=os.path.join(cfg.data_dir, DIR_ENGINE),
-            round_interval=cfg.engine_interval_ms / 1000.0,
             applier_shards=cfg.engine_applier_shards,
             wal_shards=cfg.engine_wal_shards,
             lag_share=cfg.engine_lag_share,

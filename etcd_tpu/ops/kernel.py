@@ -1168,16 +1168,67 @@ def step_routed_compact(cfg: KernelConfig, st: GroupState, inbox: jax.Array,
     return (st, inbox) + _compact_flags(st0, st)
 
 
-@jax.jit
-def gather_rows(st: GroupState, gi: jax.Array, pi: jax.Array):
-    """Fetch the engine-mirrored fields for K specific (g, p) rows:
-    (term, vote, commit, state, last_index) each (K,) plus the (K, W)
-    ring rows. K is a trace-time constant — callers pad the index
-    vectors to size buckets to bound retraces. Padding rows (0, 0) are
-    harmless: callers slice results back to the true K."""
-    return (st.term[gi, pi], st.vote[gi, pi], st.commit[gi, pi],
-            st.state[gi, pi], st.last_index[gi, pi],
-            st.log_term[gi, pi])
+# gather_rows' packed row: its linear index g*P + p, its CHG_* flags, the
+# five mirrored scalars, then the W ring slots.
+ROW_LIN, ROW_FLAGS, ROW_TERM, ROW_VOTE, ROW_COMMIT, ROW_STATE, ROW_LAST = (
+    range(7))
+ROW_RING = 7
+
+
+def _pick_rows(mark: jax.Array, kp: int) -> Tuple[jax.Array, jax.Array]:
+    """(the ascending linear indices g*P + p of the first kp set entries
+    of the (G, P) bool `mark`, padded with G*P; how many are set). What
+    np.nonzero gives the host, shaped for the chip: a count per group,
+    one cumulative sum over G, a binary search for the group that holds
+    the j-th set entry and a P-wide rank inside it. No sort, no scatter."""
+    G, P = mark.shape
+    m = mark.astype(jnp.int32)
+    upto = jnp.cumsum(jnp.sum(m, axis=1), dtype=jnp.int32)      # (G,)
+    j = jnp.arange(kp, dtype=jnp.int32)
+    g = jnp.minimum(jnp.searchsorted(upto, j, side="right"),
+                    G - 1).astype(jnp.int32)
+    within = jnp.cumsum(m[g], axis=1)                           # (kp, P)
+    rank = j - (upto[g] - within[:, -1])            # j's place in group g
+    p = jnp.argmax(within == rank[:, None] + 1, axis=1).astype(jnp.int32)
+    k = upto[G - 1]
+    return jnp.where(j < k, g * P + p, G * P), k
+
+
+@functools.partial(jax.jit, static_argnums=5)
+def gather_rows(st: GroupState, flags: jax.Array, any_need_host: jax.Array,
+                prop_count: jax.Array, prop_slot: jax.Array, kp: int
+                ) -> jax.Array:
+    """The compact round's one readback, built on the device right behind
+    the step: picks the rows the host has to see and packs them, with
+    their values, into ONE (1 + kp, ROW_RING + W) int32 buffer.
+
+    `flags` and `any_need_host` are what step_routed_compact /
+    step_routed_read_auto returned for the round (still on the device),
+    `st` the state after it, prop_count / prop_slot the very arrays the
+    step was given: a group with proposals staged contributes its leader
+    row (g, prop_slot[g]) whether or not it changed, because admission
+    reads it. The picked rows are that union in ascending g*P + p, the
+    order np.nonzero walks a flag map in.
+
+    Row 0 is the header (any_need_host, K = the union's true size, zeros);
+    row 1 + j is the j-th picked row (ROW_* columns). kp is a trace-time
+    constant, one program a size bucket; K > kp means the bucket missed
+    and rows kp.. are not there: the caller asks again with a larger kp.
+    Rows past K are padding (ROW_LIN == G*P, values of the last row)."""
+    G, P = flags.shape
+    staged = ((prop_count > 0)[:, None]
+              & (prop_slot[:, None] == jnp.arange(P, dtype=prop_slot.dtype)))
+    lin, k = _pick_rows((flags != 0) | staged, kp)
+    at = jnp.minimum(lin, G * P - 1)
+    gi, pi = at // P, at % P
+    cols = [lin, flags[gi, pi].astype(jnp.int32), st.term[gi, pi],
+            st.vote[gi, pi], st.commit[gi, pi], st.state[gi, pi],
+            st.last_index[gi, pi]]
+    rows = jnp.concatenate(
+        [jnp.stack(cols, axis=1), st.log_term[gi, pi]], axis=1)
+    head = jnp.zeros((1, rows.shape[1]), jnp.int32)
+    head = head.at[0, 0].set(any_need_host.astype(jnp.int32)).at[0, 1].set(k)
+    return jnp.concatenate([head, rows], axis=0)
 
 
 @functools.partial(jax.jit, static_argnums=0, donate_argnums=_donate_at_import((1, 2)))

@@ -133,6 +133,37 @@ def _named_partial(fn, *args, **kw):
     return p
 
 
+def mesh_gather_rows(rep):
+    """kernel.gather_rows for a state sharded over a mesh, the packed
+    buffer coming back on the sharding `rep` (replicated): one all-gather
+    brings the flag map's shards together (G*P bytes) so that every chip
+    makes the same pick, each gathers the picked rows it holds, and one
+    all-reduce of those rows brings them together. Pinning the flag map
+    is what keeps the pick's sums and searches off the groups axis
+    (tests/test_tpu_compile.py holds the collectives to these two)."""
+    import jax
+    from etcd_tpu.ops import kernel
+    body = kernel.gather_rows.__wrapped__
+
+    def gather_rows(st, flags, *rest):
+        return body(st, jax.lax.with_sharding_constraint(flags, rep), *rest)
+
+    return jax.jit(gather_rows, static_argnums=5, out_shardings=rep)
+
+
+# The longest the engine thread waits for work after a round that found
+# none (MultiEngine._run): the idle member's logical tick. Longer than the
+# front's pass over a full cohort, so a loaded member runs no empty round
+# while its front parses; short against anything that counts wall time
+# (the TTL scan's sync_interval, request timeouts).
+IDLE_TICK_S = 0.05
+
+
+def _bucket(k: int) -> int:
+    """The smallest gather_rows size bucket that holds k rows."""
+    return max(256, 1 << (k - 1).bit_length())
+
+
 class EngineViolation(RuntimeError):
     """A consensus safety violation detected by the kernel (NH_VIOLATION:
     an append conflicted with a committed entry — the condition the
@@ -170,7 +201,6 @@ class EngineConfig:
     # pinning at a fixed request count.
     batch_max: int = 4096
     batch_bytes: int = 1 << 20
-    round_interval: float = 0.0       # seconds between rounds (0 = flat out)
     ticks_per_round: int = 1          # logical clock rate
     stagger: bool = True              # deterministic fast first election
     initial_peers: Optional[int] = None  # active slots at fresh boot (<= peers)
@@ -239,23 +269,34 @@ class EngineConfig:
     # commit completes within the round it was staged, cutting ack
     # latency from ~4 round-trips to ~1.5 (kernel.step_routed_auto).
     hops: int = 3
-    # Compact readback (kernel.step_routed_compact): the round's state
-    # diff is computed ON DEVICE and the host reads back a (G, P) uint8
-    # flag map plus values for only the rows that changed, instead of
-    # the full O(G*P*W) state every round (32 MB of ring alone at
-    # G=100k). Rounds that change more rows than compact_cap, that raise
-    # need_host or that follow a snapshot-install surgery take the full
-    # readback, so saturated throughput is untouched; a round that
-    # carries quorum reads is built like any other (its step returns the
-    # same diff). None = on, mesh or not: on a mesh the flag map is
-    # sharded like the state, the need-host attestation and the
-    # gathered rows come back replicated, and the row gather is the
-    # round's one data-carrying collective across the groups axis (one
-    # all-reduce of the K gathered rows). Which path built a round's
-    # record is counted in etcd_engine_readback_rounds_total{kind}.
+    # Compact readback (kernel.step_routed_compact + gather_rows): the
+    # round's state diff is computed ON DEVICE, a (G, P) uint8 flag map
+    # that never leaves it: gather_rows, enqueued right behind the step
+    # with no host read in between, picks the rows that changed (and the
+    # staged leader rows), gathers their values and packs them with the
+    # need-host attestation into one buffer, and the host makes ONE
+    # blocking read a round instead of the full O(G*P*W) state (32 MB of
+    # ring alone at G=100k). Rounds that change more rows than
+    # compact_cap, that raise need_host or that follow a snapshot-install
+    # surgery take the full readback, so saturated throughput is
+    # untouched; a round that carries quorum reads is built like any other
+    # (its step returns the same diff). None = on, mesh or not: on a mesh
+    # the flag map is sharded like the state, the attestation and the
+    # packed buffer come back replicated, and gather_rows holds the
+    # round's two data-carrying collectives across the groups axis (one
+    # all-gather of the flag map, one all-reduce of the K gathered rows).
+    # Which path built a round's record is counted in
+    # etcd_engine_readback_rounds_total{kind}.
     compact_readback: Optional[bool] = None
     # Max changed+staged rows served by the gather path before a round
-    # falls back to full readback. 0 = auto: max(2048, G*P//8).
+    # falls back to full readback. 0 = auto: max(2048, G*P//8). It also
+    # bounds gather_rows' size buckets (powers of two from 256 to the one
+    # that holds the cap, one compiled program each, all built before the
+    # engine thread's first round): a round asks for the bucket that
+    # holds max(the most rows any of the last heartbeat_tick + 1 compact
+    # rounds picked, P x (groups staged now + last round)), and one that
+    # picks more is read a second time
+    # at the bucket that holds it (etcd_engine_gather_rebuckets_total).
     compact_cap: int = 0
     # Liveness watchdog cadence (rounds): every N rounds verify the
     # DEVICE peer_mask still equals the host h_mask and repair it from
@@ -428,11 +469,7 @@ class MultiEngine:
                 return lambda st, inbox, pc, ps, t, hold: fn(
                     st, inbox, pc, ps, t, self.drop_mask, hold=hold)
 
-            # The compact round's row gather, from gather_rows' body: every
-            # chip gathers the rows it holds and one all-reduce of the K
-            # rows brings them together, replicated.
-            self._gather_rows = jax.jit(kernel.gather_rows.__wrapped__,
-                                        out_shardings=rep)
+            self._gather_rows = mesh_gather_rows(rep)
         else:
             def step_fn(name):
                 # step_variant: undonated twin on the cpu backend (XLA:CPU
@@ -451,6 +488,17 @@ class MultiEngine:
         self._compact = (cfg.compact_readback is None
                          or bool(cfg.compact_readback))
         self._compact_cap = cfg.compact_cap or max(2048, G * P // 8)
+        # What the next compact round's gather bucket is chosen from
+        # (_gather_bucket): the rows each of the last few compact rounds
+        # picked (a follower learns a commit with its leader's next
+        # heartbeat, so a round's rows can follow its writes by that many
+        # rounds) and the groups the last round staged.
+        self._gather_ks: deque = deque([0], maxlen=cfg.heartbeat_tick + 1)
+        self._staged_prev = 0
+        # The round loop's pacing (_run): set by whoever queues work for a
+        # round, and whether the last round found none and changed nothing.
+        self._work = threading.Event()
+        self._quiet = False
         # Set whenever device state was mutated WITHOUT updating the
         # h_* mirrors (the snapshot-install surgery leaves mirrors stale
         # on purpose so the NEXT round's full diff journals the install,
@@ -876,6 +924,7 @@ class MultiEngine:
 
     def stop(self) -> None:
         self._stop_ev.set()
+        self._work.set()
         if self._thread is not None:
             self._thread.join(timeout=10)
             if self._thread.is_alive():
@@ -1120,6 +1169,7 @@ class MultiEngine:
             # the enqueue time rides too, for the staging-queue wait.
             self._pending[g].append((r.id, payload, r, t0))
             self._dirty.add(g)
+        self._work.set()
         # Reference proposal metrics (etcdserver/metrics.go), previously
         # observed only by the legacy server.py path.
         if obs_on:
@@ -1241,6 +1291,7 @@ class MultiEngine:
                     dirty.add(g)
                 for g, r in reads:
                     lease += self._park_read(g, r)
+            self._work.set()
         except BaseException:
             # Nobody will hold these tokens: leave no waiter behind.
             for tok in tokens:
@@ -1347,6 +1398,7 @@ class MultiEngine:
             self._pending[g].extend(items)
             if items:
                 self._dirty.add(g)
+        self._work.set()
         if obs_on:
             for _ in range(len(items)):
                 metrics.propose_pending.inc()
@@ -1424,6 +1476,7 @@ class MultiEngine:
         t0 = time.perf_counter()
         with self._lock:
             lease = self._park_read(g, r)
+        self._work.set()
         if obs_on:
             if lease:
                 self.obs.c_reads_lease.inc()
@@ -1605,6 +1658,7 @@ class MultiEngine:
             self._pending[g].append((rid, payload, None))
             self._dirty.add(g)
             self._confs_outstanding += 1
+        self._work.set()
         try:
             result = q.get(timeout=timeout or self.cfg.request_timeout)
         except queue.Empty:
@@ -1655,6 +1709,7 @@ class MultiEngine:
         item = (op, done, out)
         with self._lock:
             self._admin_q.append(item)
+        self._work.set()
         if not done.wait(timeout or self.cfg.request_timeout):
             # Withdraw the op if it never started — a timed-out create must
             # not silently provision later (a client retry would then
@@ -1871,21 +1926,40 @@ class MultiEngine:
         if self.obs.enabled:
             self.obs.thread_cpu.register("round")
         # From here run_round ends in the gap phase: one run_round's end
-        # to the next one's start (the round_interval sleep and the wait
-        # to get the interpreter back); a caller that drives run_round
-        # itself sees none.
+        # to the next one's start (the wait for work after an idle round
+        # and the wait to get the interpreter back); a caller that drives
+        # run_round itself sees none.
         self._looping = True
         try:
+            if self._compact:
+                self._warm_gather()
             while not self._stop_ev.is_set():
                 self.run_round()
-                if self.cfg.round_interval:
-                    time.sleep(self.cfg.round_interval)
+                # A round with nothing to do is a round's worth of the
+                # interpreter taken from the front while it parses the
+                # next cohort: wait for whoever queues work to say so
+                # (one set() a submit_pairs call, i.e. a front's pass), or
+                # for the idle tick.
+                self._work.clear()
+                if self._idle():
+                    self._work.wait(IDLE_TICK_S)
         except Exception as e:  # noqa: BLE001 — record, then re-raise
             self.failed = e
             self._stop_ev.set()
             raise
         finally:
             self._looping = False
+
+    def _idle(self) -> bool:
+        """Nothing for a round to do but tick: the last round staged
+        nothing, confirmed no read and journalled nothing, nothing is
+        queued, every group has a leader and every leader has committed
+        all it admitted (an entry waiting on a retransmit, a step-down or
+        an election is carried there at round speed, not a tick at a
+        time)."""
+        return (self._quiet and not self._dirty and not self._reads_waiting
+                and not self._ripe_waiting and not self._admin_q
+                and not self._confs_outstanding and self._settled())
 
     def run_round(self) -> None:
         """One engine round. Callable directly (tests drive the engine
@@ -1972,7 +2046,8 @@ class MultiEngine:
                     ents.append(cur)
                 if not dq:
                     self._dirty.discard(g)
-                self._staged[g] = (s, ents)
+                if ents:    # (none: every queued item was junk)
+                    self._staged[g] = (s, ents)
         # One pass builds the staged index arrays; they feed the two
         # scatter writes here AND the admission gather after the step
         # (_staged is round-thread-private and not mutated in between).
@@ -2024,7 +2099,9 @@ class MultiEngine:
 
         # -- 2. the kernel round (fused step + routing: one ASYNC
         # dispatch; jax queues it and returns immediately) ----------------
-        tick = (self.round_no % self.cfg.ticks_per_round) == 0
+        tick = jnp.asarray(bool(
+            (self.round_no % self.cfg.ticks_per_round) == 0))
+        pc_d, ps_d = jnp.asarray(prop_count), jnp.asarray(prop_slot)
         flags_d = anh_d = None
         conf_d = rc_d = None
         if read_take:
@@ -2033,23 +2110,29 @@ class MultiEngine:
             # and its step returns the same on-device diff as the
             # compact step's: one record builder serves both.
             st, inbox, conf_d, rc_d, f_d, a_d = self._step_fn_r(
-                self.st, self.inbox,
-                jnp.asarray(prop_count), jnp.asarray(prop_slot),
-                jnp.asarray(bool(tick)), hold)
+                self.st, self.inbox, pc_d, ps_d, tick, hold)
             if self._compact:
                 flags_d, anh_d = f_d, a_d
         elif self._compact:
             st, inbox, flags_d, anh_d = self._step_fn_c(
-                self.st, self.inbox,
-                jnp.asarray(prop_count), jnp.asarray(prop_slot),
-                jnp.asarray(bool(tick)), hold)
+                self.st, self.inbox, pc_d, ps_d, tick, hold)
         else:
             st, inbox = self._step_fn(
-                self.st, self.inbox,
-                jnp.asarray(prop_count), jnp.asarray(prop_slot),
-                jnp.asarray(bool(tick)), hold)
+                self.st, self.inbox, pc_d, ps_d, tick, hold)
         self.st = st
         self.inbox = inbox
+        # The compact round's one readback, enqueued right behind the
+        # step with no host read in between: gather_rows picks the rows
+        # that changed (and the staged leader rows) where the flag map
+        # lies and packs them with the attestation into one buffer. A
+        # round after a surgery takes the full readback whatever the
+        # device says, and asks for nothing here.
+        gather = buf_d = None
+        if flags_d is not None and not self._force_full:
+            gather = (st, flags_d, anh_d, pc_d, ps_d)
+            kp = self._gather_bucket(len(self._staged))
+            buf_d = self._gather_rows(*gather, kp)
+        self._staged_prev = len(self._staged)
         if o:
             t_now = time.perf_counter()
             d_dispatch = t_now - t_ph
@@ -2059,33 +2142,29 @@ class MultiEngine:
         # -- 3. read back round k (blocks until the device finishes; the
         # GIL is released while waiting, so the applier thread makes
         # progress on earlier rounds' committed work here). Compact mode
-        # reads the on-device diff flags first and fetches values for
-        # only the changed rows; need_host rounds and rounds changing
-        # more rows than the cap take the full readback below. ----------
+        # makes ONE blocking read, of gather_rows' packed buffer: the
+        # attestation, the changed rows' flags and their values; need_host
+        # rounds and rounds changing more rows than the cap take the full
+        # readback below. ------------------------------------------------
         rec = None
         need_host = None
-        # Which readback builds this round's record: "compact" (the flag
-        # map + gathered rows), "over_cap" (the attempt, then the full
+        # Which readback builds this round's record: "compact" (the rows
+        # gather_rows packed), "over_cap" (the attempt, then the full
         # readback) or "full".
         readback_kind = "full"
         d_readback = d_record = 0.0
-        if flags_d is not None:
-            # Check the 1-byte attestation BEFORE pulling the flag map:
-            # need-host/post-surgery rounds take the full readback anyway
-            # and must not pay a discarded (G, P) transfer first.
-            any_nh = bool(anh_d)
+        if buf_d is not None:
+            buf = np.asarray(buf_d)
             if o:
-                self._d2h(anh_d)
-            if not any_nh and not self._force_full:
-                flags_np = np.asarray(flags_d)
+                self._d2h(buf_d)
+            if not buf[0, 0]:           # the device attests: no need-host
                 if o:
                     t_now = time.perf_counter()
                     d_readback = t_now - t_ph
                     t_ph = t_stepped = t_now
-                    self._d2h(flags_d)
                     clock.lap("record", t_now)
-                rec = self._compact_record_admit(flags_np, staged_gs,
-                                                 staged_ss)
+                rec = self._compact_record_admit(buf, kp, gather,
+                                                 staged_gs, staged_ss)
                 # Over the cap (rec is None) the attempt still counts as
                 # record; the full readback below is a second readback lap.
                 readback_kind = "compact" if rec is not None else "over_cap"
@@ -2274,6 +2353,11 @@ class MultiEngine:
             if o:
                 o.h_need_host.observe(time.perf_counter() - t0)
 
+        # What _idle asks of the round just run, whichever readback built
+        # its record (a surgery leaves the next round a diff to journal).
+        self._quiet = (rec.is_empty() and not self._staged
+                       and not read_take and not self._force_full)
+
         if o:
             clock.lap("post", time.perf_counter())
             o.c_rounds.inc()
@@ -2331,6 +2415,13 @@ class MultiEngine:
         led = (np.where(self.h_mask, self.h_state, 0) == _LEADER).any(axis=1)
         return bool(led[self.h_mask.any(axis=1)].all())
 
+    def _settled(self) -> bool:
+        """Every provisioned group's mirror shows a leader whose log is
+        committed to its end."""
+        lead = np.where(self.h_mask, self.h_state, 0) == _LEADER
+        return (bool(lead.any(axis=1)[self.h_mask.any(axis=1)].all())
+                and not (lead & (self.h_commit < self.h_last)).any())
+
     def _admit_staged(self, rec: RoundRecord, adm_l: list, t_l: list,
                       base_l: list) -> None:
         """Turn this round's staged entries into payload-store entries +
@@ -2378,75 +2469,111 @@ class MultiEngine:
         ann.__exit__(None, None, None)
         self._rec_admit += time.perf_counter() - t_admit
 
-    def _compact_record_admit(self, flags: np.ndarray,
+    def _gather_bucket(self, n_staged: int) -> int:
+        """The size bucket (a power of two >= 256, one compiled program
+        each) asked of gather_rows for the round being dispatched, from
+        what the host can see before the step runs: the most rows any of
+        the last heartbeat_tick + 1 compact rounds picked, and the groups
+        staged now and in the last round (a staged group changes its P
+        rows in the round that admits its entries, and its followers'
+        when they learn the commit: with the next append or heartbeat).
+        A round that outgrows it is read twice
+        (etcd_engine_gather_rebuckets_total)."""
+        want = max(max(self._gather_ks),
+                   self.cfg.peers * (n_staged + self._staged_prev))
+        return _bucket(min(want, self._compact_cap))
+
+    def _gather_buckets(self) -> List[int]:
+        """Every bucket a round of this geometry can ask for."""
+        top = _bucket(min(self._compact_cap,
+                          self.cfg.groups * self.cfg.peers))
+        return [1 << i for i in range(8, top.bit_length())]
+
+    def _warm_gather(self) -> None:
+        """Build (compile, or load from the persistent cache) gather_rows
+        for every bucket before the first round, on arguments placed as a
+        round's are (jit keys its programs on that too), so that no
+        serving round ever waits for one."""
+        jax, jnp = self._jax, self._jnp
+        G, P = self.cfg.groups, self.cfg.peers
+        flags_d = jnp.zeros((G, P), jnp.uint8)
+        anh_d = jnp.zeros((), bool)
+        if self.cfg.mesh is not None:
+            from etcd_tpu.parallel.mesh import (flag_sharding,
+                                                replicated_sharding)
+            flags_d = jax.device_put(flags_d, flag_sharding(self.cfg.mesh))
+            anh_d = jax.device_put(anh_d,
+                                   replicated_sharding(self.cfg.mesh))
+        for kp in self._gather_buckets():
+            self._gather_rows(self.st, flags_d, anh_d, self._zero,
+                              self._zero, kp).block_until_ready()
+
+    def _compact_record_admit(self, buf: np.ndarray, kp: int, gather,
                               staged_gs, staged_ss
                               ) -> Optional[RoundRecord]:
         """The compact-readback round tail: build the SAME durable round
         record (byte-identical; tests/test_engine_compact.py pins it)
-        and run the same admission as the full tail, from a bounded
-        gather of only the rows the device flagged as changed. Returns
-        None when the round changed more rows than the cap — the caller
-        then falls back to the full readback (saturation: the bulk
-        transfer is amortized by the batch it carries)."""
+        and run the same admission as the full tail, from gather_rows'
+        packed buffer `buf` of only the rows the device flagged as
+        changed (and the staged leader rows). `gather` holds the
+        arguments it was called with at bucket `kp`: when the round
+        picked more rows than kp the call is made again, on the same
+        outputs, at the bucket that holds them. Returns None when the
+        round changed more rows than the cap — the caller then falls
+        back to the full readback (saturation: the bulk transfer is
+        amortized by the batch it carries)."""
         kernel = self._kernel
-        jnp = self._jnp
-        G, P, W = self.cfg.groups, self.cfg.peers, self.cfg.window
-        chg_g, chg_p = np.nonzero(flags)
-        lin = chg_g.astype(np.int64) * P + chg_p
-        if staged_gs is not None:
-            lin = np.unique(np.concatenate(
-                [lin, staged_gs * P + staged_ss]))
-        K = len(lin)
+        P, W = self.cfg.peers, self.cfg.window
+        K = int(buf[0, 1])
+        self._gather_ks.append(K)
         if K > self._compact_cap:
             return None
         rec = RoundRecord(round_no=self.round_no)
         if K == 0:
             return rec
-        gi = (lin // P).astype(np.int32)
-        pi = (lin % P).astype(np.int32)
-        # Pad to a size bucket so gather_rows retraces O(log K) times,
-        # not per distinct K. Padding rows read (0, 0) — discarded.
-        Kp = 256
-        while Kp < K:
-            Kp <<= 1
-        gi_p = np.zeros(Kp, np.int32)
-        pi_p = np.zeros(Kp, np.int32)
-        gi_p[:K], pi_p[:K] = gi, pi
-        # The second device round trip of the round: the dispatch, then
-        # one blocking read per gathered array.
+        # What is left of the gather on the host: a second call when the
+        # bucket missed, and the one buffer's unpacking.
         t_gather = time.perf_counter()
         with self.obs.span("etcd.record.gather"):
-            rows_d = self._gather_rows(
-                self.st, jnp.asarray(gi_p), jnp.asarray(pi_p))
-            t_k, v_k, c_k, s_k, l_k, r_k = (
-                np.asarray(a)[:K] for a in rows_d)
+            if K > kp:
+                buf_d = self._gather_rows(*gather, _bucket(K))
+                buf = np.asarray(buf_d)
+                if self.obs.enabled:
+                    self._d2h(buf_d)
+                    self.obs.c_gather_rebuckets.inc()
+            rows = buf[1:K + 1]
+            lin = rows[:, kernel.ROW_LIN]
+            chg = rows[:, kernel.ROW_FLAGS]
+            t_k, v_k, c_k, s_k, l_k = (
+                rows[:, c] for c in (kernel.ROW_TERM, kernel.ROW_VOTE,
+                                     kernel.ROW_COMMIT, kernel.ROW_STATE,
+                                     kernel.ROW_LAST))
+            r_k = rows[:, kernel.ROW_RING:]
+            gi, pi = np.divmod(lin, P)
         if self.obs.enabled:
-            for a in rows_d:
-                self._d2h(a)
             self._rec_gather += time.perf_counter() - t_gather
 
-        def rows(bit):
-            g, p = np.nonzero((flags & bit) != 0)
-            return g, p, np.searchsorted(lin, g.astype(np.int64) * P + p)
+        # The picked rows ascend in g*P + p, the order np.nonzero walks a
+        # flag map in: a bit's rows are a mask over them.
+        m = (chg & kernel.CHG_HS) != 0
+        rec.hs_g = gi[m].astype(np.uint32)
+        rec.hs_p = pi[m].astype(np.uint16)
+        rec.hs_term = t_k[m].astype(np.uint32)
+        rec.hs_vote = v_k[m].astype(np.uint16)
+        rec.hs_commit = c_k[m].astype(np.uint32)
 
-        g0, p0, pos0 = rows(kernel.CHG_HS)
-        rec.hs_g = g0.astype(np.uint32)
-        rec.hs_p = p0.astype(np.uint16)
-        rec.hs_term = t_k[pos0].astype(np.uint32)
-        rec.hs_vote = v_k[pos0].astype(np.uint16)
-        rec.hs_commit = c_k[pos0].astype(np.uint32)
+        m = (chg & kernel.CHG_LAST) != 0
+        rec.last_g = gi[m].astype(np.uint32)
+        rec.last_p = pi[m].astype(np.uint16)
+        rec.last_v = l_k[m].astype(np.uint32)
 
-        g1, p1, pos1 = rows(kernel.CHG_LAST)
-        rec.last_g = g1.astype(np.uint32)
-        rec.last_p = p1.astype(np.uint16)
-        rec.last_v = l_k[pos1].astype(np.uint32)
-
-        g2, p2, pos2 = rows(kernel.CHG_RING)
-        if len(g2):
-            new_rows = r_k[pos2]                    # (n2, W)
+        m = (chg & kernel.CHG_RING) != 0
+        if m.any():
+            g2, p2 = gi[m], pi[m]
+            new_rows = r_k[m]                       # (n2, W)
             sub = new_rows != self.h_ring[g2, p2]
             ai, wi = np.nonzero(sub)
-            lastv = l_k[pos2][ai]
+            lastv = l_k[m][ai]
             absi = lastv - ((lastv - wi) % W)
             keep = absi >= 1
             rec.ring_g = g2[ai][keep].astype(np.uint32)

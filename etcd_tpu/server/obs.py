@@ -95,27 +95,33 @@ round_phase_cpu = metrics.LabeledCounter(
     "interpreter.", ("phase",))
 record_part = metrics.LabeledHistogram(
     "etcd_engine_record_part_seconds",
-    "Wall time of the record phase's parts per round: gather (the "
-    "gather_rows dispatch to the last gathered array on the host), admit "
+    "Wall time of the record phase's parts per round: gather (what is "
+    "left of gather_rows on the host: unpacking its one buffer, and the "
+    "second call and read of a round that outgrew its bucket), admit "
     "(_admit_staged) and build (the rest); a full-readback round has "
     "build and admit only.", ("part",))
 d2h_syncs = metrics.Counter(
     "etcd_engine_d2h_syncs_total",
-    "Blocking device->host reads on the round thread: the need-host "
-    "attestation, the flag map, each gathered array, the full readback "
-    "(one device_get), the read step's conf/read-index arrays, the mask "
-    "check.")
+    "Blocking device->host reads on the round thread: gather_rows' "
+    "packed buffer (one a compact round, a second when the bucket "
+    "missed), the full readback (one device_get), the read step's "
+    "conf/read-index arrays, the need-host surgery's, the mask check.")
 d2h_bytes = metrics.Counter(
     "etcd_engine_d2h_bytes_total",
     "Bytes those device->host reads brought back (the arrays' nbytes).")
 READBACK_KINDS = ("compact", "full", "over_cap")
 readback_rounds = metrics.LabeledCounter(
     "etcd_engine_readback_rounds_total",
-    "Rounds by the readback that built their record: compact (flag map "
-    "+ gathered rows), over_cap (the flag map named more rows than "
-    "compact_cap, so the full readback followed it) or full (need-host "
-    "and post-surgery rounds, and every round with compact readback "
-    "off).", ("kind",))
+    "Rounds by the readback that built their record: compact (the rows "
+    "gather_rows picked and packed on the device), over_cap (it counted "
+    "more rows than compact_cap, so the full readback followed it) or "
+    "full (need-host and post-surgery rounds, and every round with "
+    "compact readback off).", ("kind",))
+gather_rebuckets = metrics.Counter(
+    "etcd_engine_gather_rebuckets_total",
+    "Compact rounds that picked more rows than the size bucket "
+    "gather_rows was called with, so the call and its blocking read were "
+    "made a second time at the bucket that holds them.")
 need_host_seconds = metrics.Histogram(
     "etcd_engine_need_host_seconds",
     "Wall time of the need-host surgery on the round thread, one "
@@ -727,6 +733,7 @@ class EngineObs:
                            for k in READBACK_KINDS}
         for c in self.c_readback.values():
             c.inc(0.0)              # flat from the start, like the phases
+        self.c_gather_rebuckets = gather_rebuckets
         self.c_d2h_syncs = d2h_syncs
         self.c_d2h_bytes = d2h_bytes
         self.h_pending_wait = pending_wait

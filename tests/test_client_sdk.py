@@ -136,8 +136,7 @@ def test_sdk_and_etcdctl_against_tenant_endpoint(tmp_path):
     (cp,) = free_ports(1)
     eng = MultiEngine(EngineConfig(
         groups=2, peers=3, data_dir=str(tmp_path), window=16, max_ents=4,
-        heartbeat_tick=3, fsync=False, request_timeout=15.0,
-        round_interval=0.0005))
+        heartbeat_tick=3, fsync=False, request_timeout=15.0))
     http = EngineHttp(eng, port=cp)
     eng.start()
     http.start()

@@ -169,8 +169,7 @@ def test_discovery_hosted_on_a_tenant_keyspace(tmp_path):
     (ep,) = free_ports(1)
     eng = MultiEngine(EngineConfig(
         groups=2, peers=3, data_dir=str(tmp_path / "eng"), window=16,
-        max_ents=4, heartbeat_tick=3, fsync=False, request_timeout=15.0,
-        round_interval=0.0005))
+        max_ents=4, heartbeat_tick=3, fsync=False, request_timeout=15.0))
     http = EngineHttp(eng, port=ep)
     eng.start()
     http.start()

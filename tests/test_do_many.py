@@ -232,7 +232,7 @@ def test_batch_http_route(tmp_path):
         except urllib.error.HTTPError as e:
             return e.code, json.loads(e.read() or b"null")
 
-    eng = make_engine(tmp_path, round_interval=0.001)
+    eng = make_engine(tmp_path)
     front = EngineHttp(eng)
     front.start()
     eng.start()
@@ -315,7 +315,7 @@ def test_batch_per_slot_auth(tmp_path):
         with urllib.request.urlopen(req, timeout=30) as r:
             return r.status, json.loads(r.read() or b"null")
 
-    eng = make_engine(tmp_path, round_interval=0.001)
+    eng = make_engine(tmp_path)
     front = EngineHttp(eng)
     front.start()
     eng.start()
@@ -404,7 +404,7 @@ def test_batchframe_route_and_wal_parity(tmp_path):
 
     d_frame, d_batch = tmp_path / "frame", tmp_path / "batch"
 
-    eng = make_engine(d_frame, round_interval=0.001)
+    eng = make_engine(d_frame)
     front = EngineHttp(eng)
     front.start()
     eng.start()
@@ -439,7 +439,7 @@ def test_batchframe_route_and_wal_parity(tmp_path):
         front.stop()
         eng.stop()
 
-    eng = make_engine(d_batch, round_interval=0.001)
+    eng = make_engine(d_batch)
     front = EngineHttp(eng)
     front.start()
     eng.start()
@@ -478,7 +478,7 @@ def test_batchframe_error_frame_and_handshake_refusals(tmp_path):
     from etcd_tpu.etcdhttp.tenants import EngineHttp
     from etcd_tpu.server import batchframe
 
-    eng = make_engine(tmp_path, round_interval=0.001)
+    eng = make_engine(tmp_path)
     front = EngineHttp(eng)
     front.start()
     eng.start()
@@ -528,7 +528,7 @@ def test_batchframe_sever_midflight_collects_staged_flushes(tmp_path):
     from etcd_tpu.server import batchframe
     from etcd_tpu.utils import metrics
 
-    eng = make_engine(tmp_path, round_interval=0.001)
+    eng = make_engine(tmp_path)
     front = EngineHttp(eng)
     front.start()
     eng.start()

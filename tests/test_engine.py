@@ -155,7 +155,7 @@ def test_engine_mask_watchdog_repairs_corrupt_device_mask(tmp_path):
 
 
 def test_engine_background_thread_serving(tmp_path):
-    eng = MultiEngine(make_cfg(tmp_path / "e2", round_interval=0.001))
+    eng = MultiEngine(make_cfg(tmp_path / "e2"))
     eng.start()
     try:
         assert eng.wait_leaders(60.0)
@@ -399,7 +399,7 @@ def test_engine_http_surface(tmp_path):
         except urllib.error.HTTPError as e:
             return e.code, json.loads(e.read() or b"null")
 
-    eng = MultiEngine(make_cfg(tmp_path / "e8", round_interval=0.001))
+    eng = MultiEngine(make_cfg(tmp_path / "e8"))
     front = EngineHttp(eng)
     front.start()
     eng.start()
@@ -1202,3 +1202,88 @@ def test_submit_pairs_refuses_a_bad_pair_alone(tmp_path):
     with pytest.raises(errors.EtcdError):
         eng.store(2).get("/a", False, False)    # tenant 2 got neither
     eng.stop()
+
+
+@pytest.mark.parametrize("compact", [None, False])
+def test_idle_engine_thread_waits_for_work_and_wakes_on_a_submit(
+        tmp_path, compact):
+    """The engine thread does not spin rounds that have nothing to do:
+    once every group has a leader and the mirrors are settled it waits
+    for whoever queues work (or for the idle tick, IDLE_TICK_S), a write
+    wakes it at once, and the rounds that carry the write to its commit
+    follow each other without a wait; whichever readback builds the
+    rounds' records."""
+    from etcd_tpu.server import engine as engine_mod
+    eng = MultiEngine(make_cfg(tmp_path / "idle", compact_readback=compact))
+    eng.start()
+    try:
+        assert eng.wait_leaders(120)
+        deadline = time.time() + 30
+        while not eng._idle() and time.time() < deadline:
+            time.sleep(0.01)
+        assert eng._idle()
+        r0, t0 = eng.round_no, time.monotonic()
+        time.sleep(1.0)
+        idle_rate = (eng.round_no - r0) / (time.monotonic() - t0)
+        # one round an idle tick, not one a millisecond
+        assert 2 <= idle_rate <= 2.0 / engine_mod.IDLE_TICK_S, idle_rate
+        took = []
+        for i in range(5):
+            time.sleep(0.02)        # inside an idle wait
+            t = time.monotonic()
+            ev = eng.do(0, Request(method="PUT", path="/k", val=f"v{i}"))
+            took.append(time.monotonic() - t)
+            assert ev.node.value == f"v{i}"
+        # woken by the submit, not by the 30 ms that were left of the tick
+        assert min(took) < 0.4 * engine_mod.IDLE_TICK_S, took
+    finally:
+        eng.stop()
+
+
+def test_a_write_waiting_on_a_lost_quorum_is_not_idled_on(tmp_path):
+    """An admitted entry that is not committed keeps the rounds coming at
+    round speed: the engine is idle only once every leader's log is
+    committed to its end, so a write held up by dropped traffic meets its
+    retransmits, step-downs and elections without a 50 ms wait a tick."""
+    import jax.numpy as jnp
+    from etcd_tpu.server import engine as engine_mod
+
+    eng = MultiEngine(make_cfg(tmp_path / "lostq"))
+    run_until(eng, lambda: eng._idle(), msg="a settled, idle engine")
+    G, P = eng.cfg.groups, eng.cfg.peers
+    cut = np.ones((G, P, P, 1), np.int32)
+    cut[0] = 0                      # group 0: nobody hears anybody
+    eng.drop_mask = jnp.asarray(cut)
+    t, out = put_async(eng, 0, "/held", "1")
+    s = eng.leader_slot(0)
+    run_until(eng, lambda: eng.h_last[0, s] > eng.h_commit[0, s],
+              msg="the write admitted at its leader")
+    # the next round changes nothing and stages nothing, and is no reason
+    # to wait: the entry is still on its way
+    quiet = 0
+    for _ in range(3):
+        eng.run_round()
+        quiet += eng._quiet
+        assert not eng._idle()
+    assert quiet, "no round under the partition journalled nothing"
+    assert t.is_alive()
+
+    # the same under the engine's own thread: rounds at round speed for
+    # as long as the quorum is lost (the held entry may be lost to the
+    # election that follows, as in the reference: its client times out);
+    # healed, the group serves again and the engine comes to rest
+    eng.start()
+    try:
+        r0, t0 = eng.round_no, time.monotonic()
+        time.sleep(0.5)
+        rate = (eng.round_no - r0) / (time.monotonic() - t0)
+        assert rate > 2.0 / engine_mod.IDLE_TICK_S, rate
+        eng.drop_mask = None
+        deadline = time.time() + 30
+        while not eng._idle() and time.time() < deadline:
+            time.sleep(0.01)
+        assert eng._idle()
+        ev = eng.do(0, Request(method="PUT", path="/healed", val="2"))
+        assert ev.node.value == "2"
+    finally:
+        eng.stop()

@@ -6,7 +6,8 @@ through elections, a leader-partition churn window, and the tiny-cap
 fallback, and in rounds that carry parked quorum reads (the read step
 returns the same diff). The compact path exists purely to cut readback
 bytes (O(changed rows) instead of O(G*P*W) per round — the ring alone is
-32 MB at G=100k); any behavioral difference is a bug."""
+32 MB at G=100k) and blocking reads (one a round: the device picks and
+packs the rows, kernel.gather_rows); any behavioral difference is a bug."""
 import os
 import queue
 import random
@@ -18,8 +19,10 @@ import pytest
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from etcd_tpu import errors  # noqa: E402
+from etcd_tpu.ops import kernel  # noqa: E402
 from etcd_tpu.server import obs  # noqa: E402
-from etcd_tpu.server.engine import EngineConfig, MultiEngine  # noqa: E402
+from etcd_tpu.server.engine import (EngineConfig, MultiEngine,  # noqa: E402
+                                    _bucket)
 from etcd_tpu.server.enginewal import EngineWAL  # noqa: E402
 from etcd_tpu.server.request import Request  # noqa: E402
 
@@ -249,29 +252,50 @@ def test_compact_restart_replays_identically(tmp_path):
     re.stop()
 
 
-def test_quiet_read_round_reads_back_flags_and_confirmation_only(tmp_path):
-    """A read round that changes no mirrored row moves the attestation,
-    the flag map and the read plane's two (G,) arrays to the host, in
-    four blocking reads, and nothing of the state (the d2h counters are
-    exact); its record comes from the compact path."""
+def _led(data_dir: str, groups: int = G, **kw) -> MultiEngine:
+    """An engine driven by hand to a leader in every group."""
     eng = MultiEngine(EngineConfig(
-        groups=G, peers=P, data_dir=str(tmp_path / "q"), window=W,
-        max_ents=E, fsync=False, sync_interval=0.0, mask_check_rounds=0,
-        checkpoint_rounds=1 << 30, pipeline_applies=False))
+        groups=groups, peers=P, data_dir=data_dir, window=W, max_ents=E,
+        fsync=False, sync_interval=0.0, mask_check_rounds=0,
+        checkpoint_rounds=1 << 30, pipeline_applies=False, **kw))
     for _ in range(400):
         eng.run_round()
-        if all(eng.leader_slot(g) >= 0 for g in range(G)):
-            break
-    q = eng.wait.register(1)
+        if all(eng.leader_slot(g) >= 0 for g in range(groups)):
+            return eng
+    raise AssertionError("no leaders")
+
+
+def _put(eng: MultiEngine, g: int, rid: int, val: str = "v"):
+    q = eng.wait.register(rid)
     with eng._lock:
-        eng._pending[3].append((1, bytes([0]) + Request(
-            method="PUT", path="/k", val="v", id=1).encode(), None))
-        eng._dirty.add(3)
+        eng._pending[g].append((rid, bytes([0]) + Request(
+            method="PUT", path="/k", val=val, id=rid).encode(), None))
+        eng._dirty.add(g)
+    return q
+
+
+def _buf_bytes(kp: int) -> int:
+    """gather_rows' packed buffer: a header row and kp rows of int32."""
+    return (1 + kp) * (kernel.ROW_RING + W) * 4
+
+
+def _mirrors(eng: MultiEngine) -> list:
+    return [getattr(eng, n).copy() for n in (
+        "h_term", "h_vote", "h_commit", "h_state", "h_last", "h_ring")]
+
+
+def test_quiet_read_round_reads_back_flags_and_confirmation_only(tmp_path):
+    """A read round that changes no mirrored row moves gather_rows'
+    buffer at the smallest bucket (the attestation and no row) and the
+    read plane's two (G,) arrays to the host, in three blocking reads,
+    and nothing of the state (the d2h counters are exact); its record
+    comes from the compact path."""
+    eng = _led(str(tmp_path / "q"))
+    q = _put(eng, 3, 1)
     for _ in range(20):         # commit indexes converge on every peer
         eng.run_round()
     q.get_nowait()
-    mirrors = [getattr(eng, n).copy() for n in (
-        "h_term", "h_vote", "h_commit", "h_state", "h_last", "h_ring")]
+    mirrors = _mirrors(eng)
 
     q = eng.wait.register(2)
     with eng._lock:
@@ -280,9 +304,9 @@ def test_quiet_read_round_reads_back_flags_and_confirmation_only(tmp_path):
     syncs, nbytes, kinds = obs.d2h_syncs.value, obs.d2h_bytes.value, _kinds()
     eng.run_round()
     assert q.get_nowait().node.value == "v"
-    assert obs.d2h_syncs.value - syncs == 4
-    # attestation + (G, P) uint8 flags + (G,) bool + (G,) int32
-    assert obs.d2h_bytes.value - nbytes == 1 + G * P + G + 4 * G
+    assert obs.d2h_syncs.value - syncs == 3
+    # the packed buffer + (G,) bool + (G,) int32
+    assert obs.d2h_bytes.value - nbytes == _buf_bytes(256) + G + 4 * G
     after = _kinds()
     assert {k: after[k] - kinds[k] for k in after} == {
         "compact": 1, "full": 0, "over_cap": 0}
@@ -290,3 +314,181 @@ def test_quiet_read_round_reads_back_flags_and_confirmation_only(tmp_path):
                           "h_last", "h_ring"), mirrors):
         assert np.array_equal(getattr(eng, name), was), name
     eng.stop()
+
+
+def test_write_round_makes_one_blocking_read(tmp_path):
+    """A round that admits writes reads the device once: gather_rows'
+    header and kp rows, nothing else; the rows it changed are in the
+    mirrors and the write is answered."""
+    eng = _led(str(tmp_path / "w"))
+    for _ in range(20):
+        eng.run_round()
+    qs = [_put(eng, g, 1 + g) for g in (2, 5, 11)]
+    syncs, nbytes, kinds = obs.d2h_syncs.value, obs.d2h_bytes.value, _kinds()
+    rebuckets = obs.gather_rebuckets.value
+    last = eng.h_last.copy()
+    eng.run_round()
+    assert obs.d2h_syncs.value - syncs == 1
+    assert obs.d2h_bytes.value - nbytes == _buf_bytes(256)
+    assert obs.gather_rebuckets.value == rebuckets
+    after = _kinds()
+    assert {k: after[k] - kinds[k] for k in after} == {
+        "compact": 1, "full": 0, "over_cap": 0}
+    # every peer of the three groups took the entry (hops=3), no other row
+    assert sorted(set(np.nonzero(eng.h_last != last)[0])) == [2, 5, 11]
+    assert eng._gather_ks[-1] == 3 * P
+    for q in qs:
+        assert q.get_nowait().action == "set"
+    eng.stop()
+
+
+def test_bucket_miss_reads_twice_and_builds_the_same_record(tmp_path):
+    """A round that picks more rows than the bucket gather_rows was
+    called with (K = 270 just over kp = 256: the rule is held to the
+    smallest bucket for that one round) makes the call and the read
+    again at the bucket that holds them, counts it once, and journals the
+    record the full readback journals."""
+    big = 100
+    full = _led(str(tmp_path / "f"), groups=big, compact_readback=False)
+    comp = _led(str(tmp_path / "c"), groups=big)
+    for eng in (full, comp):
+        for _ in range(20):
+            eng.run_round()
+    assert full.round_no == comp.round_no
+    waits = [[_put(eng, g, 1 + g, f"v{g}") for g in range(90)]
+             for eng in (full, comp)]
+    comp._gather_bucket = lambda n_staged: 256
+    syncs, nbytes = obs.d2h_syncs.value, obs.d2h_bytes.value
+    rebuckets, kinds = obs.gather_rebuckets.value, _kinds()
+    comp.run_round()
+    assert comp._gather_ks[-1] == 90 * P
+    assert obs.d2h_syncs.value - syncs == 2
+    assert obs.d2h_bytes.value - nbytes == _buf_bytes(256) + _buf_bytes(512)
+    assert obs.gather_rebuckets.value - rebuckets == 1
+    after = _kinds()
+    assert {k: after[k] - kinds[k] for k in after} == {
+        "compact": 1, "full": 0, "over_cap": 0}
+    del comp._gather_bucket
+    full.run_round()
+    for a, b in zip(_mirrors(full), _mirrors(comp)):
+        assert np.array_equal(a, b)
+    for qf, qc in zip(*waits):
+        got = _answer(qc)
+        assert got[0] == "set" and got == _answer(qf)
+    full.stop()
+    comp.stop()
+    _assert_same_records(_wal_records(str(tmp_path / "f")),
+                         _wal_records(str(tmp_path / "c")))
+
+
+def test_bucket_rule_follows_the_staged_groups_and_the_last_pick(tmp_path):
+    """The bucket is chosen before dispatch from the groups staged now
+    and in the last round and the most rows a compact round picked since
+    a heartbeat ago; a power of two >= 256, never past the cap's bucket."""
+    eng = _led(str(tmp_path / "b"), groups=2000)
+    for _ in range(20):
+        eng.run_round()
+    assert max(eng._gather_ks) == 0 and eng._staged_prev == 0
+    assert eng._gather_bucket(0) == 256
+    assert eng._gather_bucket(85) == 256            # 85 groups x 3 rows
+    assert eng._gather_bucket(86) == 512
+    eng._staged_prev = 86                           # their followers' rows
+    assert eng._gather_bucket(0) == 512
+    assert eng._gather_bucket(86) == 1024
+    eng._staged_prev = 0
+    eng._gather_ks.append(1500)                     # a catch-up, an election
+    assert eng._gather_bucket(0) == 2048
+    for _ in range(eng.cfg.heartbeat_tick):         # ... is remembered until
+        eng._gather_ks.append(0)                    # a heartbeat has passed
+        assert eng._gather_bucket(0) == 2048
+    eng._gather_ks.append(0)
+    assert eng._gather_bucket(0) == 256
+    eng._gather_ks.append(6000)                     # the last round overran
+    assert eng._compact_cap == 2048 and eng._gather_bucket(0) == 2048
+    assert eng._gather_buckets() == [256, 512, 1024, 2048]
+    eng.stop()
+
+
+def test_every_bucket_is_built_before_the_first_round(tmp_path):
+    """_warm_gather (the engine thread's first act) builds gather_rows at
+    every bucket on arguments placed as a round's are: rounds that then
+    meet each bucket add no program to the jit's cache."""
+    eng = MultiEngine(EngineConfig(
+        groups=200, peers=P, data_dir=str(tmp_path / "warm"), window=W,
+        max_ents=E, fsync=False, stagger=True, sync_interval=0.0,
+        checkpoint_rounds=1 << 30, pipeline_applies=False))
+    assert eng._gather_buckets() == [256, 512, 1024]
+    eng._warm_gather()
+    built = eng._gather_rows._cache_size()
+    assert built >= 3
+    for _ in range(80):
+        eng.run_round()
+    picked, rid = set(), 0
+    for every in (1, 2, 5):
+        for g in range(0, 200, every):
+            rid += 1
+            _put(eng, g, rid)
+        for _ in range(2):
+            eng.run_round()
+            picked.add(_bucket(eng._gather_ks[-1]))
+    assert picked == {256, 512, 1024}
+    assert eng._gather_rows._cache_size() == built
+    eng.stop()
+
+
+def _seeded_round(seed: int, groups: int, density: float):
+    """A state, a flag map and staged proposals drawn from `seed`."""
+    import jax.numpy as jnp
+    from etcd_tpu.ops.state import KernelConfig, init_state
+    rng = np.random.default_rng(seed)
+    st = init_state(KernelConfig(groups=groups, peers=P, window=W))
+    draw = lambda *shape: jnp.asarray(                      # noqa: E731
+        rng.integers(0, 1 << 20, shape), jnp.int32)
+    st = st._replace(term=draw(groups, P), vote=draw(groups, P),
+                     commit=draw(groups, P), state=draw(groups, P),
+                     last_index=draw(groups, P),
+                     log_term=draw(groups, P, W))
+    flags = ((rng.random((groups, P)) < density)
+             * rng.integers(1, 16, (groups, P))).astype(np.uint8)
+    count = ((rng.random(groups) < density)
+             * rng.integers(1, E + 1, groups)).astype(np.int32)
+    slot = rng.integers(0, P, groups).astype(np.int32)
+    return st, flags, count, slot
+
+
+@pytest.mark.parametrize("seed,groups,density", [
+    (1, G, 0.0), (2, G, 0.2), (3, G, 1.0), (4, 400, 0.02), (5, 400, 0.15),
+    (6, 400, 0.6), (7, 1000, 0.08)])
+def test_device_pick_is_the_hosts_union(seed, groups, density):
+    """gather_rows picks what the host used to: np.nonzero(flags) and the
+    staged leader rows, sorted, with their flags and values; the header
+    holds the attestation and the union's true size; rows past it (the
+    bucket's padding, or a bucket that is too small) say so."""
+    import jax.numpy as jnp
+    st, flags, count, slot = _seeded_round(seed, groups, density)
+    staged = np.flatnonzero(count)
+    lin = np.unique(np.concatenate(
+        [np.flatnonzero(flags.reshape(-1)), staged * P + slot[staged]]))
+    for kp, nh in ((256, False), (1024, True)):
+        buf = np.asarray(kernel.gather_rows(
+            st, jnp.asarray(flags), jnp.asarray(nh), jnp.asarray(count),
+            jnp.asarray(slot), kp))
+        assert buf.dtype == np.int32
+        assert buf.shape == (1 + kp, kernel.ROW_RING + W)
+        assert buf[0].tolist() == [int(nh), len(lin)] + [0] * (
+            buf.shape[1] - 2)
+        n = min(kp, len(lin))
+        rows, pad = buf[1:1 + n], buf[1 + n:]
+        assert np.array_equal(rows[:, kernel.ROW_LIN], lin[:n])
+        assert (pad[:, kernel.ROW_LIN] == groups * P).all()
+        g, p = np.divmod(lin[:n], P)
+        assert np.array_equal(rows[:, kernel.ROW_FLAGS], flags[g, p])
+        for col, name in ((kernel.ROW_TERM, "term"),
+                          (kernel.ROW_VOTE, "vote"),
+                          (kernel.ROW_COMMIT, "commit"),
+                          (kernel.ROW_STATE, "state"),
+                          (kernel.ROW_LAST, "last_index")):
+            assert np.array_equal(rows[:, col],
+                                  np.asarray(getattr(st, name))[g, p]), name
+        assert np.array_equal(rows[:, kernel.ROW_RING:],
+                              np.asarray(st.log_term)[g, p])

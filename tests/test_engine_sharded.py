@@ -209,7 +209,7 @@ def _drive(data_dir, mesh, compact, cap=0):
 
         def spy_gather(*a):
             out = gather(*a)
-            shardings["rows"].update(x.sharding for x in out)
+            shardings["rows"].add(out.sharding)
             return out
 
         eng._step_fn_c, eng._step_fn_r, eng._gather_rows = (

@@ -274,7 +274,6 @@ def test_engine_mode_serves_and_restarts(tmp_path):
     cfg = MainConfig()
     cfg.data_dir = str(tmp_path / "eng")
     cfg.engine_groups, cfg.engine_peers = 4, 3
-    cfg.engine_interval_ms = 1
     cfg.listen_client_urls = ("http://127.0.0.1:0",)
     s = EngineServer(cfg)
     s.start()
@@ -311,7 +310,7 @@ def test_engine_flag_ranges():
     for bad in (["--engine-groups", "-1"],
                 ["--engine-groups", "4", "--engine-peers", "0"],
                 ["--engine-groups", "4", "--engine-window", "2"],
-                ["--engine-groups", "4", "--engine-interval-ms", "-1"]):
+                ["--engine-groups", "4", "--engine-mesh-peers-axis", "-1"]):
         with pytest.raises(ConfigError):
             parse_args(bad)
 
@@ -351,7 +350,6 @@ def test_engine_member_sizes_the_young_generations(tmp_path):
     cfg = MainConfig()
     cfg.data_dir = str(tmp_path / "gceng")
     cfg.engine_groups, cfg.engine_peers = 4, 3
-    cfg.engine_interval_ms = 1
     cfg.listen_client_urls = ("http://127.0.0.1:0",)
     was = gc.get_threshold()
     try:
@@ -377,7 +375,6 @@ def test_engine_mesh_flag_serves(tmp_path):
     cfg = MainConfig()
     cfg.data_dir = str(tmp_path / "mesheng")
     cfg.engine_groups, cfg.engine_peers = 8, 4
-    cfg.engine_interval_ms = 1
     cfg.engine_mesh_peers_axis = 2
     cfg.listen_client_urls = ("http://127.0.0.1:0",)
     s = EngineServer(cfg)

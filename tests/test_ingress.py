@@ -51,7 +51,7 @@ class stack:
 
     def __init__(self, tmp, **ingress_kw):
         from etcd_tpu.etcdhttp.tenants import EngineHttp
-        self.eng = make_engine(tmp, round_interval=0.001)
+        self.eng = make_engine(tmp)
         self.front = EngineHttp(self.eng)
         self.front.start()
         self.eng.start()
@@ -196,7 +196,7 @@ def test_sigkill_loses_no_acked_write(tmp_path):
     import http.client
 
     from etcd_tpu.etcdhttp.tenants import EngineHttp
-    eng = make_engine(tmp_path, round_interval=0.001)
+    eng = make_engine(tmp_path)
     front = EngineHttp(eng)
     front.start()
     eng.start()
@@ -600,7 +600,7 @@ def test_many_connections_fd_smoke(tmp_path):
     writes."""
     from etcd_tpu.etcdhttp.tenants import EngineHttp
     N = int(os.environ.get("INGRESS_SMOKE_CONNS", "10000"))
-    eng = make_engine(tmp_path, round_interval=0.001)
+    eng = make_engine(tmp_path)
     front = EngineHttp(eng)
     front.start()
     eng.start()

@@ -80,8 +80,7 @@ def test_engine_concurrent_clients_no_lost_updates(tmp_path):
     event must carry a unique modifiedIndex per tenant."""
     eng = MultiEngine(EngineConfig(
         groups=4, peers=5, data_dir=str(tmp_path / "race"), window=16,
-        max_ents=4, heartbeat_tick=3, request_timeout=60.0, fsync=False,
-        round_interval=0.0))
+        max_ents=4, heartbeat_tick=3, request_timeout=60.0, fsync=False))
     eng.start()
     acked = {}           # key -> (group, modifiedIndex)
     failures = []
@@ -176,7 +175,7 @@ def test_engine_lazy_store_creation_race(tmp_path):
     eng = MultiEngine(EngineConfig(
         groups=8, peers=3, data_dir=str(tmp_path / "lazy"), window=16,
         max_ents=4, heartbeat_tick=3, request_timeout=60.0, fsync=False,
-        round_interval=0.0, initial_peers=3))
+        initial_peers=3))
     eng.start()
     try:
         assert eng.wait_leaders(60.0)

@@ -29,7 +29,7 @@ from jax.sharding import Mesh, SingleDeviceSharding
 
 from etcd_tpu.ops import kernel
 from etcd_tpu.ops.state import KernelConfig, init_state
-from etcd_tpu.server.engine import _named_partial
+from etcd_tpu.server.engine import _named_partial, mesh_gather_rows
 
 P, W, HOPS = 5, 32, 3          # BASELINE.json config 4 peers; CLI window/hops
 VARIANTS = ("step_routed_auto", "step_routed_compact",
@@ -183,34 +183,55 @@ def test_mesh_read_variant_compiles_for_v5e_2x2(topo, as_served):
     """The read step's collectives are what they were before it returned
     the flag map (the quiet predicate's scalar, one per hop: the
     ReadIndex tally is per group) plus the attestation's scalar: nothing
-    gathers the flag map or the (G,) confirmations."""
+    gathers the flag map or the (G,) confirmations (gather_rows does,
+    in its own program)."""
     _check_mesh(_compile_mesh(topo, 4, "step_routed_read_auto"), HOPS + 1)
 
 
 def _compile_mesh_gather(topo, G: int, K: int):
-    """The engine's mesh row gather (engine.py: gather_rows' body, rows
-    replicated) over a state sharded on four devices."""
-    from etcd_tpu.parallel.mesh import replicated_sharding, state_sharding
+    """The engine's mesh row gather (engine.mesh_gather_rows: gather_rows'
+    body, the packed buffer replicated) over a state sharded on four
+    devices, the flag map as the compact step leaves it."""
+    from etcd_tpu.parallel.mesh import (flag_sharding, replicated_sharding,
+                                        state_sharding)
     cfg = KernelConfig(groups=G, peers=P, window=W)
     mesh = _mesh4(topo)
     st_sh, rep = state_sharding(mesh), replicated_sharding(mesh)
-    st = _shapes(cfg, st_sh, rep, rep)[0]
-    idx = jax.ShapeDtypeStruct((K,), jnp.int32, sharding=rep)
-    fn = jax.jit(kernel.gather_rows.__wrapped__, out_shardings=rep)
-    return fn.lower(st, idx, idx).compile()
+    st, _, pc, ps, attest = _shapes(cfg, st_sh, rep, rep)
+    flags = jax.ShapeDtypeStruct((G, P), jnp.uint8,
+                                 sharding=flag_sharding(mesh))
+    return mesh_gather_rows(rep).lower(st, flags, attest, pc, ps,
+                                       K).compile()
 
 
 def _check_mesh_gather(compiled, G: int, K: int) -> None:
-    """Every chip gathers from the rows it holds and ONE all-reduce of the
-    K gathered rows brings them together: no all-gather of the sharded
-    state (the ring alone is G*P*W*4 bytes), which is what would make the
-    compact path cost more than the full readback it replaces."""
-    reduces = _collectives(compiled)
+    """ONE all-gather brings the (G, P) uint8 flag map's shards together
+    for the pick, every chip gathers from the rows it holds and ONE
+    all-reduce of the K gathered rows brings them together: nothing of
+    the pick's sums and searches crosses the chips, and no all-gather of
+    the sharded state (the ring alone is G*P*W*4 bytes), which is what
+    would make the compact path cost more than the full readback it
+    replaces."""
+    text = compiled.as_text()
+    for op in ("all-to-all", "collective-permute", "reduce-scatter"):
+        assert f" {op}(" not in text and f" {op}-start(" not in text, op
+    gathers = [ln for ln in text.splitlines()
+               if " all-gather(" in ln or " all-gather-start(" in ln]
+    assert len(gathers) <= 1, gathers
+    assert all(f"u8[4,{G // 4},{P}]" in ln for ln in gathers), gathers
+    reduces = [ln for ln in text.splitlines()
+               if " all-reduce(" in ln or " all-reduce-start(" in ln]
     assert len(reduces) == 1, reduces
     assert f"s32[{K},{W}]" in reduces[0] and f"[{G}" not in reduces[0]
+    # Temp memory within a small factor of what the program works on: the
+    # flag map's bytes, the pick's (K, P) int32 and the K packed rows
+    # (a fixed 0.6 MB up to K = 4,096 and 6.6 x at G = 50,000,
+    # K = 32,768, rows padded to the lanes), so the ring's 32 MB gathered
+    # there fails it at every bucket.
     ma = compiled.memory_analysis()
-    assert ma.output_size_in_bytes < 8 * K * (W + 5) and \
-        ma.temp_size_in_bytes < 8 * K * (W + 5)
+    need = G * P + 4 * K * P + 4 * K * (W + 7)
+    assert ma.output_size_in_bytes < 8 * K * (W + 7) and \
+        ma.temp_size_in_bytes < (1 << 20) + 8 * need
 
 
 def test_mesh_gather_rows_compiles_for_v5e_2x2(topo, as_served):

@@ -48,8 +48,7 @@ def member(tmp_path_factory, request):
     (cp,) = free_ports(1)
     eng = MultiEngine(EngineConfig(
         groups=4, peers=3, data_dir=str(tmp / "eng"), window=16,
-        max_ents=4, heartbeat_tick=3, fsync=False, request_timeout=15.0,
-        round_interval=0.0005))
+        max_ents=4, heartbeat_tick=3, fsync=False, request_timeout=15.0))
     http = EngineHttp(eng, port=cp)
     eng.start()
     http.start()
