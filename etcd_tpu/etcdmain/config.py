@@ -106,6 +106,11 @@ class MainConfig:
     engine_lag_share: float = 0.0
     engine_lag_hold_rounds: int = 256
     engine_lag_seed: int = 0
+    # Leader-election churn (server/lag.py ChurnSchedule): fault injection
+    # for measurement, off at 0 down rounds.
+    engine_churn_down_rounds: int = 0
+    engine_churn_period_rounds: int = 512
+    engine_churn_seed: int = 0
 
     @property
     def is_proxy(self) -> bool:
@@ -208,6 +213,17 @@ _FLAGS = [
     ("engine-lag-seed", int, 0,
      "Seed of the -engine-lag-share schedule: which follower of which "
      "group is held in which round"),
+    ("engine-churn-down-rounds", int, 0,
+     "Fault injection for measurement: cut every group's working leader "
+     "off from its peers, both ways, for this many rounds of every "
+     "-engine-churn-period-rounds, at most one slot a group (the others "
+     "elect a successor; quorum is never at risk). 0 = off"),
+    ("engine-churn-period-rounds", int, 512,
+     "Rounds from one cut of a group's leader to the next under "
+     "-engine-churn-down-rounds"),
+    ("engine-churn-seed", int, 0,
+     "Seed of the -engine-churn-down-rounds schedule: which group's cut "
+     "begins in which round"),
 ]
 
 
@@ -318,6 +334,22 @@ def parse_args(argv: Sequence[str],
                     "group is held)")
             if cfg.engine_lag_hold_rounds < 1:
                 raise ConfigError("-engine-lag-hold-rounds must be >= 1")
+        if cfg.engine_churn_down_rounds:
+            if cfg.engine_peers < 3:
+                raise ConfigError(
+                    "-engine-churn-down-rounds needs -engine-peers >= 3: "
+                    "cutting off a slot of a smaller group puts its quorum "
+                    "at risk")
+            if cfg.engine_lag_share:
+                raise ConfigError(
+                    "-engine-churn-down-rounds and -engine-lag-share are "
+                    "mutually exclusive: together they could take two slots "
+                    "of one group out at once")
+            if not (1 <= cfg.engine_churn_down_rounds
+                    < cfg.engine_churn_period_rounds):
+                raise ConfigError(
+                    "-engine-churn-down-rounds must be between 1 and "
+                    "-engine-churn-period-rounds - 1")
     if 5 * cfg.heartbeat_interval > cfg.election_timeout:
         raise ConfigError(
             f"-election-timeout[{cfg.election_timeout}ms] should be at least "

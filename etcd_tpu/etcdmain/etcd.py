@@ -165,6 +165,9 @@ class EngineServer:
             lag_share=cfg.engine_lag_share,
             lag_hold_rounds=cfg.engine_lag_hold_rounds,
             lag_seed=cfg.engine_lag_seed,
+            churn_down_rounds=cfg.engine_churn_down_rounds,
+            churn_period_rounds=cfg.engine_churn_period_rounds,
+            churn_seed=cfg.engine_churn_seed,
             mesh=mesh))
         client_tls = TLSInfo(cert_file=cfg.cert_file, key_file=cfg.key_file,
                              ca_file=cfg.ca_file,

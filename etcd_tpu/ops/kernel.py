@@ -571,7 +571,7 @@ def _quorum_commit(st: GroupState, cfg: KernelConfig, active: jax.Array,
 
 def _assemble_sends(st: GroupState, cfg: KernelConfig, resp: jax.Array,
                     hb_fire_term: jax.Array, vote_fire_term: jax.Array,
-                    active: jax.Array, hold=None
+                    active: jax.Array, hold=None, down=None
                     ) -> Tuple[GroupState, jax.Array]:
     """Build the outbox (G, P_from, P_to, F) and apply optimistic progress
     updates for sent appends. `hold` (G, P) bool names HELD target slots
@@ -579,7 +579,10 @@ def _assemble_sends(st: GroupState, cfg: KernelConfig, resp: jax.Array,
     sends a held slot no append and raises no snapshot for it, however far
     behind it falls; heartbeats, votes and responses are not gated, so the
     held follower keeps its leader and its term. None (the default) leaves
-    the program as it is without the hold."""
+    the program as it is without the hold. `down` (G, P) bool names slots
+    cut off from their peers (their messages are dropped after the hop,
+    down_drop_mask): a snapshot is a message too, so none is raised to or by a down
+    slot; everything else is assembled as if the link were up, and lost."""
     G, P = st.term.shape
     F = cfg.fields
     E = cfg.max_ents
@@ -611,6 +614,8 @@ def _assemble_sends(st: GroupState, cfg: KernelConfig, resp: jax.Array,
         # Without this gate a held follower of a busy group would be
         # installed every W entries DURING its hold and never lag.
         need_snap = need_snap & ~held
+    if down is not None:
+        need_snap = need_snap & ~down[:, None, :] & ~down[:, :, None]
     st = st._replace(need_host=_flag(st.need_host,
                                      jnp.any(need_snap, axis=2), NH_SNAP))
 
@@ -880,7 +885,7 @@ def _quiet_msgs(st: GroupState, cfg: KernelConfig, inbox: jax.Array,
 def _step_body(cfg: KernelConfig, st: GroupState, inbox: jax.Array,
                prop_count: jax.Array, prop_slot: Optional[jax.Array],
                tick: jax.Array, quiet: bool,
-               force_hb: bool = False, hold=None
+               force_hb: bool = False, hold=None, down=None
                ) -> Tuple[GroupState, jax.Array]:
     """Shared round skeleton; `quiet` (Python bool, traced twice under the
     cond) selects the message-phase implementation. prop_slot=None selects
@@ -923,7 +928,7 @@ def _step_body(cfg: KernelConfig, st: GroupState, inbox: jax.Array,
         st = _quorum_commit(st, cfg, active, lead_term0)
     with jax.named_scope("etcd.assemble_sends"):
         st, outbox = _assemble_sends(st, cfg, resp, hb_fire, vote_fire,
-                                     active, hold)
+                                     active, hold, down)
     bad = active & (st.commit > st.last_index)
     st = st._replace(need_host=_flag(st.need_host, bad, NH_VIOLATION))
     return st, outbox
@@ -933,7 +938,7 @@ def _step_body(cfg: KernelConfig, st: GroupState, inbox: jax.Array,
 def step_routed_auto(cfg: KernelConfig, st: GroupState, inbox: jax.Array,
                      prop_count: jax.Array, prop_slot: jax.Array,
                      tick: jax.Array, drop_mask=None,
-                     hops: int = 1, hold=None
+                     hops: int = 1, hold=None, down=None
                      ) -> Tuple[GroupState, jax.Array]:
     """step + route_local with on-device fast-path selection: quiescent
     rounds (the steady-state common case) skip the P sequential message
@@ -953,7 +958,10 @@ def step_routed_auto(cfg: KernelConfig, st: GroupState, inbox: jax.Array,
     fault-injection (partitions, message drops) hop-accurate. `hold`
     (G, P) bool, the held follower slots of _assemble_sends, applies on
     every hop too; like drop_mask it is no argument of the program when
-    None."""
+    None. `down` (G, P) bool, the slots cut off from their peers (leader-
+    election churn, server/lag.py): every message to and from a down slot
+    is dropped after every hop, as the drop_mask built from it would, and
+    no snapshot is raised for one; no argument of the program when None."""
     for h in range(hops):
         pc = prop_count if h == 0 else jnp.zeros_like(prop_count)
         tk = tick if h == 0 else jnp.asarray(False)
@@ -963,13 +971,13 @@ def step_routed_auto(cfg: KernelConfig, st: GroupState, inbox: jax.Array,
         def fast(ops):
             st, inbox, pc, ps, tick = ops
             s, out = _step_body(cfg, st, inbox, pc, ps, tick, quiet=True,
-                                hold=hold)
+                                hold=hold, down=down)
             return s, route_local(out)
 
         def full(ops):
             st, inbox, pc, ps, tick = ops
             s, out = _step_body(cfg, st, inbox, pc, ps, tick, quiet=False,
-                                hold=hold)
+                                hold=hold, down=down)
             return s, route_local(out)
 
         with jax.named_scope(f"etcd.hop{h}"):
@@ -977,7 +985,16 @@ def step_routed_auto(cfg: KernelConfig, st: GroupState, inbox: jax.Array,
                                      (st, inbox, pc, prop_slot, tk))
         if drop_mask is not None:
             inbox = inbox * drop_mask
+        if down is not None:
+            inbox = inbox * down_drop_mask(down)
     return st, inbox
+
+
+def down_drop_mask(down: jax.Array) -> jax.Array:
+    """The (G, P_to, P_from, 1) int32 drop_mask that cuts every `down`
+    (G, P) slot off from its peers, both ways."""
+    up = ~down
+    return (up[:, :, None] & up[:, None, :])[..., None].astype(jnp.int32)
 
 
 def route_local(outbox: jax.Array) -> jax.Array:
@@ -1029,7 +1046,8 @@ def _read_register(st: GroupState, cfg: KernelConfig
 def step_routed_read_auto(cfg: KernelConfig, st: GroupState,
                           inbox: jax.Array, prop_count: jax.Array,
                           prop_slot: jax.Array, tick: jax.Array,
-                          drop_mask=None, hops: int = 1, hold=None
+                          drop_mask=None, hops: int = 1, hold=None,
+                          down=None
                           ) -> Tuple[GroupState, jax.Array, jax.Array,
                                      jax.Array, jax.Array, jax.Array]:
     """step_routed_auto plus a batched ReadIndex pass: returns
@@ -1079,13 +1097,13 @@ def step_routed_read_auto(cfg: KernelConfig, st: GroupState,
         def fast(ops, _h=h):
             st, inbox, pc, ps, tick = ops
             s, out = _step_body(cfg, st, inbox, pc, ps, tick, quiet=True,
-                                force_hb=(_h == 0), hold=hold)
+                                force_hb=(_h == 0), hold=hold, down=down)
             return s, route_local(out)
 
         def full(ops, _h=h):
             st, inbox, pc, ps, tick = ops
             s, out = _step_body(cfg, st, inbox, pc, ps, tick, quiet=False,
-                                force_hb=(_h == 0), hold=hold)
+                                force_hb=(_h == 0), hold=hold, down=down)
             return s, route_local(out)
 
         with jax.named_scope(f"etcd.hop{h}"):
@@ -1093,6 +1111,8 @@ def step_routed_read_auto(cfg: KernelConfig, st: GroupState,
                                      (st, inbox, pc, prop_slot, tk))
         if drop_mask is not None:
             inbox = inbox * drop_mask
+        if down is not None:
+            inbox = inbox * down_drop_mask(down)
         # Messages routed to the registered leader slot this hop.
         to_lead = jnp.sum(
             inbox * oh_lead[:, :, None, None].astype(jnp.int32),
@@ -1137,7 +1157,7 @@ def _compact_flags(st0: GroupState, st: GroupState
 def step_routed_compact(cfg: KernelConfig, st: GroupState, inbox: jax.Array,
                         prop_count: jax.Array, prop_slot: jax.Array,
                         tick: jax.Array, drop_mask=None, hops: int = 1,
-                        hold=None
+                        hold=None, down=None
                         ) -> Tuple[GroupState, jax.Array, jax.Array,
                                    jax.Array]:
     """step_routed_auto plus an ON-DEVICE state diff: returns (st, inbox,
@@ -1164,7 +1184,8 @@ def step_routed_compact(cfg: KernelConfig, st: GroupState, inbox: jax.Array,
     anyway)."""
     st0 = st
     st, inbox = step_routed_auto.__wrapped__(
-        cfg, st, inbox, prop_count, prop_slot, tick, drop_mask, hops, hold)
+        cfg, st, inbox, prop_count, prop_slot, tick, drop_mask, hops, hold,
+        down)
     return (st, inbox) + _compact_flags(st0, st)
 
 

@@ -65,7 +65,7 @@ from etcd_tpu import errors
 from etcd_tpu.server import obs as obs_mod
 from etcd_tpu.server.enginewal import (CONF_ADD, CONF_REMOVE, EngineWAL,
                                        RoundRecord, b64_np, np_b64)
-from etcd_tpu.server.lag import LagSchedule
+from etcd_tpu.server.lag import ChurnSchedule, LagSchedule
 from etcd_tpu.server.walwriter import WALWriter
 from etcd_tpu.utils import metrics
 from etcd_tpu.server.request import (METHOD_DELETE, METHOD_GET, METHOD_POST,
@@ -332,6 +332,17 @@ class EngineConfig:
     lag_share: float = 0.0
     lag_hold_rounds: int = 256
     lag_seed: int = 0
+    # Leader-election churn (server/lag.py ChurnSchedule; fault injection
+    # for measurement, OFF by default: BASELINE.json configs[4]'s "leader-
+    # election churn + snapshot install mixed in"). churn_down_rounds > 0
+    # cuts every group's working leader off from its peers, both ways, for
+    # that many of every churn_period_rounds rounds, the groups' phases
+    # spread over the period by churn_seed. The down slots ride into the
+    # step like the hold: 0 = no such argument, today's programs. Not
+    # together with lag_share (two slots of one group could be out).
+    churn_down_rounds: int = 0
+    churn_period_rounds: int = 512
+    churn_seed: int = 0
 
 
 class _AckCounter:
@@ -466,8 +477,9 @@ class MultiEngine:
                     donate_argnums=kernel.donate_safe((0, 1)),
                     out_shardings=(self._st_sh, self._mb_sh,
                                    *extra_out[name]))
-                return lambda st, inbox, pc, ps, t, hold: fn(
-                    st, inbox, pc, ps, t, self.drop_mask, hold=hold)
+                return lambda st, inbox, pc, ps, t, hold, down=None: fn(
+                    st, inbox, pc, ps, t, self.drop_mask, hold=hold,
+                    **({} if down is None else {"down": down}))
 
             self._gather_rows = mesh_gather_rows(rep)
         else:
@@ -476,9 +488,9 @@ class MultiEngine:
                 # has a donated-buffer race, see kernel.py "CPU donation
                 # hazard"); donation stays on TPU.
                 fn = kernel.step_variant(name)
-                return lambda st, inbox, pc, ps, t, hold: fn(
+                return lambda st, inbox, pc, ps, t, hold, down=None: fn(
                     self.kcfg, st, inbox, pc, ps, t, self.drop_mask,
-                    self.cfg.hops, hold)
+                    self.cfg.hops, hold, *(() if down is None else (down,)))
 
             self._gather_rows = kernel.gather_rows
         self._step_fn = step_fn("step_routed_auto")
@@ -518,6 +530,32 @@ class MultiEngine:
                                  cfg.lag_seed) if cfg.lag_share else None)
         self._lag_held = np.zeros((G, P), bool)
         self.snap_installs = 0
+        # Leader-election churn (EngineConfig.churn_down_rounds): the
+        # schedule, the slots cut off now (host map and its device copy,
+        # uploaded again only when a cut began or ended) and the cuts begun.
+        if cfg.churn_down_rounds and cfg.lag_share:
+            raise ValueError(
+                "churn_down_rounds and lag_share together could take two "
+                "slots of one group out at once: set one of them")
+        self._churn = (ChurnSchedule(G, P, cfg.churn_down_rounds,
+                                     cfg.churn_period_rounds, cfg.churn_seed)
+                       if cfg.churn_down_rounds else None)
+        self._down = np.zeros((G, P), bool)
+        self._down_d = None
+        self.churn_cuts = 0
+        # Leader changes as the mirrors show them: when each group's
+        # routable leader last changed (perf_counter; a write older than
+        # that waited for this leader), and the groups in which an entry a
+        # deposed leader admitted may have been overwritten
+        # (_repropose_lost).
+        self._lead_since = np.zeros(G, np.float64)
+        self._resolve: set = set()
+        # The groups that show more than one active LEADER row (kept where
+        # the mirrors change role, _leaders_moved): only there does the
+        # first LEADER row differ from the routable one, so staging picks
+        # as it always did and looks again at these alone.
+        self._multi_lead: set = set()
+        self.reproposed = 0
 
         # Geometry guard BEFORE anything touches the data dir: a mismatch
         # must refuse the dir before the WAL opens/creates any file in it.
@@ -631,7 +669,8 @@ class MultiEngine:
         # of an admitted entry, so the apply loop skips re-parsing JSON it
         # produced moments ago (restart replay decodes from bytes). Popped
         # at apply; GC'd with the payload store.
-        self.payload_reqs: Dict[Tuple[int, int, int], list] = {}
+        # Value: (the Requests, the enqueue time of the oldest).
+        self.payload_reqs: Dict[Tuple[int, int, int], tuple] = {}
 
         ckpt_round, ckpt = self.wal.load_checkpoint()
         # Full consumption also positions the writer (next segment seq) and
@@ -643,6 +682,11 @@ class MultiEngine:
             self.st = init_state(self.kcfg, n_peers=self._boot_peers(),
                                  stagger=cfg.stagger)
             self.h_mask = np.asarray(self.st.peer_mask).copy()
+        if self._churn is not None and self.round_no:
+            # Restarted inside some groups' cuts: who was cut off is told
+            # from the journalled terms and votes (ChurnSchedule.recover).
+            self._down = self._churn.recover(
+                self.round_no - 1, self.h_mask, self.h_term, self.h_vote)
         if self._st_sh is not None:
             from etcd_tpu.parallel.mesh import shard_state
             self.st = shard_state(self.st, cfg.mesh)
@@ -810,6 +854,9 @@ class MultiEngine:
         last_round = ckpt_round
         for rec in recs:
             last_round = max(last_round, rec.round_no)
+            # (what each slot whose log end moves had committed before)
+            commit_was = self.h_commit[rec.last_g.astype(np.int64),
+                                       rec.last_p.astype(np.int64)].tolist()
             gi = rec.hs_g.astype(np.int64)
             pi = rec.hs_p.astype(np.int64)
             self.h_term[gi, pi] = rec.hs_term
@@ -823,13 +870,24 @@ class MultiEngine:
             for g, p, i, t in zip(rec.ring_g, rec.ring_p, rec.ring_i,
                                   rec.ring_t):
                 _log_set(g, p, i, t)
-            for g, p, new in zip(rec.last_g.astype(np.int64),
-                                 rec.last_p.astype(np.int64),
-                                 rec.last_v.astype(np.int64)):
+            for g, p, new, c_was in zip(rec.last_g.astype(np.int64),
+                                        rec.last_p.astype(np.int64),
+                                        rec.last_v.astype(np.int64),
+                                        commit_was):
                 prev = int(self.h_last[g, p])
                 self.h_last[g, p] = new
                 for i in range(max(prev + 1, int(new) - W + 1), int(new) + 1):
                     _log_set(g, p, i, self.h_ring[g, p, i % W])
+                if int(new) - W > c_was:
+                    # The log's end passed W beyond what this slot had
+                    # committed: a snapshot install put it there (appends
+                    # move commit along). What the slot held uncommitted
+                    # below the installed window (a deposed leader's lost
+                    # tail) was replaced unseen, not verified: it is no
+                    # evidence of the committed term at those indices.
+                    log_gp = slot_log.get((int(g), int(p)), {})
+                    for i in [i for i in log_gp if c_was < i <= new - W]:
+                        del log_gp[i]
             for g, i, t, payload in rec.entries:
                 self.payloads[(g, i, t)] = payload
             for g, slot, op in rec.confs:
@@ -1109,13 +1167,25 @@ class MultiEngine:
         return s
 
     def leader_slot(self, g: int) -> int:
-        """The group's current leader slot, or -1. Only ACTIVE slots count —
+        """The group's current leader slot (of the highest term, where a
+        deposed leader has not heard of its successor yet), or -1. Only
+        ACTIVE slots count —
         a just-removed slot's device row freezes in whatever state it held
         (reference removed-member tombstones make its traffic inert the same
         way, server.go:387-391)."""
-        row = np.where(self.h_mask[g], self.h_state[g], 0)
-        idx = np.nonzero(row == _LEADER)[0]
-        return int(idx[0]) if len(idx) else -1
+        slot, term = self._leaders(slice(g, g + 1))
+        return int(slot[0]) if term[0] > 0 else -1
+
+    def _leaders(self, gs=slice(None)) -> Tuple[np.ndarray, np.ndarray]:
+        """(slot, term) of the routable leader of each group in `gs` (all
+        by default): the active LEADER row of the HIGHEST term, term 0
+        where there is none. A leader cut off from its peers stays LEADER
+        in its old term until it hears a higher one (no check-quorum), so
+        a group can show two (_multi_lead); the device's read plane
+        registers at the same row (kernel._read_register)."""
+        lt = np.where(self.h_mask[gs] & (self.h_state[gs] == _LEADER),
+                      self.h_term[gs], 0)
+        return lt.argmax(axis=1), lt.max(axis=1)
 
     def wait_leaders(self, timeout: float = 30.0, groups=None) -> bool:
         """Block until every (requested) PROVISIONED group has a leader —
@@ -1992,6 +2062,11 @@ class MultiEngine:
                 self._last_sync_scan = now
                 self._stage_syncs(now)
 
+        # -- 0b. entries a deposed leader admitted and the committed log
+        # has overwritten go back to their queues (only after an election)
+        if self._resolve:
+            self._repropose_lost()
+
         # -- 1. stage proposals at known leaders --------------------------
         prop_count = np.zeros(G, np.int32)
         prop_slot = np.zeros(G, np.int32)
@@ -2005,6 +2080,13 @@ class MultiEngine:
                              == _LEADER)
                 has_lead = lead_rows.any(axis=1).tolist()
                 lead_slots = lead_rows.argmax(axis=1).tolist()
+                # Where a group shows two LEADER rows the first is not
+                # the one: the row of the HIGHEST term is (a deposed leader
+                # that has not heard of its successor gets nothing).
+                two = [g for g in self._dirty if g in self._multi_lead]
+                if two:
+                    for g, s in zip(two, self._leaders(two)[0].tolist()):
+                        lead_slots[g] = s
             B = self.cfg.batch_max
             for g in list(self._dirty):
                 dq = self._pending[g]
@@ -2064,10 +2146,11 @@ class MultiEngine:
                 if waited:
                     # Queue wait of each request staged this round (items
                     # enqueued by do()/submit_many carry their time; a
-                    # requeued item lost it, so each counts once).
+                    # requeued item carries it negated, so each counts
+                    # once).
                     for items in ents:
                         for it in items:
-                            if len(it) > 3:
+                            if len(it) > 3 and it[3] > 0:
                                 waited(t_staged - it[3])
             staged_gs = np.asarray(gs_l, np.int64)
             staged_ss = np.asarray(ss_l, np.int64)
@@ -2092,6 +2175,7 @@ class MultiEngine:
 
         # -- 1c. lagging-follower injection: this round's held slots
         hold = self._lag_hold() if self._lag is not None else None
+        down = self._churn_down() if self._churn is not None else None
 
         if o:
             t_ph = time.perf_counter()
@@ -2110,15 +2194,15 @@ class MultiEngine:
             # and its step returns the same on-device diff as the
             # compact step's: one record builder serves both.
             st, inbox, conf_d, rc_d, f_d, a_d = self._step_fn_r(
-                self.st, self.inbox, pc_d, ps_d, tick, hold)
+                self.st, self.inbox, pc_d, ps_d, tick, hold, down)
             if self._compact:
                 flags_d, anh_d = f_d, a_d
         elif self._compact:
             st, inbox, flags_d, anh_d = self._step_fn_c(
-                self.st, self.inbox, pc_d, ps_d, tick, hold)
+                self.st, self.inbox, pc_d, ps_d, tick, hold, down)
         else:
             st, inbox = self._step_fn(
-                self.st, self.inbox, pc_d, ps_d, tick, hold)
+                self.st, self.inbox, pc_d, ps_d, tick, hold, down)
         self.st = st
         self.inbox = inbox
         # The compact round's one readback, enqueued right behind the
@@ -2257,9 +2341,13 @@ class MultiEngine:
                 self._admit_staged(rec, adm_l, t_gs.tolist(),
                                    self.h_last[gs, ss].tolist())
 
+            gs_role = np.nonzero((state != self.h_state).any(axis=1))[0]
+            led = self._leaders(gs_role) if len(gs_role) else None
             self.h_term, self.h_vote, self.h_commit = term, vote, commit
             self.h_state, self.h_last, self.h_ring = state, last, ring
             self._force_full = False   # mirrors == device state again
+            if led is not None:
+                self._leaders_moved(gs_role, led)
             if o:
                 t_now = time.perf_counter()
                 d_record += t_now - t_ph
@@ -2381,6 +2469,10 @@ class MultiEngine:
             t0 = time.perf_counter() if o else 0.0
             self._drain_applies()    # checkpoint state must be consistent
             self._checkpoint()
+            if self._resolve:
+                # (the GC below drops what the groups have applied past:
+                # first take back what was lost there, not applied)
+                self._repropose_lost()
             self._gc_payloads()
             if o:
                 o.h_checkpoint.observe(time.perf_counter() - t0)
@@ -2403,6 +2495,142 @@ class MultiEngine:
         self._lag_held = held
         return self._dev("state", held)
 
+    def _churn_down(self):
+        """This round's down slots (server/lag.py ChurnSchedule), on the
+        device, sharded like the state's (G, P) fields on a mesh: the cuts
+        that ended come up, and each group whose cut begins loses its
+        working leader, if it has one and at least two other peers."""
+        r = self.round_no
+        down = self._down
+        moved = self._down_d is None
+        ends = self._churn.ending(r)
+        if len(ends) and down[ends].any():
+            down[ends] = False
+            moved = True
+        starts = self._churn.starting(r)
+        if len(starts):
+            slot, term = self._leaders(starts)
+            ok = (term > 0) & (self.h_mask[starts].sum(axis=1) >= 3)
+            if ok.any():
+                down[starts] = False
+                down[starts[ok], slot[ok]] = True
+                moved = True
+                n = int(np.count_nonzero(ok))
+                self.churn_cuts += n
+                if self.obs.enabled:
+                    self.obs.c_churn_cuts.inc(n)
+        if moved:
+            # (a copy: jnp.asarray of a live numpy array may share it)
+            self._down_d = self._dev("state", down.copy())
+            if self.obs.enabled:
+                self.obs.g_churn_down.set(np.count_nonzero(down))
+        return self._down_d
+
+    def _leaders_moved(self, gs: np.ndarray, before: tuple) -> None:
+        """The mirrors of the groups `gs` were just updated and a row in
+        each changed role; `before` is what _leaders(gs) gave before the
+        update. Which of them show two LEADER rows is kept for staging
+        (_multi_lead); a group whose routable leader is a new one (another
+        slot or term) is counted, its _lead_since set, and, as its predecessor
+        may have admitted entries that the new leader's log does not hold,
+        handed to _repropose_lost."""
+        slot0, term0 = before
+        slot1, term1 = self._leaders(gs)
+        many = (self.h_mask[gs]
+                & (self.h_state[gs] == _LEADER)).sum(axis=1) > 1
+        self._multi_lead.difference_update(gs[~many].tolist())
+        self._multi_lead.update(gs[many].tolist())
+        new = (term1 > 0) & ((term1 != term0) | (slot1 != slot0))
+        if not new.any():
+            return
+        g_new = gs[new]
+        self._lead_since[g_new] = time.perf_counter()
+        self._resolve.update(g_new.tolist())
+        if self.obs.enabled:
+            self.obs.c_leader_changes.inc(len(g_new))
+
+    def _repropose_lost(self) -> None:
+        """Propose again what a deposed leader admitted and the committed
+        log has overwritten, ONCE THE HOST KNOWS FOR CERTAIN, for the
+        groups in _resolve (those that changed leader since).
+
+        An admitted entry (g, i, t) of an older term than the group's
+        routable leader's can never commit, by log matching, if
+        (a) the group has applied index i and it was not this entry that
+            was applied there (the applier pops an entry's payload_reqs
+            key before it moves `applied` on, so a key still there at
+            i <= applied[g] was passed over: the committed entry at i has
+            another term), or
+        (b) i lies beyond the group's commit index c and the committed
+            entry at c has a later term than t: every log that can still
+            win an election holds that entry at c, and only entries of its
+            term or later behind it.
+        Its requests whose clients still wait go back to the HEAD of the
+        group's queue in their order and are staged at the new leader;
+        the waiter, the request id and the front's timeout sweep stay as
+        they are. Nothing is proposed again on suspicion (a timeout, a
+        step-down seen, a missing ack), so no request is applied twice.
+        Conf entries keep their own handling (_gc_payloads)."""
+        res = self._resolve
+        old: Dict[int, list] = {}
+        for k in list(self.payload_reqs):       # (appliers pop meanwhile)
+            if k[0] in res:
+                old.setdefault(k[0], []).append(k)
+        res.clear()
+        if not old:
+            return
+        W = self.cfg.window
+        gs = np.fromiter(old, np.int64, len(old))
+        _, lterm = self._leaders(gs)
+        cm = np.where(self.h_mask[gs], self.h_commit[gs], 0)
+        sc = cm.argmax(axis=1)
+        c = cm[np.arange(len(gs)), sc]
+        in_ring = (c >= 1) & (c > self.h_last[gs, sc] - W)
+        tc = np.where(in_ring, self.h_ring[gs, sc, c % W], 0)
+        requeue = []
+        for g, lt, c_g, tc_g in zip(gs.tolist(), lterm.tolist(), c.tolist(),
+                                    tc.tolist()):
+            keys = sorted((k for k in old[g] if k[2] < lt),
+                          key=lambda k: (k[2], k[1]))
+            if lt == 0 or not keys:
+                if lt == 0:
+                    res.add(g)              # no leader yet: look again
+                continue
+            a_g = int(self.applied[g])      # read BEFORE the pop below
+            items = []
+            for k in keys:
+                _, i, t = k
+                if not (i <= a_g or (i > c_g and tc_g > t)):
+                    res.add(g)              # undecided: look again
+                    continue
+                ent = self.payload_reqs.pop(k, None)
+                if ent is None:
+                    continue                # applied under our feet
+                payload = self.payloads.pop(k, None)
+                reqs, t0 = ent
+                if payload is None:
+                    blobs = [bytes([P_REQ]) + r.encode() for r in reqs]
+                elif payload[0] == P_MULTI:
+                    blobs = [bytes([P_REQ]) + b
+                             for b in _unpack_multi(payload)]
+                else:
+                    blobs = [payload]
+                items.extend((r.id, b, r, -t0) for r, b in zip(reqs, blobs)
+                             if self.wait.is_registered(r.id))
+            if items:
+                requeue.append((g, items))
+        if not requeue:
+            return
+        n = 0
+        with self._lock:
+            for g, items in requeue:
+                self._pending[g].extendleft(reversed(items))
+                self._dirty.add(g)
+                n += len(items)
+        self.reproposed += n
+        if self.obs.enabled:
+            self.obs.c_reproposed.inc(n)
+
     def _d2h(self, *arrays) -> None:
         """Count one blocking device->host read of `arrays` (called
         under obs.enabled only; flushed to the counters once a round)."""
@@ -2417,10 +2645,14 @@ class MultiEngine:
 
     def _settled(self) -> bool:
         """Every provisioned group's mirror shows a leader whose log is
-        committed to its end."""
-        lead = np.where(self.h_mask, self.h_state, 0) == _LEADER
-        return (bool(lead.any(axis=1)[self.h_mask.any(axis=1)].all())
-                and not (lead & (self.h_commit < self.h_last)).any())
+        committed to its end: the routable one. What a deposed leader that
+        has not heard of its successor still holds uncommitted is lost,
+        not pending (_repropose_lost), and keeps no idle member awake."""
+        slot, term = self._leaders()
+        at = np.arange(len(slot))
+        return (bool((term > 0)[self.h_mask.any(axis=1)].all())
+                and not (self.h_commit[at, slot]
+                         < self.h_last[at, slot])[term > 0].any())
 
     def _admit_staged(self, rec: RoundRecord, adm_l: list, t_l: list,
                       base_l: list) -> None:
@@ -2435,6 +2667,11 @@ class MultiEngine:
         t_admit = time.perf_counter()
         ann = self.obs.span("etcd.record.admit")
         ann.__enter__()
+        # A write older than its group's leader waited for it: in the
+        # queue, or in an entry the leader before admitted and lost.
+        since = self._lead_since
+        waited = (self.obs.h_leaderless_wait.observe
+                  if self.obs.enabled else None)
         for (g, (_, ents)), admitted, t, base in zip(
                 self._staged.items(), adm_l, t_l, base_l):
             for j, items in enumerate(ents):
@@ -2445,7 +2682,13 @@ class MultiEngine:
                     if payload[0] != P_CONF:
                         reqs = [it[2] for it in items]
                         if None not in reqs:
-                            self.payload_reqs[(g, i, t)] = reqs
+                            it = items[0]
+                            t0 = abs(it[3]) if len(it) > 3 else 0.0
+                            self.payload_reqs[(g, i, t)] = (reqs, t0)
+                            if waited and 0.0 < t0 < since[g]:
+                                for it in items:
+                                    if len(it) > 3:
+                                        waited(t_admit - abs(it[3]))
                     n_admitted += len(items)
                     if tr.every:
                         for it in items:
@@ -2455,10 +2698,11 @@ class MultiEngine:
                                 self._trace_rids.append(it[0])
                     rec.entries.append((g, i, t, payload))
                 else:
-                    # (rid, payload, request): the enqueue time stays
-                    # behind, its wait was observed at this staging.
+                    # (rid, payload, request, -enqueue time): negated,
+                    # its queue wait was observed at this staging.
                     requeue.append(
-                        (g, [it[:3] for e in ents[j:] for it in e]))
+                        (g, [it[:3] + (-abs(it[3]),) if len(it) > 3 else it
+                             for e in ents[j:] for it in e]))
                     break
         self._last_admitted = n_admitted
         if requeue:
@@ -2595,13 +2839,21 @@ class MultiEngine:
 
         # Mirror update LAST (admission reads the pre-round mirrors).
         # Gathered values are authoritative for every union row —
-        # writing back an unchanged staged row is a no-op.
+        # writing back an unchanged staged row is a no-op. A group's
+        # routable leader can change only where a row changed role.
+        m = (chg & kernel.CHG_STATE) != 0
+        led = None
+        if m.any():
+            gs_role = np.unique(gi[m])
+            led = self._leaders(gs_role)
         self.h_term[gi, pi] = t_k
         self.h_vote[gi, pi] = v_k
         self.h_commit[gi, pi] = c_k
         self.h_state[gi, pi] = s_k
         self.h_last[gi, pi] = l_k
         self.h_ring[gi, pi] = r_k
+        if led is not None:
+            self._leaders_moved(gs_role, led)
         return rec
 
     # ------------------------------------------------------------------
@@ -2712,12 +2964,13 @@ class MultiEngine:
                     # decoded at proposal time (payload_reqs sidecar);
                     # replay decodes from the durable bytes.
                     reqs = self.payload_reqs.pop(key, None)
-                    if reqs is None:
-                        if payload[0] == P_REQ:
-                            reqs = (Request.decode(payload[1:]),)
-                        else:
-                            reqs = [Request.decode(b)
-                                    for b in _unpack_multi(payload)]
+                    if reqs is not None:
+                        reqs = reqs[0]
+                    elif payload[0] == P_REQ:
+                        reqs = (Request.decode(payload[1:]),)
+                    else:
+                        reqs = [Request.decode(b)
+                                for b in _unpack_multi(payload)]
                     if not trigger and tr.every:
                         # Restart replay: sampled rids ride the durable
                         # Request payloads, so the trace picks them back
@@ -3044,7 +3297,9 @@ class MultiEngine:
         leader, snapshot-install every active follower whose needed entries
         fell below the leader's ring window (the host side of MsgSnap,
         reference raft.go:246-260 + etcdserver snapshot catch-up §3.5).
-        A follower the lag injection holds in this round is left alone."""
+        A follower the lag injection holds in this round is left alone, and
+        so is a slot the churn has cut off (an install is a message too);
+        the leader is the group's routable one, of the highest term."""
         st = self.st
         W = self.cfg.window
         flagged = np.nonzero(need_host.any(axis=1))[0]
@@ -3070,12 +3325,12 @@ class MultiEngine:
         for g in flagged:
             g = int(g)
             s = self.leader_slot(g)
-            if s < 0:
+            if s < 0 or self._down[g, s]:
                 continue
             c = int(commit[g, s])
             for f in np.nonzero(self.h_mask[g])[0]:
                 f = int(f)
-                if f == s or self._lag_held[g, f]:
+                if f == s or self._lag_held[g, f] or self._down[g, f]:
                     continue
                 # Lagging = the kernel's need_snap condition: entries from
                 # next are no longer resolvable from the leader's ring
@@ -3144,14 +3399,19 @@ class MultiEngine:
 
     def _checkpoint(self) -> None:
         import base64 as _b64
+        # (a front thread creates a tenant's store at the tenant's first
+        # request, under the lock: a checkpoint that walks the live dict
+        # meanwhile dies of "dictionary changed size during iteration" and
+        # takes the engine thread with it)
+        with self._lock:
+            stores = list(self._stores.items())
         state = {
             "round": self.round_no - 1,
             "term": np_b64(self.h_term), "vote": np_b64(self.h_vote),
             "commit": np_b64(self.h_commit), "last": np_b64(self.h_last),
             "ring": np_b64(self.h_ring), "mask": np_b64(self.h_mask),
             "applied": np_b64(self.applied),
-            "stores": {str(g): s.save().decode()
-                       for g, s in self._stores.items()},
+            "stores": {str(g): s.save().decode() for g, s in stores},
             "payloads": [
                 (g, i, t, _b64.b64encode(p).decode())
                 for (g, i, t), p in self.payloads.items()

@@ -145,6 +145,34 @@ lag_held_slots = metrics.Gauge(
     "etcd_engine_lag_held_slots",
     "Lagging-follower injection: follower slots held in the current "
     "round (at most one a group; 0 with the injection off).")
+leader_changes = metrics.Counter(
+    "etcd_engine_leader_changes_total",
+    "Groups whose routable leader (the active LEADER row of the highest "
+    "term, where the round stages a group's writes) changed slot or term, "
+    "counted where a round's readback updates the host's mirrors.")
+leaderless_wait = metrics.Histogram(
+    "etcd_engine_leaderless_wait_seconds",
+    "Age of a write when it is staged at a leader its group did not have "
+    "when the write arrived: it waited in the staging queue, or in an "
+    "entry its old leader admitted and lost, for this one. Observed at "
+    "that staging, once per request and leader.",
+    buckets=(0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0,
+             10.0))
+reproposed_requests = metrics.Counter(
+    "etcd_engine_reproposed_requests_total",
+    "Requests put back at the head of their group's queue because the "
+    "entry that carried them was admitted by a deposed leader and the "
+    "committed log holds another entry at its index, or has passed it in "
+    "a later term: it can never commit, so they are proposed again.")
+churn_down_slots = metrics.Gauge(
+    "etcd_engine_churn_down_slots",
+    "Leader-election churn (--engine-churn-down-rounds): slots cut off "
+    "from their peers in the current round (at most one a group; 0 with "
+    "the injection off).")
+churn_cuts = metrics.Counter(
+    "etcd_engine_churn_cuts_total",
+    "Leader-election churn: cuts begun, i.e. leaders taken off their "
+    "group's network for churn_down_rounds rounds.")
 pending_wait = metrics.Histogram(
     "etcd_engine_pending_wait_seconds",
     "Time a request sat in the engine's staging queue: do()/submit_many "
@@ -742,6 +770,11 @@ class EngineObs:
         self.c_snap_installs = snapshot_installs
         self.c_lag_releases = lag_releases
         self.g_lag_held = lag_held_slots
+        self.c_leader_changes = leader_changes
+        self.h_leaderless_wait = leaderless_wait
+        self.c_reproposed = reproposed_requests
+        self.g_churn_down = churn_down_slots
+        self.c_churn_cuts = churn_cuts
         for k in FRONT_KINDS:
             http_request.labels(k)
             http_front_self.labels(k)

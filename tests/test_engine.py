@@ -1287,3 +1287,28 @@ def test_a_write_waiting_on_a_lost_quorum_is_not_idled_on(tmp_path):
         assert ev.node.value == "2"
     finally:
         eng.stop()
+
+
+def test_a_checkpoint_survives_a_tenants_first_touch(tmp_path):
+    """The front creates a tenant's store at the tenant's first request, on
+    its own thread; a checkpoint walking the stores meanwhile must not die
+    of it (it took the engine thread along: every later request timed out;
+    seen on the chip in a mix that preloads nothing, PERF.md §6 PR 38)."""
+    G = 300
+    eng = MultiEngine(make_cfg(tmp_path / "ck", groups=G, peers=3,
+                               checkpoint_rounds=4))
+    eng.start()
+    try:
+        assert eng.wait_leaders(180), eng.failed
+        for g in range(100):        # stores worth a checkpoint's while
+            eng.do(g, Request(method="PUT", path="/k", val="v" * 256))
+        for g in range(100, G):
+            eng.store(g)
+            time.sleep(0.002)
+        time.sleep(0.3)
+        assert eng.failed is None
+        r0 = eng.round_no
+        eng.do(0, Request(method="PUT", path="/k", val="again"))
+        assert eng.round_no > r0
+    finally:
+        eng.stop()

@@ -79,29 +79,32 @@ def _shapes(cfg: KernelConfig, state_sh, inbox_sh, small_sh):
     return st, inbox, pc, pc, tick
 
 
-def _compile_variant(topo, name: str, G: int, hold: bool = False):
+def _compile_variant(topo, name: str, G: int, hold: bool = False,
+                     down: bool = False, peers: int = P):
     """The variant as a TPU engine runs it: donated state and inbox (the
     suite's JAX_PLATFORMS=cpu makes the module-level jits undonated, so
     the donating jit is rebuilt here from the same function). `hold`: with
     the (G, P) bool of held follower slots as its one more argument
-    (--engine-lag-share)."""
-    cfg = KernelConfig(groups=G, peers=P, window=W)
+    (--engine-lag-share); `down`: with the (G, P) bool of slots cut off
+    from their peers (--engine-churn-down-rounds), the hold then None."""
+    cfg = KernelConfig(groups=G, peers=peers, window=W)
     one = SingleDeviceSharding(topo.devices[0])
     fn = jax.jit(getattr(kernel, name).__wrapped__,
                  static_argnums=kernel._STEP_STATICS[name],
                  donate_argnums=(1, 2))
-    held = ((jax.ShapeDtypeStruct((G, P), jnp.bool_, sharding=one),)
-            if hold else ())
+    gp = jax.ShapeDtypeStruct((G, peers), jnp.bool_, sharding=one)
+    more = (None, gp) if down else (gp,) if hold else ()
     return fn.lower(cfg, *_shapes(cfg, one, one, one), None, HOPS,
-                    *held).compile()
+                    *more).compile()
 
 
-def _check_variant(compiled, G: int) -> None:
+def _check_variant(compiled, G: int, peers: int = P) -> None:
     ma = compiled.memory_analysis()
     state_bytes = sum(
         int(np.prod(s.shape)) * s.dtype.itemsize
         for s in jax.eval_shape(
-            lambda: init_state(KernelConfig(groups=G, peers=P, window=W))))
+            lambda: init_state(KernelConfig(groups=G, peers=peers,
+                                            window=W))))
     # Donation must really alias: the state arrays ARE the HBM budget, and
     # a step that copies them doubles it. Everything but scalars aliases.
     assert ma.alias_size_in_bytes >= state_bytes, (
@@ -119,6 +122,15 @@ def test_serving_variant_with_the_hold_compiles_for_v5e(topo, as_served,
                                                         name):
     """The two programs a member with --engine-lag-share serves."""
     _check_variant(_compile_variant(topo, name, 128, hold=True), 128)
+
+
+@pytest.mark.parametrize("name", VARIANTS[1:])
+def test_serving_variant_with_the_down_map_compiles_for_v5e(topo, as_served,
+                                                            name):
+    """The two programs a member with --engine-churn-down-rounds serves, at
+    BASELINE.json configs[4]'s seven peers."""
+    _check_variant(_compile_variant(topo, name, 128, down=True, peers=7),
+                   128, peers=7)
 
 
 def _mesh4(topo) -> Mesh:
@@ -250,6 +262,14 @@ def test_serving_variant_full_size(topo, as_served, name):
 def test_serving_variant_with_the_hold_full_size(topo, as_served, name):
     """mt100k-p5-lag5's programs (G=12,500 with the hold)."""
     _check_variant(_compile_variant(topo, name, 12_500, hold=True), 12_500)
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("name", VARIANTS[1:])
+def test_serving_variant_with_the_down_map_full_size(topo, as_served, name):
+    """mt100k-p7-churn's programs (G=12,500 x P=7 with the down map)."""
+    _check_variant(_compile_variant(topo, name, 12_500, down=True, peers=7),
+                   12_500, peers=7)
 
 
 @pytest.mark.slow
