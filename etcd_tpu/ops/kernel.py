@@ -209,11 +209,40 @@ def _tick(st: GroupState, cfg: KernelConfig, active: jax.Array,
 # Phase 2: one sender slot's messages, for all instances at once
 # ---------------------------------------------------------------------------
 
-def _step_msgs_from(st: GroupState, cfg: KernelConfig, q: int,
+def _onehot(q: jax.Array, P: int) -> jax.Array:
+    """(G, P, P) bool: the target column q[g, p] of each receiver's row."""
+    return jnp.arange(P, dtype=jnp.int32)[None, None, :] == q[..., None]
+
+
+def _col(x: jax.Array, q) -> jax.Array:
+    """x[:, :, q] of a per-target (G, P, P) array. q is one slot for every
+    receiver (an int: the passes by sender) or a (G, P) array, one slot a
+    receiver (the passes by rank): a one-hot select there, not a gather
+    (ring_lookup's reasoning)."""
+    if isinstance(q, int):
+        return x[:, :, q]
+    oh = _onehot(q, x.shape[2])
+    if x.dtype == jnp.bool_:
+        return jnp.any(oh & x, axis=2)
+    return jnp.sum(jnp.where(oh, x, 0), axis=2, dtype=x.dtype)
+
+
+def _set_col(x: jax.Array, q, v: jax.Array) -> jax.Array:
+    """x with x[:, :, q] = v, q as _col takes it."""
+    if isinstance(q, int):
+        return x.at[:, :, q].set(v)
+    return jnp.where(_onehot(q, x.shape[2]), v[..., None], x)
+
+
+def _step_msgs_from(st: GroupState, cfg: KernelConfig, q,
                     msg: jax.Array, active: jax.Array,
                     ) -> Tuple[GroupState, jax.Array]:
-    """Process the inbox slot from sender `q` on every instance; returns the
-    updated state and the staged response (G, P, F) addressed back to q.
+    """Process one message a receiver, `msg` (G, P, F) from sender `q`, on
+    every instance; returns the updated state and the staged response
+    (G, P, F) addressed back to q. `q` is an int (the inbox slot of one
+    sender for all: _full_msgs) or a (G, P) int32 array (each receiver's
+    own next sender: _ranked_msgs). Every update is local to the
+    receiver's row.
 
     Mirrors raft.Step (reference raft.go:462-669) as masked dense updates.
     """
@@ -251,31 +280,12 @@ def _step_msgs_from(st: GroupState, cfg: KernelConfig, q: int,
     # -- MsgVote (uniform grant rule; reference stepFollower raft.go:636-647,
     #    leaders/candidates reject naturally because vote == self) ----------
     v = live & (mtype == M_VOTE)
-    last_t = _last_term(st, cfg)
-    up_to_date = (mlogterm > last_t) | ((mlogterm == last_t)
-                                        & (mindex >= st.last_index))
-    grant = v & ((st.vote == 0) | (st.vote == q + 1)) & up_to_date
-    st = st._replace(
-        vote=_where(grant, q + 1, st.vote),
-        elapsed=_where(grant, 0, st.elapsed),
-    )
+    st, grant = _grant_vote(st, q, v, mindex, mlogterm, _last_term(st, cfg))
     resp = _stage(resp, v, M_VOTE_RESP, st.term, reject=~grant)
 
     # -- MsgVoteResp (reference stepCandidate raft.go:603-612) --------------
-    vr = live & is_c & (mtype == M_VOTE_RESP)
-    first = st.votes[:, :, q] == 0
-    # int32 literals: under x64 test configs plain ints promote to int64
-    # and the votes scatter would mix dtypes (FutureWarning today, error
-    # in future jax).
-    vote_val = _where(mreject == 0, jnp.int32(1), jnp.int32(2))
-    votes = st.votes.at[:, :, q].set(
-        _where(vr & first, vote_val, st.votes[:, :, q]))
-    st = st._replace(votes=votes)
-    granted = jnp.sum((votes == 1).astype(jnp.int32), axis=2)
-    rejected = jnp.sum((votes == 2).astype(jnp.int32), axis=2)
-    qr = quorum(st)[:, None]
-    win = vr & (granted >= qr)
-    lose = vr & ~win & (rejected >= qr)
+    st, win, lose = _tally_vote(st, q, live & is_c & (mtype == M_VOTE_RESP),
+                                mreject)
     st = _append_noop_and_lead(st, cfg, win)
     st = _become_follower(st, lose, st.term, 0)
     is_f, is_c, is_l = (st.state == FOLLOWER, st.state == CANDIDATE,
@@ -348,10 +358,10 @@ def _step_msgs_from(st: GroupState, cfg: KernelConfig, q: int,
 
     # -- MsgAppResp (reference stepLeader raft.go:514-546) ------------------
     ar = live & is_l & (mtype == M_APP_RESP)
-    match_q = st.match[:, :, q]
-    next_q = st.next[:, :, q]
-    pr_q = st.pr_state[:, :, q]
-    paused_q = st.paused[:, :, q]
+    match_q = _col(st.match, q)
+    next_q = _col(st.next, q)
+    pr_q = _col(st.pr_state, q)
+    paused_q = _col(st.paused, q)
 
     rej_resp = ar & (mreject != 0)
     # replicate: fall back to match+1 and probe (maybeDecrTo fast path)
@@ -372,14 +382,14 @@ def _step_msgs_from(st: GroupState, cfg: KernelConfig, q: int,
     next_q = jnp.maximum(next_q, _where(ok_resp, mindex + 1, 0))
 
     st = st._replace(
-        match=st.match.at[:, :, q].set(match_q),
-        next=st.next.at[:, :, q].set(next_q),
-        pr_state=st.pr_state.at[:, :, q].set(pr_q),
-        paused=st.paused.at[:, :, q].set(paused_q),
+        match=_set_col(st.match, q, match_q),
+        next=_set_col(st.next, q, next_q),
+        pr_state=_set_col(st.pr_state, q, pr_q),
+        paused=_set_col(st.paused, q, paused_q),
         # Any append response (accept or reject) is replication-liveness
         # evidence from this target.
-        ack_age=st.ack_age.at[:, :, q].set(
-            _where(ar, 0, st.ack_age[:, :, q])),
+        ack_age=_set_col(st.ack_age, q,
+                         _where(ar, 0, _col(st.ack_age, q))),
     )
 
     # -- MsgHeartbeat (reference handleHeartbeat raft.go:666-669) -----------
@@ -405,15 +415,49 @@ def _step_msgs_from(st: GroupState, cfg: KernelConfig, q: int,
     #    gate keeps steady-state traffic (acks merely in flight) free of
     #    duplicate sends. --
     hrs = live & is_l & (mtype == M_HB_RESP)
-    match_h = st.match[:, :, q]
-    next_h = st.next[:, :, q]
-    stale = (hrs & (st.pr_state[:, :, q] == PR_REPLICATE)
+    match_h = _col(st.match, q)
+    next_h = _col(st.next, q)
+    stale = (hrs & (_col(st.pr_state, q) == PR_REPLICATE)
              & (match_h < st.last_index)
-             & (st.ack_age[:, :, q] > 2 * cfg.heartbeat_tick + 2))
+             & (_col(st.ack_age, q) > 2 * cfg.heartbeat_tick + 2))
     st = st._replace(
-        next=st.next.at[:, :, q].set(
-            _where(stale, match_h + 1, next_h)))
+        next=_set_col(st.next, q, _where(stale, match_h + 1, next_h)))
     return st, resp
+
+
+def _grant_vote(st: GroupState, q, v: jax.Array, mindex: jax.Array,
+                mlogterm: jax.Array, last_t: jax.Array
+                ) -> Tuple[GroupState, jax.Array]:
+    """MsgVote from sender `q` (as _col takes it) at the receivers `v`
+    (G, P), which stand at the message's term: (state, grant). One grant
+    rule for every role: leaders and candidates refuse because their vote
+    is their own (reference stepFollower raft.go:636-647). `last_t` is
+    the receivers' last log term."""
+    up_to_date = (mlogterm > last_t) | ((mlogterm == last_t)
+                                        & (mindex >= st.last_index))
+    grant = v & ((st.vote == 0) | (st.vote == q + 1)) & up_to_date
+    return st._replace(vote=_where(grant, q + 1, st.vote),
+                       elapsed=_where(grant, 0, st.elapsed)), grant
+
+
+def _tally_vote(st: GroupState, q, vr: jax.Array, mreject: jax.Array
+                ) -> Tuple[GroupState, jax.Array, jax.Array]:
+    """MsgVoteResp from sender `q` (as _col takes it) at the candidates
+    `vr` (G, P): the first answer of a sender is recorded, and (state,
+    win, lose) says whom the count now makes leader or follower
+    (reference stepCandidate raft.go:603-612); the caller applies it."""
+    first = _col(st.votes, q) == 0
+    # int32 literals: under x64 test configs plain ints promote to int64
+    # and the votes scatter would mix dtypes (FutureWarning today, error
+    # in future jax).
+    val = _where(mreject == 0, jnp.int32(1), jnp.int32(2))
+    votes = _set_col(st.votes, q,
+                     _where(vr & first, val, _col(st.votes, q)))
+    granted = jnp.sum((votes == 1).astype(jnp.int32), axis=2)
+    rejected = jnp.sum((votes == 2).astype(jnp.int32), axis=2)
+    qr = quorum(st)[:, None]
+    win = vr & (granted >= qr)
+    return st._replace(votes=votes), win, vr & ~win & (rejected >= qr)
 
 
 def _stage(resp: jax.Array, mask: jax.Array, mtype: int, term: jax.Array,
@@ -714,7 +758,7 @@ def step(cfg: KernelConfig, st: GroupState, inbox: jax.Array,
     corrupted state and raises NH_VIOLATION).
     """
     return _step_body(cfg, st, inbox, prop_count, prop_slot, tick,
-                      quiet=False)
+                      _full_msgs)[:2]
 
 
 # ---------------------------------------------------------------------------
@@ -726,53 +770,65 @@ def step(cfg: KernelConfig, st: GroupState, inbox: jax.Array,
 # columns and therefore commute across senders — and each follower receives
 # AT MOST one append-or-heartbeat, from its leader (one leader per term;
 # send assembly emits one message per (leader, target) per round). Both
-# facts collapse the message phase into ONE vectorized pass. step_auto
-# checks the quiescence predicate on device and lax.cond-selects the fast
-# or the full path — election rounds automatically take the full path, so
-# the two are behaviorally identical (tests/test_quiet_path.py drives
-# bit-exactness round by round).
+# facts collapse the message phase into ONE vectorized pass. The step
+# programs check the quiescence predicate on device, group by group
+# (_quiet_pred), and lax.cond-select per hop (_hops): no busy group, the
+# fast path (_quiet_msgs); any, the sequential passes for all, made by
+# rank and not by sender (_ranked_msgs: what the P passes of _full_msgs
+# compute, in as many passes as the busiest receiver holds messages that
+# need one — one or none on most hops of a member whose groups elect all
+# the time, where _full_msgs makes P). The paths are behaviorally
+# identical (tests/test_quiet_path.py drives bit-exactness round by round,
+# that of the one pass for every group the predicate calls quiet while
+# others are busy, and that of the passes by rank on scripts and on
+# random inboxes). The counts of busy groups and of passes come back with
+# the state. Gathering the busy groups into a sub-batch, the other way to
+# spare the quiet groups a busy hop's passes, costs more on the chip than
+# the passes over all G (G lies in the lanes there: PERF.md section 6,
+# PR 40).
 # ---------------------------------------------------------------------------
 
 def _quiet_pred(st: GroupState, cfg: KernelConfig, inbox: jax.Array,
                 active: jax.Array, tick: jax.Array) -> jax.Array:
-    """() bool: NOTHING this round can need the sequential message phases.
-    Conservative — false positives are impossible, false negatives only
-    cost a slow round."""
+    """(G,) bool: the groups NOTHING of which can need the sequential
+    message passes this hop: _quiet_msgs equals them bit for bit there.
+    Conservative — a group called busy that the one pass would have served
+    costs time, never correctness."""
     mtype = inbox[..., F_TYPE]
     present = mtype != M_NONE
     vote_ish = present & ((mtype == M_VOTE) | (mtype == M_VOTE_RESP))
     # Any cross-term message (stale or new-term) needs the term gate.
     term_mism = present & (inbox[..., F_TERM] != st.term[:, :, None])
+    # What _quiet_msgs rests on, read where it shows: a receiver holds at
+    # most one append-or-heartbeat. Every message left carries its
+    # receiver's term and a term has one leader, so this holds however
+    # many rows call themselves LEADER (a leader cut off from its peers
+    # stays LEADER in its old term beside its successor: no check-quorum).
+    many_app = jnp.sum((present & ((mtype == M_APP) | (mtype == M_HB)))
+                       .astype(jnp.int32), axis=2) > 1
     is_c = active & (st.state == CANDIDATE)
     # A follower whose clock would reach its election timeout this round
     # might campaign (and must draw from the PRNG stream either way).
     could_campaign = (tick & active & (st.state != LEADER)
                       & (st.elapsed + 1 >= cfg.election_tick))
-    n_lead = jnp.sum((active & (st.state == LEADER)).astype(jnp.int32),
-                     axis=1)
     pending_host = st.need_host != 0
-    return ~(jnp.any(vote_ish) | jnp.any(term_mism) | jnp.any(is_c)
-             | jnp.any(could_campaign) | jnp.any(n_lead > 1)
-             | jnp.any(pending_host))
+    return ~(jnp.any(vote_ish | term_mism, axis=(1, 2))
+             | jnp.any(many_app | is_c | could_campaign | pending_host,
+                       axis=1))
 
 
-def _quiet_msgs(st: GroupState, cfg: KernelConfig, inbox: jax.Array,
-                active: jax.Array) -> Tuple[GroupState, jax.Array]:
-    """One-pass message processing for quiescent rounds; returns (state,
-    resp) with resp shaped (G, P, P, F) like the full path's."""
-    G, P = st.term.shape
-    F = cfg.fields
+def _leader_resps(st: GroupState, cfg: KernelConfig, inbox: jax.Array,
+                  on: jax.Array) -> GroupState:
+    """The append and heartbeat responses held at the (receiver, sender)
+    cells `on` (G, P, P), all senders in one shot: a leader's per-sender
+    progress columns are independent of one another, so as long as none of
+    a leader's messages can change its role or term (the caller's `on`
+    says where that holds) the order of the senders does not matter."""
     mtype_all = inbox[..., F_TYPE]
-    is_l = st.state == LEADER
-    recv = active[..., None]
-
-    # -- responses to leaders: per-sender columns are independent, so all
-    # P columns update in one shot (the q-loop of the full path exists
-    # only for cross-column state transitions, which quiescence excludes).
     mindex_all = inbox[..., F_INDEX]
     mreject_all = inbox[..., F_REJECT]
     mhint_all = inbox[..., F_HINT]
-    ar = recv & is_l[..., None] & (mtype_all == M_APP_RESP)
+    ar = on & (mtype_all == M_APP_RESP)
     match, nxt = st.match, st.next
     prs, paused = st.pr_state, st.paused
 
@@ -793,13 +849,27 @@ def _quiet_msgs(st: GroupState, cfg: KernelConfig, inbox: jax.Array,
     nxt = jnp.maximum(nxt, _where(ok, mindex_all + 1, 0))
     ack_age = _where(ar, 0, st.ack_age)
 
-    hrs = recv & is_l[..., None] & (mtype_all == M_HB_RESP)
+    hrs = on & (mtype_all == M_HB_RESP)
     stale = (hrs & (prs == PR_REPLICATE)
              & (match < st.last_index[..., None])
              & (ack_age > 2 * cfg.heartbeat_tick + 2))
     nxt = _where(stale, match + 1, nxt)
-    st = st._replace(match=match, next=nxt, pr_state=prs, paused=paused,
-                     ack_age=ack_age)
+    return st._replace(match=match, next=nxt, pr_state=prs, paused=paused,
+                       ack_age=ack_age)
+
+
+def _quiet_msgs(st: GroupState, cfg: KernelConfig, inbox: jax.Array,
+                active: jax.Array
+                ) -> Tuple[GroupState, jax.Array, jax.Array]:
+    """One-pass message processing for quiescent rounds; returns (state,
+    resp, 0 sequential passes) with resp shaped (G, P, P, F) like the full
+    path's."""
+    G, P = st.term.shape
+    F = cfg.fields
+    mtype_all = inbox[..., F_TYPE]
+    is_l = st.state == LEADER
+    recv = active[..., None]
+    st = _leader_resps(st, cfg, inbox, recv & is_l[..., None])
 
     # -- the one append-or-heartbeat each follower may hold: reduce over
     # the sender axis (at most one slot is populated — one leader per
@@ -809,8 +879,7 @@ def _quiet_msgs(st: GroupState, cfg: KernelConfig, inbox: jax.Array,
                                     | (mtype_all == M_HB))
     has_fm = jnp.any(fm, axis=2)
     s_idx = jnp.argmax(fm, axis=2).astype(jnp.int32)      # (G, P)
-    onehot_s = (jnp.arange(P, dtype=jnp.int32)[None, None, :]
-                == s_idx[..., None])
+    onehot_s = _onehot(s_idx, P)
     # dtype pinned: under x64 test configs jnp.sum promotes int32 -> int64.
     msg = jnp.sum(inbox * (fm & onehot_s)[..., None].astype(jnp.int32),
                   axis=2, dtype=jnp.int32)                 # (G, P, F)
@@ -879,18 +948,142 @@ def _quiet_msgs(st: GroupState, cfg: KernelConfig, inbox: jax.Array,
     # Route each follower's response back to its sender slot.
     resp = (resp_f[:, :, None, :]
             * onehot_s[..., None].astype(jnp.int32))        # (G, P, P, F)
+    return st, resp, jnp.int32(0)
+
+
+def _full_msgs(st: GroupState, cfg: KernelConfig, inbox: jax.Array,
+               active: jax.Array
+               ) -> Tuple[GroupState, jax.Array, jax.Array]:
+    """The message phase as P sequential passes, one a sender slot: what
+    the message phase means (the scalar equivalence harness mirrors it).
+    (state, resp, P) with resp shaped (G, P, P, F) like _quiet_msgs'."""
+    G, P = st.term.shape
+    resp = jnp.zeros((G, P, P, cfg.fields), jnp.int32)
+    for q in range(P):
+        st, r = _step_msgs_from(st, cfg, q, inbox[:, :, q, :], active)
+        resp = resp.at[:, :, q, :].set(r)
+    return st, resp, jnp.int32(P)
+
+
+def _ranked_msgs(st: GroupState, cfg: KernelConfig, inbox: jax.Array,
+                 active: jax.Array
+                 ) -> Tuple[GroupState, jax.Array, jax.Array]:
+    """_full_msgs bit for bit, in as many passes as the busiest receiver
+    holds messages that need one: the message phase of a hop on which some
+    group is busy, chosen receiver by receiver and dense over all G (no
+    group is picked out of the state: on the chip G lies in the lanes).
+
+    Every update of _step_msgs_from is local to its receiver's row, so a
+    receiver only has to see ITS messages in sender order; which pass
+    brings which is free. A message below its receiver's term is ignored
+    wherever it stands (terms only rise) and is dropped here. Like
+    _quiet_msgs this rests on a leader's own progress column standing at
+    its last index (every path that makes or extends a leader leaves it
+    so; the P passes re-set it at every pass). Then:
+    - a leader all of whose messages are append or heartbeat responses at
+      its own term: none can change its role or term and each touches its
+      own sender's progress column, so they are taken in one shot
+      (_leader_resps, _quiet_msgs' first half): no pass;
+    - a candidate all of whose messages are vote responses at its own
+      term: the tally (_count_votes), no pass;
+    - a receiver all of whose messages are vote requests (a group whose
+      leader was cut off has up to P - 1 candidates at one tick): the
+      ballot (_take_votes) on the hard state alone, no pass;
+    - every other receiver: pass k hands each its k-th message in sender
+      order (the sender is then a (G, P) array, not one slot for all),
+      until no receiver has one left. A follower behind its leader holds
+      one, a returning leader's peers one.
+    Returns (state, resp, the number of passes made)."""
+    G, P = st.term.shape
+    mtype = inbox[..., F_TYPE]
+    mterm = inbox[..., F_TERM]
+    term = st.term[..., None]
+    present = active[..., None] & (mtype != M_NONE) & (mterm >= term)
+    own = mterm == term
+    only = lambda kind: jnp.all(~present | (own & kind), axis=2)
+    leads = (st.state == LEADER) & only((mtype == M_APP_RESP)
+                                        | (mtype == M_HB_RESP))
+    counts = (st.state == CANDIDATE) & only(mtype == M_VOTE_RESP)
+    ballots = jnp.all(~present | (mtype == M_VOTE), axis=2)
+    st = _leader_resps(st, cfg, inbox, present & leads[..., None])
+    st = _count_votes(st, cfg, inbox, present & counts[..., None])
+    st, resp = _take_votes(st, cfg, inbox, present & ballots[..., None])
+
+    def one_pass(carry):
+        todo, n, st, resp = carry
+        q = jnp.argmax(todo, axis=2).astype(jnp.int32)
+        pick = todo & _onehot(q, P)
+        msg = jnp.sum(inbox * pick[..., None].astype(jnp.int32), axis=2,
+                      dtype=jnp.int32)
+        st, r = _step_msgs_from(st, cfg, q, msg, active)
+        resp = jnp.where(pick[..., None], r[:, :, None, :], resp)
+        return todo & ~pick, n + 1, st, resp
+
+    _, n, st, resp = jax.lax.while_loop(
+        lambda carry: jnp.any(carry[0]), one_pass,
+        (present & ~(leads | counts | ballots)[..., None], jnp.int32(0),
+         st, resp))
+    return st, resp, n
+
+
+def _count_votes(st: GroupState, cfg: KernelConfig, inbox: jax.Array,
+                 on: jax.Array) -> GroupState:
+    """The vote responses held at the (receiver, sender) cells `on`
+    (G, P, P), for candidates that hold nothing else and all at their own
+    term: _step_msgs_from's MsgVoteResp block (_tally_vote) sender by
+    sender, and what the count decides (the win's no-op entry and progress
+    reset, the loss's step-down) applied once, where the P passes apply it
+    at the deciding sender and ignore the responses behind it (the
+    receiver is no candidate any more)."""
+    G, P = st.term.shape
+    cell = on & (inbox[..., F_TYPE] == M_VOTE_RESP)
+    win = lose = jnp.zeros((G, P), bool)
+    for q in range(P):
+        st, won, lost = _tally_vote(st, q, cell[:, :, q] & ~(win | lose),
+                                    inbox[:, :, q, F_REJECT])
+        win, lose = win | won, lose | lost
+    st = _append_noop_and_lead(st, cfg, win)
+    return _become_follower(st, lose, st.term, 0)
+
+
+def _take_votes(st: GroupState, cfg: KernelConfig, inbox: jax.Array,
+                on: jax.Array) -> Tuple[GroupState, jax.Array]:
+    """The vote requests held at the (receiver, sender) cells `on`
+    (G, P, P), for receivers that hold nothing else: _step_msgs_from's
+    term gate and MsgVote block (_grant_vote) sender by sender (a vote
+    moves no log: the receiver's last entry is read once). Returns the
+    state and the responses, (G, P, P, F), zero elsewhere."""
+    G, P = st.term.shape
+    cell = on & (inbox[..., F_TYPE] == M_VOTE)
+    last_t = _last_term(st, cfg)
+    asked, at_term, refused = [], [], []
+    for q in range(P):
+        mterm = inbox[:, :, q, F_TERM]
+        st = _become_follower(st, cell[:, :, q] & (mterm > st.term), mterm,
+                              0)
+        v = cell[:, :, q] & (mterm == st.term)
+        st, grant = _grant_vote(st, q, v, inbox[:, :, q, F_INDEX],
+                                inbox[:, :, q, F_LOGTERM], last_t)
+        asked.append(v)
+        at_term.append(st.term)
+        refused.append(v & ~grant)
+    resp = _stage(jnp.zeros((G, P, P, cfg.fields), jnp.int32),
+                  jnp.stack(asked, axis=2), M_VOTE_RESP,
+                  jnp.stack(at_term, axis=2),
+                  reject=jnp.stack(refused, axis=2))
     return st, resp
 
 
 def _step_body(cfg: KernelConfig, st: GroupState, inbox: jax.Array,
                prop_count: jax.Array, prop_slot: Optional[jax.Array],
-               tick: jax.Array, quiet: bool,
+               tick: jax.Array, msgs,
                force_hb: bool = False, hold=None, down=None
-               ) -> Tuple[GroupState, jax.Array]:
-    """Shared round skeleton; `quiet` (Python bool, traced twice under the
-    cond) selects the message-phase implementation. prop_slot=None selects
-    per-SLOT proposal admission (prop_count is then (G, P) — the
-    multi-host engine's sharded input). `force_hb` (Python bool) makes
+               ) -> Tuple[GroupState, jax.Array, jax.Array]:
+    """Shared round skeleton, (state, outbox, sequential passes made);
+    `msgs` (_quiet_msgs, _ranked_msgs or _full_msgs) is the message-phase
+    implementation. prop_slot=None selects per-SLOT proposal admission
+    (prop_count is then (G, P) — the multi-host engine's sharded input).
+    `force_hb` (Python bool) makes
     every active leader broadcast a heartbeat this pass regardless of its
     heartbeat clock — the ReadIndex step uses it to solicit the quorum
     acks that confirm leadership (reference bcastHeartbeat on a pending
@@ -899,7 +1092,6 @@ def _step_body(cfg: KernelConfig, st: GroupState, inbox: jax.Array,
     # is byte-identical) so a device trace's ops can be told apart after
     # a refactor.
     active = active_mask(st)
-    P = st.term.shape[1]
     st = st._replace(ack_age=jnp.minimum(st.ack_age + 1, 1 << 20))
     with jax.named_scope("etcd.tick"):
         st, hb_fire, vote_fire = _tick(st, cfg, active, tick)
@@ -910,15 +1102,7 @@ def _step_body(cfg: KernelConfig, st: GroupState, inbox: jax.Array,
         st = st._replace(paused=_where(ldr[..., None], False, st.paused))
     lead_term0 = _where(st.state == LEADER, st.term, 0)
     with jax.named_scope("etcd.step_msgs"):
-        if quiet:
-            st, resp = _quiet_msgs(st, cfg, inbox, active)
-        else:
-            resp = jnp.zeros((st.term.shape[0], P, P, cfg.fields),
-                             jnp.int32)
-            for q in range(P):
-                st, r = _step_msgs_from(st, cfg, q, inbox[:, :, q, :],
-                                        active)
-                resp = resp.at[:, :, q, :].set(r)
+        st, resp, passes = msgs(st, cfg, inbox, active)
     with jax.named_scope("etcd.apply_proposals"):
         if prop_slot is None:
             st = _apply_proposals_slots(st, cfg, prop_count, active)
@@ -931,19 +1115,21 @@ def _step_body(cfg: KernelConfig, st: GroupState, inbox: jax.Array,
                                      active, hold, down)
     bad = active & (st.commit > st.last_index)
     st = st._replace(need_host=_flag(st.need_host, bad, NH_VIOLATION))
-    return st, outbox
+    return st, outbox, passes
 
 
-@functools.partial(jax.jit, static_argnums=(0, 7), donate_argnums=_donate_at_import((1, 2)))
+@functools.partial(jax.jit, static_argnums=(0, 7, 10), donate_argnums=_donate_at_import((1, 2)))
 def step_routed_auto(cfg: KernelConfig, st: GroupState, inbox: jax.Array,
                      prop_count: jax.Array, prop_slot: jax.Array,
                      tick: jax.Array, drop_mask=None,
-                     hops: int = 1, hold=None, down=None
-                     ) -> Tuple[GroupState, jax.Array]:
+                     hops: int = 1, hold=None, down=None,
+                     by_sender: bool = False
+                     ) -> Tuple[GroupState, jax.Array, jax.Array]:
     """step + route_local with on-device fast-path selection: quiescent
-    rounds (the steady-state common case) skip the P sequential message
+    hops (the steady-state common case) skip the P sequential message
     passes. ONE compiled program; lax.cond executes exactly one branch at
-    runtime.
+    runtime. Returns (state, inbox, hop_stats), the last _hops' (2, hops)
+    counts of busy groups and sequential passes.
 
     `hops` chains that many message-phase+routing passes INSIDE the one
     compiled program: proposals and the tick fire only on the first hop,
@@ -961,33 +1147,62 @@ def step_routed_auto(cfg: KernelConfig, st: GroupState, inbox: jax.Array,
     None. `down` (G, P) bool, the slots cut off from their peers (leader-
     election churn, server/lag.py): every message to and from a down slot
     is dropped after every hop, as the drop_mask built from it would, and
-    no snapshot is raised for one; no argument of the program when None."""
+    no snapshot is raised for one; no argument of the program when None.
+
+    `by_sender` (trace-time) gives a busy hop the P passes by sender
+    (_full_msgs) where it would make the passes by rank: those end on
+    "does any receiver hold another message", which is one more collective
+    a pass once the groups are sharded, so a mesh's programs are built
+    with it (engine.py) and keep one scalar all-reduce a hop."""
+    return _hops(cfg, st, inbox, prop_count, prop_slot, tick, drop_mask,
+                 hops, hold, down, by_sender)[:3]
+
+
+def _hops(cfg: KernelConfig, st: GroupState, inbox: jax.Array,
+          prop_count: jax.Array, prop_slot: Optional[jax.Array],
+          tick: jax.Array, drop_mask, hops: int, hold, down,
+          by_sender: bool = False, force_hb: bool = False, after_hop=None,
+          seen=None):
+    """The hop loop of the step programs: (state, inbox, hop_stats, seen),
+    hop_stats a (2, hops) int32: how many groups were busy on each hop
+    (_quiet_pred) and how many sequential passes its message phase made.
+    A hop with no busy group runs the one-pass message phase
+    (_quiet_msgs: 0 passes), any other _ranked_msgs (_full_msgs where
+    `by_sender`: the state sharded over chips or hosts), over all G.
+    `force_hb` forces the leaders' heartbeat on hop 0 and
+    `seen = after_hop(seen, inbox)` folds every hop's routed inbox (the
+    read step's ack tally)."""
+    hop_busy, hop_passes = [], []
+    busy = _full_msgs if by_sender else _ranked_msgs
     for h in range(hops):
         pc = prop_count if h == 0 else jnp.zeros_like(prop_count)
         tk = tick if h == 0 else jnp.asarray(False)
-        active = active_mask(st)
-        quiet = _quiet_pred(st, cfg, inbox, active, tk)
+        n = jnp.sum(~_quiet_pred(st, cfg, inbox, active_mask(st), tk),
+                    dtype=jnp.int32)
+        hop_busy.append(n)
 
-        def fast(ops):
-            st, inbox, pc, ps, tick = ops
-            s, out = _step_body(cfg, st, inbox, pc, ps, tick, quiet=True,
-                                hold=hold, down=down)
-            return s, route_local(out)
-
-        def full(ops):
-            st, inbox, pc, ps, tick = ops
-            s, out = _step_body(cfg, st, inbox, pc, ps, tick, quiet=False,
-                                hold=hold, down=down)
-            return s, route_local(out)
+        def path(msgs, _h=h):
+            def run(ops):
+                st, inbox, pc, ps, tick = ops
+                s, out, passes = _step_body(
+                    cfg, st, inbox, pc, ps, tick, msgs,
+                    force_hb=force_hb and _h == 0, hold=hold, down=down)
+                return s, route_local(out), passes
+            return run
 
         with jax.named_scope(f"etcd.hop{h}"):
-            st, inbox = jax.lax.cond(quiet, fast, full,
-                                     (st, inbox, pc, prop_slot, tk))
+            st, inbox, passes = jax.lax.cond(
+                n == 0, path(_quiet_msgs), path(busy),
+                (st, inbox, pc, prop_slot, tk))
+        hop_passes.append(passes)
         if drop_mask is not None:
             inbox = inbox * drop_mask
         if down is not None:
             inbox = inbox * down_drop_mask(down)
-    return st, inbox
+        if after_hop is not None:
+            seen = after_hop(seen, inbox)
+    return st, inbox, jnp.stack([jnp.stack(hop_busy),
+                                 jnp.stack(hop_passes)]), seen
 
 
 def down_drop_mask(down: jax.Array) -> jax.Array:
@@ -1042,19 +1257,21 @@ def _read_register(st: GroupState, cfg: KernelConfig
     return read_slot, read_term, read_commit, has_ldr
 
 
-@functools.partial(jax.jit, static_argnums=(0, 7), donate_argnums=_donate_at_import((1, 2)))
+@functools.partial(jax.jit, static_argnums=(0, 7, 10), donate_argnums=_donate_at_import((1, 2)))
 def step_routed_read_auto(cfg: KernelConfig, st: GroupState,
                           inbox: jax.Array, prop_count: jax.Array,
                           prop_slot: jax.Array, tick: jax.Array,
                           drop_mask=None, hops: int = 1, hold=None,
-                          down=None
+                          down=None, by_sender: bool = False
                           ) -> Tuple[GroupState, jax.Array, jax.Array,
-                                     jax.Array, jax.Array, jax.Array]:
+                                     jax.Array, jax.Array, jax.Array,
+                                     jax.Array]:
     """step_routed_auto plus a batched ReadIndex pass: returns
     (st, inbox, confirmed (G,) bool, read_commit (G,) int32, flags,
-    any_need_host), the last two being step_routed_compact's on-device
-    diff against the pre-step state (_compact_flags), so a read round's
-    record is built from what changed, like a write round's.
+    any_need_host, hop_stats), flags and any_need_host being
+    step_routed_compact's on-device diff against the pre-step state
+    (_compact_flags), so a read round's record is built from what
+    changed, like a write round's, and hop_stats step_routed_auto's.
 
     Protocol (reference raft.go step MsgReadIndex + ReadOnlySafe recvAck,
     data-parallel over (groups, peers)): each group's leader registers
@@ -1087,32 +1304,8 @@ def step_routed_read_auto(cfg: KernelConfig, st: GroupState,
     read_slot, read_term, read_commit, has_ldr = _read_register(st, cfg)
     oh_lead = (jnp.arange(P, dtype=jnp.int32)[None, :]
                == read_slot[:, None])                        # (G, P)
-    acks = jnp.zeros((G, P), bool)
-    for h in range(hops):
-        pc = prop_count if h == 0 else jnp.zeros_like(prop_count)
-        tk = tick if h == 0 else jnp.asarray(False)
-        active = active_mask(st)
-        quiet = _quiet_pred(st, cfg, inbox, active, tk)
 
-        def fast(ops, _h=h):
-            st, inbox, pc, ps, tick = ops
-            s, out = _step_body(cfg, st, inbox, pc, ps, tick, quiet=True,
-                                force_hb=(_h == 0), hold=hold, down=down)
-            return s, route_local(out)
-
-        def full(ops, _h=h):
-            st, inbox, pc, ps, tick = ops
-            s, out = _step_body(cfg, st, inbox, pc, ps, tick, quiet=False,
-                                force_hb=(_h == 0), hold=hold, down=down)
-            return s, route_local(out)
-
-        with jax.named_scope(f"etcd.hop{h}"):
-            st, inbox = jax.lax.cond(quiet, fast, full,
-                                     (st, inbox, pc, prop_slot, tk))
-        if drop_mask is not None:
-            inbox = inbox * drop_mask
-        if down is not None:
-            inbox = inbox * down_drop_mask(down)
+    def count_acks(acks, inbox):
         # Messages routed to the registered leader slot this hop.
         to_lead = jnp.sum(
             inbox * oh_lead[:, :, None, None].astype(jnp.int32),
@@ -1120,12 +1313,18 @@ def step_routed_read_auto(cfg: KernelConfig, st: GroupState,
         mt = to_lead[..., F_TYPE]
         fresh = (((mt == M_HB_RESP) | (mt == M_APP_RESP))
                  & (to_lead[..., F_TERM] == read_term[:, None]))
-        acks = acks | fresh
+        return acks | fresh
+
+    st, inbox, hop_stats, acks = _hops(
+        cfg, st, inbox, prop_count, prop_slot, tick, drop_mask, hops, hold,
+        down, by_sender, force_hb=True, after_hop=count_acks,
+        seen=jnp.zeros((G, P), bool))
     n_acks = jnp.sum((acks & ~oh_lead).astype(jnp.int32), axis=1)
     still = ((_at_slot(st.state, read_slot) == LEADER)
              & (_at_slot(st.term, read_slot) == read_term))
     confirmed = has_ldr & still & (n_acks + 1 >= quorum(st))
-    return (st, inbox, confirmed, read_commit) + _compact_flags(st0, st)
+    return ((st, inbox, confirmed, read_commit) + _compact_flags(st0, st)
+            + (hop_stats,))
 
 
 # Per-(g, p) change flags emitted by step_routed_compact and
@@ -1153,16 +1352,16 @@ def _compact_flags(st0: GroupState, st: GroupState
     return flags, any_nh
 
 
-@functools.partial(jax.jit, static_argnums=(0, 7), donate_argnums=_donate_at_import((1, 2)))
+@functools.partial(jax.jit, static_argnums=(0, 7, 10), donate_argnums=_donate_at_import((1, 2)))
 def step_routed_compact(cfg: KernelConfig, st: GroupState, inbox: jax.Array,
                         prop_count: jax.Array, prop_slot: jax.Array,
                         tick: jax.Array, drop_mask=None, hops: int = 1,
-                        hold=None, down=None
+                        hold=None, down=None, by_sender: bool = False
                         ) -> Tuple[GroupState, jax.Array, jax.Array,
-                                   jax.Array]:
+                                   jax.Array, jax.Array]:
     """step_routed_auto plus an ON-DEVICE state diff: returns (st, inbox,
-    flags, any_need_host) where flags is a (G, P) uint8 CHG_* bitmask of
-    what changed this round vs the pre-step state.
+    flags, any_need_host, hop_stats) where flags is a (G, P) uint8 CHG_*
+    bitmask of what changed this round vs the pre-step state.
 
     Why: the serving engine's per-round full-state readback is O(G*P*W)
     bytes (the ring alone is 32 MB at G=100k) even when a round changed
@@ -1183,10 +1382,10 @@ def step_routed_compact(cfg: KernelConfig, st: GroupState, inbox: jax.Array,
     host rounds do snapshot/violation surgery that reads bulk state
     anyway)."""
     st0 = st
-    st, inbox = step_routed_auto.__wrapped__(
-        cfg, st, inbox, prop_count, prop_slot, tick, drop_mask, hops, hold,
-        down)
-    return (st, inbox) + _compact_flags(st0, st)
+    st, inbox, hop_stats = _hops(cfg, st, inbox, prop_count, prop_slot,
+                                 tick, drop_mask, hops, hold, down,
+                                 by_sender)[:3]
+    return (st, inbox) + _compact_flags(st0, st) + (hop_stats,)
 
 
 # gather_rows' packed row: its linear index g*P + p, its CHG_* flags, the
@@ -1194,6 +1393,7 @@ def step_routed_compact(cfg: KernelConfig, st: GroupState, inbox: jax.Array,
 ROW_LIN, ROW_FLAGS, ROW_TERM, ROW_VOTE, ROW_COMMIT, ROW_STATE, ROW_LAST = (
     range(7))
 ROW_RING = 7
+HEAD_STATS = 2       # the header row's first hop_stats column
 
 
 def _pick_rows(mark: jax.Array, kp: int) -> Tuple[jax.Array, jax.Array]:
@@ -1215,23 +1415,24 @@ def _pick_rows(mark: jax.Array, kp: int) -> Tuple[jax.Array, jax.Array]:
     return jnp.where(j < k, g * P + p, G * P), k
 
 
-@functools.partial(jax.jit, static_argnums=5)
+@functools.partial(jax.jit, static_argnums=6)
 def gather_rows(st: GroupState, flags: jax.Array, any_need_host: jax.Array,
-                prop_count: jax.Array, prop_slot: jax.Array, kp: int
-                ) -> jax.Array:
+                hop_stats: jax.Array, prop_count: jax.Array,
+                prop_slot: jax.Array, kp: int) -> jax.Array:
     """The compact round's one readback, built on the device right behind
     the step: picks the rows the host has to see and packs them, with
     their values, into ONE (1 + kp, ROW_RING + W) int32 buffer.
 
-    `flags` and `any_need_host` are what step_routed_compact /
-    step_routed_read_auto returned for the round (still on the device),
+    `flags`, `any_need_host` and `hop_stats` are what step_routed_compact
+    / step_routed_read_auto returned for the round (still on the device),
     `st` the state after it, prop_count / prop_slot the very arrays the
     step was given: a group with proposals staged contributes its leader
     row (g, prop_slot[g]) whether or not it changed, because admission
     reads it. The picked rows are that union in ascending g*P + p, the
     order np.nonzero walks a flag map in.
 
-    Row 0 is the header (any_need_host, K = the union's true size, zeros);
+    Row 0 is the header (any_need_host, K = the union's true size, the
+    hops' hop_stats, busy groups then passes, from HEAD_STATS on, zeros);
     row 1 + j is the j-th picked row (ROW_* columns). kp is a trace-time
     constant, one program a size bucket; K > kp means the bucket missed
     and rows kp.. are not there: the caller asks again with a larger kp.
@@ -1249,6 +1450,8 @@ def gather_rows(st: GroupState, flags: jax.Array, any_need_host: jax.Array,
         [jnp.stack(cols, axis=1), st.log_term[gi, pi]], axis=1)
     head = jnp.zeros((1, rows.shape[1]), jnp.int32)
     head = head.at[0, 0].set(any_need_host.astype(jnp.int32)).at[0, 1].set(k)
+    head = head.at[0, HEAD_STATS:HEAD_STATS + hop_stats.size].set(
+        hop_stats.reshape(-1))
     return jnp.concatenate([head, rows], axis=0)
 
 
@@ -1261,8 +1464,8 @@ def step_routed_slots(cfg: KernelConfig, st: GroupState, inbox: jax.Array,
     path, fused routing — an all_to_all over the peers mesh axis when the
     state is sharded across hosts (the ICI/DCN consensus transport of
     SURVEY §2.4)."""
-    st, outbox = _step_body(cfg, st, inbox, cnt_gp, None, tick,
-                            quiet=False)
+    st, outbox, _ = _step_body(cfg, st, inbox, cnt_gp, None, tick,
+                               _full_msgs)
     return st, route_local(outbox)
 
 
@@ -1273,7 +1476,9 @@ def step_routed_slots_auto(cfg: KernelConfig, st: GroupState,
                            hops: int = 1) -> Tuple[GroupState, jax.Array]:
     """step_routed_slots with the quiescent fast path (and the same
     multi-hop/drop-mask machinery as step_routed_auto — this IS that
-    function with per-slot admission selected via prop_slot=None).
+    function with per-slot admission selected via prop_slot=None). A busy
+    hop makes the P passes by sender (by_sender): the peers are sharded
+    over hosts, where a pass by rank would end on a collective of its own.
 
     DURABILITY CONSTRAINT (multi-host callers): hops MUST stay 1 when
     peers are sharded across independently-failing hosts. With hops>1
@@ -1284,8 +1489,8 @@ def step_routed_slots_auto(cfg: KernelConfig, st: GroupState,
     quorum WITHOUT an acked entry (the exact loss the persist-before-
     send contract exists to prevent). Multi-hop is safe only where all
     peers share one failure domain (the single-host MultiEngine)."""
-    return step_routed_auto.__wrapped__(cfg, st, inbox, cnt_gp, None,
-                                        tick, drop_mask, hops)
+    return _hops(cfg, st, inbox, cnt_gp, None, tick, drop_mask, hops, None,
+                 None, by_sender=True)[:2]
 
 
 @functools.partial(jax.jit, static_argnums=0, donate_argnums=_donate_at_import((1, 2)))
@@ -1325,9 +1530,9 @@ def step_routed(cfg: KernelConfig, st: GroupState, inbox: jax.Array,
 # TPU box conservatively).
 
 _STEP_STATICS = {
-    "step_routed_auto": (0, 7),
-    "step_routed_compact": (0, 7),
-    "step_routed_read_auto": (0, 7),
+    "step_routed_auto": (0, 7, 10),
+    "step_routed_compact": (0, 7, 10),
+    "step_routed_read_auto": (0, 7, 10),
     "step_routed_slots_auto": (0, 6),
 }
 

@@ -148,7 +148,7 @@ def mesh_gather_rows(rep):
     def gather_rows(st, flags, *rest):
         return body(st, jax.lax.with_sharding_constraint(flags, rep), *rest)
 
-    return jax.jit(gather_rows, static_argnums=5, out_shardings=rep)
+    return jax.jit(gather_rows, static_argnums=6, out_shardings=rep)
 
 
 # The longest the engine thread waits for work after a round that found
@@ -443,7 +443,11 @@ class MultiEngine:
         # round plus a forced leader heartbeat and a per-group
         # read-quorum tally; a (G,) confirmed flag and a (G,) captured
         # commit index come back with the state, and the compact step's
-        # flag map and attestation).
+        # flag map and attestation). Each returns last the (2, hops) counts
+        # of groups busy on each hop and of the sequential passes its
+        # message phase made (kernel._hops): a hop with no busy group
+        # ran the one-pass message phase, any other as many passes as
+        # its busiest receiver needed (P on a mesh, below).
         self._st_sh = self._mb_sh = None
         if cfg.mesh is not None:
             # Mesh placement: pinned out_shardings keep the state AND the
@@ -452,10 +456,13 @@ class MultiEngine:
             # all_to_all over the "peers" mesh axis, the ICI transport of
             # SURVEY §2.4; lax.cond keeps the sharded layouts:
             # tests/test_tpu_compile.py holds the collectives to one
-            # scalar all-reduce per hop). What a step returns beside them
+            # scalar all-reduce per hop: by_sender gives a busy hop the P
+            # passes by sender, since the loop of passes by rank would
+            # end every pass on one more). What a step returns beside them
             # is pinned too: the flag map sharded like the state, the
             # attestation replicated, the read plane's two (G,) arrays
-            # sharded on groups (the read step returns all four).
+            # sharded on groups (the read step returns all four), the
+            # hops' counts replicated.
             from etcd_tpu.parallel.mesh import (flag_sharding,
                                                 group_sharding,
                                                 mailbox_sharding,
@@ -473,10 +480,10 @@ class MultiEngine:
             def step_fn(name):
                 fn = jax.jit(
                     _named_partial(getattr(kernel, name).__wrapped__,
-                                   self.kcfg, hops=cfg.hops),
+                                   self.kcfg, hops=cfg.hops, by_sender=True),
                     donate_argnums=kernel.donate_safe((0, 1)),
                     out_shardings=(self._st_sh, self._mb_sh,
-                                   *extra_out[name]))
+                                   *extra_out[name], rep))
                 return lambda st, inbox, pc, ps, t, hold, down=None: fn(
                     st, inbox, pc, ps, t, self.drop_mask, hold=hold,
                     **({} if down is None else {"down": down}))
@@ -2193,27 +2200,27 @@ class MultiEngine:
             # the forced leader heartbeat all ride the same program),
             # and its step returns the same on-device diff as the
             # compact step's: one record builder serves both.
-            st, inbox, conf_d, rc_d, f_d, a_d = self._step_fn_r(
+            st, inbox, conf_d, rc_d, f_d, a_d, stats_d = self._step_fn_r(
                 self.st, self.inbox, pc_d, ps_d, tick, hold, down)
             if self._compact:
                 flags_d, anh_d = f_d, a_d
         elif self._compact:
-            st, inbox, flags_d, anh_d = self._step_fn_c(
+            st, inbox, flags_d, anh_d, stats_d = self._step_fn_c(
                 self.st, self.inbox, pc_d, ps_d, tick, hold, down)
         else:
-            st, inbox = self._step_fn(
+            st, inbox, stats_d = self._step_fn(
                 self.st, self.inbox, pc_d, ps_d, tick, hold, down)
         self.st = st
         self.inbox = inbox
         # The compact round's one readback, enqueued right behind the
         # step with no host read in between: gather_rows picks the rows
         # that changed (and the staged leader rows) where the flag map
-        # lies and packs them with the attestation into one buffer. A
-        # round after a surgery takes the full readback whatever the
-        # device says, and asks for nothing here.
+        # lies and packs them with the attestation and the hops'
+        # counts into one buffer. A round after a surgery takes the full
+        # readback whatever the device says, and asks for nothing here.
         gather = buf_d = None
         if flags_d is not None and not self._force_full:
-            gather = (st, flags_d, anh_d, pc_d, ps_d)
+            gather = (st, flags_d, anh_d, stats_d, pc_d, ps_d)
             kp = self._gather_bucket(len(self._staged))
             buf_d = self._gather_rows(*gather, kp)
         self._staged_prev = len(self._staged)
@@ -2239,6 +2246,8 @@ class MultiEngine:
         d_readback = d_record = 0.0
         if buf_d is not None:
             buf = np.asarray(buf_d)
+            hop_stats = buf[0, self._kernel.HEAD_STATS:][
+                :2 * self.cfg.hops].reshape(2, -1)
             if o:
                 self._d2h(buf_d)
             if not buf[0, 0]:           # the device attests: no need-host
@@ -2260,9 +2269,9 @@ class MultiEngine:
                               t_now)
         if rec is None:
             full = (st.term, st.vote, st.commit, st.state,
-                    st.last_index, st.log_term, st.need_host)
-            (term, vote, commit, state, last, ring, need_host) = (
-                np.array(a) for a in self._jax.device_get(full))
+                    st.last_index, st.log_term, st.need_host, stats_d)
+            (term, vote, commit, state, last, ring, need_host,
+             hop_stats) = (np.array(a) for a in self._jax.device_get(full))
             if o:
                 t_now = time.perf_counter()
                 d_readback += t_now - t_ph
@@ -2389,6 +2398,11 @@ class MultiEngine:
             part["build"].observe(
                 d_record - self._rec_gather - self._rec_admit)
             o.c_readback[readback_kind].inc()
+            hop_busy, hop_passes = hop_stats
+            n_full = int(np.count_nonzero(hop_busy))
+            o.c_step_hops["full"].inc(n_full)
+            o.c_step_hops["quiet"].inc(len(hop_busy) - n_full)
+            o.c_step_passes.inc(int(hop_passes.sum()))
             o.flight.mark(r_no, obs_mod.STEPPED, t_stepped)
             if self._staged:
                 o.h_batch.observe(self._last_admitted)
@@ -2742,14 +2756,16 @@ class MultiEngine:
         G, P = self.cfg.groups, self.cfg.peers
         flags_d = jnp.zeros((G, P), jnp.uint8)
         anh_d = jnp.zeros((), bool)
+        stats_d = jnp.zeros((2, self.cfg.hops), jnp.int32)
         if self.cfg.mesh is not None:
             from etcd_tpu.parallel.mesh import (flag_sharding,
                                                 replicated_sharding)
+            rep = replicated_sharding(self.cfg.mesh)
             flags_d = jax.device_put(flags_d, flag_sharding(self.cfg.mesh))
-            anh_d = jax.device_put(anh_d,
-                                   replicated_sharding(self.cfg.mesh))
+            anh_d = jax.device_put(anh_d, rep)
+            stats_d = jax.device_put(stats_d, rep)
         for kp in self._gather_buckets():
-            self._gather_rows(self.st, flags_d, anh_d, self._zero,
+            self._gather_rows(self.st, flags_d, anh_d, stats_d, self._zero,
                               self._zero, kp).block_until_ready()
 
     def _compact_record_admit(self, buf: np.ndarray, kp: int, gather,

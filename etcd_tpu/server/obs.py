@@ -122,6 +122,20 @@ gather_rebuckets = metrics.Counter(
     "Compact rounds that picked more rows than the size bucket "
     "gather_rows was called with, so the call and its blocking read were "
     "made a second time at the bucket that holds them.")
+STEP_PATHS = ("quiet", "full")
+step_hops = metrics.LabeledCounter(
+    "etcd_engine_step_hops_total",
+    "Hops of the device step by the message phase that ran, which the "
+    "step chooses there: quiet (no group busy: one pass over all groups) "
+    "or full (some group electing, changing term or waiting for the "
+    "host: sequential passes over all groups, as many as the busiest "
+    "receiver holds messages that need one; one a peer slot on a mesh).",
+    ("path",))
+step_passes = metrics.Counter(
+    "etcd_engine_step_passes_total",
+    "Sequential message passes, summed over the hops of the device step "
+    "(a quiet hop makes none; a full hop as many as its busiest receiver "
+    "needs by rank, or one a peer slot by sender).")
 need_host_seconds = metrics.Histogram(
     "etcd_engine_need_host_seconds",
     "Wall time of the need-host surgery on the round thread, one "
@@ -762,6 +776,10 @@ class EngineObs:
         for c in self.c_readback.values():
             c.inc(0.0)              # flat from the start, like the phases
         self.c_gather_rebuckets = gather_rebuckets
+        self.c_step_hops = {k: step_hops.labels(k) for k in STEP_PATHS}
+        for c in self.c_step_hops.values():
+            c.inc(0.0)
+        self.c_step_passes = step_passes
         self.c_d2h_syncs = d2h_syncs
         self.c_d2h_bytes = d2h_bytes
         self.h_pending_wait = pending_wait

@@ -471,12 +471,14 @@ def test_device_pick_is_the_hosts_union(seed, groups, density):
         [np.flatnonzero(flags.reshape(-1)), staged * P + slot[staged]]))
     for kp, nh in ((256, False), (1024, True)):
         buf = np.asarray(kernel.gather_rows(
-            st, jnp.asarray(flags), jnp.asarray(nh), jnp.asarray(count),
+            st, jnp.asarray(flags), jnp.asarray(nh),
+            jnp.asarray([3, 0, kp], jnp.int32), jnp.asarray(count),
             jnp.asarray(slot), kp))
         assert buf.dtype == np.int32
         assert buf.shape == (1 + kp, kernel.ROW_RING + W)
-        assert buf[0].tolist() == [int(nh), len(lin)] + [0] * (
-            buf.shape[1] - 2)
+        assert kernel.HEAD_STATS == 2   # the hops' counts ride along
+        assert buf[0].tolist() == [int(nh), len(lin), 3, 0, kp] + [0] * (
+            buf.shape[1] - 5)
         n = min(kp, len(lin))
         rows, pad = buf[1:1 + n], buf[1 + n:]
         assert np.array_equal(rows[:, kernel.ROW_LIN], lin[:n])

@@ -19,9 +19,22 @@
     the same through the process entry with the three flags and a real
     SIGKILL;
 (e) with the feature off the lowered step programs are textually the
-    parent's and take no extra argument, and a seeded run's trajectory is
-    the parent's bit for bit (tests/step_programs_parent.json, recorded on
-    the parent commit by `python tests/test_lagging_followers.py --record`).
+    recorded ones and take no extra argument, and a seeded run's trajectory
+    is the parent's bit for bit (tests/step_programs_parent.json, written by
+    `python tests/test_lagging_followers.py --record`). The three program
+    hashes were recorded again in PR 40, on its own tree: the quiescence
+    predicate is folded group by group there (and asks for at most one
+    append a receiver where it asked for one LEADER row a group), a busy
+    hop makes its sequential passes by rank (kernel._ranked_msgs) and the
+    hops' counts of busy groups and passes come back with the state, so
+    the text changed by design. `args` and `trajectory_sha256` are PR 35's parent's
+    still, unedited: the unchanged digest is the proof that a seeded
+    160-round run with elections is that parent's bit for bit.
+    `by_sender_step_sha256` is new in PR 40 and is PR 40's parent's text
+    (computed there from `_step_body(..., quiet=False)`, and the same on
+    PR 40's tree): the step with the P passes by sender, which is what a
+    busy hop runs in a mesh's programs and in the multi-host step
+    (`by_sender`), lowers to what it was before the passes by rank came.
 """
 import hashlib
 import json
@@ -77,6 +90,19 @@ def _lowered(name, hold=None):
     return getattr(kernel, name).lower(*args).as_text()
 
 
+def _lowered_by_sender():
+    """The step whose message phase is the P passes by sender, lowered
+    alone: a busy hop of a program built with by_sender=True."""
+    cfg, st, inbox = _small()
+    z = jnp.zeros(cfg.groups, jnp.int32)
+
+    def body(st, inbox, pc, ps, tick):
+        return kernel._step_body(cfg, st, inbox, pc, ps, tick,
+                                 kernel._full_msgs)[:2]
+
+    return jax.jit(body).lower(st, inbox, z, z, jnp.asarray(True)).as_text()
+
+
 def _n_args(text):
     head = text[text.index("func.func public @main("):]
     return head[:head.index("->")].count("%arg")
@@ -95,7 +121,7 @@ def _trajectory_digest(hold=None):
                             .astype(np.int32))
         pc = jnp.asarray((rng.integers(0, cfg.max_ents + 1, size=cfg.groups)
                           * (state == LEADER).any(axis=1)).astype(np.int32))
-        st, inbox, _, _ = kernel.step_routed_compact(
+        st, inbox, *_ = kernel.step_routed_compact(
             cfg, st, inbox, pc, slots, jnp.asarray(True), None, 3, *extra)
     h = hashlib.sha256()
     for k, v in sorted(st._asdict().items()):
@@ -112,6 +138,8 @@ def _record():
         doc["programs"][name] = {
             "sha256": hashlib.sha256(text.encode()).hexdigest(),
             "args": _n_args(text)}
+    doc["by_sender_step_sha256"] = hashlib.sha256(
+        _lowered_by_sender().encode()).hexdigest()
     doc["trajectory_sha256"] = _trajectory_digest()
     return doc
 
@@ -137,6 +165,14 @@ def test_without_the_hold_the_step_program_is_the_parents(name, parent):
     assert "tensor<8x5xi1>" in held[:held.index("->")]
 
 
+def test_the_step_by_sender_is_the_parents_text(parent):
+    """What by_sender=True puts on a busy hop (a mesh's programs,
+    engine.py; step_routed_slots_auto) is the parent's full path, text
+    for text: tests/test_tpu_compile.py counts its collectives."""
+    assert (hashlib.sha256(_lowered_by_sender().encode()).hexdigest()
+            == parent["by_sender_step_sha256"])
+
+
 def test_without_the_hold_a_seeded_trajectory_is_the_parents(parent):
     assert _trajectory_digest() == parent["trajectory_sha256"]
     # a hold that holds nothing changes nothing either
@@ -149,8 +185,8 @@ def test_a_held_column_gets_heartbeats_and_no_append_in_the_kernel():
     G, P = cfg.groups, cfg.peers
     z = jnp.zeros(G, jnp.int32)
     for _ in range(40):                         # elect
-        st, inbox = kernel.step_routed_auto(cfg, st, inbox, z, z,
-                                            jnp.asarray(True), None, 1)
+        st, inbox, _ = kernel.step_routed_auto(cfg, st, inbox, z, z,
+                                               jnp.asarray(True), None, 1)
     state = np.asarray(st.state)
     assert (state == LEADER).sum(axis=1).tolist() == [1] * G
     lead = (state == LEADER).argmax(axis=1)
@@ -162,7 +198,7 @@ def test_a_held_column_gets_heartbeats_and_no_append_in_the_kernel():
     apps = hbs = 0
     for r in range(120):
         pc = jnp.asarray(np.full(G, 2, np.int32))
-        st, inbox = kernel.step_routed_auto(
+        st, inbox, _ = kernel.step_routed_auto(
             cfg, st, inbox, pc, jnp.asarray(lead.astype(np.int32)),
             jnp.asarray(True), None, 1, jnp.asarray(hold))
         to_victim = np.asarray(inbox)[np.arange(G), victim][..., F_TYPE]

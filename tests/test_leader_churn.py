@@ -141,9 +141,12 @@ def _n_args(text):
 
 @pytest.mark.parametrize("name", STEPS)
 def test_the_down_map_is_one_more_argument_of_the_step_program(name):
-    """(that without it the programs are the parent's, text and arguments,
-    is tests/test_lagging_followers.py's, against step_programs_parent.json,
-    which this PR leaves as it was)"""
+    """(that without it the programs are the recorded ones, text and
+    arguments, is tests/test_lagging_followers.py's, against
+    step_programs_parent.json: its program hashes recorded again in PR 40,
+    whose steps fold the quiescence predicate group by group, make a busy
+    hop's passes by rank and return the hops' counts, its `args` and
+    `trajectory_sha256` left as they were)"""
     cfg, st, inbox = _small()
     z = jnp.zeros(cfg.groups, jnp.int32)
     args = (cfg, st, inbox, z, z, jnp.asarray(True), None, 3)
@@ -163,8 +166,8 @@ def test_the_down_map_is_one_more_argument_of_the_step_program(name):
 def _elect(cfg, st, inbox, rounds=60, hops=1):
     z = jnp.zeros(cfg.groups, jnp.int32)
     for _ in range(rounds):
-        st, inbox = kernel.step_routed_auto(cfg, st, inbox, z, z,
-                                            jnp.asarray(True), None, hops)
+        st, inbox, _ = kernel.step_routed_auto(cfg, st, inbox, z, z,
+                                               jnp.asarray(True), None, hops)
     state = np.asarray(st.state)
     assert (state == LEADER).sum(axis=1).tolist() == [1] * cfg.groups
     return st, inbox, (state == LEADER).argmax(axis=1)
@@ -198,10 +201,11 @@ def test_the_down_map_is_the_equivalent_drop_mask(hops):
                           * (terms > 0)).astype(np.int32))
         ps = jnp.asarray(slots.astype(np.int32))
         dd = jnp.asarray(d)
-        a, ia = kernel.step_routed_auto(cfg, a, ia, pc, ps, jnp.asarray(True),
-                                        None, hops, None, dd)
-        b, ib = kernel.step_routed_auto(cfg, b, ib, pc, ps, jnp.asarray(True),
-                                        kernel.down_drop_mask(dd), hops)
+        a, ia, _ = kernel.step_routed_auto(
+            cfg, a, ia, pc, ps, jnp.asarray(True), None, hops, None, dd)
+        b, ib, _ = kernel.step_routed_auto(
+            cfg, b, ib, pc, ps, jnp.asarray(True),
+            kernel.down_drop_mask(dd), hops)
         for k, v in a._asdict().items():
             if k != "need_host":
                 assert np.array_equal(np.asarray(v),
@@ -266,7 +270,7 @@ def test_the_kernel_under_the_down_map_is_the_scalar_raft_at_seven_peers():
         pc = np.where((terms > 0) & (rng.rand(G) < 0.5),
                       rng.randint(1, cfg.max_ents + 1, G), 0).astype(np.int32)
         ps = slots.astype(np.int32)
-        st, nxt = kernel.step_routed_auto(
+        st, nxt, _ = kernel.step_routed_auto(
             cfg, st, jnp.asarray(inbox), jnp.asarray(pc), jnp.asarray(ps),
             jnp.asarray(True), None, 1, None, jnp.asarray(down))
         mirror.run_round(inbox, pc, ps)
@@ -306,7 +310,7 @@ def test_a_cut_and_a_return_end_where_the_scalar_network_ends():
 
     def step(n, slot, down):
         nonlocal st, inbox
-        st, inbox = kernel.step_routed_auto(
+        st, inbox, _ = kernel.step_routed_auto(
             cfg, st, inbox, jnp.asarray([n], jnp.int32),
             jnp.asarray([slot], jnp.int32), jnp.asarray(True), None, 1, None,
             jnp.asarray(down))

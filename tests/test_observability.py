@@ -355,7 +355,7 @@ def test_profiler_trace_carries_the_round_phases(tmp_path):
 
 
 _OBS_OFF_CHILD = r"""
-import json, os, sys, threading, urllib.request
+import json, os, sys, threading, time, urllib.request
 os.environ["ETCD_TPU_OBS"] = "off"
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 from etcd_tpu.etcdhttp.tenants import EngineHttp
@@ -381,6 +381,9 @@ before = http("GET", base + "/metrics")
 for i in range(8):
     http("PUT", f"{base}/tenants/{i % 4}/v2/keys/off/k{i}", f"value=v{i}")
     http("GET", f"{base}/tenants/{i % 4}/v2/keys/off/k{i}?quorum=true")
+deadline = time.time() + 30     # a fast member is done in ~32 rounds: let
+while eng.round_no <= 32 and time.time() < deadline:    # idle ones tick on
+    time.sleep(0.05)
 after = http("GET", base + "/metrics")
 print(json.dumps({"before": before, "after": after,
                   "rounds": eng.round_no}))

@@ -21,7 +21,8 @@ import pytest
 
 from etcd_tpu.ops import kernel
 from etcd_tpu.ops.state import GroupState, KernelConfig, init_state
-from etcd_tpu.parallel.mesh import (mailbox_sharding, make_mesh, shard_state,
+from etcd_tpu.parallel.mesh import (mailbox_sharding, make_mesh,
+                                    replicated_sharding, shard_state,
                                     state_sharding)
 
 pytestmark = pytest.mark.skipif(
@@ -39,9 +40,9 @@ def test_sharded_step_routed_is_bit_identical(peers_axis):
     # auto kernel, cfg.hops, drop mask traced in and cut per hop.
     step_sh = jax.jit(
         functools.partial(kernel.step_routed_auto.__wrapped__, cfg,
-                          hops=HOPS),
+                          hops=HOPS, by_sender=True),
         donate_argnums=(0, 1),
-        out_shardings=(state_sharding(mesh), mb))
+        out_shardings=(state_sharding(mesh), mb, replicated_sharding(mesh)))
 
     st_ref = init_state(cfg, stagger=True)
     st_sh = shard_state(init_state(cfg, stagger=True), mesh)
@@ -57,10 +58,10 @@ def test_sharded_step_routed_is_bit_identical(peers_axis):
         drop = jnp.asarray(
             1 - (rng.rand(G, P, P) < 0.25)[..., None].astype(np.int32))
 
-        st_ref, inbox_ref = kernel.step_routed_auto(
+        st_ref, inbox_ref, _ = kernel.step_routed_auto(
             cfg, st_ref, inbox_ref, pc, ps, jnp.asarray(True), drop, HOPS)
-        st_sh, inbox_sh = step_sh(st_sh, inbox_sh, pc, ps,
-                                  jnp.asarray(True), drop)
+        st_sh, inbox_sh, _ = step_sh(st_sh, inbox_sh, pc, ps,
+                                     jnp.asarray(True), drop)
 
         for name in GroupState._fields:
             a = np.asarray(getattr(st_ref, name))

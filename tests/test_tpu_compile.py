@@ -155,8 +155,10 @@ def _compile_mesh(topo, G: int, name: str = "step_routed_auto"):
         out_sh += (group_sharding(mesh),) * 2
     if name != "step_routed_auto":
         out_sh += (flag_sharding(mesh), rep)
+    out_sh += (rep,)                    # the hops' counts
     fn = jax.jit(
-        _named_partial(getattr(kernel, name).__wrapped__, cfg, hops=HOPS),
+        _named_partial(getattr(kernel, name).__wrapped__, cfg, hops=HOPS,
+                       by_sender=True),
         donate_argnums=(0, 1), out_shardings=out_sh)
     return fn.lower(*_shapes(cfg, st_sh, mb_sh, rep), None).compile()
 
@@ -174,11 +176,16 @@ def _collectives(compiled) -> list:
 def _check_mesh(compiled, scalars: int = HOPS) -> None:
     # Groups never talk to each other: nothing data-sized may cross the
     # groups axis. The only collective is the scalar all-reduce of the
-    # global quiet predicate that selects the lax.cond branch, one per hop
-    # (the compact step folds its need-host attestation in one more).
+    # count of busy groups that selects the lax.cond branch, one per hop
+    # (the compact step folds its need-host attestation in one more). The
+    # busy branch is the P passes by sender (by_sender): the loop of passes
+    # by rank ends every pass on "does any receiver hold another message",
+    # a collective of its own once the groups are sharded, so a mesh's
+    # programs have no loop at all.
     reduces = _collectives(compiled)
     assert len(reduces) <= scalars, reduces
     assert all("[]" in ln.split("=", 2)[1] for ln in reduces), reduces
+    assert " while(" not in compiled.as_text()
 
 
 def test_mesh_variant_compiles_for_v5e_2x2(topo, as_served):
@@ -212,7 +219,8 @@ def _compile_mesh_gather(topo, G: int, K: int):
     st, _, pc, ps, attest = _shapes(cfg, st_sh, rep, rep)
     flags = jax.ShapeDtypeStruct((G, P), jnp.uint8,
                                  sharding=flag_sharding(mesh))
-    return mesh_gather_rows(rep).lower(st, flags, attest, pc, ps,
+    stats = jax.ShapeDtypeStruct((2, HOPS), jnp.int32, sharding=rep)
+    return mesh_gather_rows(rep).lower(st, flags, attest, stats, pc, ps,
                                        K).compile()
 
 
