@@ -729,9 +729,10 @@ class TenantAPI:
         ctx.send_json(200, obs.flight.to_trace_events())
 
     def handle_debug_traces(self, ctx: Ctx, suffix: str) -> None:
-        """GET /debug/traces — sampled end-to-end proposal spans (stage
-        -> relative seconds per request id); empty unless
-        ETCD_TPU_TRACE_EVERY is set."""
+        """GET /debug/traces — the sampled requests' spans (stage ->
+        relative seconds per request id; 1 id in 16 unless
+        ETCD_TPU_TRACE_EVERY says otherwise): the newest finished ones
+        and those in flight."""
         obs = getattr(self.engine, "obs", None)
         if obs is None:
             ctx.send_json(404, {"message": "engine has no tracer"})

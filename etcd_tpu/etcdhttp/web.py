@@ -499,7 +499,8 @@ class LoopOp:
         submitter.expire(token) -> the value to answer a request with
             that outlived `timeout` (and must not be delivered later)
         submitter.tracer (optional) -> a server.obs.Tracer for the
-            front_in / woke / replied marks of a sampled rid
+            front_in and woke marks of a sampled rid, and its finish()
+            where the reply is handed to the socket
 
     (MultiEngine is one: its items are (tenant, Request) pairs.)
 
@@ -1106,11 +1107,12 @@ class HttpServer:
 
     def _handed(self, replies: list, now: float) -> None:
         """Handed to the socket at `now`, the front's span of each ends: a
-        pass's spans in one call per kind, a sampled mark on its own."""
+        pass's spans in one call per kind, a sampled request's span
+        folded on its own."""
         spans: Dict[str, Tuple[list, list]] = {}
         for t_in, kind, waited, op in replies:
             if op is not None and op.traced:
-                op.submitter.tracer.mark(op.token.rid, "replied", t=now)
+                op.submitter.tracer.finish(op.token.rid, kind, now)
             if self._obs_on:
                 whole, own = spans.setdefault(kind, ([], []))
                 whole.append(now - t_in)
@@ -1140,6 +1142,7 @@ class HttpServer:
                     self._refuse(op.conn, 500, str(e))
                 continue
             tr = getattr(sub, "tracer", None)
+            every = tr.every if tr is not None else 0
             deadline = time.monotonic()
             for op, tok in zip(ops, tokens):
                 op.t_submit = t
@@ -1149,7 +1152,7 @@ class HttpServer:
                 op.token = tok
                 op.deadline = deadline + op.timeout
                 self._inflight[tok.rid] = op
-                if tr is not None and tr.every and tr.sampled(tok.rid):
+                if every and tok.rid % every == 0:
                     op.traced = True
                     tr.mark(tok.rid, "front_in", t=op.ctx.t_in)
 
@@ -1357,5 +1360,5 @@ class HttpServer:
                 self._h_self[kind].observe(dt - front.blocked)
                 if front.trace is not None:
                     tracer, rid = front.trace
-                    tracer.mark(rid, "replied")
+                    tracer.finish(rid, kind, ctx.t_in + dt)
         return keep and not conn.closing

@@ -1082,13 +1082,24 @@ class MultiEngine:
                 if batch.acked or batch.items:
                     t_gate = time.perf_counter()
                     self.wal.wait_durable(view[4])
+                    t_acked = time.perf_counter()
                     if o:
-                        o.h_ack_wait.observe(time.perf_counter()
-                                             - t_gate)
-                    if tr.every:
+                        o.h_ack_wait.observe(t_acked - t_gate)
+                    every = tr.every
+                    if every:
+                        # `durable` is the writer's own stamp for the
+                        # view's ticket (a side stamp: applies run ahead
+                        # of the WAL); `acked` ends the gate segment.
+                        t_dur = None
                         for rid, _res in batch.items:
-                            tr.mark(rid, "durable", ticket=view[4])
-                            tr.mark(rid, "acked")
+                            if rid % every == 0:
+                                if t_dur is None:
+                                    t_dur = (self.wal.durable_at(view[4])
+                                             or t_acked)
+                                tr.mark(rid, "durable", t=t_dur,
+                                        ticket=view[4])
+                                tr.mark(rid, "acked", t=t_acked,
+                                        acked_round=view[5])
                     # The whole ack batch at once: the waiters the event
                     # loop front registered are released under one signal.
                     self.wait.trigger_many(batch.items)
@@ -1235,11 +1246,11 @@ class MultiEngine:
             r = Request(**{**r.__dict__, "id": self.reqid.next()})
         obs_on = self.obs.enabled
         tr = self.obs.tracer
-        if tr.every:
-            tr.mark(r.id, "submit", g=g)
         q = self.wait.register(r.id)
         payload = bytes([P_REQ]) + r.encode()
         t0 = time.perf_counter()
+        if tr.every and r.id % tr.every == 0:
+            tr.mark(r.id, "submit", t=t0, g=g)
         with self._lock:
             # The decoded Request rides along so the live apply path never
             # re-parses JSON it already has (replay still decodes bytes);
@@ -1324,6 +1335,7 @@ class MultiEngine:
         release behind wait_durable), a read's from _serve_ripe_reads."""
         obs_on = self.obs.enabled
         tr = self.obs.tracer
+        every = tr.every
         register = self.wait.register
         writes: List[tuple] = []
         reads: List[tuple] = []
@@ -1354,11 +1366,13 @@ class MultiEngine:
                 tokens.append(e if isinstance(e, errors.EtcdError) else
                               errors.EtcdError(errors.ECODE_RAFT_INTERNAL,
                                                cause=str(e)))
+                if every and r.id:
+                    tr.drop(r.id)
                 continue
             (reads if read else writes).append(item)
             tokens.append(_Submitted(r.id, g, read, t0))
-            if tr.every:
-                tr.mark(r.id, "submit", g=g)
+            if every and r.id % every == 0:
+                tr.mark(r.id, "submit", t=t0, g=g)
         lease = 0
         try:
             with self._lock:
@@ -1467,8 +1481,8 @@ class MultiEngine:
         for r in reqs:
             if r.id == 0:
                 r = Request(**{**r.__dict__, "id": self.reqid.next()})
-            if tr.every:
-                tr.mark(r.id, "submit", g=g)
+            if tr.every and r.id % tr.every == 0:
+                tr.mark(r.id, "submit", t=t0, g=g)
             queues.append((r.id, self.wait.register(r.id)))
             items.append((r.id, bytes([P_REQ]) + r.encode(), r, t0))
         with self._lock:
@@ -1547,10 +1561,10 @@ class MultiEngine:
             r = Request(**{**r.__dict__, "id": self.reqid.next()})
         obs_on = self.obs.enabled
         tr = self.obs.tracer
-        if tr.every:
-            tr.mark(r.id, "submit", g=g)
         q = self.wait.register(r.id)
         t0 = time.perf_counter()
+        if tr.every and r.id % tr.every == 0:
+            tr.mark(r.id, "submit", t=t0, g=g)
         with self._lock:
             lease = self._park_read(g, r)
         self._work.set()
@@ -1603,7 +1617,7 @@ class MultiEngine:
         return 0
 
     def _confirm_reads(self, read_take: Dict[int, int], conf: np.ndarray,
-                       rc: np.ndarray) -> None:
+                       rc: np.ndarray) -> List[int]:
         """Move snapshotted parked reads of confirmed groups to the ripe
         queue at this round's captured read index. Only the
         PRE-DISPATCH snapshot count moves — a read that parked after the
@@ -1611,8 +1625,12 @@ class MultiEngine:
         index above the captured one, so it waits for its own round.
         Unconfirmed groups keep their reads parked: a deposed leader's
         reads either re-confirm under the next leader (at its >= read
-        index — still linearizable) or time out; never served stale."""
+        index — still linearizable) or time out; never served stale.
+        Returns the sampled rids among the reads moved (the round stamps
+        their `staged` and `confirmed` from its own clock readings)."""
         o = self.obs if self.obs.enabled else None
+        every = self.obs.tracer.every
+        sampled: List[int] = []
         n_conf = 0
         now = time.monotonic()
         lease_s = self.cfg.read_lease_ms / 1000.0
@@ -1625,7 +1643,10 @@ class MultiEngine:
                 dq = self._reads[g]
                 moved = min(take, len(dq))
                 for _ in range(moved):
-                    self._ripe[g].append(dq.popleft() + (ri,))
+                    item = dq.popleft()
+                    self._ripe[g].append(item + (ri,))
+                    if every and item[0] % every == 0:
+                        sampled.append(item[0])
                 if moved:
                     self._ripe_dirty.add(g)
                     self._ripe_waiting += moved
@@ -1639,6 +1660,7 @@ class MultiEngine:
                     self._lease_term[g] = self._mirror_term(g)
         if o:
             o.h_read_confirms.observe(n_conf)
+        return sampled
 
     def _serve_ripe_reads(self) -> None:
         """Serve every ripe read whose group's apply cursor has reached
@@ -1664,6 +1686,7 @@ class MultiEngine:
             return
         o = self.obs if self.obs.enabled else None
         tr = self.obs.tracer
+        every = tr.every
         # Read coalescing: every read in this pass is at-or-past its
         # read index NOW, so one store get per distinct (group, path,
         # recursive, sorted) answers all of them — the get's instant
@@ -1685,8 +1708,8 @@ class MultiEngine:
                     result = err
                 memo[k] = result
             results.append((rid, result))
-            if tr.every:
-                tr.mark(rid, "acked", g=g)
+            if every and rid % every == 0:
+                tr.mark(rid, "acked", acked_round=self.round_no)
         self.wait.trigger_many(results)
         if o:
             o.c_reads_served.inc(len(served))
@@ -1974,27 +1997,6 @@ class MultiEngine:
             info["device_peak_bytes"] = max(peaks)
         return info
 
-    def profile(self, rounds: int = 20, out_dir: Optional[str] = None) -> str:
-        """Capture an XLA/device profile of `rounds` engine rounds (the
-        per-batch-step profiler hook SURVEY §5 calls for). Writes a
-        TensorBoard-loadable trace under <data_dir>/profiles and returns
-        the path. Drive rounds manually if the engine thread isn't
-        running."""
-        import os
-        out = out_dir or os.path.join(self.cfg.data_dir, "profiles")
-        os.makedirs(out, exist_ok=True)
-        running = self._thread is not None and self._thread.is_alive()
-        with self._jax.profiler.trace(out):
-            if running:
-                target = self.round_no + rounds
-                while (self.round_no < target
-                       and not self._stop_ev.is_set()):
-                    time.sleep(0.001)
-            else:
-                for _ in range(rounds):
-                    self.run_round()
-        return out
-
     # ------------------------------------------------------------------
     # the round
     # ------------------------------------------------------------------
@@ -2146,6 +2148,7 @@ class MultiEngine:
             gs_l, ss_l, cnt_l = [], [], []
             waited = o.h_pending_wait.observe if o else None
             t_staged = time.perf_counter() if o else 0.0
+            every = o.tracer.every if o else 0
             for g, (s, ents) in self._staged.items():
                 gs_l.append(g)
                 ss_l.append(s)
@@ -2154,11 +2157,16 @@ class MultiEngine:
                     # Queue wait of each request staged this round (items
                     # enqueued by do()/submit_many carry their time; a
                     # requeued item carries it negated, so each counts
-                    # once).
+                    # once, and a sampled one keeps its first `staged`:
+                    # its queue segment is the interval observed here).
                     for items in ents:
                         for it in items:
                             if len(it) > 3 and it[3] > 0:
                                 waited(t_staged - it[3])
+                                if every and it[0] % every == 0:
+                                    o.tracer.mark(it[0], "staged",
+                                                  t=t_staged,
+                                                  staged_round=r_no)
             staged_gs = np.asarray(gs_l, np.int64)
             staged_ss = np.asarray(ss_l, np.int64)
             prop_count[staged_gs] = cnt_l
@@ -2185,7 +2193,7 @@ class MultiEngine:
         down = self._churn_down() if self._churn is not None else None
 
         if o:
-            t_ph = time.perf_counter()
+            t_take = t_ph = time.perf_counter()
             clock.lap("dispatch", t_ph)
 
         # -- 2. the kernel round (fused step + routing: one ASYNC
@@ -2264,7 +2272,7 @@ class MultiEngine:
                 if o:
                     t_now = time.perf_counter()
                     d_record = t_now - t_ph
-                    t_ph = t_now
+                    t_ph = t_conf = t_now
                     clock.lap("tail" if rec is not None else "readback",
                               t_now)
         if rec is None:
@@ -2358,7 +2366,7 @@ class MultiEngine:
             if led is not None:
                 self._leaders_moved(gs_role, led)
             if o:
-                t_now = time.perf_counter()
+                t_conf = t_now = time.perf_counter()
                 d_record += t_now - t_ph
                 clock.lap("tail", t_now)
 
@@ -2368,11 +2376,18 @@ class MultiEngine:
         # mirrors the confirmation consults equal to this round's device
         # state).
         if conf_d is not None:
-            self._confirm_reads(read_take, np.asarray(conf_d),
-                                np.asarray(rc_d))
+            sampled = self._confirm_reads(read_take, np.asarray(conf_d),
+                                          np.asarray(rc_d))
             if o:
                 self._d2h(conf_d)
                 self._d2h(rc_d)
+                # A sampled read's `staged` is the pre-dispatch reading of
+                # the round that confirmed it, `confirmed` the reading
+                # behind that round's readback (phase boundaries both).
+                for rid in sampled:
+                    o.tracer.mark(rid, "staged", t=t_take,
+                                  staged_round=r_no)
+                    o.tracer.mark(rid, "confirmed", t=t_conf)
 
         # -- 6. persist, then apply+ack. WAL fsync strictly precedes the
         # acks of everything this round committed (doc.go:31-39 ordering)
@@ -2419,10 +2434,15 @@ class MultiEngine:
             if o:
                 o.h_wal_submit.observe(time.perf_counter() - t0)
                 o.flight.mark(r_no, obs_mod.WAL_SUBMITTED)
-            tr = self.obs.tracer
-            if tr.every and self._trace_rids:
+            if self._trace_rids:
+                tr = self.obs.tracer
+                t_sub = time.perf_counter()
                 for rid in self._trace_rids:
-                    tr.mark(rid, "wal_submit", ticket=self.wal.ticket)
+                    tr.mark(rid, "wal_submit", t=t_sub,
+                            ticket=self.wal.ticket)
+                    if sync_round:      # append_sync returned: it is
+                        tr.mark(rid, "durable", t=self.wal.durable_at(
+                            self.wal.ticket) or t_sub)
             self._recent_recs.append(rec)
         if sync_round:
             self._drain_applies()
@@ -2677,6 +2697,7 @@ class MultiEngine:
         insertion order, which both tails' scalar lists follow."""
         requeue: List[Tuple[int, List[Tuple[int, bytes]]]] = []
         tr = self.obs.tracer
+        every = tr.every
         n_admitted = 0
         t_admit = time.perf_counter()
         ann = self.obs.span("etcd.record.admit")
@@ -2704,10 +2725,10 @@ class MultiEngine:
                                     if len(it) > 3:
                                         waited(t_admit - abs(it[3]))
                     n_admitted += len(items)
-                    if tr.every:
+                    if every:
                         for it in items:
-                            if tr.sampled(it[0]):
-                                tr.mark(it[0], "admitted", g=g,
+                            if it[0] % every == 0:
+                                tr.mark(it[0], "admitted",
                                         round=rec.round_no)
                                 self._trace_rids.append(it[0])
                     rec.entries.append((g, i, t, payload))
@@ -2932,6 +2953,7 @@ class MultiEngine:
         the view's durability ticket clears the WAL watermark."""
         W = self.cfg.window
         tr = self.obs.tracer
+        every = tr.every
         if acct is None:
             acct = self._acks
         if view is None:
@@ -2987,12 +3009,13 @@ class MultiEngine:
                     else:
                         reqs = [Request.decode(b)
                                 for b in _unpack_multi(payload)]
-                    if not trigger and tr.every:
+                    if not trigger and every:
                         # Restart replay: sampled rids ride the durable
                         # Request payloads, so the trace picks them back
                         # up in the new process.
                         for r0 in reqs:
-                            tr.mark(r0.id, "replayed", g=g)
+                            if r0.id % every == 0:
+                                tr.mark(r0.id, "replayed", g=g)
                     # Batched fast path: runs of plain-file PUTs with no
                     # conditions and no TTL apply through ONE
                     # GIL-releasing C call per run
@@ -3038,7 +3061,8 @@ class MultiEngine:
                         except errors.EtcdError as err:
                             result = err
                         if trigger:
-                            if tr.every:
+                            traced = every and r.id % every == 0
+                            if traced:
                                 tr.mark(r.id, "applied")
                             if sink is not None:
                                 if r.method != METHOD_SYNC:
@@ -3047,9 +3071,10 @@ class MultiEngine:
                             else:
                                 if r.method != METHOD_SYNC:
                                     acct.acked += 1
+                                if traced:      # before its waiter runs
+                                    tr.mark(r.id, "acked",
+                                            acked_round=self.round_no)
                                 self.wait.trigger(r.id, result)
-                                if tr.every:
-                                    tr.mark(r.id, "acked")
                     if fp:
                         self._flush_many(st, fp, fv, fneed, frids,
                                          trigger, acct, sink)
@@ -3088,6 +3113,7 @@ class MultiEngine:
         _, descs = st.set_applied_many(fp, fv, need=fneed)
         if trigger:
             tr = self.obs.tracer
+            every = tr.every
             if sink is not None:
                 sink.acked += len(fp)
             else:
@@ -3099,14 +3125,15 @@ class MultiEngine:
                                                 index=idx)
                 else:
                     res = LazyWriteEvent(nd, pd, idx, now)
-                if tr.every:
+                traced = every and rid % every == 0
+                if traced:
                     tr.mark(rid, "applied")
                 if sink is not None:
                     sink.items.append((rid, res))
                 else:
+                    if traced:          # before its waiter runs
+                        tr.mark(rid, "acked", acked_round=self.round_no)
                     self.wait.trigger(rid, res)
-                    if tr.every:
-                        tr.mark(rid, "acked")
 
     def _apply_request(self, g: int, r: Request):
         """Deterministic request->store mapping (reference applyRequest

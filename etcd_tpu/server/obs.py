@@ -1,5 +1,5 @@
 """Pipeline observability plane: per-compartment metrics, a round
-flight recorder, and sampled end-to-end proposal traces.
+flight recorder, and sampled end-to-end request traces.
 
 This module gives each stage of the compartment pipeline (round loop
 -> WAL writer shards -> applier shards -> ack gate) the live
@@ -24,12 +24,15 @@ Three planes, all built to stay off the round loop's critical path:
     Perfetto) via SIGUSR2, GET /debug/flight, or automatically when a
     compartment fail-stops.
 
-  * Tracer: one in N proposals (ETCD_TPU_TRACE_EVERY) is followed by
-    request id through the HTTP front (engine.do), admission into a
-    round batch, the WAL submit, the durability gate, apply and ack —
-    an end-to-end span breakdown per sampled proposal. The rid rides
-    the durable Request payload, so a SIGKILL'd engine's replay
-    re-marks surviving sampled rids as "replayed".
+  * Tracer: one request in N (1 in 16 unless ETCD_TPU_TRACE_EVERY
+    says otherwise) is followed by request id through the HTTP front,
+    the staging queue, the round that takes it, the WAL submit, apply,
+    the durability gate, the front's wake and the reply. When the reply
+    is handed to the socket the span's consecutive stamps are folded
+    into etcd_request_segment_seconds{kind, segment} (the segments tile
+    the request) and etcd_request_rounds{kind}. The rid rides the
+    durable Request payload, so a SIGKILL'd engine's replay re-marks
+    surviving sampled rids as "replayed".
 
 On top of those, the accounting a host-bound member needs (PR 24): the
 round loop's seven disjoint phases tile the loop (RoundClock: wall,
@@ -52,6 +55,7 @@ import logging
 import os
 import threading
 import time
+from collections import deque
 from typing import Dict, List, Optional
 
 from etcd_tpu.utils import metrics
@@ -508,30 +512,91 @@ class FlightRecorder:
             return None
 
 
-# -- sampled proposal traces -------------------------------------------------
+# -- sampled request traces ----------------------------------------------------
 
-TRACE_STAGES = ("front_in", "submit", "admitted", "wal_submit", "durable",
-                "applied", "acked", "woke", "replied", "replayed")
+TRACE_STAGES = ("front_in", "submit", "staged", "admitted", "wal_submit",
+                "confirmed", "durable", "applied", "acked", "woke",
+                "replied", "replayed")
+# One request id in this many is followed, unless ETCD_TPU_TRACE_EVERY
+# says otherwise (0: none).
+TRACE_EVERY_DEFAULT = 16
+
+# The stamps that bound a request's segments, in order, and the segment
+# each consecutive pair makes: disjoint, and summing to replied - front_in.
+# `admitted` and `durable` are side stamps (/debug/traces shows them).
+SEGMENT_STAMPS = {
+    "write": ("front_in", "submit", "staged", "wal_submit", "applied",
+              "acked", "woke", "replied"),
+    "qread": ("front_in", "submit", "staged", "confirmed", "acked", "woke",
+              "replied"),
+}
+SEGMENT_NAMES = {
+    "write": ("parse", "queue", "round", "apply", "gate", "wake", "reply"),
+    "qread": ("parse", "queue", "round", "apply", "wake", "reply"),
+}
+_ROUND_BUCKETS = (1, 2, 3, 4, 6, 8, 12, 16, 32)
+
+request_segment = metrics.LabeledHistogram(
+    "etcd_request_segment_seconds",
+    "One segment of a sampled request's life (1 in 16 request ids, "
+    "ETCD_TPU_TRACE_EVERY), observed when its reply is handed to the "
+    "socket; a kind's segments tile etcd_http_request_seconds. parse: "
+    "head parsed to submit_pairs; queue: to the round that staged it (a "
+    "read: the round that confirmed it); round: to the WAL submit (a "
+    "read: to the confirmation read back); apply: to applied (a read: "
+    "the wait for the apply cursor and the serve); gate: the rest of the "
+    "applier pass and the wait for the fsync, to the ack released "
+    "(writes); wake: to the front's loop draining it; reply: to the "
+    "reply handed to the socket.",
+    ("kind", "segment"))
+request_rounds = metrics.LabeledHistogram(
+    "etcd_request_rounds",
+    "Engine rounds a sampled request took: the round whose commit view "
+    "(a read: whose serve) released it minus the round that staged it, "
+    "plus one.", ("kind",), buckets=_ROUND_BUCKETS)
+spans_dropped = metrics.Counter(
+    "etcd_request_spans_dropped_total",
+    "Sampled requests whose span was not folded into "
+    "etcd_request_segment_seconds: a stamp is missing (refused, timed "
+    "out, answered under a read lease, replayed after a restart, not a "
+    "write or quorum read) or the request never reached a reply the "
+    "front accounts for (engine.do without the front, a batch) and was "
+    "pushed out of the in-flight table.")
+# Every label set exists from import on: a window's first scrape already
+# exports it, at zero.
+_SEGMENT_HISTS = {kind: [request_segment.labels(kind, seg) for seg in names]
+                  for kind, names in SEGMENT_NAMES.items()}
+_ROUNDS_HISTS = {kind: request_rounds.labels(kind) for kind in SEGMENT_NAMES}
 
 
 class Tracer:
-    """Deterministic 1-in-N proposal sampling by request id.
+    """Deterministic 1-in-N request sampling by request id.
 
-    rid % every == 0 selects a proposal at the HTTP front (engine.do);
-    the same predicate re-selects it at every later stage — including a
-    restarted process's WAL replay, because the rid rides the durable
-    Request payload — so no sampling decision needs to travel. Off
-    (every=0) every call is one predicate test.
+    rid % every == 0 selects a request where the engine takes it in
+    (`submit`, which opens its span); the same predicate re-selects it at
+    every later stage — including a restarted process's WAL replay,
+    because the rid rides the durable Request payload — so no sampling
+    decision needs to travel. A call site on a per-request path tests the
+    predicate itself (one modulo an unsampled request) and calls mark()
+    for a sampled rid only. finish() ends the span where the front hands
+    the reply to the socket: the span leaves the in-flight table for the
+    bounded list /debug/traces serves and is folded into the segment
+    histograms, once. Off (every=0, or ETCD_TPU_OBS=off) every call is one
+    predicate test.
     """
 
-    MAX_SPANS = 512
+    MAX_LIVE = 1024     # sampled requests in flight
+    MAX_SPANS = 4096    # ended spans kept for /debug/traces
 
     def __init__(self, every: Optional[int] = None) -> None:
         if every is None:
-            every = int(os.environ.get("ETCD_TPU_TRACE_EVERY", "0"))
+            every = (int(os.environ.get("ETCD_TPU_TRACE_EVERY",
+                                        TRACE_EVERY_DEFAULT))
+                     if obs_enabled() else 0)
         self.every = max(0, every)
         self._lock = threading.Lock()
-        self._spans: Dict[int, dict] = {}
+        self._live: Dict[int, dict] = {}
+        self._done: deque = deque(maxlen=self.MAX_SPANS)
 
     def sampled(self, rid: int) -> bool:
         return bool(self.every) and rid % self.every == 0
@@ -539,35 +604,97 @@ class Tracer:
     def mark(self, rid: int, stage: str, t: Optional[float] = None,
              **extra) -> None:
         """Record one stage timestamp (now, or the perf_counter reading
-        `t` taken earlier) for a sampled rid. Cold path by construction
-        (1 in N); unsampled rids pay one modulo."""
+        `t` taken earlier) for a sampled rid. `submit` opens the span; a
+        mark for a rid with no open span (its request timed out, or was
+        pushed out) is dropped. Cold path by construction (1 in N), and
+        lock-free on an open span: a stamp is one dict store, and the
+        threads that stamp one request do so one after the other (only
+        opening and ending a span change the table, under the lock, both
+        on the front's loop for a request it serves), so the round and
+        applier threads never wait here for each other or for the loop."""
+        if not self.sampled(rid):
+            return
+        if t is None:
+            t = time.perf_counter()
+        span = self._live.get(rid)
+        if span is None:
+            if stage == "submit":
+                span = self._open(rid)
+            elif stage == "replayed":
+                # nothing follows a replay: straight to the list
+                with self._lock:
+                    self._done.append(
+                        {"rid": rid, "stages": {stage: t}, **extra})
+                spans_dropped.inc()
+                return
+            else:
+                return
+        span["stages"][stage] = t
+        if extra:
+            span.update(extra)
+
+    def _open(self, rid: int) -> dict:
+        with self._lock:
+            if len(self._live) >= self.MAX_LIVE:
+                # The oldest in flight will not see a reply any more.
+                self._done.append(self._live.pop(next(iter(self._live))))
+                spans_dropped.inc()
+            span = self._live[rid] = {"rid": rid, "stages": {}}
+        return span
+
+    def drop(self, rid: int) -> None:
+        """A sampled request was refused before its span opened."""
+        if self.sampled(rid):
+            spans_dropped.inc()
+
+    def finish(self, rid: int, kind: str,
+               t: Optional[float] = None) -> None:
+        """The reply of `rid`, a request of the front's `kind`, was handed
+        to the socket (at `t`): stamp `replied`, take the span out of the
+        in-flight table and fold it. One call per finished span, on the
+        thread that replied."""
         if not self.sampled(rid):
             return
         if t is None:
             t = time.perf_counter()
         with self._lock:
-            span = self._spans.get(rid)
-            if span is None:
-                if len(self._spans) >= self.MAX_SPANS:
-                    # Drop the oldest finished span first, else oldest.
-                    victim = next(
-                        (k for k, s in self._spans.items()
-                         if "acked" in s["stages"]
-                         or "replayed" in s["stages"]),
-                        next(iter(self._spans)))
-                    del self._spans[victim]
-                span = self._spans[rid] = {"rid": rid, "stages": {}}
-            span["stages"][stage] = t
-            span.update(extra)
+            span = self._live.pop(rid, None)
+            if span is not None:
+                span["stages"]["replied"] = t
+                span["kind"] = kind
+                self._fold(span, kind)
+                self._done.append(span)
+
+    @staticmethod
+    def _fold(span: dict, kind: str) -> None:
+        stages = span["stages"]
+        stamps = SEGMENT_STAMPS.get(kind, ())
+        if (not stamps or not all(s in stages for s in stamps)
+                or "staged_round" not in span or "acked_round" not in span):
+            spans_dropped.inc()
+            return
+        prev = stages[stamps[0]]
+        for hist, stamp in zip(_SEGMENT_HISTS[kind], stamps[1:]):
+            t = stages[stamp]
+            hist.observe(t - prev)
+            prev = t
+        span["rounds"] = span["acked_round"] - span["staged_round"] + 1
+        _ROUNDS_HISTS[kind].observe(span["rounds"])
+
+    def live(self) -> int:
+        """Sampled requests in flight."""
+        return len(self._live)
 
     def spans(self) -> List[dict]:
+        """Ended spans (the newest MAX_SPANS) and those in flight."""
         with self._lock:
-            return [dict(s, stages=dict(s["stages"]))
-                    for s in self._spans.values()]
+            return list(self._done) + [dict(s, stages=dict(s["stages"]))
+                                       for s in self._live.values()]
 
     def dump(self) -> dict:
-        """Spans with per-stage deltas (seconds from submit, or from
-        the earliest stage seen — replayed spans have no submit)."""
+        """Spans with per-stage deltas (seconds from the earliest stage
+        seen: front_in, or submit without the front; replayed spans have
+        neither)."""
         out = []
         for s in sorted(self.spans(), key=lambda s: s["rid"]):
             stages = s["stages"]

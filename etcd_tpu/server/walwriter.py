@@ -166,6 +166,10 @@ class WALWriter:
         # restart can actually observe.
         self._wm = threading.Condition()
         self._durable = 0
+        # The last advances of the watermark as (from, to, the writer's
+        # clock at the end of the fsync that made it): the sampled request
+        # trace's `durable` stamp (durable_at), kept only with an obs plane.
+        self._durable_at: deque = deque(maxlen=64)
         self._last_ticket = 0
         self._submitted = 0
         self._closed = False
@@ -237,17 +241,21 @@ class WALWriter:
                 with self._wm:
                     self._wm.notify_all()   # wake waiters to observe exc
                 return
+            t_dur = 0.0
             if ob is not None:
-                ob.h_wal_fsync[sh.idx].observe(time.perf_counter() - t0)
+                t_dur = time.perf_counter()
+                ob.h_wal_fsync[sh.idx].observe(t_dur - t0)
                 ob.h_wal_commit[sh.idx].observe(len(batch))
                 for _t, rnd, _sub in batch:
-                    ob.flight.mark(rnd, _FLIGHT_DURABLE)
+                    ob.flight.mark(rnd, _FLIGHT_DURABLE, t_dur)
             sh.fsyncs += 1
             sh.batch_sizes.append(len(batch))
             with self._wm:
                 sh.durable = top_ticket
                 d = min(s.durable for s in self.shards)
                 if d > self._durable:
+                    if ob is not None:
+                        self._durable_at.append((self._durable, d, t_dur))
                     self._durable = d
                     self._wm.notify_all()
             if ob is not None:
@@ -292,6 +300,17 @@ class WALWriter:
                     break
                 self._wm.wait(0.2)
         self._raise_exc()
+
+    def durable_at(self, ticket: int) -> Optional[float]:
+        """The writer's own perf_counter reading at the end of the fsync
+        that carried the watermark to `ticket` or past it (with several
+        shards: the last of them to get there); None before that, without
+        an obs plane, or once 64 later advances have pushed it out."""
+        with self._wm:
+            for lo, hi, t in self._durable_at:
+                if lo < ticket <= hi:
+                    return t
+        return None
 
     def flush(self) -> None:
         """Barrier: every submitted record durable."""

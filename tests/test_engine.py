@@ -438,14 +438,19 @@ def test_engine_http_surface(tmp_path):
         eng.stop()
 
 
-def test_engine_profile_hook(tmp_path):
-    """SURVEY §5 A1: the per-batch-step XLA profiler hook produces a
-    TensorBoard-loadable trace directory."""
+def test_engine_rounds_under_the_profiler(tmp_path):
+    """SURVEY §5 A1: engine rounds driven under jax.profiler.trace (what
+    the benchmark's member does from outside; MultiEngine.profile() went
+    in PR 41) leave a TensorBoard-loadable trace directory."""
     import os
+    import jax
     eng = MultiEngine(make_cfg(tmp_path / "e9"))
     try:
         run_until(eng, lambda: eng.leader_slot(0) >= 0, msg="leader")
-        out = eng.profile(rounds=3)
+        out = str(tmp_path / "profiles")
+        with jax.profiler.trace(out):
+            for _ in range(3):
+                eng.run_round()
         assert os.path.isdir(out)
         found = []
         for root, _, files in os.walk(out):

@@ -58,10 +58,22 @@ def scrapes(prom):
     with a checkpoint between the two."""
     from etcd_tpu.etcdhttp.tenants import EngineHttp
     from etcd_tpu.server.engine import EngineConfig, MultiEngine
-    eng = MultiEngine(EngineConfig(
-        groups=G, peers=P, data_dir=tempfile.mkdtemp(prefix="series-test-"),
-        window=16, max_ents=4, heartbeat_tick=3, fsync=False,
-        checkpoint_rounds=64, request_timeout=60.0))
+    # Every request id sampled: at the default of 1 in 16 this little
+    # traffic would fold no span of one kind or the other, and the
+    # request-segment files would have nothing counted to read.
+    prev = os.environ.get("ETCD_TPU_TRACE_EVERY")
+    os.environ["ETCD_TPU_TRACE_EVERY"] = "1"
+    try:
+        eng = MultiEngine(EngineConfig(
+            groups=G, peers=P,
+            data_dir=tempfile.mkdtemp(prefix="series-test-"),
+            window=16, max_ents=4, heartbeat_tick=3, fsync=False,
+            checkpoint_rounds=64, request_timeout=60.0))
+    finally:
+        if prev is None:
+            os.environ.pop("ETCD_TPU_TRACE_EVERY", None)
+        else:
+            os.environ["ETCD_TPU_TRACE_EVERY"] = prev
     eng.start()
     assert eng.wait_leaders(180), f"no leaders: {eng.failed}"
     front = EngineHttp(eng, port=0)

@@ -19,6 +19,11 @@
   CPU by thread class, the three front marks of a sampled rid, the
   profiler annotations on a CPU trace — and all of it flat under
   ETCD_TPU_OBS=off.
+- The sampled request trace of PR 41: a finished span's consecutive
+  stamps fold into etcd_request_segment_seconds{kind, segment}, which
+  tile etcd_http_request_seconds, and etcd_request_rounds{kind}; `durable`
+  is the WAL writer's own stamp; 1 request id in 16 by default; what lacks
+  a stamp is counted, not folded; nothing stays in the in-flight table.
 """
 import importlib.util
 import json
@@ -248,7 +253,8 @@ def test_tracer_mark_takes_an_earlier_reading():
     stages = tr.dump()["spans"][0]["stages"]
     assert list(stages) == ["front_in", "submit"]
     assert 0.9 < stages["submit"] < 1.5
-    assert {"front_in", "woke", "replied"} <= set(obs_mod.TRACE_STAGES)
+    assert {"front_in", "staged", "confirmed", "woke",
+            "replied"} <= set(obs_mod.TRACE_STAGES)
 
 
 def test_d2h_syncs_per_round_repeat_exactly(tmp_path):
@@ -357,6 +363,7 @@ def test_profiler_trace_carries_the_round_phases(tmp_path):
 _OBS_OFF_CHILD = r"""
 import json, os, sys, threading, time, urllib.request
 os.environ["ETCD_TPU_OBS"] = "off"
+os.environ["ETCD_TPU_TRACE_EVERY"] = "1"    # the master switch wins
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 from etcd_tpu.etcdhttp.tenants import EngineHttp
 from etcd_tpu.server.engine import EngineConfig, MultiEngine
@@ -386,7 +393,8 @@ while eng.round_no <= 32 and time.time() < deadline:    # idle ones tick on
     time.sleep(0.05)
 after = http("GET", base + "/metrics")
 print(json.dumps({"before": before, "after": after,
-                  "rounds": eng.round_no}))
+                  "rounds": eng.round_no,
+                  "traces": json.loads(http("GET", base + "/debug/traces"))}))
 front.stop()
 eng.stop()
 """
@@ -411,6 +419,12 @@ NEW_SERIES = (
     "etcd_http_front_served_total",
     "etcd_http_front_wakes_total",
     "etcd_http_front_completions_total",
+    # PR 41: the sampled request trace's folds
+    "etcd_request_segment_seconds_sum",
+    "etcd_request_segment_seconds_count",
+    "etcd_request_rounds_sum",
+    "etcd_request_rounds_count",
+    "etcd_request_spans_dropped_total",
 )
 
 
@@ -424,6 +438,7 @@ def test_new_series_stay_flat_with_obs_off(tmp_path):
     assert r.returncode == 0, r.stderr[-2000:]
     doc = json.loads(r.stdout.strip().splitlines()[-1])
     assert doc["rounds"] > 32          # checkpoints at 16 rounds happened
+    assert doc["traces"] == {"every": 0, "spans": []}
     parse = _load_script("etcd_top").parse_metrics
     before, after = parse(doc["before"]), parse(doc["after"])
     for series in NEW_SERIES:
@@ -915,6 +930,363 @@ def test_sampled_rid_carries_the_front_marks_in_order(eng_http):
     reads = [s["stages"] for s in tr["spans"]
              if "replied" in s["stages"] and "admitted" not in s["stages"]]
     assert reads and all(list(s)[0] == "front_in" for s in reads)
+
+
+# -- PR 41: a finished span folds into the segment histograms ----------------
+
+SEG = "etcd_request_segment_seconds"
+ROUNDS = "etcd_request_rounds"
+DROPPED = "etcd_request_spans_dropped_total"
+
+
+@pytest.fixture(scope="module")
+def eng_all():
+    """A member that samples every request id, behind the event-loop
+    front (the tracer reads ETCD_TPU_TRACE_EVERY when it is built)."""
+    prev = os.environ.get("ETCD_TPU_TRACE_EVERY")
+    os.environ["ETCD_TPU_TRACE_EVERY"] = "1"
+    from etcd_tpu.etcdhttp.tenants import EngineHttp
+    from etcd_tpu.server.engine import EngineConfig, MultiEngine
+    try:
+        eng = MultiEngine(EngineConfig(
+            groups=G, peers=P, data_dir=tempfile.mkdtemp(prefix="seg-test-"),
+            window=16, max_ents=4, heartbeat_tick=3, fsync=False,
+            checkpoint_rounds=1 << 30, applier_shards=2, wal_shards=2,
+            request_timeout=60.0))
+    finally:
+        if prev is None:
+            os.environ.pop("ETCD_TPU_TRACE_EVERY", None)
+        else:
+            os.environ["ETCD_TPU_TRACE_EVERY"] = prev
+    eng.start()
+    assert eng.wait_leaders(180), f"no leaders: {eng.failed}"
+    front = EngineHttp(eng, port=0)
+    front.start()
+    try:
+        yield eng, front.url.rstrip("/")
+    finally:
+        front.stop()
+        eng.stop()
+
+
+def _folded(a, b, kind):
+    return _delta(a, b, ROUNDS + "_count", kind=kind)
+
+
+def test_segments_tile_the_request_and_leave_nothing_in_flight(eng_all):
+    """Every request sampled: per kind, each segment is observed once a
+    request, the segments' sums add up to the front's span of the same
+    requests (etcd_http_request_seconds), a round count is folded for
+    each, nothing is dropped, and the in-flight table is empty once every
+    request has its reply."""
+    eng, base = eng_all
+    assert eng.obs.tracer.every == 1
+    n = 24
+    a, b = _load(base, n=n)
+    for kind in ("write", "qread"):
+        names = obs_mod.SEGMENT_NAMES[kind]
+        assert {_delta(a, b, SEG + "_count", kind=kind, segment=seg)
+                for seg in names} == {n}, kind
+        tiled = sum(_delta(a, b, SEG + "_sum", kind=kind, segment=seg)
+                    for seg in names)
+        assert tiled == pytest.approx(
+            _delta(a, b, "etcd_http_request_seconds_sum", kind=kind),
+            rel=0.01), kind
+        assert _folded(a, b, kind) == n
+        assert _delta(a, b, ROUNDS + "_sum", kind=kind) >= n
+    # a read has no gate: nothing is fsynced for it
+    assert _val(b, SEG + "_count", kind="qread", segment="gate") is None
+    assert _delta(a, b, DROPPED) == 0
+    assert eng.obs.tracer.live() == 0
+    # the queue segment is the staging wait, here of every write
+    assert _delta(a, b, SEG + "_sum", kind="write", segment="queue") == \
+        pytest.approx(_delta(a, b, "etcd_engine_pending_wait_seconds_sum"))
+
+
+def _finished(base, kind, seen, want, timeout=30.0):
+    """Spans of `kind` that /debug/traces shows finished and `seen` (rids)
+    does not hold, once there are `want` of them."""
+    deadline = time.time() + timeout
+    while True:
+        spans = [s for s in json.loads(_http(
+            "GET", base + "/debug/traces"))["spans"]
+            if s.get("kind") == kind and s["rid"] not in seen]
+        if len(spans) >= want or time.time() > deadline:
+            assert len(spans) >= want, (kind, len(spans), want)
+            return spans
+        time.sleep(0.05)
+
+
+def _cut_leader_off(eng, g):
+    """Nothing reaches tenant g's leader (it still sends: its followers
+    keep hearing it, so nobody stands for election, and nothing it admits
+    commits). The mask is (G, to, from, 1)."""
+    import jax.numpy as jnp
+    import numpy as np
+    cut = np.ones((eng.cfg.groups, eng.cfg.peers, eng.cfg.peers, 1),
+                  np.int32)
+    cut[g, eng.leader_slot(g)] = 0
+    eng.drop_mask = jnp.asarray(cut)
+
+
+def _puts(base, g, n, tag):
+    out = []
+
+    def put(i):
+        out.append(_http("PUT", f"{base}/tenants/{g}/v2/keys/{tag}/k{i}",
+                         f"value=v{i}"))
+    ths = [threading.Thread(target=put, args=(i,)) for i in range(n)]
+    for t in ths:
+        t.start()
+    return ths, out
+
+
+def _traffic_write(eng, base):
+    for i in range(6):
+        _http("PUT", f"{base}/tenants/{i % G}/v2/keys/seg/w{i}", "value=v")
+    return "write", 6, lambda spans: None
+
+
+def _traffic_qread(eng, base):
+    _http("PUT", f"{base}/tenants/2/v2/keys/seg/r", "value=v")
+    for _ in range(6):
+        _http("GET", f"{base}/tenants/2/v2/keys/seg/r?quorum=true")
+    return "qread", 6, lambda spans: None
+
+
+def _traffic_requeued(eng, base):
+    """One entry a write (batch_max 1), four entries a round (max_ents):
+    with tenant 0's leader cut off its uncommitted tail reaches half the
+    window (8 of 16), admission stops, and what the next rounds stage
+    goes back to the queue until the cut heals."""
+    a = _reg()
+    eng.cfg.batch_max = 1
+    _cut_leader_off(eng, 0)
+    try:
+        ths, out = _puts(base, 0, 12, "seg-rq")
+        deadline = time.time() + 30
+        while (_delta(a, _reg(), "etcd_engine_pending_wait_seconds_count")
+               < 12 and time.time() < deadline):
+            time.sleep(0.02)
+        r0 = eng.round_no
+        while eng.round_no < r0 + 4 and time.time() < deadline:
+            time.sleep(0.02)            # a few rounds that requeue
+    finally:
+        eng.drop_mask = None
+        eng.cfg.batch_max = 4096
+    for t in ths:
+        t.join(60)
+    assert len(out) == 12
+
+    def check(spans):
+        late = [s for s in spans if s["round"] > s["staged_round"]]
+        assert late, "no write was staged in one round and admitted later"
+        # staged once: the first time, where its queue wait was observed
+        assert all(s["rounds"] >= s["round"] - s["staged_round"] + 1
+                   for s in late)
+    return "write", 12, check
+
+
+def _traffic_sync_round(eng, base):
+    """A conf change of tenant 1 that cannot commit (its leader hears
+    nobody) stays outstanding: every round takes the synchronous path, so
+    tenant 0's writes are applied and acked on the round thread."""
+    follower = (eng.leader_slot(1) + 1) % P
+    _cut_leader_off(eng, 1)
+    conf = threading.Thread(target=_http, args=(
+        "POST", f"{base}/tenants/1/conf",
+        json.dumps({"op": "remove", "slot": follower})))
+    try:
+        conf.start()
+        deadline = time.time() + 30
+        while not eng._confs_outstanding and time.time() < deadline:
+            time.sleep(0.01)
+        assert eng._confs_outstanding == 1
+        for i in range(6):
+            _http("PUT", f"{base}/tenants/0/v2/keys/seg/s{i}", "value=v")
+        assert eng._confs_outstanding == 1
+    finally:
+        eng.drop_mask = None
+    conf.join(60)
+    assert not conf.is_alive()
+    _http("POST", f"{base}/tenants/1/conf",
+          json.dumps({"op": "add", "slot": follower}))
+    return "write", 6, lambda spans: None
+
+
+@pytest.mark.parametrize("traffic", [
+    _traffic_write, _traffic_qread, _traffic_requeued, _traffic_sync_round],
+    ids=lambda f: f.__name__[len("_traffic_"):])
+def test_every_folded_segment_is_positive_and_takes_a_round(eng_all, traffic):
+    """The stamps that bound a kind's segments are in order in every
+    finished span, whichever path acked it, and it took at least a round."""
+    eng, base = eng_all
+    seen = {s["rid"] for s in eng.obs.tracer.spans()}
+    a = _reg()
+    kind, n, check = traffic(eng, base)
+    spans = _finished(base, kind, seen, n)
+    stamps = obs_mod.SEGMENT_STAMPS[kind]
+    for s in spans:
+        at = [s["stages"][k] for k in stamps]
+        assert at == sorted(at) and at[0] == 0.0, s
+        assert s["rounds"] >= 1, s
+    check(spans)
+    b = _reg()
+    assert _delta(a, b, DROPPED) == 0
+    for seg in obs_mod.SEGMENT_NAMES[kind]:
+        assert _delta(a, b, SEG + "_sum", kind=kind, segment=seg) >= 0
+    assert eng.obs.tracer.live() == 0
+
+
+def test_durable_is_the_wal_writers_stamp_inside_the_gate(eng_all,
+                                                          monkeypatch):
+    """Every stream's fsync takes 30 ms more: a write is applied ahead of
+    it and waits at the gate, `applied -> acked` spans the fsync, and
+    `durable`, the writer's own clock at the fsync's end, lies inside."""
+    eng, base = eng_all
+    for sh in eng.wal.shards:
+        def slow(sync=sh.wal.sync):
+            time.sleep(0.03)
+            return sync()
+        monkeypatch.setattr(sh.wal, "sync", slow)
+    seen = {s["rid"] for s in eng.obs.tracer.spans()}
+    a = _reg()
+    for i in range(4):
+        _http("PUT", f"{base}/tenants/{i}/v2/keys/seg/d{i}", "value=v")
+    for s in _finished(base, "write", seen, 4):
+        st = s["stages"]
+        assert st["applied"] <= st["durable"] <= st["acked"], s
+        assert st["acked"] - st["applied"] >= 0.02, s
+        assert st["wal_submit"] <= st["durable"], s
+    b = _reg()
+    assert _delta(a, b, SEG + "_sum", kind="write", segment="gate") >= 4 * 0.02
+
+
+def _served(tmp_path, **kw):
+    from etcd_tpu.etcdhttp.tenants import EngineHttp
+    eng = _small_engine(tmp_path, **kw)
+    eng.start()
+    assert eng.wait_leaders(180), f"no leaders: {eng.failed}"
+    front = EngineHttp(eng, port=0)
+    front.start()
+    return eng, front
+
+
+@pytest.mark.parametrize("env,every", [(None, 16), ("0", 0), ("4", 4)])
+def test_one_request_id_in_16_is_sampled_unless_the_variable_says_otherwise(
+        env, every, tmp_path, monkeypatch):
+    if env is None:
+        monkeypatch.delenv("ETCD_TPU_TRACE_EVERY", raising=False)
+    else:
+        monkeypatch.setenv("ETCD_TPU_TRACE_EVERY", env)
+    assert obs_mod.TRACE_EVERY_DEFAULT == 16
+    eng, front = _served(tmp_path)
+    try:
+        assert eng.obs.tracer.every == every
+        base = front.url.rstrip("/")
+        n = 40
+        id0 = eng.reqid.next()
+        a = _reg()
+        for i in range(n):
+            _http("PUT", f"{base}/tenants/{i % 4}/v2/keys/dflt/k{i}",
+                  "value=v")
+            _http("GET", f"{base}/tenants/{i % 4}/v2/keys/dflt/k{i}"
+                         "?quorum=true")
+        deadline = time.time() + 20
+        while time.time() < deadline:
+            b = _reg()
+            if _delta(a, b, "etcd_http_request_seconds_count",
+                      kind="qread") >= n:
+                break
+            time.sleep(0.02)
+        id1 = eng.reqid.next()
+        assert id1 - id0 == 2 * n + 1       # the requests took every id
+        want = [rid for rid in range(id0 + 1, id1)
+                if every and rid % every == 0]
+        assert len(want) == (2 * n // every if every else 0)
+        assert _folded(a, b, "write") + _folded(a, b, "qread") == len(want)
+        assert _delta(a, b, DROPPED) == 0
+        doc = json.loads(_http("GET", base + "/debug/traces"))
+        assert doc["every"] == every
+        assert sorted(s["rid"] for s in doc["spans"]) == want
+        assert eng.obs.tracer.live() == 0
+    finally:
+        front.stop()
+        eng.stop()
+
+
+def test_a_timed_out_and_a_refused_request_fold_nothing(tmp_path,
+                                                        monkeypatch):
+    """A span that lacks a stamp is counted, not folded: a write whose
+    entry cannot commit is answered by the front's sweep, and a request
+    the engine will not register is refused at once. What the round and
+    the applier mark for the timed-out write afterwards opens no span."""
+    import urllib.error
+    monkeypatch.setenv("ETCD_TPU_TRACE_EVERY", "1")
+    eng, front = _served(tmp_path, request_timeout=0.5)
+    try:
+        base = front.url.rstrip("/")
+        _http("PUT", f"{base}/tenants/0/v2/keys/to/warm", "value=v")
+        a = _reg()
+        _cut_leader_off(eng, 0)
+        try:
+            with pytest.raises(urllib.error.HTTPError) as err:
+                _http("PUT", f"{base}/tenants/0/v2/keys/to/late", "value=v")
+            assert "timed out" in err.value.read().decode()
+        finally:
+            eng.drop_mask = None
+        with monkeypatch.context() as m:
+            def refuse(wid, sink=None):
+                raise ValueError("refused for the test")
+            m.setattr(eng.wait, "register", refuse)
+            with pytest.raises(urllib.error.HTTPError) as err:
+                _http("PUT", f"{base}/tenants/1/v2/keys/to/no", "value=v")
+            assert "refused for the test" in err.value.read().decode()
+        # healed, the late write commits and is applied: marks of a rid
+        # whose span has ended
+        deadline = time.time() + 30
+        while not eng._idle() and time.time() < deadline:
+            time.sleep(0.02)
+        assert eng._idle()
+        deadline = time.time() + 20
+        while time.time() < deadline:
+            b = _reg()
+            if _delta(a, b, "etcd_http_request_seconds_count",
+                      kind="write") >= 2:
+                break
+            time.sleep(0.02)
+        assert _delta(a, b, DROPPED) == 2
+        assert _folded(a, b, "write") == 0
+        for seg in obs_mod.SEGMENT_NAMES["write"]:
+            assert _delta(a, b, SEG + "_count", kind="write",
+                          segment=seg) == 0
+        assert eng.obs.tracer.live() == 0
+        late = [s for s in eng.obs.tracer.spans()
+                if s.get("kind") == "write" and "rounds" not in s]
+        assert len(late) == 1 and "acked" not in late[0]["stages"]
+    finally:
+        front.stop()
+        eng.stop()
+
+
+def test_the_in_flight_table_is_bounded_without_a_scan():
+    """A request that never reaches a reply the front accounts for
+    (engine.do without the front) is pushed out by the oldest-first bound
+    and counted; a late mark never reopens it."""
+    tr = obs_mod.Tracer(every=1)
+    a = _reg()
+    for rid in range(1, tr.MAX_LIVE + 4):
+        tr.mark(rid, "submit")
+    assert tr.live() == tr.MAX_LIVE
+    assert _delta(a, _reg(), DROPPED) == 3
+    tr.mark(1, "acked")                         # pushed out: dropped
+    assert tr.live() == tr.MAX_LIVE
+    assert [s["rid"] for s in tr.spans()[:3]] == [1, 2, 3]
+    tr.mark(10_000, "applied")                  # never opened
+    assert tr.live() == tr.MAX_LIVE
+    tr.finish(4, "other")                       # not a write or a read
+    assert tr.live() == tr.MAX_LIVE - 1
+    assert _delta(a, _reg(), DROPPED) == 4
 
 
 # -- trace ids survive SIGKILL + WAL replay ----------------------------------
