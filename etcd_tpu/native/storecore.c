@@ -937,57 +937,38 @@ desc_from(const char *key, Py_ssize_t klen, const char *val,
 }
 
 /* Batched plain-file SETs for the engine apply loop: paths/values are
- * equal-length lists of str, no TTL, no dirs. Runs in three phases:
- *   1. GIL held: parse every path/value/need item up front (a non-str
- *      item fails the whole batch BEFORE any mutation).
- *   2. GIL RELEASED, per-core mutex held: the pure-C mutation loop.
- *      This is the phase that lets K applier shards (disjoint tenant
- *      cores) apply in true parallel on a multi-core box.
- *   3. GIL reacquired, mutex STILL held: build desc tuples and ring
+ * equal-length lists of str, no TTL, no dirs. A batch runs in three
+ * phases, shared by Core.set_many (one core) and set_many_multi (a
+ * commit view's writes over many tenants' cores):
+ *   1. set_ops_parse, GIL held: every path/value/need item up front (a
+ *      non-str item fails the whole batch BEFORE any mutation).
+ *   2. set_ops_mutate, per-core mutex held, no Python object touched (so
+ *      the GIL may be released around it): the pure-C mutation loop.
+ *   3. set_ops_publish, GIL held, mutex STILL held: desc tuples and ring
  *      records for the applied prefix — holding the mutex through the
  *      history tail means no reader ever observes current_index
  *      advanced ahead of the ring (a watch registering mid-batch would
  *      otherwise scan past events that "already happened").
  * Per-op etcd errors (e.g. set over a dir) fail THAT op exactly as the
  * scalar call would — stats counted, index unmoved — and the batch
- * continues; only fatal errors (OOM, a non-str item) abort. CONTRACT
- * on a fatal abort: ops before the failing one HAVE been applied and
- * current_index HAS advanced, and the exception does not say how far —
- * the caller must treat it as fatal to the apply loop and HALT (the
- * engine applier fail-stops and re-raises, server/engine.py
- * _applier_loop; recovery is WAL replay, which re-applies the span
- * deterministically). Continuing past it would diverge replicas on a
- * nondeterministic failure (e.g. OOM on one member only).
- * Returns (first_index, last_index, n_failed, recs, descs):
- *   recs  — [(nd, pd|None, index)] per applied op when want_recs (so a
- *           watcher fan-out can notify without rescanning the ring),
- *           else None.
- *   descs — when `need` (a sequence of op positions) is given, one
- *           entry per requested position: (pos, nd, pd|None, index) for
- *           an applied op, (pos, None, (code, cause), index_at_failure)
- *           for a per-op etcd failure. This is the descriptor-based
- *           waiter wake: the applier hands these raw C descriptors to
- *           the wait registry and the HTTP thread materializes the
- *           Event/JSON. None when `need` is None.
- * first > last when nothing applied. */
-static PyObject *
-Core_set_many(CoreObject *c, PyObject *args)
+ * continues; only fatal errors (OOM, a non-str item) abort. */
+
+/* Phase 1. Returns the ops (free with set_ops_free), NULL with the
+ * Python error set. */
+static SetOp *
+set_ops_parse(PyObject *paths, PyObject *vals, PyObject *need_o,
+              Py_ssize_t *n_out)
 {
-    PyObject *paths, *vals, *need_o = Py_None;
-    double now;
-    int want_recs = 0;
-    if (!PyArg_ParseTuple(args, "O!O!d|pO", &PyList_Type, &paths,
-                          &PyList_Type, &vals, &now, &want_recs, &need_o))
-        return NULL;
     Py_ssize_t n = PyList_GET_SIZE(paths);
     if (PyList_GET_SIZE(vals) != n) {
         PyErr_SetString(PyExc_ValueError, "paths/values length mismatch");
         return NULL;
     }
     SetOp *ops = (SetOp *)calloc(n ? n : 1, sizeof(SetOp));
-    if (ops == NULL)
-        return PyErr_NoMemory();
-    /* -- phase 1 (GIL): parse everything up front */
+    if (ops == NULL) {
+        PyErr_NoMemory();
+        return NULL;
+    }
     for (Py_ssize_t i = 0; i < n; i++) {
         ops[i].path = PyUnicode_AsUTF8AndSize(PyList_GET_ITEM(paths, i),
                                               &ops[i].plen);
@@ -1024,13 +1005,24 @@ Core_set_many(CoreObject *c, PyObject *args)
         }
         Py_DECREF(seq);
     }
-    uint64_t first = 0;
-    Py_ssize_t failed = 0;
-    Py_ssize_t fatal = -1;  /* op index where an OOM abort hit */
-    /* -- phase 2 (no GIL, mutex held): pure-C mutations */
-    Py_BEGIN_ALLOW_THREADS
-    PyThread_acquire_lock(c->mux, WAIT_LOCK);
-    first = c->current_index + 1;
+    *n_out = n;
+    return ops;
+}
+
+static void
+set_ops_free(SetOp *ops, Py_ssize_t n)
+{
+    for (Py_ssize_t i = 0; i < n; i++)
+        free(ops[i].pv);
+    free(ops);
+}
+
+/* Phase 2: run ops[0..n) against c (its mutex held). Returns -1, or the
+ * index of the op at which an allocation failed: the ops before it HAVE
+ * been applied, it and the ops behind it have not. */
+static Py_ssize_t
+set_ops_mutate(CoreObject *c, SetOp *ops, Py_ssize_t n, Py_ssize_t *failed)
+{
     for (Py_ssize_t i = 0; i < n; i++) {
         SetOp *op = &ops[i];
         if (core_is_readonly(c, op->path, op->plen)) {
@@ -1039,7 +1031,7 @@ Core_set_many(CoreObject *c, PyObject *args)
             op->cause = "/";
             op->clen = 1;
             op->eidx = c->current_index;
-            failed++;
+            (*failed)++;
             continue;
         }
         uint64_t next = c->current_index + 1;
@@ -1053,15 +1045,13 @@ Core_set_many(CoreObject *c, PyObject *args)
                                          &cz, &cl);
         if (parent == NULL) {
             c->stats[ST_SETS_FAIL]++;
-            if (ecode == -1) {
-                fatal = i;
-                break;
-            }
+            if (ecode == -1)
+                return i;
             op->code = ecode;
             op->cause = cz;
             op->clen = cl;
             op->eidx = c->current_index;
-            failed++;
+            (*failed)++;
             continue;
         }
         CNode *existing = cmap_get(parent->children, name,
@@ -1073,27 +1063,23 @@ Core_set_many(CoreObject *c, PyObject *args)
             op->cause = op->path;
             op->clen = op->plen;
             op->eidx = c->current_index;
-            failed++;
+            (*failed)++;
             continue;
         }
         if (existing != NULL) {
             /* snapshot prev BEFORE the in-place overwrite (the desc
              * tuple is built in phase 3, under the GIL) */
             op->pv = (char *)malloc(existing->value_len + 1);
-            if (op->pv == NULL) {
-                fatal = i;
-                break;
-            }
+            if (op->pv == NULL)
+                return i;
             memcpy(op->pv, existing->value, existing->value_len + 1);
             op->pvlen = existing->value_len;
             op->pcr = existing->created;
             op->pmo = existing->modified;
             op->pex = existing->expire;
             op->had_prev = 1;
-            if (node_set_value(existing, op->value, op->vlen) < 0) {
-                fatal = i;
-                break;
-            }
+            if (node_set_value(existing, op->value, op->vlen) < 0)
+                return i;
             /* a SET is a brand-new node: both indices move; a stale
              * TTL-heap entry invalidates lazily (heap_top) */
             existing->created = existing->modified = next;
@@ -1104,17 +1090,117 @@ Core_set_many(CoreObject *c, PyObject *args)
             if (nn == NULL || cmap_add(parent->children, nn) < 0) {
                 if (nn)
                     node_decref(nn);
-                fatal = i;
-                break;
+                return i;
             }
         }
-        /* no heap_push: set_many never carries a TTL */
+        /* no heap_push: a batched SET never carries a TTL */
         c->current_index = next;
         c->stats[ST_SETS_OK]++;
         op->idx = next;
     }
+    return -1;
+}
+
+/* Phase 3: ring records for ops[0..lim) of c, and where asked
+ *   recs  += (nd, pd|None, index) per applied op (so a watcher fan-out
+ *            can notify without rescanning the ring);
+ *   descs += one entry per op with `need` set: (base + i, nd, pd|None,
+ *            index) for an applied op, (base + i, None, (code, cause),
+ *            index_at_failure) for a per-op etcd failure. This is the
+ *            descriptor-based waiter wake: the applier hands these raw C
+ *            descriptors to the wait registry and the HTTP thread
+ *            materializes the Event/JSON.
+ * Returns 0, or -1 with the Python error set. */
+static int
+set_ops_publish(CoreObject *c, SetOp *ops, Py_ssize_t lim, Py_ssize_t base,
+                double now, PyObject *recs, PyObject *descs)
+{
+    for (Py_ssize_t i = 0; i < lim; i++) {
+        SetOp *op = &ops[i];
+        if (op->idx == 0) {
+            if (op->need) {
+                PyObject *d = Py_BuildValue(
+                    "(nO(is#)K)", base + i, Py_None, op->code, op->cause,
+                    op->clen, (unsigned long long)op->eidx);
+                if (d == NULL || PyList_Append(descs, d) < 0) {
+                    Py_XDECREF(d);
+                    return -1;
+                }
+                Py_DECREF(d);
+            }
+            continue;
+        }
+        if (!op->need && recs == NULL && c->ring_cap == 0)
+            continue;
+        PyObject *nd = desc_from(op->path, op->plen, op->value,
+                                 op->vlen, op->idx, op->idx, NAN);
+        if (nd == NULL)
+            return -1;
+        PyObject *pd = NULL;
+        if (op->had_prev) {
+            pd = desc_from(op->path, op->plen, op->pv, op->pvlen,
+                           op->pcr, op->pmo, op->pex);
+            if (pd == NULL) {
+                Py_DECREF(nd);
+                return -1;
+            }
+        }
+        ring_push(c, ACT_SET, nd, pd, op->idx, now);
+        int bad = 0;
+        if (recs != NULL) {
+            PyObject *rec = Py_BuildValue(
+                "(OOK)", nd, pd == NULL ? Py_None : pd,
+                (unsigned long long)op->idx);
+            bad = rec == NULL || PyList_Append(recs, rec) < 0;
+            Py_XDECREF(rec);
+        }
+        if (!bad && op->need) {
+            PyObject *d = Py_BuildValue(
+                "(nOOK)", base + i, nd, pd == NULL ? Py_None : pd,
+                (unsigned long long)op->idx);
+            bad = d == NULL || PyList_Append(descs, d) < 0;
+            Py_XDECREF(d);
+        }
+        Py_DECREF(nd);
+        Py_XDECREF(pd);
+        if (bad)
+            return -1;
+    }
+    return 0;
+}
+
+/* Core.set_many: one core's batch; the mutations run with the GIL
+ * released. CONTRACT on a fatal abort: ops before the failing one HAVE
+ * been applied and current_index HAS advanced, and the exception does
+ * not say how far — the caller must treat it as fatal to the apply loop
+ * and HALT (recovery is WAL replay, which re-applies the span
+ * deterministically). Continuing past it would diverge replicas on a
+ * nondeterministic failure (e.g. OOM on one member only).
+ * Returns (first_index, last_index, n_failed, recs, descs): recs when
+ * want_recs and descs when `need` (a sequence of op positions) is given
+ * as set_ops_publish builds them, else None. first > last when nothing
+ * applied. */
+static PyObject *
+Core_set_many(CoreObject *c, PyObject *args)
+{
+    PyObject *paths, *vals, *need_o = Py_None;
+    double now;
+    int want_recs = 0;
+    if (!PyArg_ParseTuple(args, "O!O!d|pO", &PyList_Type, &paths,
+                          &PyList_Type, &vals, &now, &want_recs, &need_o))
+        return NULL;
+    Py_ssize_t n;
+    SetOp *ops = set_ops_parse(paths, vals, need_o, &n);
+    if (ops == NULL)
+        return NULL;
+    uint64_t first = 0;
+    Py_ssize_t failed = 0;
+    Py_ssize_t fatal;       /* op index where an OOM abort hit, or -1 */
+    Py_BEGIN_ALLOW_THREADS
+    PyThread_acquire_lock(c->mux, WAIT_LOCK);
+    first = c->current_index + 1;
+    fatal = set_ops_mutate(c, ops, n, &failed);
     Py_END_ALLOW_THREADS
-    /* -- phase 3 (GIL + mutex): descs/recs/ring for the applied prefix */
     PyObject *recs = NULL, *descs = NULL, *ret = NULL;
     if (want_recs) {
         recs = PyList_New(0);
@@ -1126,67 +1212,9 @@ Core_set_many(CoreObject *c, PyObject *args)
         if (descs == NULL)
             goto done;
     }
-    {
-        Py_ssize_t lim = fatal >= 0 ? fatal : n;
-        for (Py_ssize_t i = 0; i < lim; i++) {
-            SetOp *op = &ops[i];
-            if (op->idx == 0) {
-                if (op->need) {
-                    PyObject *d = Py_BuildValue(
-                        "(nO(is#)K)", i, Py_None, op->code, op->cause,
-                        op->clen, (unsigned long long)op->eidx);
-                    if (d == NULL || PyList_Append(descs, d) < 0) {
-                        Py_XDECREF(d);
-                        goto done;
-                    }
-                    Py_DECREF(d);
-                }
-                continue;
-            }
-            if (!op->need && recs == NULL && c->ring_cap == 0)
-                continue;
-            PyObject *nd = desc_from(op->path, op->plen, op->value,
-                                     op->vlen, op->idx, op->idx, NAN);
-            if (nd == NULL)
-                goto done;
-            PyObject *pd = NULL;
-            if (op->had_prev) {
-                pd = desc_from(op->path, op->plen, op->pv, op->pvlen,
-                               op->pcr, op->pmo, op->pex);
-                if (pd == NULL) {
-                    Py_DECREF(nd);
-                    goto done;
-                }
-            }
-            ring_push(c, ACT_SET, nd, pd, op->idx, now);
-            if (recs != NULL) {
-                PyObject *rec = Py_BuildValue(
-                    "(OOK)", nd, pd == NULL ? Py_None : pd,
-                    (unsigned long long)op->idx);
-                if (rec == NULL || PyList_Append(recs, rec) < 0) {
-                    Py_XDECREF(rec);
-                    Py_DECREF(nd);
-                    Py_XDECREF(pd);
-                    goto done;
-                }
-                Py_DECREF(rec);
-            }
-            if (op->need) {
-                PyObject *d = Py_BuildValue(
-                    "(nOOK)", i, nd, pd == NULL ? Py_None : pd,
-                    (unsigned long long)op->idx);
-                if (d == NULL || PyList_Append(descs, d) < 0) {
-                    Py_XDECREF(d);
-                    Py_DECREF(nd);
-                    Py_XDECREF(pd);
-                    goto done;
-                }
-                Py_DECREF(d);
-            }
-            Py_DECREF(nd);
-            Py_XDECREF(pd);
-        }
-    }
+    if (set_ops_publish(c, ops, fatal >= 0 ? fatal : n, 0, now, recs,
+                        descs) < 0)
+        goto done;
     if (fatal >= 0) {
         PyErr_NoMemory();
         goto done;
@@ -1200,9 +1228,7 @@ done:
     core_unlock(c);
     Py_XDECREF(recs);
     Py_XDECREF(descs);
-    for (Py_ssize_t i = 0; i < n; i++)
-        free(ops[i].pv);
-    free(ops);
+    set_ops_free(ops, n);
     return ret;
 }
 
@@ -2265,9 +2291,187 @@ static PyTypeObject CoreType = {
     .tp_getset = Core_getset,
 };
 
+/* ------------------------------------------------- one batch, many cores */
+
+/* A set_many_multi call of fewer ops than this keeps the GIL through its
+ * mutations: a hand-over costs the caller a wait for whichever thread
+ * took the interpreter (up to the switch interval), against ~0.3 us an
+ * op that the others would gain. A view of one or a few writes is a
+ * closed loop of few clients, or a cohort's tail. */
+#define SET_MULTI_RELEASE_MIN 32
+
+static int
+cmp_core_ptr(const void *a, const void *b)
+{
+    uintptr_t x = (uintptr_t)*(CoreObject *const *)a;
+    uintptr_t y = (uintptr_t)*(CoreObject *const *)b;
+    return x < y ? -1 : x > y;
+}
+
+/* set_many_multi(cores, counts, paths, values, now, need=None)
+ *     -> (done, descs, spans)
+ * One batch over many tenants' cores: cores[k] takes the next counts[k]
+ * entries of the flat paths/values, as Core.set_many would take them;
+ * `need` lists flat positions. The cores are distinct. Every core's
+ * mutex is taken before the first mutation, in address order (two such
+ * calls never wait for each other in a ring; a single-core entry point
+ * holds one mutex and waits for none); the mutations run core by core
+ * in list order, with the GIL released once for the whole call where
+ * the call is long enough to be worth a hand-over
+ * (SET_MULTI_RELEASE_MIN); then each core is published and unlocked in
+ * turn.
+ *   done  — flat ops whose mutation ran (applied, or failed by an etcd
+ *           error of their own). done < len(paths) says an allocation
+ *           failed at flat position `done`: everything before it is
+ *           applied and published, nothing from it on. The caller sets
+ *           its cursors by it and HALTs (Core.set_many's contract, with
+ *           the place said).
+ *   descs — as set_ops_publish builds them for `need`, positions flat;
+ *           None without `need`.
+ *   spans — [(first_index, last_index)] a core; first > last where
+ *           nothing applied.
+ * An error in phase 3 (no memory for a descriptor) raises with the
+ * mutations made: fatal to the apply loop, like Core.set_many's. */
+static PyObject *
+set_many_multi(PyObject *Py_UNUSED(mod), PyObject *args)
+{
+    PyObject *cores, *counts, *paths, *vals, *need_o = Py_None;
+    double now;
+    if (!PyArg_ParseTuple(args, "O!O!O!O!d|O", &PyList_Type, &cores,
+                          &PyList_Type, &counts, &PyList_Type, &paths,
+                          &PyList_Type, &vals, &now, &need_o))
+        return NULL;
+    Py_ssize_t K = PyList_GET_SIZE(cores);
+    if (PyList_GET_SIZE(counts) != K) {
+        PyErr_SetString(PyExc_ValueError, "cores/counts length mismatch");
+        return NULL;
+    }
+    Py_ssize_t n;
+    SetOp *ops = set_ops_parse(paths, vals, need_o, &n);
+    if (ops == NULL)
+        return NULL;
+    /* cs: the cores in list order; by_addr: in lock order; off[k]: where
+     * core k's slice starts (off[K] = n); first[k]: its span's start */
+    Py_ssize_t Ka = K ? K : 1;
+    CoreObject **cs = (CoreObject **)calloc(2 * Ka, sizeof(CoreObject *));
+    Py_ssize_t *off = (Py_ssize_t *)calloc(K + 1, sizeof(Py_ssize_t));
+    uint64_t *first = (uint64_t *)calloc(Ka, sizeof(uint64_t));
+    PyObject *descs = NULL, *spans = NULL, *ret = NULL;
+    Py_ssize_t owned = 0;   /* cs[0..owned) hold a reference */
+    Py_ssize_t locked = 0;  /* cs[unlocked..K) are locked once this is K */
+    Py_ssize_t unlocked = 0;
+    if (cs == NULL || off == NULL || first == NULL) {
+        PyErr_NoMemory();
+        goto out;
+    }
+    CoreObject **by_addr = cs + Ka;
+    for (; owned < K; owned++) {
+        PyObject *co = PyList_GET_ITEM(cores, owned);
+        if (!PyObject_TypeCheck(co, &CoreType)) {
+            PyErr_SetString(PyExc_TypeError,
+                            "cores must hold Core objects");
+            goto out;
+        }
+        Py_ssize_t cnt = PyLong_AsSsize_t(PyList_GET_ITEM(counts, owned));
+        if (cnt < 0 || cnt > n - off[owned]) {
+            if (!PyErr_Occurred())
+                PyErr_SetString(PyExc_ValueError,
+                                "counts do not fit the flat lists");
+            goto out;
+        }
+        Py_INCREF(co);      /* ours while the GIL is away */
+        cs[owned] = by_addr[owned] = (CoreObject *)co;
+        off[owned + 1] = off[owned] + cnt;
+    }
+    if (off[K] != n) {
+        PyErr_SetString(PyExc_ValueError,
+                        "counts do not add up to the flat lists");
+        goto out;
+    }
+    qsort(by_addr, K, sizeof(CoreObject *), cmp_core_ptr);
+    for (Py_ssize_t j = 1; j < K; j++) {
+        if (by_addr[j] == by_addr[j - 1]) {
+            PyErr_SetString(PyExc_ValueError, "a core is listed twice");
+            goto out;
+        }
+    }
+    if (need_o != Py_None && (descs = PyList_New(0)) == NULL)
+        goto out;
+    if ((spans = PyList_New(K)) == NULL)
+        goto out;
+    /* -- phase 2: every mutex, then the mutations core by core */
+    Py_ssize_t stop = K;    /* the core at which an allocation failed */
+    Py_ssize_t done = n;
+    Py_ssize_t failed = 0;  /* summed; the spans say it core by core */
+    PyThreadState *ts = n >= SET_MULTI_RELEASE_MIN ? PyEval_SaveThread()
+                                                   : NULL;
+    for (; locked < K; locked++) {
+        if (ts != NULL)
+            PyThread_acquire_lock(by_addr[locked]->mux, WAIT_LOCK);
+        else
+            core_lock(by_addr[locked]);
+    }
+    for (Py_ssize_t j = 0; j < K; j++) {
+        first[j] = cs[j]->current_index + 1;
+        if (j > stop)
+            continue;
+        Py_ssize_t fatal = set_ops_mutate(cs[j], ops + off[j],
+                                          off[j + 1] - off[j], &failed);
+        if (fatal >= 0) {
+            stop = j;
+            done = off[j] + fatal;
+        }
+    }
+    if (ts != NULL)
+        PyEval_RestoreThread(ts);
+    /* -- phase 3: publish and unlock core by core */
+    for (; unlocked < K; unlocked++) {
+        CoreObject *c = cs[unlocked];
+        Py_ssize_t lo = off[unlocked], hi = off[unlocked + 1];
+        if (hi > done)
+            hi = done > lo ? done : lo;
+        if (set_ops_publish(c, ops + lo, hi - lo, lo, now, NULL, descs) < 0)
+            goto out;
+        PyObject *sp = Py_BuildValue("(KK)",
+                                     (unsigned long long)first[unlocked],
+                                     (unsigned long long)c->current_index);
+        if (sp == NULL)
+            goto out;
+        PyList_SET_ITEM(spans, unlocked, sp);
+        core_unlock(c);
+    }
+    ret = Py_BuildValue("(nOO)", done, descs == NULL ? Py_None : descs,
+                        spans);
+out:
+    if (locked == K && cs != NULL) {
+        for (; unlocked < K; unlocked++)
+            core_unlock(cs[unlocked]);
+    }
+    for (Py_ssize_t j = 0; j < owned; j++)
+        Py_DECREF((PyObject *)cs[j]);
+    Py_XDECREF(descs);
+    Py_XDECREF(spans);
+    free(cs);
+    free(off);
+    free(first);
+    set_ops_free(ops, n);
+    return ret;
+}
+
+static PyMethodDef storecore_functions[] = {
+    {"set_many_multi", set_many_multi, METH_VARARGS,
+     "set_many_multi(cores, counts, paths, values, now, need=None) -> "
+     "(done, descs|None, spans); Core.set_many's batch over many cores "
+     "in one call: cores[k] takes the next counts[k] of the flat "
+     "paths/values, `need` and descs hold flat positions, spans = "
+     "[(first_index, last_index)] a core; done < len(paths) says where "
+     "an allocation failed (see the function comment)"},
+    {NULL}
+};
+
 static struct PyModuleDef storecore_module = {
     PyModuleDef_HEAD_INIT, "storecore",
-    "native v2 store node-tree core", -1, NULL
+    "native v2 store node-tree core", -1, storecore_functions
 };
 
 PyMODINIT_FUNC

@@ -48,6 +48,7 @@ ring window, host snapshot-install beyond it).
 """
 from __future__ import annotations
 
+import bisect
 import functools
 import json
 import logging
@@ -71,7 +72,7 @@ from etcd_tpu.utils import metrics
 from etcd_tpu.server.request import (METHOD_DELETE, METHOD_GET, METHOD_POST,
                                      METHOD_PUT, METHOD_QGET, METHOD_SYNC,
                                      Request)
-from etcd_tpu.store import new_store
+from etcd_tpu.store import NativeStore, new_store, set_applied_view
 from etcd_tpu.store.event import LazyWriteEvent
 from etcd_tpu.utils import idutil
 from etcd_tpu.utils.wait import Sink, Wait
@@ -387,13 +388,29 @@ class _Submitted:
         self.t0 = t0
 
 
+class _ViewBatch:
+    """The plain PUTs one pass of _apply_committed applies in one native
+    call: stores[k] takes the next counts[k] of the flat paths / vals;
+    need / rids are the flat positions and ids of the waiter-held ones;
+    cur_g / cur_i / cur_end say, entry by entry in flat order, which
+    group's cursor moves to which index once the flat lists are applied
+    up to cur_end."""
+
+    __slots__ = ("stores", "counts", "paths", "vals", "need", "rids",
+                 "cur_g", "cur_i", "cur_end")
+
+    def __init__(self) -> None:
+        for name in self.__slots__:
+            setattr(self, name, [])
+
+
 class _ApplierShard:
     """One compartment of the applier pool: a worker thread owning the
     contiguous tenant range [g_lo, g_hi), with its own commit-view
     queue, its own backpressure/condition variable, and its own ack
     tally. Shards share no mutable state except disjoint slices of
-    engine.applied and disjoint tenant stores, so K workers drive K
-    GIL-releasing storecore batch applies in true parallel."""
+    engine.applied and disjoint tenant stores: each worker makes its
+    view's one native call over its own tenants' cores."""
 
     __slots__ = ("idx", "g_lo", "g_hi", "cv", "q", "stop", "exc",
                  "thread", "acct")
@@ -2950,7 +2967,18 @@ class MultiEngine:
         charge — the worker's own, or the engine's synchronous one.
         With `sink` set, waiter wakeups and the ack tally are DEFERRED
         into it instead of fired inline — the worker releases them after
-        the view's durability ticket clears the WAL watermark."""
+        the view's durability ticket clears the WAL watermark.
+
+        A write is applied one of two ways. A group whose whole span in
+        this view is plain-file PUTs with no conditions and no TTL, on a
+        native store with no watcher, joins the VIEW BATCH: the pass
+        makes one native call over all those tenants' cores
+        (_apply_view), so a cohort of writes spread over as many tenants
+        costs one call, not one a request. Any other group (a condition,
+        a TTL, a directory, DELETE / POST / QGET / SYNC, a conf change,
+        a live watcher, a Python Store) is applied request by request
+        as it is met (_apply_entries). Tenants share no state, so only
+        the order inside a group is observable, and both keep it."""
         W = self.cfg.window
         tr = self.obs.tracer
         every = tr.every
@@ -2962,46 +2990,46 @@ class MultiEngine:
         if g_hi is None:
             g_hi = len(gc)
         changed = np.nonzero(gc[g_lo:g_hi] > self.applied[g_lo:g_hi])[0]
-        for g in changed:
-            g = int(g) + g_lo
-            s, lo, hi = int(s_vec[g]), int(self.applied[g]), int(gc[g])
-            ring_row = h_ring[g, s]
-            last_gs = int(h_last[g, s])
-            for i in range(lo + 1, hi + 1):
-                t = 0
-                if i > last_gs - W:
-                    t = int(ring_row[i % W])
-                if t == 0 and hist is not None:
-                    # Restore path: the span slot's ring can hold the 0
-                    # sentinel INSIDE the window — a slot removed and
-                    # later re-added had its ring zeroed at the join, so
-                    # indices below its join point are unresolvable from
-                    # it even though other slots know them. hist (built
-                    # from every slot's replayed log history) supplies
-                    # the committed term; without this fallback those
-                    # entries would silently apply as leader no-ops and
-                    # ACKED WRITES WOULD VANISH on restart (soak-found).
-                    t = hist.get((g, i), 0)
-                if t == 0:
-                    # Live path: unreachable (applies are incremental, so
-                    # the span never reaches below a re-added slot's join
-                    # point or the ring window); refusing beats
-                    # misapplying.
-                    log.error("engine: no term for committed entry g=%d "
-                              "i=%d (slot=%d last=%d)", g, i, s, last_gs)
-                    continue
+        if not len(changed):
+            return
+        changed += g_lo
+        # One new entry a group is the common span: its term for every
+        # changed group in one index, not int() by int().
+        s_c, hi_c = s_vec[changed], gc[changed]
+        rows = zip(changed.tolist(), s_c.tolist(),
+                   self.applied[changed].tolist(), hi_c.tolist(),
+                   h_last[changed, s_c].tolist(),
+                   h_ring[changed, s_c, hi_c % W].tolist())
+        payloads, payload_reqs = self.payloads, self.payload_reqs
+        stores = self._stores
+        is_reg = self.wait.is_registered
+        vb = _ViewBatch()
+        paths, vals, need, rids = vb.paths, vb.vals, vb.need, vb.rids
+        cur_g, cur_i, cur_end = vb.cur_g, vb.cur_i, vb.cur_end
+        n_scalar = 0
+        for g, s, lo, hi, last_gs, t_hi in rows:
+            if hi == lo + 1 and hi > last_gs - W and t_hi:
+                span = ((hi, t_hi),)
+            else:
+                span = self._span_terms(g, s, lo, hi, h_ring, last_gs, hist)
+            ents = []           # (index, requests | None, conf payload)
+            plain = True
+            for i, t in span:
                 key = (g, i, t)
-                payload = self.payloads.get(key)
+                payload = payloads.get(key)
                 if payload is None:
                     continue  # leader no-op
-                if payload[0] in (P_REQ, P_MULTI):
+                if payload[0] == P_CONF:
+                    ents.append((i, None, payload))
+                    plain = False
+                elif payload[0] in (P_REQ, P_MULTI):
                     # Coalesced entries: each request applies independently
                     # in order, with its own result/error and its own
                     # waiter trigger — semantically identical to one entry
                     # per request. The live path reuses the Requests
                     # decoded at proposal time (payload_reqs sidecar);
                     # replay decodes from the durable bytes.
-                    reqs = self.payload_reqs.pop(key, None)
+                    reqs = payload_reqs.pop(key, None)
                     if reqs is not None:
                         reqs = reqs[0]
                     elif payload[0] == P_REQ:
@@ -3016,124 +3044,189 @@ class MultiEngine:
                         for r0 in reqs:
                             if r0.id % every == 0:
                                 tr.mark(r0.id, "replayed", g=g)
-                    # Batched fast path: runs of plain-file PUTs with no
-                    # conditions and no TTL apply through ONE
-                    # GIL-releasing C call per run
-                    # (NativeStore.set_applied_many) instead of a full
-                    # Python dispatch per request — the apply loop's
-                    # throughput ceiling at scale. Waiter-held plain PUTs
-                    # ride the batch too: their positions go in `need`,
-                    # the C call returns raw node descriptors for them,
-                    # and the waiter is woken with a LazyWriteEvent (the
-                    # Event/JSON churn happens on the HTTP thread that
-                    # resolves it, not here — the ack/waiter stage of the
-                    # compartmentalized path). A request that carries
-                    # conditions/TTL or isn't a plain PUT flushes the run
-                    # and applies through the scalar path, preserving log
-                    # order exactly. Runs never span log entries (the
-                    # per-entry cursor advance below must stay exact).
-                    # Fast-path requests are client writes (SYNC never
-                    # qualifies: its method is not PUT); their per-op
-                    # store errors count as served, same as a scalar
-                    # error result.
-                    st = self.store(g)
-                    many = getattr(st, "set_applied_many", None)
-                    is_reg = self.wait.is_registered
-                    fp, fv, fneed, frids = [], [], [], []
+                    ents.append((i, reqs, None))
+                    if plain:
+                        for r in reqs:
+                            # a file PUT with no condition and no TTL is
+                            # what the view batch's native call applies
+                            if (r.method != METHOD_PUT or r.dir
+                                    or r.refresh or r.prev_exist is not None
+                                    or r.prev_index or r.prev_value
+                                    or r.expiration is not None):
+                                plain = False
+                                break
+            if not ents:
+                self.applied[g] = hi
+                continue
+            st = stores.get(g) or self.store(g)
+            if (plain and type(st) is NativeStore
+                    and not st.watcher_hub.count):
+                # Joins the view batch, in log order. A waiter-held
+                # request's flat position goes in `need`: the native call
+                # returns its raw node descriptors and the waiter is woken
+                # with a LazyWriteEvent (the Event/JSON churn happens on
+                # the HTTP thread that resolves it, not here). The cursor
+                # moves only once the call has applied an entry (cur_*).
+                n0 = len(paths)
+                for i, reqs, _ in ents:
                     for r in reqs:
-                        if (many is not None and r.method == METHOD_PUT
-                                and not r.dir and not r.refresh
-                                and r.prev_exist is None
-                                and not r.prev_index and not r.prev_value
-                                and r.expiration is None):
-                            if is_reg(r.id):
-                                fneed.append(len(fp))
-                                frids.append(r.id)
-                            fp.append(r.path)
-                            fv.append(r.val or "")
-                            continue
-                        if fp:
-                            self._flush_many(st, fp, fv, fneed, frids,
-                                             trigger, acct, sink)
-                            fp, fv, fneed, frids = [], [], [], []
-                        try:
-                            result = self._apply_request(g, r)
-                        except errors.EtcdError as err:
-                            result = err
-                        if trigger:
-                            traced = every and r.id % every == 0
-                            if traced:
-                                tr.mark(r.id, "applied")
-                            if sink is not None:
-                                if r.method != METHOD_SYNC:
-                                    sink.acked += 1
-                                sink.items.append((r.id, result))
-                            else:
-                                if r.method != METHOD_SYNC:
-                                    acct.acked += 1
-                                if traced:      # before its waiter runs
-                                    tr.mark(r.id, "acked",
-                                            acked_round=self.round_no)
-                                self.wait.trigger(r.id, result)
-                    if fp:
-                        self._flush_many(st, fp, fv, fneed, frids,
-                                         trigger, acct, sink)
-                elif payload[0] == P_CONF:
-                    d = json.loads(payload[1:].decode())
-                    self._apply_conf(g, d["op"], d["slot"])
-                    if trigger:
-                        self.wait.trigger(
-                            d["id"],
-                            [int(x) for x in np.nonzero(self.h_mask[g])[0]])
-                # Advance the cursor PER ENTRY, not at span end: if an
-                # apply raises mid-span, a retry (or post-mortem) must
-                # resume after the last applied entry, never re-apply it
-                # (duplicate watch events / double store mutations).
-                self.applied[g] = i
-            self.applied[g] = hi
-
-    def _flush_many(self, st, fp: list, fv: list, fneed: list,
-                    frids: list, trigger: bool, acct: _AckCounter,
-                    sink: Optional[_AckBatch] = None) -> None:
-        """Apply one batched run of plain-file PUTs. Positions listed in
-        fneed hold waiters: the C call returns their raw node
-        descriptors, and each waiter is woken with a LazyWriteEvent (or
-        the per-op EtcdError) — Event materialization is deferred to the
-        HTTP thread that resolves it in do(). With `sink`, wakeups and
-        the tally are deferred for post-watermark release instead."""
-        if not fneed:
-            st.set_applied_many(fp, fv)
-            if trigger:
-                if sink is not None:
-                    sink.acked += len(fp)
-                else:
-                    acct.acked += len(fp)
-            return
-        now = st.clock()
-        _, descs = st.set_applied_many(fp, fv, need=fneed)
-        if trigger:
-            tr = self.obs.tracer
-            every = tr.every
-            if sink is not None:
-                sink.acked += len(fp)
+                        if is_reg(r.id):
+                            need.append(len(paths))
+                            rids.append(r.id)
+                        paths.append(r.path)
+                        vals.append(r.val or "")
+                    cur_g.append(g)
+                    cur_i.append(i)
+                    cur_end.append(len(paths))
+                cur_i[-1] = hi      # and over the span's trailing no-ops
+                vb.stores.append(st)
+                vb.counts.append(len(paths) - n0)
             else:
-                acct.acked += len(fp)
-            for (pos, nd, pd, idx), rid in zip(descs, frids):
-                if nd is None:
-                    code, cause = pd
-                    res: Any = errors.EtcdError(code, cause=cause,
-                                                index=idx)
-                else:
-                    res = LazyWriteEvent(nd, pd, idx, now)
-                traced = every and rid % every == 0
-                if traced:
-                    tr.mark(rid, "applied")
-                if sink is not None:
-                    sink.items.append((rid, res))
-                else:
-                    if traced:          # before its waiter runs
-                        tr.mark(rid, "acked", acked_round=self.round_no)
-                    self.wait.trigger(rid, res)
+                n_scalar += self._apply_entries(g, ents, trigger, acct, sink)
+                self.applied[g] = hi
+        if paths:
+            self._apply_view(vb, trigger, acct, sink)
+        if self.obs.enabled:
+            if paths:
+                self.obs.c_apply["view"].inc(len(paths))
+            if n_scalar:
+                self.obs.c_apply["scalar"].inc(n_scalar)
+
+    def _span_terms(self, g: int, s: int, lo: int, hi: int, h_ring,
+                    last_gs: int, hist) -> List[Tuple[int, int]]:
+        """(index, term) of group g's committed entries lo+1..hi, from
+        slot s's ring (the slot with the highest commit: its ring covers
+        the span, the admission throttle keeps last-commit <= W/2)."""
+        W = self.cfg.window
+        ring_row = h_ring[g, s]
+        span = []
+        for i in range(lo + 1, hi + 1):
+            t = 0
+            if i > last_gs - W:
+                t = int(ring_row[i % W])
+            if t == 0 and hist is not None:
+                # Restore path: the span slot's ring can hold the 0
+                # sentinel INSIDE the window — a slot removed and
+                # later re-added had its ring zeroed at the join, so
+                # indices below its join point are unresolvable from
+                # it even though other slots know them. hist (built
+                # from every slot's replayed log history) supplies
+                # the committed term; without this fallback those
+                # entries would silently apply as leader no-ops and
+                # ACKED WRITES WOULD VANISH on restart (soak-found).
+                t = hist.get((g, i), 0)
+            if t == 0:
+                # Live path: unreachable (applies are incremental, so
+                # the span never reaches below a re-added slot's join
+                # point or the ring window); refusing beats
+                # misapplying.
+                log.error("engine: no term for committed entry g=%d "
+                          "i=%d (slot=%d last=%d)", g, i, s, last_gs)
+                continue
+            span.append((i, t))
+        return span
+
+    def _apply_entries(self, g: int, ents: list, trigger: bool,
+                       acct: _AckCounter,
+                       sink: Optional[_AckBatch]) -> int:
+        """Apply group g's entries request by request, in log order (the
+        path of everything the view batch does not take). Returns the
+        number of requests applied."""
+        tr = self.obs.tracer
+        every = tr.every
+        n = 0
+        for i, reqs, conf in ents:
+            if reqs is None:
+                d = json.loads(conf[1:].decode())
+                self._apply_conf(g, d["op"], d["slot"])
+                if trigger:
+                    self.wait.trigger(
+                        d["id"],
+                        [int(x) for x in np.nonzero(self.h_mask[g])[0]])
+                reqs = ()
+            for r in reqs:
+                try:
+                    result = self._apply_request(g, r)
+                except errors.EtcdError as err:
+                    result = err
+                n += 1
+                if trigger:
+                    traced = every and r.id % every == 0
+                    if traced:
+                        tr.mark(r.id, "applied")
+                    if sink is not None:
+                        if r.method != METHOD_SYNC:
+                            sink.acked += 1
+                        sink.items.append((r.id, result))
+                    else:
+                        if r.method != METHOD_SYNC:
+                            acct.acked += 1
+                        if traced:      # before its waiter runs
+                            tr.mark(r.id, "acked",
+                                    acked_round=self.round_no)
+                        self.wait.trigger(r.id, result)
+            # Advance the cursor PER ENTRY, not at span end: if an
+            # apply raises mid-span, a retry (or post-mortem) must
+            # resume after the last applied entry, never re-apply it
+            # (duplicate watch events / double store mutations).
+            self.applied[g] = i
+        return n
+
+    def _apply_view(self, vb: "_ViewBatch", trigger: bool,
+                    acct: _AckCounter, sink: Optional[_AckBatch]) -> None:
+        """The view batch: one native call over many tenants' cores, the
+        cursors, then each waiter's LazyWriteEvent (or its per-op
+        EtcdError) — Event materialization is deferred to the HTTP
+        thread that resolves it. With `sink`, wakeups and the tally are
+        deferred for post-watermark release instead. View-batch requests
+        are client writes (SYNC never qualifies: its method is not PUT);
+        their per-op store errors count as served, same as a scalar
+        error result."""
+        n = len(vb.paths)
+        tr = self.obs.tracer
+        every = tr.every
+        done, descs, now = set_applied_view(vb.stores, vb.counts, vb.paths,
+                                            vb.vals, vb.need or None)
+        t_applied = time.perf_counter() if every else 0.0
+        # cur_end is where each entry's requests end in the flat lists:
+        # the entries that end at or before `done` are applied, whole.
+        # One cursor a group: a group with several entries in the view
+        # (a hot tenant, replay's deep span) stands at its LAST applied
+        # one; the dict keeps the later of two, in log order.
+        k = bisect.bisect_right(vb.cur_end, done)
+        cur = dict(zip(vb.cur_g[:k], vb.cur_i[:k]))
+        self.applied[list(cur)] = list(cur.values())
+        if done < n:
+            # The native call ran out of memory at flat position `done`.
+            # The cursors stand behind exactly what was applied (a group
+            # cut inside an entry stands before that entry: it HAS taken
+            # part of it); no result of this batch is handed out and the
+            # caller HALTs.
+            raise MemoryError(
+                f"view batch stopped at request {done} of {n}")
+        if not trigger:
+            return
+        if sink is not None:
+            sink.acked += n
+        else:
+            acct.acked += n
+        if not descs:
+            return
+        for (_pos, nd, pd, idx), rid in zip(descs, vb.rids):
+            if nd is None:
+                code, cause = pd
+                res: Any = errors.EtcdError(code, cause=cause, index=idx)
+            else:
+                res = LazyWriteEvent(nd, pd, idx, now)
+            traced = every and rid % every == 0
+            if traced:
+                tr.mark(rid, "applied", t=t_applied)
+            if sink is not None:
+                sink.items.append((rid, res))
+            else:
+                if traced:          # before its waiter runs
+                    tr.mark(rid, "acked", acked_round=self.round_no)
+                self.wait.trigger(rid, res)
 
     def _apply_request(self, g: int, r: Request):
         """Deterministic request->store mapping (reference applyRequest

@@ -1412,7 +1412,7 @@ class HostEngine:
                                 # Locally-proposed waiter-held PUTs ride
                                 # the batch: the waiter is woken with the
                                 # raw descriptors (LazyWriteEvent; see
-                                # MultiEngine._flush_many).
+                                # MultiEngine._apply_view).
                                 fneed.append(len(fp))
                                 frids.append(r.id)
                             fp.append(r.path)
@@ -1463,7 +1463,8 @@ class HostEngine:
                     fneed: List[int], frids: List[int],
                     trigger: bool) -> None:
         """One batched run of plain-file PUTs; need-listed waiters are
-        woken with raw descriptors (see MultiEngine._flush_many)."""
+        woken with raw descriptors (see MultiEngine._apply_view, which
+        batches over a view's tenants; this copy is one call a group)."""
         if not fneed:
             st.set_applied_many(fp, fv)
             if trigger:

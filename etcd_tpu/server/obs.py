@@ -275,6 +275,14 @@ applier_batch = metrics.LabeledHistogram(
     "etcd_applier_apply_batch_requests",
     "Client requests applied+acked by one applier-shard pass.",
     ("shard",), buckets=_COUNT_BUCKETS)
+APPLY_PATHS = ("view", "scalar")
+apply_requests = metrics.LabeledCounter(
+    "etcd_engine_apply_requests_total",
+    "Requests applied, by how: view (a plain PUT of a group whose whole "
+    "span in the commit view is plain PUTs, on a native store with no "
+    "watcher: the pass applies all of them in one native call over the "
+    "tenants' cores) or scalar (everything else, request by request).",
+    ("path",))
 ack_gate_wait = metrics.Histogram(
     "etcd_ack_gate_wait_seconds",
     "Time an applier shard waited at the durability gate "
@@ -940,6 +948,9 @@ class EngineObs:
                              for k in range(applier_shards)]
         self.h_appl_batch = [applier_batch.labels(k)
                              for k in range(applier_shards)]
+        self.c_apply = {k: apply_requests.labels(k) for k in APPLY_PATHS}
+        for c in self.c_apply.values():
+            c.inc(0.0)
         self.h_ack_wait = ack_gate_wait
         self.c_rounds = rounds_total
         self.c_acked = acked_total

@@ -9,10 +9,10 @@ from etcd_tpu.store.watcher import Watcher, WatcherHub
 try:
     if _os.environ.get("ETCD_TPU_PYSTORE") == "1":
         raise ImportError("forced Python store")
-    from etcd_tpu.store.native_store import NativeStore
+    from etcd_tpu.store.native_store import NativeStore, set_applied_view
     HAVE_NATIVE_STORE = True
 except ImportError:
-    NativeStore = None  # type: ignore[assignment,misc]
+    NativeStore = set_applied_view = None  # type: ignore[assignment,misc]
     HAVE_NATIVE_STORE = False
 
 
@@ -28,7 +28,8 @@ def new_store(history_capacity=None, clock=None, namespaces=()):
                clock or time.time, namespaces=namespaces)
 
 
-__all__ = ["Store", "NativeStore", "HAVE_NATIVE_STORE", "new_store", "Event",
+__all__ = ["Store", "NativeStore", "HAVE_NATIVE_STORE", "new_store",
+           "set_applied_view", "Event",
            "EventHistory", "NodeExtern", "Watcher", "WatcherHub", "GET",
            "CREATE", "SET", "UPDATE", "DELETE", "COMPARE_AND_SWAP",
            "COMPARE_AND_DELETE", "EXPIRE"]

@@ -30,7 +30,7 @@ from etcd_tpu.store.event import Event, LazyWriteEvent, NodeExtern, ttl_of
 from etcd_tpu.store.store import Stats, normalize
 from etcd_tpu.store.watcher import Watcher, WatcherHub
 
-from etcd_tpu.native.storecore import Core  # type: ignore
+from etcd_tpu.native.storecore import Core, set_many_multi  # type: ignore
 
 # Action strings indexed by the C core's ACT_* codes.
 _ACTIONS = (ev.SET, ev.CREATE, ev.UPDATE, ev.COMPARE_AND_SWAP, ev.DELETE,
@@ -46,6 +46,17 @@ def _norm(p: str) -> str:
             and not p.endswith("/.") and not p.endswith("/..")):
         return p
     return normalize(p)
+
+
+def _canonical(paths: List[str]) -> List[str]:
+    """_norm() over a batch's paths with the canonical-path check inline:
+    one "//" scan + one "." scan (no dots rules out every "." / ".."
+    segment form at once) instead of a _norm() call per request — the
+    call alone was ~35% of set_applied_many's time at deep-queue load
+    (1 M calls/s)."""
+    norm = _norm
+    return [p if (p and p[0] == "/" and p[-1] != "/" and "//" not in p
+                  and "." not in p) else norm(p) for p in paths]
 
 
 def _extern(d, now: float) -> NodeExtern:
@@ -274,44 +285,43 @@ class NativeStore:
         now = self.clock()
         hub = self.watcher_hub
         want_recs = not hub.quiet()
-        # Inline canonical-path fast check: one "//" scan + one "." scan
-        # (no dots rules out every "." / ".." segment form at once)
-        # instead of a _norm() call per request — the call alone was
-        # ~35% of this method's time at deep-queue load (1 M calls/s).
-        norm = _norm
         first, last, failed, recs, descs = self._core.set_many(
-            [p if (p and p[0] == "/" and p[-1] != "/" and "//" not in p
-                   and "." not in p) else norm(p) for p in paths],
-            values, now, want_recs, need)
+            _canonical(paths), values, now, want_recs, need)
         applied = len(paths) - failed
-        if last < first:
-            return applied if need is None else (applied, descs)
-        if recs is not None:
-            if not hub.quiet():
-                for nd, pd, idx in recs:
-                    hub.notify(Event(
-                        ev.SET, node=_extern(nd, now),
-                        prev_node=None if pd is None else _extern(pd, now),
-                        etcd_index=idx))
-        elif not hub.quiet():
-            # Registration raced the atomic batch; replay what the ring
-            # still holds (single pass over the clamped span).
-            lo = max(first, self._core.ring_bounds()[0])
-            if lo > first:
-                # The batch evicted part of its own span: a stream
-                # watcher that registered mid-batch would silently skip
-                # the evicted events. Resync instead of lying: wake every
-                # watcher with the cleared sentinel (store Recovery
-                # semantics); re-registration with a stale waitIndex gets
-                # 401 EventIndexCleared from the next scan.
-                hub.clear()
-                return applied if need is None else (applied, descs)
-            scan = hub.event_history.scan
-            for i in range(lo, last + 1):
-                e = scan("/", True, i)
-                if e is not None and e.etcd_index <= last:
-                    hub.notify(e)
+        if recs is None:
+            self._notify_raced(first, last)
+        elif last >= first and not hub.quiet():
+            for nd, pd, idx in recs:
+                hub.notify(Event(
+                    ev.SET, node=_extern(nd, now),
+                    prev_node=None if pd is None else _extern(pd, now),
+                    etcd_index=idx))
         return applied if need is None else (applied, descs)
+
+    def _notify_raced(self, first: int, last: int) -> None:
+        """Behind a batch that found the hub quiet and so collected no
+        records: if a watcher registered between that check and the
+        atomic C call, notify it of [first, last] from the ring (single
+        pass over the clamped span), or clear the hub where the batch
+        evicted part of its own span (see set_applied_many)."""
+        hub = self.watcher_hub
+        if last < first or hub.quiet():
+            return
+        lo = max(first, self._core.ring_bounds()[0])
+        if lo > first:
+            # The batch evicted part of its own span: a stream watcher
+            # that registered mid-batch would silently skip the evicted
+            # events. Resync instead of lying: wake every watcher with
+            # the cleared sentinel (store Recovery semantics);
+            # re-registration with a stale waitIndex gets 401
+            # EventIndexCleared from the next scan.
+            hub.clear()
+            return
+        scan = hub.event_history.scan
+        for i in range(lo, last + 1):
+            e = scan("/", True, i)
+            if e is not None and e.etcd_index <= last:
+                hub.notify(e)
 
     # -- mutations -----------------------------------------------------------
 
@@ -460,6 +470,40 @@ class NativeStore:
     def json_stats(self) -> dict:
         self.stats.watchers = self.watcher_hub.count
         return self.stats.to_dict()
+
+
+def set_applied_view(stores: List[NativeStore], counts: List[int],
+                     paths: List[str], values: List[str],
+                     need: Optional[List[int]] = None):
+    """set_applied_many for a commit view's plain-file PUTs over MANY
+    tenants' stores in ONE native call (storecore.set_many_multi):
+    stores[k] takes the next counts[k] entries of the flat paths/values,
+    `need` lists flat positions whose callers hold a waiter. The caller
+    passes only stores it found quiet (no watcher); one that gained a
+    watcher since is notified from its ring afterwards, as
+    set_applied_many does.
+
+    Returns (done, descs, now): `descs` as set_applied_many's with flat
+    positions (None without `need`), `now` the one clock reading the
+    records carry. done < len(paths) says the native call ran out of
+    memory at flat position `done`: everything before it is applied,
+    nothing from it on, and the caller must HALT its apply loop
+    (Core.set_many's contract)."""
+    now = stores[0].clock()
+    done, descs, spans = set_many_multi(
+        [st._core for st in stores], counts, _canonical(paths), values,
+        now, need)
+    for st, span in zip(stores, spans):
+        # _notify_raced's own test reads the count under the hub's lock,
+        # because watch() holds that lock from its history scan to its
+        # registration. The lock is looked at FIRST: a watcher that
+        # scanned before the mutation either still holds it here, or has
+        # released it with its count already raised, so one of the two
+        # reads sees it whatever runs between them.
+        hub = st.watcher_hub
+        if hub._lock.locked() or hub.count:
+            st._notify_raced(*span)
+    return done, descs, now
 
 
 def _json_of(t) -> dict:

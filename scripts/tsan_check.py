@@ -74,8 +74,8 @@ def codec():
         walcodec.pack_multi([(1, b"\x00" + b"y" * 40)] * 8, 2)
 
 # Applier-pool shapes: K shard cores, each applied by its own thread
-# through set_many(need=...) — the per-shard apply + descriptor-wake
-# path (engine._flush_many) — and a SHARED core hit by two set_many
+# through set_many(need=...) — the per-store apply + descriptor-wake
+# path (HostEngine._flush_many) — and a SHARED core hit by two set_many
 # threads at once: its batch mutation phase drops the GIL under the
 # per-Core mutex, so these interleave in real C, with a reader walking
 # the same tree through the locked scalar path.

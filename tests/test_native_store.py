@@ -334,3 +334,151 @@ def test_history_wraparound_since_before_window_differential():
             assert (ep is None) == (en is None), (key, since)
             if ep is not None:
                 assert ev_sig(ep) == ev_sig(en), (key, since)
+
+
+# -- one batch over many cores (storecore.set_many_multi) --------------------
+
+
+def _multi_case(seed, shape, need_mode):
+    """K cores' slices of one flat batch: PUTs over a few files, a PUT
+    onto a directory (102), a read-only path (107) and a path through a
+    file (104) mixed in by the seed."""
+    rng = random.Random(seed * 7919 + 13)
+    if shape == "ones":
+        counts = [1] * 9
+    elif shape == "zeros":
+        counts = [rng.choice([0, 0, 1, 3]) for _ in range(9)]
+        counts[0] = counts[-1] = 0
+    else:                       # "many": deep slices, past the ring
+        counts = [rng.randint(0, 40) for _ in range(6)]
+    paths, vals = [], []
+    for k, n in enumerate(counts):
+        for j in range(n):
+            roll = rng.random()
+            if roll < 0.08:
+                p = "/1/d"              # a directory (seeded below)
+            elif roll < 0.14:
+                p = "/0"                # read-only
+            elif roll < 0.20:
+                p = "/1/f0/x"           # through a file, once f0 exists
+            else:
+                p = f"/1/f{rng.randint(0, 4)}"
+            paths.append(p)
+            vals.append(f"v{seed}_{k}_{j}")
+    n = len(paths)
+    need = {"none": None, "empty": [],
+            "partial": sorted(rng.sample(range(n), n // 2)),
+            "full": list(range(n))}[need_mode]
+    return counts, paths, vals, need
+
+
+def _seeded_store(clock):
+    st = NativeStore(history_capacity=16, clock=clock,
+                     namespaces=("/0", "/1"))
+    st.set("/1/d", is_dir=True)
+    st.set("/1/f0", value="seed")
+    return st
+
+
+def _store_sig(st):
+    hist = st.watcher_hub.event_history
+    ring, i = [], hist.start_index
+    while i <= hist.last_index:
+        e = hist.scan("/", True, i)
+        if e is None:
+            break
+        ring.append(ev_sig(e))
+        i = e.etcd_index + 1
+    return (st.current_index, st.json_stats(), st.save(), ring,
+            (hist.start_index, hist.last_index, len(hist)))
+
+
+@pytest.mark.parametrize("need_mode", ["none", "empty", "partial", "full"])
+@pytest.mark.parametrize("shape", ["ones", "zeros", "many"])
+@pytest.mark.parametrize("seed", range(4))
+def test_set_many_multi_equals_a_set_many_per_core(seed, shape, need_mode):
+    """set_many_multi over K cores gives the indices, descriptors, per-op
+    errors, stats and history ring of K set_many calls on the same
+    slices."""
+    from etcd_tpu.native.storecore import set_many_multi
+
+    counts, paths, vals, need = _multi_case(seed, shape, need_mode)
+    clock = Clock()
+    one = [_seeded_store(clock) for _ in counts]
+    per = [_seeded_store(clock) for _ in counts]
+
+    done, descs, spans = set_many_multi(
+        [st._core for st in one], counts, paths, vals, clock(), need)
+    assert done == len(paths)
+    assert len(spans) == len(counts)
+
+    want_descs = None if need is None else []
+    off = 0
+    for st, n, span in zip(per, counts, spans):
+        mine = None if need is None else [p - off for p in need
+                                          if off <= p < off + n]
+        first, last, failed, recs, d = st._core.set_many(
+            paths[off:off + n], vals[off:off + n], clock(), False, mine)
+        assert recs is None
+        assert span == (first, last)
+        if need is not None:
+            want_descs += [(x[0] + off,) + tuple(x[1:]) for x in d]
+        off += n
+    assert descs == want_descs
+    for a, b in zip(one, per):
+        assert _store_sig(a) == _store_sig(b)
+    if need_mode == "full":
+        codes = {d[2][0] for d in descs if d[1] is None}
+        assert codes <= {errors.ECODE_NOT_FILE, errors.ECODE_ROOT_RONLY,
+                         errors.ECODE_NOT_DIR}
+
+
+def test_set_many_multi_refuses_what_it_cannot_slice():
+    from etcd_tpu.native.storecore import set_many_multi
+
+    a, b = (_seeded_store(Clock())._core for _ in range(2))
+    with pytest.raises(ValueError, match="twice"):
+        set_many_multi([a, a], [1, 1], ["/1/x", "/1/y"], ["1", "2"], 1.0)
+    with pytest.raises(ValueError, match="counts"):
+        set_many_multi([a, b], [1, 2], ["/1/x", "/1/y"], ["1", "2"], 1.0)
+    with pytest.raises(ValueError, match="counts"):
+        set_many_multi([a, b], [1, 0], ["/1/x", "/1/y"], ["1", "2"], 1.0)
+    with pytest.raises(TypeError):
+        set_many_multi([a, object()], [1, 1], ["/1/x", "/1/y"],
+                       ["1", "2"], 1.0)
+    with pytest.raises(IndexError):
+        set_many_multi([a, b], [1, 1], ["/1/x", "/1/y"], ["1", "2"], 1.0,
+                       [2])
+    # nothing above touched a core, and every mutex was given back
+    assert a.index == b.index == 2
+    assert set_many_multi([a, b], [1, 1], ["/1/x", "/1/y"], ["1", "2"],
+                          1.0) == (2, None, [(3, 3), (3, 3)])
+
+
+def test_set_applied_view_notifies_a_watcher_that_raced_the_batch(
+        monkeypatch):
+    """A watcher that registers between the caller's quiet check and the
+    native call sees the batch's events for its tenant, in order, from
+    the ring; the other tenants' stores stay silent."""
+    clock = Clock()
+    stores = [_seeded_store(clock) for _ in range(3)]
+    real = native_store.set_many_multi
+    raced = []
+
+    def racing(cores, counts, paths, vals, now, need=None):
+        raced.append(stores[1].watch("/", recursive=True, stream=True,
+                                     since_index=0))
+        return real(cores, counts, paths, vals, now, need)
+
+    monkeypatch.setattr(native_store, "set_many_multi", racing)
+    done, descs, now = native_store.set_applied_view(
+        stores, [1, 3, 1],
+        ["/1/a", "/1/b", "/1/d", "/1/b", "/1/c"], ["1", "2", "x", "3", "4"],
+        [1, 2])
+    assert done == 5 and now == clock()
+    assert [d[0] for d in descs] == [1, 2]
+    assert descs[1][1] is None and descs[1][2][0] == errors.ECODE_NOT_FILE
+    got = []
+    while (e := raced[0].next_event(timeout=0.05)) is not None:
+        got.append((e.action, e.node.key, e.node.value, e.etcd_index))
+    assert got == [("set", "/1/b", "2", 3), ("set", "/1/b", "3", 4)]

@@ -285,3 +285,71 @@ def test_frames_plane_concurrent_clients_race(tmp_path):
                 e.stop()
             except Exception:  # noqa: BLE001
                 pass
+
+
+def test_view_batches_over_shared_cores_race_readers_and_each_other():
+    """storecore.set_many_multi from more threads than cores, over core
+    sets that overlap and come in opposite orders (the call takes every
+    mutex before it mutates: a ring of waits would hang here), short
+    calls that keep the interpreter beside long ones that release it,
+    with readers walking the same trees meanwhile: every op lands
+    exactly once (index = ops applied, a core
+    at a time) and the history ring ends on the last index."""
+    storecore = pytest.importorskip("etcd_tpu.native.storecore")
+    import os
+
+    cores = [storecore.Core(namespaces=("/0", "/1"), history_capacity=64)
+             for _ in range(12)]
+    applied = [0] * len(cores)
+    tally = threading.Lock()
+    stop = threading.Event()
+    failures = []
+
+    def batcher(tid, order, per_core):
+        try:
+            for b in range(40):
+                mine = [cores[k] for k in order]
+                n = per_core * len(mine)
+                paths = [f"/1/t{tid}/k{(b + j) % 5}" for j in range(n)]
+                done, descs, spans = storecore.set_many_multi(
+                    mine, [per_core] * len(mine), paths, ["v" * 32] * n,
+                    1.0, [0, n - 1])
+                assert done == n and len(descs) == 2, (done, descs)
+                for k, (first, last) in zip(order, spans):
+                    assert last - first + 1 == per_core, (first, last)
+                with tally:
+                    for k in order:
+                        applied[k] += per_core
+        except Exception as e:  # noqa: BLE001 - reported by the test
+            failures.append(e)
+
+    def reader():
+        try:
+            while not stop.is_set():
+                for c in cores:
+                    # an index seen (read without the mutex) is in the
+                    # ring by the time a locked reader gets in
+                    seen = c.index
+                    lo, hi, n = c.ring_bounds()
+                    assert seen <= hi <= c.index and n <= 64, (seen, lo, hi)
+                    c.get("/", True, False)
+        except Exception as e:  # noqa: BLE001
+            failures.append(e)
+
+    fwd, rev = list(range(12)), list(range(11, -1, -1))
+    workers = [threading.Thread(target=batcher, args=a) for a in (
+        (0, fwd, 4), (1, rev, 4),           # 48 ops: the GIL is released
+        (2, fwd[:6], 1), (3, rev[:6], 1),   # 6 ops: it is kept
+        (4, fwd[3:9], 6), (5, [8, 2, 5, 0], 2))]
+    readers = [threading.Thread(target=reader)
+               for _ in range(max(2, (os.cpu_count() or 2)))]
+    for t in readers + workers:
+        t.start()
+    for t in workers:
+        t.join(timeout=120)
+    stop.set()
+    for t in readers:
+        t.join(timeout=30)
+    assert not any(t.is_alive() for t in workers + readers), "hung"
+    assert not failures, failures[:3]
+    assert [c.index for c in cores] == applied
