@@ -1455,6 +1455,36 @@ def gather_rows(st: GroupState, flags: jax.Array, any_need_host: jax.Array,
     return jnp.concatenate([head, rows], axis=0)
 
 
+# The bodies of the need-host surgery's two device programs (server/
+# engine.py _service_need_host jits them, with a mesh's shardings where
+# there is one): the host works on the flagged groups' rows alone, K groups
+# a call (one program each whatever the number flagged), and never brings a
+# whole state array across.
+NEED_HOST_READ = ("next", "match", "pr_state", "paused", "lead", "elapsed")
+NEED_HOST_WRITE = ("term", "vote", "commit", "last_index", "log_term",
+                   "lead", "state", "elapsed", "match", "next", "pr_state",
+                   "paused")
+
+
+def pick_groups(fields: Tuple[jax.Array, ...], idx: jax.Array
+                ) -> Tuple[jax.Array, ...]:
+    """The groups `idx` (K,) of each of `fields` (leading axis G): what the
+    surgery reads of the state the host keeps no mirror of. An index past
+    the end (the padding of a short call) reads the last group."""
+    at = jnp.minimum(idx, fields[0].shape[0] - 1)
+    return tuple(x[at] for x in fields)
+
+
+def put_groups(fields: Tuple[jax.Array, ...], need_host: jax.Array,
+               idx: jax.Array, rows: Tuple[jax.Array, ...]):
+    """`fields` with the groups `idx` (K,) set to `rows` (K, ...), and
+    need_host cleared: the surgery's write-back. An index past the end is
+    dropped; every other group keeps what it holds."""
+    out = tuple(x.at[idx].set(r.astype(x.dtype), mode="drop")
+                for x, r in zip(fields, rows))
+    return out, jnp.zeros_like(need_host)
+
+
 @functools.partial(jax.jit, static_argnums=0, donate_argnums=_donate_at_import((1, 2)))
 def step_routed_slots(cfg: KernelConfig, st: GroupState, inbox: jax.Array,
                       cnt_gp: jax.Array, tick: jax.Array

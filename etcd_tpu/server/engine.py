@@ -160,6 +160,16 @@ def mesh_gather_rows(rep):
 IDLE_TICK_S = 0.05
 
 
+# The need-host surgery works on this many flagged groups a pass (one
+# compiled kernel.pick_groups / put_groups each, whatever the number
+# flagged: a round flags a few), and takes these fields' rows from the
+# host's mirrors.
+NEED_HOST_GROUPS = 64
+_NEED_HOST_MIRRORS = (("term", "h_term"), ("vote", "h_vote"),
+                      ("commit", "h_commit"), ("last_index", "h_last"),
+                      ("log_term", "h_ring"), ("state", "h_state"))
+
+
 def _bucket(k: int) -> int:
     """The smallest gather_rows size bucket that holds k rows."""
     return max(256, 1 << (k - 1).bit_length())
@@ -520,6 +530,20 @@ class MultiEngine:
         self._step_fn = step_fn("step_routed_auto")
         self._step_fn_c = step_fn("step_routed_compact")
         self._step_fn_r = step_fn("step_routed_read_auto")
+        # The need-host surgery's two programs (_need_host_pass): on a mesh
+        # the picked rows come back replicated and the write-back lands on
+        # the fields' pinned shardings; in place where donation is safe.
+        pick_out = put_out = None
+        if self._st_sh is not None:
+            pick_out = rep
+            put_out = (tuple(getattr(self._st_sh, f)
+                             for f in kernel.NEED_HOST_WRITE),
+                       self._st_sh.need_host)
+        self._pick_groups = jax.jit(kernel.pick_groups,
+                                    out_shardings=pick_out)
+        self._put_groups = jax.jit(kernel.put_groups,
+                                   donate_argnums=kernel.donate_safe((0, 1)),
+                                   out_shardings=put_out)
         # None = on, mesh or not (EngineConfig.compact_readback).
         self._compact = (cfg.compact_readback is None
                          or bool(cfg.compact_readback))
@@ -633,10 +657,12 @@ class MultiEngine:
         # admitted this round (round-thread-private, reset per round).
         self._last_admitted = 0
         self._trace_rids: List[int] = []
-        # This round's blocking device->host reads (count, bytes) and the
-        # record phase's clocked parts; round-thread-private, reset per
-        # round, written only under obs.enabled.
+        # This round's blocking device->host reads and host->device
+        # uploads (count, bytes) and the record phase's clocked parts;
+        # round-thread-private, reset per round, written only under
+        # obs.enabled.
         self._d2h_n = self._d2h_b = 0
+        self._h2d_n = self._h2d_b = 0
         self._rec_gather = self._rec_admit = 0.0
         # The WAL compartment: submit() hands records to the writer
         # stage; acks gate on its durability watermark (wait_durable).
@@ -805,6 +831,8 @@ class MultiEngine:
         x = self._jnp.asarray(arr)
         if self._st_sh is not None:
             x = self._jax.device_put(x, getattr(self._st_sh, name))
+        if self.obs.enabled:
+            self._h2d(x)
         return x
 
     # ------------------------------------------------------------------
@@ -2029,6 +2057,8 @@ class MultiEngine:
         try:
             if self._compact:
                 self._warm_gather()
+            # (the need-host surgery's two programs: a pass over no group)
+            self._need_host_pass(np.zeros(0, np.int64))
             while not self._stop_ev.is_set():
                 self.run_round()
                 # A round with nothing to do is a round's worth of the
@@ -2072,6 +2102,7 @@ class MultiEngine:
         if o:
             clock.lap("stage", t_round)
             self._d2h_n = self._d2h_b = 0
+            self._h2d_n = self._h2d_b = 0
             self._rec_gather = self._rec_admit = 0.0
             o.flight.mark(r_no, obs_mod.SUBMITTED, t_round)
 
@@ -2205,9 +2236,14 @@ class MultiEngine:
                                  for g in self._read_dirty
                                  if self._reads[g]}
 
-        # -- 1c. lagging-follower injection: this round's held slots
-        hold = self._lag_hold() if self._lag is not None else None
-        down = self._churn_down() if self._churn is not None else None
+        # -- 1c. fault injection: this round's held follower slots, or its
+        # slots cut off from their peers (the map's upkeep and its upload)
+        hold = down = None
+        if self._lag is not None or self._churn is not None:
+            with self.obs.span("etcd.round.fault_map"):
+                hold = self._lag_hold() if self._lag is not None else None
+                down = (self._churn_down() if self._churn is not None
+                        else None)
 
         if o:
             t_take = t_ph = time.perf_counter()
@@ -2218,6 +2254,8 @@ class MultiEngine:
         tick = jnp.asarray(bool(
             (self.round_no % self.cfg.ticks_per_round) == 0))
         pc_d, ps_d = jnp.asarray(prop_count), jnp.asarray(prop_slot)
+        if o:
+            self._h2d(tick, pc_d, ps_d)
         flags_d = anh_d = None
         conf_d = rc_d = None
         if read_take:
@@ -2486,11 +2524,8 @@ class MultiEngine:
         # acked). need_host is None on a compact round — the device
         # already attested any_need_host == False for it.
         if need_host is not None and need_host.any():
-            t0 = time.perf_counter() if o else 0.0
             with self.obs.span("etcd.round.need_host"):
                 self._service_need_host(need_host)
-            if o:
-                o.h_need_host.observe(time.perf_counter() - t0)
 
         # What _idle asks of the round just run, whichever readback built
         # its record (a surgery leaves the next round a diff to journal).
@@ -2531,6 +2566,8 @@ class MultiEngine:
             if self._d2h_n:
                 o.c_d2h_syncs.inc(self._d2h_n)
                 o.c_d2h_bytes.inc(self._d2h_b)
+            o.c_h2d_syncs.inc(self._h2d_n)
+            o.c_h2d_bytes.inc(self._h2d_b)
             # (opened here, not in _run: the thread can lose the
             # interpreter for tens of ms on its way out of this call)
             clock.lap("gap" if self._looping else None, time.perf_counter())
@@ -2688,6 +2725,13 @@ class MultiEngine:
         self._d2h_n += 1
         for a in arrays:
             self._d2h_b += a.nbytes
+
+    def _h2d(self, *arrays) -> None:
+        """Count the upload of each of `arrays`, host->device (called
+        under obs.enabled only; flushed to the counters once a round)."""
+        self._h2d_n += len(arrays)
+        for a in arrays:
+            self._h2d_b += a.nbytes
 
     def _all_led(self) -> bool:
         """Every provisioned group's mirror shows a leader."""
@@ -3435,35 +3479,90 @@ class MultiEngine:
         reference raft.go:246-260 + etcdserver snapshot catch-up §3.5).
         A follower the lag injection holds in this round is left alone, and
         so is a slot the churn has cut off (an install is a message too);
-        the leader is the group's routable one, of the highest term."""
-        st = self.st
-        W = self.cfg.window
+        the leader is the group's routable one, of the highest term.
+
+        Only the flagged groups' rows cross between device and host,
+        NEED_HOST_GROUPS groups a pass: the progress fields the host keeps
+        no mirror of are picked on the device (kernel.pick_groups, one
+        blocking read), the rows of the mirrored fields come from the
+        mirrors (a need-host round took the full readback, so they ARE the
+        device's), and the result is scattered back under the fields'
+        shardings (kernel.put_groups), which also clears need_host."""
         flagged = np.nonzero(need_host.any(axis=1))[0]
         if not len(flagged):
             return
-        if self.obs.enabled:
-            for a in (st.next, st.match, st.pr_state, st.paused, st.lead,
-                      st.elapsed):
-                self._d2h(a)
-        nxt = np.asarray(st.next).copy()
-        match = np.asarray(st.match).copy()
-        prs = np.asarray(st.pr_state).copy()
-        paused = np.asarray(st.paused).copy()
-        term = self.h_term.copy()
-        vote = self.h_vote.copy()
-        commit = self.h_commit.copy()
-        lastv = self.h_last.copy()
-        ring = self.h_ring.copy()
-        lead = np.asarray(st.lead).copy()
-        stat = self.h_state.copy()
-        elapsed = np.asarray(st.elapsed).copy()
+        parts = [0.0, 0.0, 0.0]         # obs.NEED_HOST_PARTS' seconds
         installs = 0
-        for g in flagged:
-            g = int(g)
+        t0 = time.perf_counter()
+        for lo in range(0, len(flagged), NEED_HOST_GROUPS):
+            n, t1, t2 = self._need_host_pass(flagged[lo:lo + NEED_HOST_GROUPS])
+            t3 = time.perf_counter()
+            installs += n
+            for k, d in enumerate((t1 - t0, t2 - t1, t3 - t2)):
+                parts[k] += d
+            t0 = t3
+        if installs:
+            # Mirrors stay pre-surgery: the next round must therefore run
+            # the FULL readback so its diff journals the install's
+            # term/commit/ring/last changes, making it durable — a compact
+            # (device-vs-device) diff cannot see surgery that happened
+            # between rounds.
+            self._force_full = True
+        self.snap_installs += installs
+        if self.obs.enabled:
+            o = self.obs
+            o.c_snap_installs.inc(installs)
+            for name, d in zip(obs_mod.NEED_HOST_PARTS, parts):
+                o.h_need_host_part[name].observe(d)
+            o.h_need_host.observe(sum(parts))
+
+    def _need_host_pass(self, gs: np.ndarray) -> Tuple[int, float, float]:
+        """The surgery over the groups `gs` (at most NEED_HOST_GROUPS; none:
+        the programs are built and the state stays as it is). Returns the
+        installs made and the clock readings behind the blocking read and
+        behind the host's work."""
+        K, kernel, st = NEED_HOST_GROUPS, self._kernel, self.st
+        idx = np.full(K, self.cfg.groups, np.int32)     # padding: past G
+        idx[:len(gs)] = gs
+        idx_d = self._jnp.asarray(idx)
+        picked = self._pick_groups(
+            tuple(getattr(st, f) for f in kernel.NEED_HOST_READ), idx_d)
+        rows = dict(zip(kernel.NEED_HOST_READ,
+                        (np.array(a) for a in self._jax.device_get(picked))))
+        t1 = time.perf_counter()
+        for f, mirror in _NEED_HOST_MIRRORS:
+            a = getattr(self, mirror)
+            rows[f] = np.zeros((K,) + a.shape[1:], a.dtype)
+            rows[f][:len(gs)] = a[gs]
+        installs = self._install_rows(gs, rows)
+        t2 = time.perf_counter()
+        out = tuple(rows[f] for f in kernel.NEED_HOST_WRITE)
+        new, nh = self._put_groups(
+            tuple(getattr(st, f) for f in kernel.NEED_HOST_WRITE),
+            st.need_host, idx_d, out)
+        self.st = st._replace(need_host=nh,
+                              **dict(zip(kernel.NEED_HOST_WRITE, new)))
+        if self.obs.enabled:
+            self._d2h(*picked)
+            self._h2d(idx_d, *out)
+        return installs, t1, t2
+
+    def _install_rows(self, gs: np.ndarray, rows: Dict[str, np.ndarray]
+                      ) -> int:
+        """The host's part of the surgery: `rows[field][j]` is group
+        gs[j]'s row of the field, changed in place; returns the installs
+        made."""
+        W = self.cfg.window
+        term, vote, commit = rows["term"], rows["vote"], rows["commit"]
+        lastv, ring, stat = rows["last_index"], rows["log_term"], rows["state"]
+        nxt, match, prs = rows["next"], rows["match"], rows["pr_state"]
+        paused, lead, elapsed = rows["paused"], rows["lead"], rows["elapsed"]
+        installs = 0
+        for j, g in enumerate(gs.tolist()):
             s = self.leader_slot(g)
             if s < 0 or self._down[g, s]:
                 continue
-            c = int(commit[g, s])
+            c = int(commit[j, s])
             for f in np.nonzero(self.h_mask[g])[0]:
                 f = int(f)
                 if f == s or self._lag_held[g, f] or self._down[g, f]:
@@ -3471,63 +3570,37 @@ class MultiEngine:
                 # Lagging = the kernel's need_snap condition: entries from
                 # next are no longer resolvable from the leader's ring
                 # (next <= last - W; see kernel ents_ok/sendable).
-                if nxt[g, s, f] > lastv[g, s] - W:
+                if nxt[j, s, f] > lastv[j, s] - W:
                     continue  # still reachable by appends
-                if term[g, f] > term[g, s]:
+                if term[j, f] > term[j, s]:
                     continue  # follower is ahead in term; let raft sort it
                 log.debug("engine: snapshot-install g=%d slot=%d from "
                           "leader=%d commit=%d", g, f, s, c)
-                if term[g, f] < term[g, s]:
-                    vote[g, f] = 0
-                term[g, f] = term[g, s]
+                if term[j, f] < term[j, s]:
+                    vote[j, f] = 0
+                term[j, f] = term[j, s]
                 # Copy the leader's ring, but zero slots holding leader
                 # entries ABOVE the install point: on the follower those
                 # positions alias indices c-W..c and would otherwise carry
                 # wrong terms (the device never reads them below commit,
                 # but the WAL ring-diff would record the junk).
-                row = ring[g, s].copy()
-                l_s = int(lastv[g, s])
+                row = ring[j, s].copy()
+                l_s = int(lastv[j, s])
                 for w in range(W):
                     if l_s - ((l_s - w) % W) > c:
                         row[w] = 0
-                ring[g, f] = row
-                lastv[g, f] = c
-                commit[g, f] = c
-                stat[g, f] = 0
-                lead[g, f] = s + 1
-                elapsed[g, f] = 0
-                match[g, s, f] = c
-                nxt[g, s, f] = c + 1
-                prs[g, s, f] = 1       # PR_REPLICATE
-                paused[g, s, f] = False
+                ring[j, f] = row
+                lastv[j, f] = c
+                commit[j, f] = c
+                stat[j, f] = 0
+                lead[j, f] = s + 1
+                elapsed[j, f] = 0
+                match[j, s, f] = c
+                nxt[j, s, f] = c + 1
+                prs[j, s, f] = 1       # PR_REPLICATE
+                paused[j, s, f] = False
                 installs += 1
-        nh = np.zeros_like(need_host)
-        if installs:
-            # Mirrors stay pre-surgery (see NOTE below); the next round
-            # must therefore run the FULL readback so its diff journals
-            # the install — a compact (device-vs-device) diff cannot see
-            # surgery that happened between rounds.
-            self._force_full = True
-            self.st = st._replace(
-                term=self._dev("term", term), vote=self._dev("vote", vote),
-                commit=self._dev("commit", commit),
-                last_index=self._dev("last_index", lastv),
-                log_term=self._dev("log_term", ring),
-                lead=self._dev("lead", lead),
-                state=self._dev("state", stat),
-                elapsed=self._dev("elapsed", elapsed),
-                match=self._dev("match", match), next=self._dev("next", nxt),
-                pr_state=self._dev("pr_state", prs),
-                paused=self._dev("paused", paused),
-                need_host=self._dev("need_host", nh))
-            # NOTE: the h_* mirrors deliberately KEEP their pre-surgery
-            # values — the next round's WAL diff then records the install's
-            # term/commit/ring/last changes, making it durable.
-        else:
-            self.st = st._replace(need_host=self._dev("need_host", nh))
-        self.snap_installs += installs
-        if self.obs.enabled:
-            self.obs.c_snap_installs.inc(installs)
+        return installs
 
     # ------------------------------------------------------------------
     # checkpoint

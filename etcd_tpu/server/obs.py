@@ -113,6 +113,17 @@ d2h_syncs = metrics.Counter(
 d2h_bytes = metrics.Counter(
     "etcd_engine_d2h_bytes_total",
     "Bytes those device->host reads brought back (the arrays' nbytes).")
+h2d_syncs = metrics.Counter(
+    "etcd_engine_h2d_syncs_total",
+    "Host->device uploads the round thread makes, one an array: the "
+    "round's staged proposals (count, slot and the tick: three a round), "
+    "the fault maps (--engine-lag-share: one a round; "
+    "--engine-churn-down-rounds: one in a round in which a cut began or "
+    "ended), and what host surgery writes back (the need-host surgery's "
+    "rows or arrays, a conf change's, a tenant reset's, a mask repair).")
+h2d_bytes = metrics.Counter(
+    "etcd_engine_h2d_bytes_total",
+    "Bytes those host->device uploads handed over (the arrays' nbytes).")
 READBACK_KINDS = ("compact", "full", "over_cap")
 readback_rounds = metrics.LabeledCounter(
     "etcd_engine_readback_rounds_total",
@@ -140,15 +151,25 @@ step_passes = metrics.Counter(
     "Sequential message passes, summed over the hops of the device step "
     "(a quiet hop makes none; a full hop as many as its busiest receiver "
     "needs by rank, or one a peer slot by sender).")
+_NEED_HOST_BUCKETS = (0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 0.5,
+                      1.0, 2.5, 5.0)
 need_host_seconds = metrics.Histogram(
     "etcd_engine_need_host_seconds",
     "Wall time of the need-host surgery on the round thread, one "
-    "observation a serviced round (it lies at the end of tail): the progress "
-    "arrays read back whole, the leader's ring row copied into each "
-    "lagging follower's on the host, thirteen state arrays uploaded "
-    "again.",
-    buckets=(0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 0.5, 1.0,
-             2.5, 5.0))
+    "observation a serviced round (it lies at the end of tail): the "
+    "flagged groups' rows of the progress fields picked on the device and "
+    "read back, the leader's ring row copied into each lagging "
+    "follower's on the host, twelve fields' rows scattered back.",
+    buckets=_NEED_HOST_BUCKETS)
+NEED_HOST_PARTS = ("read", "surgery", "write")
+need_host_part = metrics.LabeledHistogram(
+    "etcd_engine_need_host_part_seconds",
+    "The need-host surgery's wall time by part, one observation of each a "
+    "serviced round: read (its blocking device->host reads), surgery (the "
+    "host's work on what it read) and write (handing the result back to "
+    "the device, on the fields' pinned shardings on a mesh). The three "
+    "tile etcd_engine_need_host_seconds: their sums add up to its sum.",
+    ("part",), buckets=_NEED_HOST_BUCKETS)
 snapshot_installs = metrics.Counter(
     "etcd_engine_snapshot_installs_total",
     "Followers the host snapshot-installed: their entries had fallen out "
@@ -917,9 +938,13 @@ class EngineObs:
         self.c_step_passes = step_passes
         self.c_d2h_syncs = d2h_syncs
         self.c_d2h_bytes = d2h_bytes
+        self.c_h2d_syncs = h2d_syncs
+        self.c_h2d_bytes = h2d_bytes
         self.h_pending_wait = pending_wait
         self.h_checkpoint = checkpoint_seconds
         self.h_need_host = need_host_seconds
+        self.h_need_host_part = {p: need_host_part.labels(p)
+                                 for p in NEED_HOST_PARTS}
         self.c_snap_installs = snapshot_installs
         self.c_lag_releases = lag_releases
         self.g_lag_held = lag_held_slots

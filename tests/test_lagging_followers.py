@@ -712,6 +712,70 @@ def test_installs_are_counted_timed_and_annotated(tmp_path):
     assert len(surgeries) == serviced
     for _, s, e in surgeries:
         assert any(ts <= s and e <= te for _, ts, te in tails)
+    # the fault map's upkeep and upload: inside `stage`, once a round
+    maps = [h for h in host if h[0] == "etcd.round.fault_map"]
+    stages = [h for h in host if h[0] == "etcd.round.stage"]
+    assert len(maps) >= len(tails) - 1 and len(maps) >= 120
+    for _, s, e in maps:
+        assert any(ts <= s and e <= te for _, ts, te in stages)
+
+
+def test_the_surgery_moves_rows_and_its_three_parts_tile_its_time(tmp_path):
+    """The need-host surgery reads and writes the flagged groups' rows, a
+    fixed number a pass whatever G: one blocking read of the six progress
+    fields' rows and thirteen uploads (the indices and twelve fields'
+    rows), counted to the byte; a pass over no group (how the programs are
+    built before the first round) leaves the state as it is; and over a run
+    with installs the three parts' sums add up to
+    etcd_engine_need_host_seconds' sum, one observation of each a serviced
+    round."""
+    from etcd_tpu.server import obs
+    from etcd_tpu.server.engine import MultiEngine, NEED_HOST_GROUPS as K
+    eng = MultiEngine(make_cfg(tmp_path / "d", lag_share=0.125,
+                               lag_hold_rounds=40, lag_seed=7))
+    if not eng.obs.enabled:
+        eng.stop()
+        pytest.skip("ETCD_TPU_OBS=off")
+    P, W = eng.cfg.peers, eng.cfg.window
+    parts = {p: obs.need_host_part.labels(p) for p in obs.NEED_HOST_PARTS}
+    before = (obs.need_host_seconds.count, obs.need_host_seconds.sum,
+              {p: (h.count, h.sum) for p, h in parts.items()},
+              obs.h2d_syncs.value, obs.h2d_bytes.value)
+    try:
+        run_until(eng, lambda: all_led(eng), msg="leaders")
+        was = {f: np.asarray(getattr(eng.st, f)).copy()
+               for f in eng.st._fields}
+        eng._d2h_n = eng._d2h_b = eng._h2d_n = eng._h2d_b = 0
+        assert eng._need_host_pass(np.zeros(0, np.int64))[0] == 0
+        for f, a in was.items():
+            assert np.array_equal(np.asarray(getattr(eng.st, f)), a), f
+        assert eng._d2h_n == 1 and eng._h2d_n == 1 + len(
+            kernel.NEED_HOST_WRITE) == 13
+        assert eng._d2h_b == K * (3 * P * P * 4 + P * P + 2 * P * 4)
+        assert eng._h2d_b == K * (4 + 7 * P * 4 + P * W * 4
+                                  + 3 * P * P * 4 + P * P)
+        rounds = eng.round_no
+        for i in range(60):
+            put(eng, i % 4, f"/k{i // 4}", "v")
+            put(eng, 0, f"/hot{i}", "v")
+        rounds = eng.round_no - rounds
+    finally:
+        eng.stop()
+    assert eng.snap_installs >= 1
+    serviced = obs.need_host_seconds.count - before[0]
+    total = obs.need_host_seconds.sum - before[1]
+    assert serviced >= 1 and total > 0
+    tiled = 0.0
+    for p, h in parts.items():
+        assert h.count - before[2][p][0] == serviced, p
+        assert h.sum - before[2][p][1] > 0, p
+        tiled += h.sum - before[2][p][1]
+    assert abs(tiled - total) <= 1e-9 + 1e-6 * total
+    # every round uploads its staged proposals (count, slot, tick) and the
+    # hold map; a serviced round the surgery's thirteen more
+    assert obs.h2d_syncs.value - before[3] >= 4 * rounds + 13 * serviced
+    assert obs.h2d_bytes.value - before[4] >= rounds * (
+        2 * eng.cfg.groups * 4 + eng.cfg.groups * P)
 
 
 def _http(method, url, form=None, timeout=30.0):

@@ -425,6 +425,11 @@ NEW_SERIES = (
     "etcd_request_rounds_sum",
     "etcd_request_rounds_count",
     "etcd_request_spans_dropped_total",
+    # PR 43: what a round uploads, and the need-host surgery's parts
+    "etcd_engine_h2d_syncs_total",
+    "etcd_engine_h2d_bytes_total",
+    "etcd_engine_need_host_part_seconds_sum",
+    "etcd_engine_need_host_part_seconds_count",
 )
 
 
@@ -660,6 +665,8 @@ def _load(base, n=24, reads=True):
       for p in obs_mod.RECORD_PARTS],
     ("etcd_engine_d2h_syncs_total", {}),
     ("etcd_engine_d2h_bytes_total", {}),
+    ("etcd_engine_h2d_syncs_total", {}),
+    ("etcd_engine_h2d_bytes_total", {}),
     ("etcd_engine_pending_wait_seconds_count", {}),
     *[("etcd_http_request_seconds_count", {"kind": k})
       for k in obs_mod.FRONT_KINDS],
@@ -679,18 +686,22 @@ def test_new_series_move_under_load(eng_http, series, labels):
     assert _delta(a, b, series, **labels) > 0, (series, labels)
 
 
-@pytest.mark.parametrize("series", [
-    "etcd_engine_checkpoint_seconds_count",
-    "etcd_engine_gather_rebuckets_total",
-    "etcd_jax_compiles_total", "etcd_jax_compile_seconds_total"])
-def test_rare_event_series_are_exposed(eng_http, series):
-    """Checkpoints, bucket misses and compiles need not happen in a
-    window; their series are there all the same, at 0 from the start
-    (the compile counters have counted this process's step variants)."""
+@pytest.mark.parametrize("series,labels", [
+    ("etcd_engine_checkpoint_seconds_count", {}),
+    ("etcd_engine_gather_rebuckets_total", {}),
+    *[("etcd_engine_need_host_part_seconds_count", {"part": p})
+      for p in obs_mod.NEED_HOST_PARTS],
+    ("etcd_jax_compiles_total", {}), ("etcd_jax_compile_seconds_total", {})])
+def test_rare_event_series_are_exposed(eng_http, series, labels):
+    """Checkpoints, bucket misses, need-host surgeries and compiles need
+    not happen in a window (a member without fault injection may never
+    see a surgery); their series are there all the same, at 0 from the
+    start (the compile counters have counted this process's step
+    variants)."""
     eng, base = eng_http
     scrape = _load_script("etcd_top").parse_metrics(
         _http("GET", base + "/metrics"))
-    assert _val(scrape, series) is not None
+    assert _val(scrape, series, **labels) is not None
     if "jax" in series:
         assert _val(scrape, series) > 0
 

@@ -138,15 +138,19 @@ def _mesh4(topo) -> Mesh:
     return Mesh(np.array(topo.devices).reshape(4, 1), ("groups", "peers"))
 
 
-def _compile_mesh(topo, G: int, name: str = "step_routed_auto"):
+def _compile_mesh(topo, G: int, name: str = "step_routed_auto",
+                  down: bool = False, peers: int = P):
     """The engine's mesh step (engine.py: out_shardings pinned, donated);
     step_routed_compact adds the flag map, sharded like the state, and
     the replicated need-host attestation; step_routed_read_auto the read
-    plane's two (G,) arrays, sharded on groups, and then those two."""
+    plane's two (G,) arrays, sharded on groups, and then those two.
+    `down`: with the (G, P) bool of slots cut off from their peers as the
+    engine hands it over (--engine-churn-down-rounds on a mesh: sharded
+    like the state's (G, P) fields, the hold None)."""
     from etcd_tpu.parallel.mesh import (flag_sharding, group_sharding,
                                         mailbox_sharding,
                                         replicated_sharding, state_sharding)
-    cfg = KernelConfig(groups=G, peers=P, window=W)
+    cfg = KernelConfig(groups=G, peers=peers, window=W)
     mesh = _mesh4(topo)
     st_sh, mb_sh = state_sharding(mesh), mailbox_sharding(mesh)
     rep = replicated_sharding(mesh)
@@ -160,7 +164,11 @@ def _compile_mesh(topo, G: int, name: str = "step_routed_auto"):
         _named_partial(getattr(kernel, name).__wrapped__, cfg, hops=HOPS,
                        by_sender=True),
         donate_argnums=(0, 1), out_shardings=out_sh)
-    return fn.lower(*_shapes(cfg, st_sh, mb_sh, rep), None).compile()
+    more = {}
+    if down:
+        more = {"hold": None, "down": jax.ShapeDtypeStruct(
+            (G, peers), jnp.bool_, sharding=flag_sharding(mesh))}
+    return fn.lower(*_shapes(cfg, st_sh, mb_sh, rep), None, **more).compile()
 
 
 def _collectives(compiled) -> list:
@@ -205,6 +213,64 @@ def test_mesh_read_variant_compiles_for_v5e_2x2(topo, as_served):
     gathers the flag map or the (G,) confirmations (gather_rows does,
     in its own program)."""
     _check_mesh(_compile_mesh(topo, 4, "step_routed_read_auto"), HOPS + 1)
+
+
+@pytest.mark.parametrize("name", VARIANTS[1:])
+def test_mesh_variant_with_the_down_map_compiles_for_v5e_2x2(topo, as_served,
+                                                             name):
+    """The two programs a mesh member with --engine-churn-down-rounds serves
+    (mt100k-p7-churn-mesh4, seven peers): the down map arrives sharded like
+    the state and cuts shard by shard, so the collectives stay the hop's one
+    scalar and the attestation's, and the seven passes by sender of a busy
+    hop are unrolled: no loop."""
+    _check_mesh(_compile_mesh(topo, 8, name, down=True, peers=7), HOPS + 1)
+
+
+def _compile_need_host(topo, G: int, peers: int = 7):
+    """The need-host surgery's two programs as a mesh engine builds them
+    (engine.py: kernel.pick_groups with its rows replicated,
+    kernel.put_groups donated and pinned to the fields' shardings), at the
+    engine's NEED_HOST_GROUPS groups a pass."""
+    from etcd_tpu.parallel.mesh import replicated_sharding, state_sharding
+    from etcd_tpu.server.engine import NEED_HOST_GROUPS as K
+    cfg = KernelConfig(groups=G, peers=peers, window=W)
+    mesh = _mesh4(topo)
+    st_sh, rep = state_sharding(mesh), replicated_sharding(mesh)
+    st = _shapes(cfg, st_sh, rep, rep)[0]
+    idx = jax.ShapeDtypeStruct((K,), jnp.int32, sharding=rep)
+    read = tuple(getattr(st, f) for f in kernel.NEED_HOST_READ)
+    pick = jax.jit(kernel.pick_groups,
+                   out_shardings=rep).lower(read, idx).compile()
+    write = tuple(getattr(st, f) for f in kernel.NEED_HOST_WRITE)
+    rows = tuple(jax.ShapeDtypeStruct((K,) + x.shape[1:], x.dtype,
+                                      sharding=rep) for x in write)
+    put = jax.jit(
+        kernel.put_groups, donate_argnums=(0, 1),
+        out_shardings=(tuple(getattr(st_sh, f)
+                             for f in kernel.NEED_HOST_WRITE),
+                       st_sh.need_host)).lower(write, st.need_host, idx,
+                                               rows).compile()
+    return pick, put, K, write
+
+
+def _check_need_host(pick, put, G: int, K: int, write) -> None:
+    """No state array crosses the chips: every chip picks from the groups
+    it holds and all-reduces K groups' rows (never G / 4), and the
+    write-back scatters into each chip's own shard with no collective at
+    all, in place (the donated fields alias)."""
+    reduces = _collectives(pick)
+    assert reduces and all(f"[{K}," in ln and f"[{G // 4}," not in ln
+                           for ln in reduces), reduces
+    assert _collectives(put) == []
+    shard_bytes = sum(int(np.prod(x.shape)) * x.dtype.itemsize
+                      for x in write) // 4
+    assert put.memory_analysis().alias_size_in_bytes >= shard_bytes
+    assert pick.memory_analysis().output_size_in_bytes < 4 * K * 7 * 7 * 6 * 4
+
+
+def test_need_host_programs_compile_for_v5e_2x2(topo, as_served):
+    pick, put, K, write = _compile_need_host(topo, 512)
+    _check_need_host(pick, put, 512, K, write)
 
 
 def _compile_mesh_gather(topo, G: int, K: int):
@@ -291,6 +357,25 @@ def test_mesh_variant_full_size(topo, as_served, name, scalars):
     _check_mesh(compiled, scalars)
     ma = compiled.memory_analysis()    # per device
     assert ma.temp_size_in_bytes + ma.argument_size_in_bytes < 16 << 30
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("name", VARIANTS[1:])
+def test_mesh_variant_with_the_down_map_full_size(topo, as_served, name):
+    """mt100k-p7-churn-mesh4's programs: G=50,000 x P=7 over four devices,
+    12,500 rows a device, with the down map (the cell
+    meshchurn50k.put256-zipf)."""
+    compiled = _compile_mesh(topo, 50_000, name, down=True, peers=7)
+    _check_mesh(compiled, HOPS + 1)
+    ma = compiled.memory_analysis()    # per device
+    assert ma.temp_size_in_bytes + ma.argument_size_in_bytes < 16 << 30
+
+
+@pytest.mark.slow
+def test_need_host_programs_full_size(topo, as_served):
+    """mt100k-p7-churn-mesh4's surgery: G=50,000 x P=7 over four devices."""
+    pick, put, K, write = _compile_need_host(topo, 50_000)
+    _check_need_host(pick, put, 50_000, K, write)
 
 
 @pytest.mark.slow
