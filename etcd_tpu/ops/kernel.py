@@ -52,7 +52,7 @@ def _donate_at_import(argnums):
     without initializing the backend (illegal at import: multihost
     scripts set distributed state after importing this module), so the
     decorators keep donation and serving engines re-decide per live
-    backend via step_variant()/donate_safe(). ETCD_TPU_DONATE=on|off
+    backend via donate_safe(). ETCD_TPU_DONATE=on|off
     overrides both layers."""
     mode = os.environ.get("ETCD_TPU_DONATE", "auto")
     if mode in ("on", "1"):
@@ -1396,6 +1396,12 @@ ROW_RING = 7
 HEAD_STATS = 2       # the header row's first hop_stats column
 
 
+# The state fields gather_rows reads (the ones the host mirrors): a caller
+# may hand it anything that carries these six in place of a GroupState
+# (server/engine.py gather_program does, ten buffers a call for 22).
+GATHER_FIELDS = ("term", "vote", "commit", "state", "last_index", "log_term")
+
+
 def _pick_rows(mark: jax.Array, kp: int) -> Tuple[jax.Array, jax.Array]:
     """(the ascending linear indices g*P + p of the first kp set entries
     of the (G, P) bool `mark`, padded with G*P; how many are set). What
@@ -1550,8 +1556,9 @@ def step_routed(cfg: KernelConfig, st: GroupState, inbox: jax.Array,
 # Two gates keep cpu runs off donation: the module-level jits import
 # undonated whenever JAX_PLATFORMS pins a non-TPU platform
 # (_donate_at_import — covers the test suite and every kernel-direct
-# caller), and serving engines re-decide per LIVE backend below (covers
-# the JAX_PLATFORMS-unset cpu fallback). TPU keeps donation — the state
+# caller), and serving engines build their own entry points with what
+# donate_safe says of the LIVE backend (server/engine.py step_program;
+# covers the JAX_PLATFORMS-unset cpu fallback). TPU keeps donation — the state
 # arrays ARE the HBM budget there, and the race has only ever been
 # observed on cpu. The engine's
 # peer_mask watchdog (EngineConfig.mask_check_rounds) stays on as
@@ -1580,21 +1587,3 @@ def donate_safe(argnums):
     if mode in ("off", "0"):
         return ()
     return () if jax.default_backend() == "cpu" else tuple(argnums)
-
-
-@functools.lru_cache(maxsize=None)
-def _undonated(name):
-    return jax.jit(globals()[name].__wrapped__,
-                   static_argnums=_STEP_STATICS[name])
-
-
-def step_variant(name):
-    """The module-level jitted step `name`, or its undonated twin when
-    donation is unsafe on the live backend (cached — one compile per
-    shape either way). When the module jits already imported undonated
-    (_donate_at_import, e.g. the JAX_PLATFORMS=cpu test suite) the
-    module jit IS the undonated twin — reuse it so kernel-direct tests
-    and engine tests share one compile cache."""
-    if donate_safe((1,)) or not _donate_at_import((1,)):
-        return globals()[name]
-    return _undonated(name)

@@ -49,6 +49,7 @@ ring window, host snapshot-install beyond it).
 from __future__ import annotations
 
 import bisect
+import collections
 import functools
 import json
 import logging
@@ -125,31 +126,59 @@ def _unpack_multi(payload: bytes) -> List[bytes]:
     return blobs
 
 
-def _named_partial(fn, *args, **kw):
-    """functools.partial that keeps fn's name: jax.jit names its program
-    (profiler traces, compile-cache entries) after __name__, and a bare
-    partial has none — the mesh steps would all be `jit__unknown`."""
-    p = functools.partial(fn, *args, **kw)
-    p.__name__ = fn.__name__
-    return p
+@functools.lru_cache(maxsize=None)
+def step_program(name: str, kcfg, hops: int, donate: tuple,
+                 by_sender: bool = False, out_shardings=None):
+    """The engine's jitted entry point for the kernel step `name`
+    (step_routed_auto / _compact / _read_auto): the kernel's own body, the
+    round's staged proposals arriving as ONE (2, G) int32 array (row 0 the
+    count, row 1 the leader slot a group) and unpacked inside the trace, so
+    a round hands the runtime one buffer where the kernel's signature has
+    two. Called fn(st, inbox, prop, tick, drop_mask, hold, down); the last
+    three are no argument of the program when None. `donate`: (0, 1) where
+    donating the state and the inbox is safe (kernel.donate_safe), `by_sender`
+    and `out_shardings` what a mesh's programs are built with. One jit a
+    geometry in a process: engines of the same shape share its programs, and
+    the program keeps the kernel step's name (profiler traces find it by)."""
+    import jax
+    from etcd_tpu.ops import kernel
+    body = getattr(kernel, name).__wrapped__
+
+    def step(st, inbox, prop, tick, drop_mask, hold, down):
+        return body(kcfg, st, inbox, prop[0], prop[1], tick, drop_mask, hops,
+                    hold, down, by_sender)
+
+    step.__name__ = name
+    return jax.jit(step, donate_argnums=donate, out_shardings=out_shardings)
 
 
-def mesh_gather_rows(rep):
-    """kernel.gather_rows for a state sharded over a mesh, the packed
-    buffer coming back on the sharding `rep` (replicated): one all-gather
-    brings the flag map's shards together (G*P bytes) so that every chip
-    makes the same pick, each gathers the picked rows it holds, and one
-    all-reduce of those rows brings them together. Pinning the flag map
-    is what keeps the pick's sums and searches off the groups axis
-    (tests/test_tpu_compile.py holds the collectives to these two)."""
+@functools.lru_cache(maxsize=None)
+def gather_program(rep=None):
+    """The engine's jitted kernel.gather_rows, handed what it reads and no
+    more: the state's six mirrored fields (kernel.GATHER_FIELDS, in that
+    order) in place of its 17 leaves, and the staged proposals as the step's
+    one (2, G) array: fn(fields, flags, any_need_host, hop_stats, prop, kp),
+    ten buffers a call.
+
+    With `rep` (a mesh's replicated sharding) the state is sharded and the
+    packed buffer comes back replicated: one all-gather brings the flag
+    map's shards together (G*P bytes) so that every chip makes the same
+    pick, each gathers the picked rows it holds, and one all-reduce of those
+    rows brings them together. Pinning the flag map is what keeps the
+    pick's sums and searches off the groups axis (tests/test_tpu_compile.py
+    holds the collectives to these two)."""
     import jax
     from etcd_tpu.ops import kernel
     body = kernel.gather_rows.__wrapped__
+    Fields = collections.namedtuple("GatherFields", kernel.GATHER_FIELDS)
 
-    def gather_rows(st, flags, *rest):
-        return body(st, jax.lax.with_sharding_constraint(flags, rep), *rest)
+    def gather_rows(fields, flags, any_need_host, hop_stats, prop, kp):
+        if rep is not None:
+            flags = jax.lax.with_sharding_constraint(flags, rep)
+        return body(Fields(*fields), flags, any_need_host, hop_stats,
+                    prop[0], prop[1], kp)
 
-    return jax.jit(gather_rows, static_argnums=6, out_shardings=rep)
+    return jax.jit(gather_rows, static_argnums=5, out_shardings=rep)
 
 
 # The longest the engine thread waits for work after a round that found
@@ -476,6 +505,7 @@ class MultiEngine:
         # ran the one-pass message phase, any other as many passes as
         # its busiest receiver needed (P on a mesh, below).
         self._st_sh = self._mb_sh = None
+        rep = extra_out = None
         if cfg.mesh is not None:
             # Mesh placement: pinned out_shardings keep the state AND the
             # routed inbox on their canonical shardings round over round
@@ -503,30 +533,28 @@ class MultiEngine:
             extra_out = {"step_routed_auto": (),
                          "step_routed_compact": diff_out,
                          "step_routed_read_auto": (g_sh, g_sh) + diff_out}
+        # The engine's own entry points (step_program, gather_program): st
+        # and inbox donated on the chip, undonated on the cpu backend
+        # (XLA:CPU has a donated-buffer race, see kernel.py "CPU donation
+        # hazard").
+        donate = kernel.donate_safe((0, 1))
 
-            def step_fn(name):
-                fn = jax.jit(
-                    _named_partial(getattr(kernel, name).__wrapped__,
-                                   self.kcfg, hops=cfg.hops, by_sender=True),
-                    donate_argnums=kernel.donate_safe((0, 1)),
-                    out_shardings=(self._st_sh, self._mb_sh,
-                                   *extra_out[name], rep))
-                return lambda st, inbox, pc, ps, t, hold, down=None: fn(
-                    st, inbox, pc, ps, t, self.drop_mask, hold=hold,
-                    **({} if down is None else {"down": down}))
+        def step_fn(name):
+            outs = None if rep is None else (
+                self._st_sh, self._mb_sh, *extra_out[name], rep)
+            fn = step_program(name, self.kcfg, cfg.hops, donate,
+                              rep is not None, outs)
+            return lambda st, inbox, prop, t, hold, down=None: fn(
+                st, inbox, prop, t, self.drop_mask, hold, down)
 
-            self._gather_rows = mesh_gather_rows(rep)
-        else:
-            def step_fn(name):
-                # step_variant: undonated twin on the cpu backend (XLA:CPU
-                # has a donated-buffer race, see kernel.py "CPU donation
-                # hazard"); donation stays on TPU.
-                fn = kernel.step_variant(name)
-                return lambda st, inbox, pc, ps, t, hold, down=None: fn(
-                    self.kcfg, st, inbox, pc, ps, t, self.drop_mask,
-                    self.cfg.hops, hold, *(() if down is None else (down,)))
-
-            self._gather_rows = kernel.gather_rows
+        self._gather_rows = gather_program(rep)
+        # Where a round's uploads land, the one placement both programs
+        # are compiled to take them in: the device, or on a mesh every chip
+        # (replicated: the gather wants all of the staged array on each,
+        # the step slices its shard of it locally), so that neither call
+        # re-shards what the other was handed.
+        self._put = (jax.device_put if rep is None
+                     else functools.partial(jax.device_put, device=rep))
         self._step_fn = step_fn("step_routed_auto")
         self._step_fn_c = step_fn("step_routed_compact")
         self._step_fn_r = step_fn("step_routed_read_auto")
@@ -743,7 +771,11 @@ class MultiEngine:
         inbox0 = jnp.zeros((G, P, P, self.kcfg.fields), jnp.int32)
         self.inbox = (jax.device_put(inbox0, self._mb_sh)
                       if self._mb_sh is not None else inbox0)
-        self._zero = jnp.zeros(G, jnp.int32)
+        # What the dispatch lap hands over without an upload, made once and
+        # placed as a round's own upload is (_put): the staged array of a
+        # round that staged nothing, and the two values of the tick.
+        self._prop_zero = self._put(np.zeros((2, G), np.int32))
+        self._ticks = (self._put(np.bool_(False)), self._put(np.bool_(True)))
         # Chaos hook: (G, P_to, P_from, 1)-broadcastable 0/1 mask applied to
         # the routed inbox (tests inject drops/partitions here).
         self.drop_mask = None
@@ -2125,8 +2157,6 @@ class MultiEngine:
             self._repropose_lost()
 
         # -- 1. stage proposals at known leaders --------------------------
-        prop_count = np.zeros(G, np.int32)
-        prop_slot = np.zeros(G, np.int32)
         self._staged.clear()
         with self._lock:
             if self._dirty:
@@ -2191,7 +2221,9 @@ class MultiEngine:
         # scatter writes here AND the admission gather after the step
         # (_staged is round-thread-private and not mutated in between).
         # Batching replaces ~2*G numpy scalar stores at ~0.2 µs each.
-        staged_gs = staged_ss = None
+        # (prop: the round's one upload, row 0 the entries staged a group,
+        # row 1 its leader's slot; None when nothing was staged)
+        staged_gs = staged_ss = prop = None
         if self._staged:
             gs_l, ss_l, cnt_l = [], [], []
             waited = o.h_pending_wait.observe if o else None
@@ -2217,8 +2249,9 @@ class MultiEngine:
                                                   staged_round=r_no)
             staged_gs = np.asarray(gs_l, np.int64)
             staged_ss = np.asarray(ss_l, np.int64)
-            prop_count[staged_gs] = cnt_l
-            prop_slot[staged_gs] = ss_l
+            prop = np.zeros((2, G), np.int32)
+            prop[0, staged_gs] = cnt_l
+            prop[1, staged_gs] = ss_l
 
         # -- 1b. read plane: snapshot how many parked quorum reads each
         # group carries BEFORE the step is dispatched. A read parking
@@ -2250,12 +2283,19 @@ class MultiEngine:
             clock.lap("dispatch", t_ph)
 
         # -- 2. the kernel round (fused step + routing: one ASYNC
-        # dispatch; jax queues it and returns immediately) ----------------
-        tick = jnp.asarray(bool(
-            (self.round_no % self.cfg.ticks_per_round) == 0))
-        pc_d, ps_d = jnp.asarray(prop_count), jnp.asarray(prop_slot)
+        # dispatch; jax queues it and returns immediately). The lap hands
+        # over ONE upload, the staged array, onto the placement both
+        # programs take it in, and none in a round that staged nothing (the
+        # boot-time zeros; neither program donates it) ---------------------
+        tick = self._ticks[self.round_no % self.cfg.ticks_per_round == 0]
+        if prop is None:
+            prop_d = self._prop_zero
+        else:
+            prop_d = self._put(prop)
+            if o:
+                self._h2d(prop_d)
         if o:
-            self._h2d(tick, pc_d, ps_d)
+            t_up = time.perf_counter()
         flags_d = anh_d = None
         conf_d = rc_d = None
         if read_take:
@@ -2264,32 +2304,40 @@ class MultiEngine:
             # and its step returns the same on-device diff as the
             # compact step's: one record builder serves both.
             st, inbox, conf_d, rc_d, f_d, a_d, stats_d = self._step_fn_r(
-                self.st, self.inbox, pc_d, ps_d, tick, hold, down)
+                self.st, self.inbox, prop_d, tick, hold, down)
             if self._compact:
                 flags_d, anh_d = f_d, a_d
         elif self._compact:
             st, inbox, flags_d, anh_d, stats_d = self._step_fn_c(
-                self.st, self.inbox, pc_d, ps_d, tick, hold, down)
+                self.st, self.inbox, prop_d, tick, hold, down)
         else:
             st, inbox, stats_d = self._step_fn(
-                self.st, self.inbox, pc_d, ps_d, tick, hold, down)
+                self.st, self.inbox, prop_d, tick, hold, down)
         self.st = st
         self.inbox = inbox
+        if o:
+            t_step = time.perf_counter()
         # The compact round's one readback, enqueued right behind the
         # step with no host read in between: gather_rows picks the rows
         # that changed (and the staged leader rows) where the flag map
         # lies and packs them with the attestation and the hops'
-        # counts into one buffer. A round after a surgery takes the full
-        # readback whatever the device says, and asks for nothing here.
+        # counts into one buffer, from the six fields it reads. A round
+        # after a surgery takes the full readback whatever the device
+        # says, and asks for nothing here.
         gather = buf_d = None
         if flags_d is not None and not self._force_full:
-            gather = (st, flags_d, anh_d, stats_d, pc_d, ps_d)
+            gather = (self._gather_fields(st), flags_d, anh_d, stats_d,
+                      prop_d)
             kp = self._gather_bucket(len(self._staged))
             buf_d = self._gather_rows(*gather, kp)
         self._staged_prev = len(self._staged)
         if o:
             t_now = time.perf_counter()
             d_dispatch = t_now - t_ph
+            part = o.h_dispatch_part
+            part["upload"].observe(t_up - t_ph)
+            part["step"].observe(t_step - t_up)
+            part["gather"].observe(t_now - t_step)
             t_ph = t_now
             clock.lap("readback", t_now)
 
@@ -2823,6 +2871,10 @@ class MultiEngine:
                    self.cfg.peers * (n_staged + self._staged_prev))
         return _bucket(min(want, self._compact_cap))
 
+    def _gather_fields(self, st) -> tuple:
+        """What gather_rows reads of the state `st`."""
+        return tuple(getattr(st, f) for f in self._kernel.GATHER_FIELDS)
+
     def _gather_buckets(self) -> List[int]:
         """Every bucket a round of this geometry can ask for."""
         top = _bucket(min(self._compact_cap,
@@ -2846,9 +2898,10 @@ class MultiEngine:
             flags_d = jax.device_put(flags_d, flag_sharding(self.cfg.mesh))
             anh_d = jax.device_put(anh_d, rep)
             stats_d = jax.device_put(stats_d, rep)
+        fields = self._gather_fields(self.st)
         for kp in self._gather_buckets():
-            self._gather_rows(self.st, flags_d, anh_d, stats_d, self._zero,
-                              self._zero, kp).block_until_ready()
+            self._gather_rows(fields, flags_d, anh_d, stats_d,
+                              self._prop_zero, kp).block_until_ready()
 
     def _compact_record_admit(self, buf: np.ndarray, kp: int, gather,
                               staged_gs, staged_ss

@@ -83,6 +83,7 @@ _COUNT_BUCKETS = (1, 2, 4, 8, 16, 32, 64, 128, 256, 512, 1024, 4096,
 ROUND_PHASES = ("stage", "dispatch", "readback", "record", "tail", "post",
                 "gap")
 RECORD_PARTS = ("gather", "build", "admit")
+DISPATCH_PARTS = ("upload", "step", "gather")
 FRONT_KINDS = ("write", "qread", "other")
 
 round_phase = metrics.LabeledHistogram(
@@ -104,6 +105,14 @@ record_part = metrics.LabeledHistogram(
     "second call and read of a round that outgrew its bucket), admit "
     "(_admit_staged) and build (the rest); a full-readback round has "
     "build and admit only.", ("part",))
+dispatch_part = metrics.LabeledHistogram(
+    "etcd_engine_dispatch_part_seconds",
+    "Wall time of the dispatch phase's three hand-overs per round: upload "
+    "(the round's staged proposals to the device, and picking the tick), "
+    "step (the call that enqueues the step program) and gather (choosing "
+    "the bucket and the call that enqueues gather_rows; a round that asks "
+    "for no gather has the lap's last few lines there). They tile the "
+    "phase: gather + step + upload = dispatch.", ("part",))
 d2h_syncs = metrics.Counter(
     "etcd_engine_d2h_syncs_total",
     "Blocking device->host reads on the round thread: gather_rows' "
@@ -927,6 +936,8 @@ class EngineObs:
         self.clock = RoundClock() if self.enabled else None
         self.thread_cpu = ThreadCpu()
         self.h_rec_part = {p: record_part.labels(p) for p in RECORD_PARTS}
+        self.h_dispatch_part = {p: dispatch_part.labels(p)
+                                for p in DISPATCH_PARTS}
         self.c_readback = {k: readback_rounds.labels(k)
                            for k in READBACK_KINDS}
         for c in self.c_readback.values():

@@ -57,7 +57,7 @@ def _answer(q):
 
 
 def _drive(data_dir: str, compact: bool, cap: int = 0,
-           reads: bool = False) -> MultiEngine:
+           reads: bool = False, mesh=None) -> MultiEngine:
     """Deterministic traffic: seeded enqueues, a leader-partition window
     (exercises elections, demotions, ring overwrites — the CHG_STATE and
     CHG_RING corners), no wall-clock dependence (sync_interval=0).
@@ -68,11 +68,13 @@ def _drive(data_dir: str, compact: bool, cap: int = 0,
     group GROW past the ring window while one of its followers is cut
     off, so the heal ends in a snapshot install. The engine then carries `.rounds`,
     one (carried reads, staged proposals, serviced need-host, followed a
-    surgery, readback kind) per round, and `.answers` by request id."""
+    surgery, readback kind) per round, and `.answers` by request id.
+    `mesh`: the same script with the state sharded over it
+    (tests/test_round_dispatch.py)."""
     eng = MultiEngine(EngineConfig(
         groups=G, peers=P, data_dir=data_dir, window=W, max_ents=E,
         fsync=False, stagger=True, sync_interval=0.0,
-        compact_readback=compact, compact_cap=cap,
+        compact_readback=compact, compact_cap=cap, mesh=mesh,
         checkpoint_rounds=1 << 30, pipeline_applies=False))
 
     class _Seq:  # idutil embeds wall time; payload bytes must be equal

@@ -189,18 +189,22 @@ def _drive(data_dir, mesh, compact, cap=0):
     eng.reqid = _Seq()
     G, P = eng.cfg.groups, eng.cfg.peers
     shardings = {"flags": set(), "attest": set(), "rows": set(),
-                 "read": set(), "read_flags": set(), "read_attest": set()}
+                 "read": set(), "read_flags": set(), "read_attest": set(),
+                 "handed": set(), "gather_buffers": set()}
     if mesh is not None and compact:
         step_c, step_r, gather = (eng._step_fn_c, eng._step_fn_r,
                                   eng._gather_rows)
 
         def spy_step(*a):
+            # (st, inbox, the staged array, the tick, hold, down)
+            shardings["handed"].update(x.sharding for x in a[2:4])
             out = step_c(*a)
             shardings["flags"].add(out[2].sharding)
             shardings["attest"].add(out[3].sharding)
             return out
 
         def spy_read(*a):
+            shardings["handed"].update(x.sharding for x in a[2:4])
             out = step_r(*a)
             shardings["read"].update(x.sharding for x in out[2:4])
             shardings["read_flags"].add(out[4].sharding)
@@ -208,6 +212,10 @@ def _drive(data_dir, mesh, compact, cap=0):
             return out
 
         def spy_gather(*a):
+            # (the six fields, flags, attestation, hops' counts, the
+            # staged array, the bucket)
+            shardings["handed"].add(a[4].sharding)
+            shardings["gather_buffers"].add(len(jax.tree.leaves(a[:5])))
             out = gather(*a)
             shardings["rows"].add(out.sharding)
             return out
@@ -357,6 +365,12 @@ def test_mesh_compact_readback_equals_one_device(tmp_path, mesh, reference,
     assert all(sh.is_equivalent_to(rep, 0) for sh in seen["attest"])
     assert seen["rows"] and all(sh.is_fully_replicated
                                 for sh in seen["rows"])
+    # what a round hands both programs (the staged array, uploaded or the
+    # boot-time zeros, and the tick) lies replicated before the call, and
+    # the gather is handed ten buffers
+    assert seen["handed"] and all(sh.is_equivalent_to(rep, 2)
+                                  for sh in seen["handed"])
+    assert seen["gather_buffers"] == {10}
     # the read step's four: (G,) confirmed and read index on groups, its
     # flag map and attestation where the compact step's are
     assert seen["read"] and all(

@@ -430,6 +430,9 @@ NEW_SERIES = (
     "etcd_engine_h2d_bytes_total",
     "etcd_engine_need_host_part_seconds_sum",
     "etcd_engine_need_host_part_seconds_count",
+    # PR 44: the dispatch lap's three hand-overs
+    "etcd_engine_dispatch_part_seconds_sum",
+    "etcd_engine_dispatch_part_seconds_count",
 )
 
 
@@ -663,6 +666,8 @@ def _load(base, n=24, reads=True):
       for p in obs_mod.ROUND_PHASES],
     *[("etcd_engine_record_part_seconds_count", {"part": p})
       for p in obs_mod.RECORD_PARTS],
+    *[("etcd_engine_dispatch_part_seconds_count", {"part": p})
+      for p in obs_mod.DISPATCH_PARTS],
     ("etcd_engine_d2h_syncs_total", {}),
     ("etcd_engine_d2h_bytes_total", {}),
     ("etcd_engine_h2d_syncs_total", {}),
@@ -771,6 +776,45 @@ def test_record_parts_add_up_to_record(eng_http):
                                                  + 2 * record / rounds)
     assert parts["gather"] > 0 and parts["admit"] > 0
     assert parts["build"] > 0
+
+
+def _dispatch_snapshot():
+    """(the three parts' sums, dispatch's sum, the parts' counts, rounds,
+    uploads, bytes) read from the registry between two rounds: read until
+    two readings 5 ms apart agree (an idle member runs a round of a
+    millisecond or two every 50 ms)."""
+    def read():
+        parts = [obs_mod.dispatch_part.labels(p)
+                 for p in obs_mod.DISPATCH_PARTS]
+        return (tuple(h.sum for h in parts),
+                obs_mod.round_phase.labels("dispatch").sum,
+                tuple(h.count for h in parts), obs_mod.rounds_total.value,
+                obs_mod.h2d_syncs.value, obs_mod.h2d_bytes.value)
+    deadline = time.time() + 20
+    while time.time() < deadline:
+        one = read()
+        time.sleep(0.005)
+        if read() == one:
+            return one
+    raise AssertionError("the member never stood between two rounds")
+
+
+def test_dispatch_parts_add_up_to_dispatch(eng_http):
+    """upload + step + gather tile the dispatch phase round by round (two
+    more clock readings a round), and a round uploads at most its one
+    staged array: rounds that staged nothing upload nothing."""
+    eng, base = eng_http
+    a = _dispatch_snapshot()
+    _load(base, n=24, reads=False)
+    b = _dispatch_snapshot()
+    parts = [y - x for x, y in zip(a[0], b[0])]
+    dispatch, rounds = b[1] - a[1], b[3] - a[3]
+    assert all(v > 0 for v in parts), parts
+    assert abs(sum(parts) - dispatch) <= 1e-6 * rounds
+    assert [y - x for x, y in zip(a[2], b[2])] == [rounds] * 3
+    uploads = b[4] - a[4]
+    assert 0 < uploads <= rounds
+    assert b[5] - a[5] == uploads * 2 * 4 * eng.cfg.groups
 
 
 def test_front_self_time_is_the_span_minus_the_engine_wait(eng_http):

@@ -29,7 +29,7 @@ from jax.sharding import Mesh, SingleDeviceSharding
 
 from etcd_tpu.ops import kernel
 from etcd_tpu.ops.state import KernelConfig, init_state
-from etcd_tpu.server.engine import _named_partial, mesh_gather_rows
+from etcd_tpu.server.engine import gather_program, step_program
 
 P, W, HOPS = 5, 32, 3          # BASELINE.json config 4 peers; CLI window/hops
 VARIANTS = ("step_routed_auto", "step_routed_compact",
@@ -63,8 +63,9 @@ def as_served():
 
 
 def _shapes(cfg: KernelConfig, state_sh, inbox_sh, small_sh):
-    """(state, inbox, prop_count, prop_slot, tick) as shapes carrying
-    shardings — there is no device to hold an array."""
+    """(state, inbox, prop, tick) as the engine's own entry points take
+    them (engine.step_program: the staged proposals one (2, G) array), as
+    shapes carrying shardings — there is no device to hold an array."""
     G = cfg.groups
     st = jax.eval_shape(lambda: init_state(cfg))
     if not isinstance(state_sh, tuple):      # one sharding for every field
@@ -74,28 +75,26 @@ def _shapes(cfg: KernelConfig, state_sh, inbox_sh, small_sh):
         st, state_sh)
     inbox = jax.ShapeDtypeStruct((G, cfg.peers, cfg.peers, cfg.fields),
                                  jnp.int32, sharding=inbox_sh)
-    pc = jax.ShapeDtypeStruct((G,), jnp.int32, sharding=small_sh)
+    prop = jax.ShapeDtypeStruct((2, G), jnp.int32, sharding=small_sh)
     tick = jax.ShapeDtypeStruct((), jnp.bool_, sharding=small_sh)
-    return st, inbox, pc, pc, tick
+    return st, inbox, prop, tick
 
 
 def _compile_variant(topo, name: str, G: int, hold: bool = False,
                      down: bool = False, peers: int = P):
-    """The variant as a TPU engine runs it: donated state and inbox (the
-    suite's JAX_PLATFORMS=cpu makes the module-level jits undonated, so
-    the donating jit is rebuilt here from the same function). `hold`: with
-    the (G, P) bool of held follower slots as its one more argument
+    """The variant as a TPU engine runs it (engine.step_program: the
+    kernel's body behind the engine's entry point, the staged proposals one
+    (2, G) array), state and inbox donated (the suite's JAX_PLATFORMS=cpu
+    would leave them undonated, so donation is asked for here). `hold`:
+    with the (G, P) bool of held follower slots as its one more argument
     (--engine-lag-share); `down`: with the (G, P) bool of slots cut off
     from their peers (--engine-churn-down-rounds), the hold then None."""
     cfg = KernelConfig(groups=G, peers=peers, window=W)
     one = SingleDeviceSharding(topo.devices[0])
-    fn = jax.jit(getattr(kernel, name).__wrapped__,
-                 static_argnums=kernel._STEP_STATICS[name],
-                 donate_argnums=(1, 2))
+    fn = step_program(name, cfg, HOPS, (0, 1))
     gp = jax.ShapeDtypeStruct((G, peers), jnp.bool_, sharding=one)
-    more = (None, gp) if down else (gp,) if hold else ()
-    return fn.lower(cfg, *_shapes(cfg, one, one, one), None, HOPS,
-                    *more).compile()
+    return fn.lower(*_shapes(cfg, one, one, one), None,
+                    gp if hold else None, gp if down else None).compile()
 
 
 def _check_variant(compiled, G: int, peers: int = P) -> None:
@@ -140,7 +139,9 @@ def _mesh4(topo) -> Mesh:
 
 def _compile_mesh(topo, G: int, name: str = "step_routed_auto",
                   down: bool = False, peers: int = P):
-    """The engine's mesh step (engine.py: out_shardings pinned, donated);
+    """The engine's mesh step (engine.step_program: out_shardings pinned,
+    donated, the staged proposals and the tick replicated as a round's
+    upload and the boot-time constants are placed);
     step_routed_compact adds the flag map, sharded like the state, and
     the replicated need-host attestation; step_routed_read_auto the read
     plane's two (G,) arrays, sharded on groups, and then those two.
@@ -160,15 +161,12 @@ def _compile_mesh(topo, G: int, name: str = "step_routed_auto",
     if name != "step_routed_auto":
         out_sh += (flag_sharding(mesh), rep)
     out_sh += (rep,)                    # the hops' counts
-    fn = jax.jit(
-        _named_partial(getattr(kernel, name).__wrapped__, cfg, hops=HOPS,
-                       by_sender=True),
-        donate_argnums=(0, 1), out_shardings=out_sh)
-    more = {}
-    if down:
-        more = {"hold": None, "down": jax.ShapeDtypeStruct(
-            (G, peers), jnp.bool_, sharding=flag_sharding(mesh))}
-    return fn.lower(*_shapes(cfg, st_sh, mb_sh, rep), None, **more).compile()
+    fn = step_program(name, cfg, HOPS, (0, 1), True, out_sh)
+    down_map = (jax.ShapeDtypeStruct((G, peers), jnp.bool_,
+                                     sharding=flag_sharding(mesh))
+                if down else None)
+    return fn.lower(*_shapes(cfg, st_sh, mb_sh, rep), None, None,
+                    down_map).compile()
 
 
 def _collectives(compiled) -> list:
@@ -273,21 +271,26 @@ def test_need_host_programs_compile_for_v5e_2x2(topo, as_served):
     _check_need_host(pick, put, 512, K, write)
 
 
-def _compile_mesh_gather(topo, G: int, K: int):
-    """The engine's mesh row gather (engine.mesh_gather_rows: gather_rows'
-    body, the packed buffer replicated) over a state sharded on four
-    devices, the flag map as the compact step leaves it."""
+def _lower_mesh_gather(topo, G: int, K: int):
+    """The engine's mesh row gather (engine.gather_program: gather_rows'
+    body, the packed buffer replicated) over the six fields it reads of a
+    state sharded on four devices, the flag map as the compact step leaves
+    it, the staged array replicated as the round's upload is placed."""
     from etcd_tpu.parallel.mesh import (flag_sharding, replicated_sharding,
                                         state_sharding)
     cfg = KernelConfig(groups=G, peers=P, window=W)
     mesh = _mesh4(topo)
     st_sh, rep = state_sharding(mesh), replicated_sharding(mesh)
-    st, _, pc, ps, attest = _shapes(cfg, st_sh, rep, rep)
+    st, _, prop, attest = _shapes(cfg, st_sh, rep, rep)
+    fields = tuple(getattr(st, f) for f in kernel.GATHER_FIELDS)
     flags = jax.ShapeDtypeStruct((G, P), jnp.uint8,
                                  sharding=flag_sharding(mesh))
     stats = jax.ShapeDtypeStruct((2, HOPS), jnp.int32, sharding=rep)
-    return mesh_gather_rows(rep).lower(st, flags, attest, stats, pc, ps,
-                                       K).compile()
+    return gather_program(rep).lower(fields, flags, attest, stats, prop, K)
+
+
+def _compile_mesh_gather(topo, G: int, K: int):
+    return _lower_mesh_gather(topo, G, K).compile()
 
 
 def _check_mesh_gather(compiled, G: int, K: int) -> None:
@@ -322,6 +325,26 @@ def _check_mesh_gather(compiled, G: int, K: int) -> None:
 
 def test_mesh_gather_rows_compiles_for_v5e_2x2(topo, as_served):
     _check_mesh_gather(_compile_mesh_gather(topo, 128, 256), 128, 256)
+
+
+def test_mesh_gather_rows_takes_ten_buffers(topo, as_served):
+    """What a round hands the gather: the six fields it reads, the flag
+    map, the attestation, the hops' counts and the one staged array, each
+    on the sharding it already lies on (the fields and the flag map as the
+    step's pinned outputs, the rest replicated), so the call moves
+    nothing between the chips before the program runs."""
+    from etcd_tpu.parallel.mesh import (flag_sharding, replicated_sharding,
+                                        state_sharding)
+    lowered = _lower_mesh_gather(topo, 128, 256)
+    assert len(jax.tree.leaves(lowered.in_avals)) == 10
+    mesh = _mesh4(topo)
+    st_sh, rep = state_sharding(mesh), replicated_sharding(mesh)
+    want = (tuple(getattr(st_sh, f) for f in kernel.GATHER_FIELDS),
+            flag_sharding(mesh), rep, rep, rep)
+    got = lowered.compile().input_shardings[0]
+    for w, g, nd in zip(jax.tree.leaves(want), jax.tree.leaves(got),
+                        (2, 2, 2, 2, 2, 3, 2, 0, 2, 2)):
+        assert g.is_equivalent_to(w, nd), (g, w)
 
 
 @pytest.mark.slow
