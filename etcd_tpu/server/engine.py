@@ -2150,6 +2150,8 @@ class MultiEngine:
             if now - self._last_sync_scan >= self.cfg.sync_interval:
                 self._last_sync_scan = now
                 self._stage_syncs(now)
+                if o:
+                    o.h_sync_scan.observe(time.time() - now)
 
         # -- 0b. entries a deposed leader admitted and the committed log
         # has overwritten go back to their queues (only after an election)
@@ -3680,6 +3682,8 @@ class MultiEngine:
                 if i > self.applied[g]],
         }
         self.wal.save_checkpoint(self.round_no - 1, state)
+        if self.obs.enabled:
+            self.obs.c_checkpoint_stores.inc(len(stores))
 
     def _gc_payloads(self) -> None:
         dead = [k for k in self.payloads if k[1] <= self.applied[k[0]]]

@@ -231,6 +231,17 @@ checkpoint_seconds = metrics.Histogram(
     "write and payload GC.",
     buckets=(0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 0.5, 1.0,
              2.5, 5.0, 10.0, 30.0, 60.0))
+checkpoint_stores = metrics.Counter(
+    "etcd_engine_checkpoint_stores_total",
+    "Tenant stores serialised by full checkpoints (added once a "
+    "checkpoint): the denominator of etcd_engine_checkpoint_seconds.")
+sync_scan_seconds = metrics.Histogram(
+    "etcd_engine_sync_scan_seconds",
+    "One TTL scan on the round thread (_stage_syncs: next_expiration() on "
+    "every tenant's store, every sync_interval); a round that scans "
+    "nothing observes nothing.",
+    buckets=(0.0001, 0.00025, 0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025,
+             0.05, 0.1, 0.25, 0.5, 1.0, 2.5))
 jax_compiles = metrics.Counter(
     "etcd_jax_compiles_total",
     "Programs XLA built for this process, compiled or loaded from the "
@@ -953,6 +964,8 @@ class EngineObs:
         self.c_h2d_bytes = h2d_bytes
         self.h_pending_wait = pending_wait
         self.h_checkpoint = checkpoint_seconds
+        self.c_checkpoint_stores = checkpoint_stores
+        self.h_sync_scan = sync_scan_seconds
         self.h_need_host = need_host_seconds
         self.h_need_host_part = {p: need_host_part.labels(p)
                                  for p in NEED_HOST_PARTS}

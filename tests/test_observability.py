@@ -290,6 +290,67 @@ def test_d2h_syncs_per_round_repeat_exactly(tmp_path):
         eng.stop()
 
 
+@pytest.mark.parametrize("sync_interval,scans", [(3600.0, 1), (0.0, 0)])
+def test_one_observation_a_scan_and_none_in_a_round_without_one(
+        tmp_path, monkeypatch, sync_interval, scans):
+    """etcd_engine_sync_scan_seconds is observed inside run_round's scan
+    branch: once in the round whose clock says a scan is due, never in a
+    round that scans nothing, and not at all with the scan switched off."""
+    eng = _small_engine(tmp_path, sync_interval=sync_interval)
+    calls = []
+    stage_syncs = eng._stage_syncs
+    monkeypatch.setattr(eng, "_stage_syncs",
+                        lambda now: (calls.append(now), stage_syncs(now)))
+    try:
+        _elect(eng)                  # (the first round of all scanned)
+        a = _reg()
+        n = len(calls)
+        for _ in range(10):
+            eng.run_round()
+        b = _reg()
+        assert len(calls) == n
+        assert _delta(a, b, "etcd_engine_sync_scan_seconds_count") == 0
+        assert _delta(a, b, "etcd_engine_sync_scan_seconds_sum") == 0
+        eng._last_sync_scan = 0.0    # the clock says: due
+        for _ in range(6):
+            eng.run_round()
+        c = _reg()
+        assert len(calls) == n + scans
+        assert _delta(b, c, "etcd_engine_sync_scan_seconds_count") == scans
+        assert (_delta(b, c, "etcd_engine_sync_scan_seconds_sum") > 0) == (
+            scans > 0)
+    finally:
+        eng.stop()
+
+
+def test_checkpoint_stores_total_is_the_stores_a_checkpoint_wrote(tmp_path):
+    """etcd_engine_checkpoint_stores_total grows once a checkpoint, by the
+    stores that checkpoint serialised: etcd_engine_checkpoint_seconds'
+    denominator."""
+    eng = _small_engine(tmp_path)
+    try:
+        _elect(eng)
+        for g in (0, 2, 3):          # three of the four tenants have a store
+            eng.store(g)
+        a = _reg()
+        for rounds in (1 << 30, 1, 1 << 30):    # one checkpoint, in between
+            eng.cfg.checkpoint_rounds = rounds
+            eng.run_round()
+        b = _reg()
+        assert _delta(a, b, "etcd_engine_checkpoint_seconds_count") == 1
+        _, state = eng.wal.load_checkpoint()
+        assert sorted(state["stores"]) == ["0", "2", "3"]
+        assert _delta(a, b, "etcd_engine_checkpoint_stores_total") == 3
+        eng.store(1)
+        eng.cfg.checkpoint_rounds = 1
+        eng.run_round()
+        c = _reg()
+        assert _delta(b, c, "etcd_engine_checkpoint_seconds_count") == 1
+        assert _delta(b, c, "etcd_engine_checkpoint_stores_total") == 4
+    finally:
+        eng.stop()
+
+
 def test_round_ms_ewma_is_seeded_after_the_elections(tmp_path):
     """/engine/status round_ms_ewma: 0 through the boot rounds (the
     first pays the step's compile and, staggered, elects every group in
@@ -433,6 +494,11 @@ NEW_SERIES = (
     # PR 44: the dispatch lap's three hand-overs
     "etcd_engine_dispatch_part_seconds_sum",
     "etcd_engine_dispatch_part_seconds_count",
+    # PR 46: the TTL scan's time and the checkpoint's denominator (the
+    # child scans twice a second and checkpoints every 16 rounds)
+    "etcd_engine_sync_scan_seconds_sum",
+    "etcd_engine_sync_scan_seconds_count",
+    "etcd_engine_checkpoint_stores_total",
 )
 
 
@@ -693,6 +759,8 @@ def test_new_series_move_under_load(eng_http, series, labels):
 
 @pytest.mark.parametrize("series,labels", [
     ("etcd_engine_checkpoint_seconds_count", {}),
+    ("etcd_engine_checkpoint_stores_total", {}),
+    ("etcd_engine_sync_scan_seconds_count", {}),
     ("etcd_engine_gather_rebuckets_total", {}),
     *[("etcd_engine_need_host_part_seconds_count", {"part": p})
       for p in obs_mod.NEED_HOST_PARTS],
